@@ -89,29 +89,28 @@
 #   make probe-overlap  fetch/compute overlap isolation experiment
 #                     (VERDICT r5 Weak #3): two independently fetchable
 #                     device programs + the pipeline executor on a fake
-#                     workload; writes PROBE_OVERLAP.json
+#                     workload
 #   make bench-overload  zipfian closed-loop overload bench (1x and 2x
 #                     saturating concurrency, per-lane p50/p99 latency,
-#                     shed rate, cache hit rate); writes OVERLOAD.json
+#                     shed rate, cache hit rate)
 #   make bench-routers  multi-router scale-out bench: the same zipfian
 #                     closed loop at equal offered load through 1, 2,
 #                     and 4 stateless routers; admitted interactive
 #                     q/s must scale (2 routers >= 1.6x the 1-router
-#                     baseline); writes BENCH_r07.json
+#                     baseline)
 #   make bench-kernel  r14 kernel-headroom bench: A-build v3 vs v4 vs
 #                     the XLA oracle (parity gated in-run), the
 #                     analytic A-build op-count model, and steady
 #                     commit cost incremental-df vs full-recompute
 #                     across a 4x corpus sweep on the mesh-ELL and
 #                     segments indexes (df_full_recomputes witness
-#                     asserted zero); writes BENCH_r09.json
+#                     asserted zero)
 #   make bench-replay  r16 capture/replay bench: a zipfian closed loop
 #                     through a router with the durable request log
 #                     (capture) enabled, then the SAME traffic re-driven
 #                     open-loop at recorded offsets against a fresh
 #                     router — fidelity gated in-run (every captured
-#                     admitted request must replay admitted); writes
-#                     BENCH_r10.json
+#                     admitted request must replay admitted)
 #   make bench-hybrid r17 hybrid-retrieval bench: batched dense q/s
 #                     (with the achieved model-flop rate) beside the
 #                     sparse plane on the same engine/stream, a
@@ -323,16 +322,16 @@ probe-overlap:
 	python probe_overlap.py
 
 bench-overload:
-	BENCH_OUT=OVERLOAD.json python bench.py --overload
+	python bench.py --overload
 
 bench-routers:
-	BENCH_OUT=BENCH_r07.json python bench.py --routers
+	python bench.py --routers
 
 bench-kernel:
-	BENCH_OUT=BENCH_r09.json python bench.py --kernel
+	python bench.py --kernel
 
 bench-replay:
-	BENCH_OUT=BENCH_r10.json python bench.py --replay
+	python bench.py --replay
 
 bench-hybrid:
 	BENCH_OUT=BENCH_r11.json python bench.py --hybrid

@@ -15,10 +15,7 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(os.path.dirname(__file__), ".jax_cache"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+from tfidf_tpu.utils.compile_cache import configure_compile_cache
 
 import jax  # noqa: E402
 
@@ -238,4 +235,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

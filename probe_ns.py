@@ -13,10 +13,7 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(os.path.dirname(__file__), ".jax_cache"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+from tfidf_tpu.utils.compile_cache import configure_compile_cache
 
 from bench import (NS_AVG_LEN, NS_DOCS, NS_VOCAB, make_doc_arrays,  # noqa: E402
                    make_queries)
@@ -118,4 +115,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -18,8 +18,7 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(os.path.dirname(__file__), ".jax_cache"))
+from tfidf_tpu.utils.compile_cache import configure_compile_cache
 
 from bench import NS_VOCAB, make_doc_arrays, make_queries  # noqa: E402
 
@@ -111,4 +110,5 @@ def main():
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

@@ -1,0 +1,138 @@
+"""Compile — never run — the Pallas kernel for TPU v5e, on a CPU box.
+
+libtpu ships a compile-only client: ``get_topology_desc`` hands back the
+devices of a ``v5e:2x2`` slice that does not exist, and lowering a jitted
+function on ShapeDtypeStructs sharded onto them runs the real Mosaic and
+XLA:TPU compilers. That is enough to learn, in seconds and with no chip,
+whether the compiler ACCEPTS a kernel — which the interpreter cannot say
+(PR 14's packed-i16 variant and its v4 tile schedule passed every
+interpret-mode test and were refused here).
+
+Run as a script (``tests/test_kernel_compile.py`` does, in a subprocess:
+the compile-only client is process-global state); prints one JSON line
+``{"failures": [...], "compiled": N}``.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+
+import jax  # noqa: E402
+
+jax.config.update("jax_num_cpu_devices", 4)
+
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding,  # noqa: E402
+                          PartitionSpec, SingleDeviceSharding)
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+from tfidf_tpu.ops import ell  # noqa: E402
+
+# lower the Mosaic program, not the interpreter the CPU backend selects
+ell.pallas_interpret = lambda: False
+
+BATCHES = (32, 512, 1024, 2048)
+# the ladder's ends and its 1.5x rungs, plus what a terms-axis split
+# leaves of a rung (8/8 = 1) and an odd width (v4's tail row)
+WIDTHS = (1, 8, 12, 33, 64, 256)
+assert set(WIDTHS) & set(ell.ELL_WIDTH_LADDER) >= {8, 12, 64, 256}
+
+
+def block_shapes(B: int):
+    """(rows_cap, u_cap) pairs that reach every tile the schedule can
+    pick at batch ``B``: 512-multiples take the big doc/uniq tiles, 768
+    rows / 256 slots the small ones (the same once B caps both)."""
+    yield 1024, 1024
+    if B <= 512:
+        yield 768, 256
+
+
+def compile_block(dev, rows: int, width: int, B: int, u_cap: int,
+                  a_build: str) -> None:
+    sh = SingleDeviceSharding(dev)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    assert ell._pallas_eligible(rows, B, u_cap, a_build)
+    fn = jax.jit(lambda imp, term, uniq, nu, qc, nr: ell.score_block_pallas(
+        imp, term, uniq, nu, qc, nr, a_build=a_build))
+    fn.lower(s((rows, width), jnp.float32), s((rows, width), jnp.int32),
+             s((u_cap,), jnp.int32), s((), jnp.int32),
+             s((B, u_cap + 1), jnp.float32), s((), jnp.int32)).compile()
+
+
+def compile_mesh_step(devices) -> None:
+    """The served mesh program — ``make_mesh_ell_search`` on a (4, 1)
+    ("docs", "terms") mesh: a small index is built for real on four
+    virtual CPU devices, then every array is replaced by its shape on
+    the same mesh of v5e devices."""
+    from tfidf_tpu.engine import Engine
+    from tfidf_tpu.parallel.mesh_ell import make_mesh_ell_search
+    from tfidf_tpu.utils.config import Config
+
+    engine = Engine(Config(engine_mode="mesh", query_batch=32,
+                           min_doc_capacity=256))
+    rng = np.random.default_rng(0)
+    for i in range(400):
+        ids = np.unique(rng.integers(0, 500, size=rng.integers(3, 40)))
+        engine.index.add_document_arrays(
+            f"d{i}", ids.astype(np.int32),
+            np.ones(ids.shape[0], np.float32), float(ids.shape[0]))
+    engine.commit()
+    snap = engine.index.snapshot
+    qb, _ = engine.searcher._vectorize(["x"] * 32, 32)
+    tpu_mesh = Mesh(np.asarray(devices).reshape(4, 1), ("docs", "terms"))
+
+    def abstract(x):
+        x = jnp.asarray(x)   # host-side query arrays: replicated
+        spec = getattr(x.sharding, "spec", PartitionSpec())
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(tpu_mesh, spec))
+
+    args = jax.tree.map(abstract, (snap.base, snap.delta, snap.df_g,
+                                   snap.n_docs, snap.avgdl, qb))
+    for a_build in ell.A_BUILD_VARIANTS:
+        make_mesh_ell_search(tpu_mesh, k=10, a_build=a_build,
+                             packed=True).lower(*args).compile()
+
+
+def main() -> int:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    failures: list[str] = []
+    compiled = 0
+    for a_build in ell.A_BUILD_VARIANTS:
+        for B in BATCHES:
+            for rows, u_cap in block_shapes(B):
+                for width in WIDTHS:
+                    what = (f"{a_build} rows={rows} width={width} "
+                            f"B={B} u_cap={u_cap}")
+                    try:
+                        compile_block(topo.devices[0], rows, width, B,
+                                      u_cap, a_build)
+                        compiled += 1
+                    except Exception as e:   # reported, all of them
+                        failures.append(
+                            f"{what}: {type(e).__name__}: "
+                            f"{str(e)[:300]}")
+    try:
+        compile_mesh_step(topo.devices)
+        compiled += len(ell.A_BUILD_VARIANTS)
+    except Exception as e:
+        failures.append(f"mesh (4,1) step: {type(e).__name__}: "
+                        f"{str(e)[:600]}")
+    print(json.dumps({"failures": failures, "compiled": compiled}))
+    return 1 if failures else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1106,7 +1106,7 @@ class TestChaosOverload:
             assert two_x["sheds"] > one_x["sheds"], (one_x, two_x)
             # p99 of ADMITTED interactive queries stays bounded: within
             # 4x of the 1x p99 (CI-generous; the acceptance bar is 2x
-            # on quiet hardware — see OVERLOAD.json) and an absolute
+            # on quiet hardware) and an absolute
             # ceiling that unbounded queueing would blow through
             assert two_x["p99"] <= max(4.0 * one_x["p99"], 2.0), \
                 (one_x, two_x)

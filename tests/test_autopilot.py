@@ -902,8 +902,8 @@ class TestChaosAutopilot:
             two_x = run_phase(12, 16.0, mid_phase=kill_worker)
             assert two_x["n"] > 0
             # admitted-interactive p99 stays bounded through the step
-            # change AND the kill (CI-generous 4x; the committed
-            # BENCH_r06 artifact holds the quiet-hardware 1.5x bar)
+            # change AND the kill (CI-generous 4x; the quiet-hardware
+            # bar is 1.5x)
             assert two_x["p99"] <= max(4.0 * one_x["p99"], 2.0), \
                 (one_x, two_x)
 

@@ -261,8 +261,8 @@ class TestPallasKernel:
     @pytest.mark.parametrize("a_build", ["v3", "v4"])
     @pytest.mark.parametrize("vocab", [1 << 12, 1 << 17])
     def test_matches_xla_block_path(self, rng, a_build, vocab):
-        """Both A-build variants vs the XLA oracle, on both sides of
-        the i16 packed-compare vocabulary bound."""
+        """Both A-build variants vs the XLA oracle, on a small and a
+        large vocabulary."""
         from tfidf_tpu.ops.ell import _score_block, score_block_pallas
         from tfidf_tpu.ops.scoring import (_compile_queries,
                                            make_query_batch)
@@ -275,7 +275,7 @@ class TestPallasKernel:
         ref = _score_block(imp, term, slot_of, qc_ext.T, 256)
         out = score_block_pallas(imp, term, jnp.asarray(qb.uniq),
                                  jnp.asarray(qb.n_uniq), qc_ext,
-                                 a_build=a_build, vocab_cap=vocab)
+                                 a_build=a_build)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
 
@@ -283,11 +283,11 @@ class TestPallasKernel:
     def test_v4_bitwise_equals_v3(self, rng, width):
         """The pair fold adds 0.0 exactly where v3 adds it, so v4
         (odd widths included — the static tail row) must agree with v3
-        to the BIT, packed or not."""
+        to the BIT."""
         from tfidf_tpu.ops.ell import score_block_pallas
         from tfidf_tpu.ops.scoring import (_compile_queries,
                                            make_query_batch)
-        for vocab in (1 << 14, 1 << 16):        # packed and unpacked
+        for vocab in (1 << 14, 1 << 16):
             rows_cap, B = 512, 32
             imp, term = self._block(rng, rows_cap, width, vocab)
             q_terms = rng.integers(0, vocab, size=(B, 4)).astype(np.int32)
@@ -297,7 +297,7 @@ class TestPallasKernel:
             _slot_of, qc_ext = _compile_queries(qb, vocab)
             outs = [np.asarray(score_block_pallas(
                 imp, term, jnp.asarray(qb.uniq), jnp.asarray(qb.n_uniq),
-                qc_ext, a_build=a, vocab_cap=vocab))
+                qc_ext, a_build=a))
                 for a in ("v3", "v4")]
             assert np.abs(outs[0]).max() > 0
             np.testing.assert_array_equal(outs[0], outs[1])
@@ -324,8 +324,7 @@ class TestPallasKernel:
     def test_end_to_end_engine_equivalence(self, tmp_path):
         """Engine with use_pallas on eligible shapes == engine without,
         for BOTH A-build variants. min_doc_capacity=512 makes every
-        block eligible (rows_cap 512); the small vocabulary also arms
-        the v4 i16 packed sub-variant."""
+        block eligible (rows_cap 512)."""
         from tfidf_tpu.engine.engine import Engine
         from tfidf_tpu.utils.config import Config
 

@@ -217,3 +217,57 @@ class TestCheckpoint:
         other = make_mesh((2, 1), devices=jax.devices()[:2])
         with pytest.raises(ValueError, match="rebuild"):
             load_sharded_arrays(path, other)
+
+
+@pytest.mark.parametrize("layout", ["coo", "ell"])
+def test_index_size_is_readable_during_concurrent_ingest(tmp_path, layout):
+    """The leader polls ``/worker/index-size`` while upload handlers
+    ingest. On a mesh worker that read iterated the pending-doc dict
+    unlocked and raised "dictionary changed size during iteration"
+    under a real ingest — the leader then dropped the worker as sick
+    (found by the four-chip smoke run). More threads than cores and a
+    short switch interval make the interleaving certain."""
+    import sys
+    import threading
+
+    engine = make_engine(tmp_path, f"size-{layout}", "mesh",
+                         mesh_layout=layout)
+    stop = threading.Event()
+    errors: list[BaseException] = []
+
+    def ingest(t: int) -> None:
+        i = 0
+        while not stop.is_set():
+            engine.index.add_document_arrays(
+                f"w{t}-{i}", np.asarray([1, 2, 3 + i % 50], np.int32),
+                np.ones(3, np.float32), 3.0)
+            i += 1
+
+    def poll() -> None:
+        try:
+            last = 0
+            for _ in range(300):
+                size = engine.index_size_bytes()
+                assert size >= last   # adds only: never shrinks
+                last = size
+        except BaseException as e:
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        writers = [threading.Thread(target=ingest, args=(t,))
+                   for t in range(16)]
+        reader = threading.Thread(target=poll)
+        for th in writers + [reader]:
+            th.start()
+        reader.join(timeout=60)
+        stop.set()
+        for th in writers:
+            th.join(timeout=10)
+        assert not reader.is_alive()
+        assert not any(th.is_alive() for th in writers)
+    finally:
+        sys.setswitchinterval(old)
+        stop.set()
+    assert not errors, errors

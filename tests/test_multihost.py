@@ -56,16 +56,6 @@ def test_multiprocess_mesh_engine_parity(tmp_path):
     results. The worker body lives in tests/mp_mesh_worker.py."""
     import os
 
-    import jax
-
-    # cross-process collectives on the CPU backend were only implemented
-    # in newer jax ("Multiprocess computations aren't implemented on the
-    # CPU backend" on 0.4.x) — skip rather than fail where the runtime
-    # lacks the capability; real TPU pods are unaffected
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        pytest.skip("multiprocess CPU collectives unsupported on "
-                    f"jax {jax.__version__}")
-
     n = 2
     port = _free_port()
     env = dict(os.environ)

@@ -16,6 +16,7 @@ import socket
 import urllib.error
 import urllib.request
 
+import jax
 import pytest
 
 from tfidf_tpu.cluster.coordination import CoordinationCore, LocalCoordination
@@ -582,14 +583,16 @@ class TestCompileRetryGate:
             node.engine.commit()
             calls = {"n": 0}
 
-            def always_500(queries, k=None, unbounded=False):
+            def always_fails(queries, k=None, unbounded=False):
                 calls["n"] += 1
-                raise RuntimeError(
-                    "INTERNAL: remote_compile: HTTP 500: "
-                    "tpu_compile_helper subprocess exit code 1")
+                raise jax.errors.JaxRuntimeError(
+                    "RESOURCE_EXHAUSTED: Ran out of memory in memory "
+                    "space vmem while allocating on stack. Scoped "
+                    "allocation with size 18.20M and limit 16.00M "
+                    "exceeded scoped vmem limit by 2.20M.")
 
             orig = node.engine.search_batch
-            node.engine.search_batch = always_500
+            node.engine.search_batch = always_fails
             # first batch at this bucket: one retry (budget -> 0)
             with pytest.raises(RuntimeError):
                 node.worker_search_batch(["needle"])
@@ -605,7 +608,7 @@ class TestCompileRetryGate:
             # success refills: a later transient at the bucket retries
             node.engine.search_batch = orig
             assert node.worker_search_batch(["needle"])
-            node.engine.search_batch = always_500
+            node.engine.search_batch = always_fails
             calls["n"] = 0
             with pytest.raises(RuntimeError):
                 node.worker_search_batch(["needle"])

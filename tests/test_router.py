@@ -15,8 +15,7 @@ Tier-1 (deterministic): follower load/watch-refresh/re-arm mechanics,
 router exact parity + route stamps, per-router cache invalidation on
 observed flushes, unmapped-hit dropping (never summing), write
 forwarding, worker-death failover through a router, any-node reads,
-the frozen/partitioned staleness contract, CLI surfaces, and the
-committed BENCH_r07 multi-router scaling artifact.
+the frozen/partitioned staleness contract, and CLI surfaces.
 
 Slow (``make chaos-router``): kill -9 a router AND the leader
 mid-workload under 2x zipfian load through two routers — the
@@ -830,36 +829,6 @@ class TestRouterCli:
             if router is not None:
                 router.stop()
             _stop_all(nodes)
-
-
-# ---------------------------------------------------------------------------
-# the committed multi-router scaling artifact
-# ---------------------------------------------------------------------------
-
-class TestBenchArtifact:
-    def test_bench_r07_scaling_table(self):
-        """BENCH_r07.json (make bench-routers) is the headline
-        artifact: admitted interactive q/s through 1/2/4 stateless
-        routers at equal offered load, 2 routers >= 1.6x the 1-router
-        baseline (the acceptance bar), parity-checked in-run."""
-        import os
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "..", "BENCH_r07.json")
-        with open(path, encoding="utf-8") as f:
-            art = json.load(f)
-        assert art["metric"] == "router_scaleout_admitted_qps_2r"
-        table = art["extra"]["routers"]
-        assert set(table) == {"1", "2", "4"}
-        q1 = table["1"]["admitted_qps"]
-        q2 = table["2"]["admitted_qps"]
-        assert q1 > 0
-        ratio = q2 / q1
-        assert ratio >= 1.6, f"2-router scaling {ratio:.2f}x < 1.6x"
-        assert art["extra"]["scaling_2r_vs_1r"] == pytest.approx(
-            ratio, rel=1e-3)
-        # in-run correctness gate: the bench cross-checks router
-        # results against the leader's before measuring
-        assert art["extra"]["parity_checked"] is True
 
 
 # ---------------------------------------------------------------------------

@@ -378,10 +378,9 @@ class QueryBatcher(Coalescer):
     generic :class:`Coalescer` this is built on.
 
     ``pipeline`` scorer threads run concurrent ``search_batch`` calls
-    (the engine is a pure function of its snapshot, so this is safe). On
-    a high-RTT device link (remote-TPU tunnel) a second in-flight batch
-    hides one batch's result fetch under the next batch's device
-    compute — the same trick Searcher.search plays across chunks,
+    (the engine is a pure function of its snapshot, so this is safe). A
+    second in-flight batch hides one batch's result fetch under the
+    next batch's device compute — the same trick Searcher.search plays across chunks,
     applied across micro-batches."""
 
     def __init__(self, engine, max_batch: int = 32,

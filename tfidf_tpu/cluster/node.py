@@ -696,10 +696,11 @@ class SearchNode(ScatterReadPlane):
     # Retry gate classifier: the structured compute-fault taxonomy
     # (cluster/resilience.classify_compute_fault — the same function
     # the engine's health machine and the leader's poison quarantine
-    # use, so the three can never drift). Only "compile" (the tunnel's
-    # remote-compile flakes, a fresh executable may succeed) and
-    # "transient" (one-off dispatch failure) earn the single budgeted
-    # retry; "oom" already ran the engine's batch-backoff ladder and
+    # use, so the three can never drift). Only "compile" (retried
+    # within a per-bucket budget that a deterministic refusal — a
+    # kernel Mosaic rejects, a tile schedule over scoped VMEM — drains
+    # at once) and "transient" (one-off dispatch failure) earn the
+    # single budgeted retry; "oom" already ran the engine's batch-backoff ladder and
     # "poison" must surface unretried for the leader to quarantine.
     @staticmethod
     def _is_retryable_compute_fault(e: BaseException) -> bool:
@@ -714,12 +715,12 @@ class SearchNode(ScatterReadPlane):
     def _search_batch_guarded(self, n_queries: int, run,
                               deadline: float | None = None):
         """Shared wrapper for the batched-scatter entrypoints: NRT
-        commit, timing, and the transient-compile retry. A failure
-        matching the known transient remote-compile signature is
-        retried once, with a per-bucket-size budget: a deterministic
-        compile error (e.g. OOM at a new bucket) drains the budget and
-        then propagates immediately instead of doubling every batch's
-        cost forever.
+        commit, timing, and the compile/transient retry. A failure
+        classified "compile" or "transient" is retried once, with a
+        per-bucket-size budget: a deterministic compile error (a
+        kernel the compiler refuses at a new bucket) drains the budget
+        and then propagates immediately instead of doubling every
+        batch's cost forever.
 
         ``deadline`` (monotonic seconds) is the leader's propagated
         scatter budget: re-checked AFTER the NRT commit (which can eat
@@ -2583,6 +2584,10 @@ class _NodeHandler(_HttpHandlerBase):
                     # bytes resident) for the CLI status fan-out; null
                     # when the dense plane is disabled
                     "embedding": node.engine.dense_stats(),
+                    # whether the C++ ingest library was built and
+                    # loaded (config.native_ingest asked for it; a
+                    # missing compiler quietly means the Python chain)
+                    "native_ingest": node.engine.native is not None,
                     # tiered-postings residency counters (ISSUE 18):
                     # hot/cold segment counts, HBM bytes vs budget,
                     # hit/skip rates — {"enabled": false} when off.

@@ -3,8 +3,8 @@
 The single-coordinator design inherited from the reference (every query
 funnels through the elected leader's scatter loop, ``Leader.java:39-92``)
 caps the whole cluster's interactive front door near one Python
-process's worth of HTTP + merge work — ~92 q/s in OVERLOAD.json against
-a 6,243 q/s engine. This module retires that ceiling by splitting the
+process's worth of HTTP + merge work, far below what one engine
+scores. This module retires that ceiling by splitting the
 node into two planes:
 
 - **Read plane** (:class:`ScatterReadPlane`) — the scatter / owner-merge
@@ -31,7 +31,7 @@ node into two planes:
 plane (``python -m tfidf_tpu router``): it owns its OWN admission
 controller, scatter coalescer, generation-keyed result cache, resilience
 stack (breakers/retries/hedges/deadlines), and placement follower — so
-admitted interactive throughput scales with router count (BENCH_r07)
+admitted interactive throughput scales with router count
 while correctness still rests on per-request owner assignment. Routers
 register ephemeral znodes under ``/router_registry`` so ``status`` and
 ``/api/routers`` can enumerate the tier; the k8s Deployment + HPA in
@@ -1615,8 +1615,8 @@ class QueryRouter(ScatterReadPlane):
     """One stateless router: a read-plane process with no engine, no
     shard, and no authority — just the scatter spine pointed at a
     follower view of the placement znode. Kill one and nothing is
-    lost; add N and the interactive front door scales ~N-fold
-    (BENCH_r07)."""
+    lost; add N and the interactive front door scales with N (by how
+    much on a chip machine: not measured)."""
 
     def __init__(self, config: Config | None = None, coord=None,
                  coord_factory=None) -> None:

@@ -500,9 +500,33 @@ class Engine:
             raise
 
     def compute_stats(self) -> dict:
-        """ComputeHealth summary for /api/health and `status`."""
+        """ComputeHealth summary for /api/health and `status`, plus
+        WHERE the compute runs: platform, device kind and count, how
+        many of the committed posting blocks ride the fused Pallas
+        kernel (and whether that kernel is interpreted), which devices
+        hold the index and what each has allocated — so nothing outside
+        this process has to infer whether the chip is doing the work."""
+        import jax
+
+        from tfidf_tpu.ops.ell import pallas_interpret
         d = self.compute.snapshot()
         d["fallback_available"] = self._fallback is not None
+        devs = jax.local_devices()
+        blocks = self.searcher.posting_blocks()
+        d["platform"] = devs[0].platform
+        d["device_kind"] = devs[0].device_kind
+        d["device_count"] = jax.device_count()
+        d["posting_blocks"] = len(blocks)
+        d["kernel_blocks"] = sum(bool(k) for _a, k in blocks)
+        d["kernel_interpret"] = pallas_interpret()
+        d["index_devices"] = sorted(
+            {s.device.id for a, _k in blocks
+             for s in a.addressable_shards})
+        d["device_memory"] = [
+            {"id": dev.id, "bytes_in_use": ms["bytes_in_use"],
+             "peak_bytes_in_use": ms["peak_bytes_in_use"]}
+            for dev in devs
+            if (ms := dev.memory_stats()) is not None]
         return d
 
     def search(self, query: str, k: int | None = None,

@@ -27,7 +27,8 @@ from tfidf_tpu.models.base import ScoringModel
 from tfidf_tpu.ops.analyzer import Analyzer
 from tfidf_tpu.ops.blockmax import query_upper_bounds, skip_mask
 from tfidf_tpu.ops.csr import next_capacity
-from tfidf_tpu.ops.ell import score_ell_batch, score_segments_batch
+from tfidf_tpu.ops.ell import (_pallas_eligible, score_ell_batch,
+                               score_segments_batch)
 from tfidf_tpu.ops.scoring import (QueryBatch, make_query_batch,
                                    score_coo_batch)
 from tfidf_tpu.ops.topk import (fetch_packed, full_ranking, packed_topk,
@@ -126,8 +127,8 @@ class QueryVectorizerMixin:
 
     def _use_executor(self) -> bool:
         """Resolve ``pipeline_mode``: the executor buys overlap only
-        where the d2h fetch has real latency (TPU/GPU, tunneled links);
-        on the CPU backend a "fetch" is a shared-memory view, and the
+        where the d2h fetch has real latency (an accelerator); on the
+        CPU backend a "fetch" is a shared-memory view, and the
         three thread hand-offs per chunk cost more than they hide —
         measured ~27% concurrent-caller throughput loss — so "auto"
         keeps CPU inline and turns the executor on for accelerators."""
@@ -242,11 +243,10 @@ class Searcher(QueryVectorizerMixin):
         Chunks are PIPELINED ``pipeline_depth`` deep (default 2): later
         chunks' device programs are dispatched before earlier chunks'
         packed top-k buffers are fetched, so the device->host round trip
-        and host-side hit assembly hide under device time. On
-        high-latency links (remote-TPU tunnels, ~100ms RTT) this is the
-        difference between latency-bound and compute-bound throughput;
-        fetches serialize on one stream, so depth beyond 2 does not help
-        (PERF.md) — batch size is the throughput lever there.
+        and host-side hit assembly hide under device time. Fetches
+        serialize on one stream, so depth beyond 2 buys nothing; what
+        depth a locally attached chip needs is not measured (ROADMAP
+        D3).
         """
         snap = self.index.snapshot
         if snap is None or not snap.num_names or not queries:
@@ -306,6 +306,25 @@ class Searcher(QueryVectorizerMixin):
                  else snap.doc_names)
         global_metrics.inc("queries_served", len(queries))
         return vals, ids, kk, names
+
+    def posting_blocks(self) -> list[tuple]:
+        """``(array, rides_kernel)`` for every posting block of the
+        committed snapshot — what ``Engine.compute_stats`` reports on:
+        where the index lives, and how many blocks the fused Pallas
+        kernel scores at this searcher's full query batch (the same
+        predicate ``score_ell_impl`` dispatches on)."""
+        snap = self.index.snapshot
+        if snap is None:
+            return []
+        if isinstance(snap, SegmentedSnapshot):
+            views = snap.views or tuple(v for _i, _b, v in snap.hot)
+            return [(tf, False) for v in views for tf in v.tfs]
+        if not snap.is_ell:
+            return [(snap.tf, False)]
+        return [(imp, self.use_pallas and _pallas_eligible(
+                    imp.shape[0], self.query_batch, self._u_floor,
+                    self.kernel_a_build))
+                for imp in snap.ell_impacts]
 
     def _score_chunk(self, snap: Snapshot, queries: list[str]):
         cap = self._batch_cap(len(queries))

@@ -285,7 +285,7 @@ class SegmentedIndex:
         # pace * (that block's upload time) so the commit's small puts
         # get real gaps — the r3 8.8M run showed commit p99 5.4s /
         # max 12.1s from gigabytes of merged postings queueing ahead of
-        # commits (MSMARCO_SCALE.json). Idle-stream merges pay no sleep,
+        # commits. Idle-stream merges pay no sleep,
         # so quiesce stays fast. 0 disables pacing entirely.
         self.merge_upload_pace = merge_upload_pace
         self._commit_active = False   # racy hint read by the merge thread

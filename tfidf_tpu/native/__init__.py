@@ -1,8 +1,10 @@
 """ctypes bindings + build-on-demand for the native ingest hot path.
 
 The shared library is compiled from ``tfidf_native.cpp`` with the system
-g++ on first use (cached next to the source; rebuilt when the source is
-newer). Everything degrades gracefully: if no compiler is available the
+g++ on first use and cached next to the source under a name that carries
+a hash of the source — a copied tree keeps no mtimes, so only content can
+say whether a library found there was built from this source. Everything
+degrades gracefully: if no compiler is available the
 framework runs on the pure-Python analyzer with identical results —
 :func:`available` is the capability probe.
 
@@ -13,8 +15,11 @@ pinned by parity tests against the Python chain in tests/test_native.py).
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
+import tempfile
 import threading
 
 import numpy as np
@@ -25,24 +30,42 @@ log = get_logger("native")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "tfidf_native.cpp")
-_LIB = os.path.join(_HERE, "libtfidf_native.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libtfidf_native.{digest}.so")
+
+
+def _build(lib_path: str) -> bool:
+    # a temp name of its own, then rename: nodes that start together
+    # each build a whole file and the last rename wins, identically
+    fd, tmp = tempfile.mkstemp(dir=_HERE, prefix="libtfidf_native.",
+                               suffix=".so.tmp")
+    os.close(fd)
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC,
-           "-o", _LIB + ".tmp"]
+           "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, lib_path)
     except (OSError, subprocess.SubprocessError) as e:
         log.warning("native build failed; using pure-Python analyzer",
                     err=repr(e))
+        if os.path.exists(tmp):
+            os.unlink(tmp)
         return False
-    os.replace(_LIB + ".tmp", _LIB)
-    log.info("native library built", path=_LIB)
+    for old in glob.glob(os.path.join(_HERE, "libtfidf_native*.so")):
+        if old != lib_path:   # built from a source that is gone
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    log.info("native library built", path=lib_path)
     return True
 
 
@@ -52,12 +75,11 @@ def _load() -> ctypes.CDLL | None:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        stale = (not os.path.exists(_LIB)
-                 or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
-        if stale and not _build():
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path) and not _build(lib_path):
             return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(lib_path)
         except OSError as e:
             log.warning("native library load failed", err=repr(e))
             return None

@@ -31,6 +31,7 @@ Replaces the posting-list traversal inside Lucene's ``searcher.search``
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import NamedTuple
 
@@ -39,11 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# newer jax renamed TPUCompilerParams -> CompilerParams; resolve once so
-# the kernel wrapper below works on either
-_TPUCompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 from tfidf_tpu.ops.csr import CooShard, next_capacity
 from tfidf_tpu.ops.scoring import (QueryBatch, _compile_queries,
@@ -226,24 +222,33 @@ ell_impacts = jax.jit(ell_impacts, static_argnames=("model", "k1", "b"))
 #   the pair folds into ONE nested select chain and ONE accumulate add
 #   — the loop-carried add chain halves (width/2 deep instead of
 #   width), and because +0.0 is exact in f32 the result is
-#   BIT-IDENTICAL to v3. Where every term id fits in 15 bits
-#   (vocab_cap <= 2^15) the packed-compare sub-variant additionally
-#   casts term ids and uniq ids to i16 — Mosaic packs i16 two per
-#   32-bit lane (16x128 vreg vs 8x128 for i32), halving the compare
-#   vreg cost and the term tile's VMEM/HBM bytes. Cost per 2 entries:
-#   2 cmp (1 vreg-op packed) + 2 sel + 1 add = 2.0 vreg-ops/entry
-#   packed, 2.5 unpacked, vs v3's 3.0 (the op-count model bench.py
-#   --kernel emits into BENCH_r09.json).
+#   BIT-IDENTICAL to v3. Cost per 2 entries: 2 cmp + 2 sel + 1 add
+#   = 2.5 vreg-ops/entry vs v3's 3.0 (an op-count model, ``bench.py
+#   --kernel``; the on-chip speed of v4 against v3 is not measured).
+#   Term ids stay i32 even where the vocabulary fits 15 bits: Mosaic
+#   for v5e refuses a dynamic sublane load from an i16 tile ("cannot
+#   statically prove that index in dimension 0 is a multiple of 8")
+#   and, with the rows unrolled, an i16 compare mask feeding an f32
+#   select ("Invalid relayout"), so packed compares cannot be built
+#   this way.
 #
 # The XLA reduce-fusion path (``_score_block``) stays untouched as the
-# oracle for both.
+# oracle for both. ``tests/test_kernel_compile.py`` compiles every
+# shape class ``_pallas_eligible`` admits for v5e (compile-only, no
+# chip needed).
 
 _PL_TD = 512          # docs per grid tile (256 for small blocks)
 _PL_MAX_B = 2048      # VMEM: qc [B, TU] + out [B, TD] stay ~8MB
-# term ids below this bound compare as packed i16 in the v4 A-build
-# (two ids per 32-bit lane); -1 (the uniq pad sentinel) still fits
-_PACKED_VOCAB_MAX = 1 << 15
 A_BUILD_VARIANTS = ("v3", "v4")
+
+
+def pallas_interpret() -> bool:
+    """Whether the fused kernel runs in the Pallas reference
+    interpreter instead of lowering a Mosaic program: true on every
+    backend but TPU (CPU tests). The ONE place that decides it, so the
+    health surface (``Engine.compute_stats``) reports exactly what the
+    kernel wrapper does."""
+    return jax.default_backend() != "tpu"
 
 
 def check_a_build(a_build: str) -> str:
@@ -314,7 +319,7 @@ def _pallas_kernel_v4(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
     @pl.when(jnp.logical_and(u * tu < lims_ref[0],
                              d * td < lims_ref[1]))
     def _tile():
-        uniq_col = uniq_ref[:]                       # [TU, 1] i32|i16
+        uniq_col = uniq_ref[:]                       # [TU, 1] i32
 
         def pair(w, a):                              # a [TU, Td]
             t0 = term_ref[w, :][None, :]             # [1, Td]
@@ -341,21 +346,16 @@ def _pallas_kernel_v4(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
                               precision=jax.lax.Precision.HIGHEST)
 
 
-def _pl_tiles(rows_cap: int, B: int, u_cap: int,
-              a_build: str = "v3") -> tuple[int, int]:
+def _pl_tiles(rows_cap: int, B: int, u_cap: int) -> tuple[int, int]:
     """(doc tile, uniq tile) for a block/batch shape. Bigger tiles
     amortize grid overhead; both tiles shrink as B grows so the
     multi-buffered qc [B, TU] / out [B, TD] blocks plus the A
     accumulator and MXU temporaries stay inside the 16MB scoped-VMEM
-    budget (measured: Mosaic's buffering costs ~2x the naive block
-    arithmetic, so the schedule is deliberately conservative). v4 gets
-    its own schedule: the pair loop holds half the loop temporaries
-    and (packed) an i16 term tile at half the bytes, so it keeps the
-    512 tile cap up to B=1024 where v3 already drops to 256."""
-    if a_build == "v4":
-        cap = 512 if B <= 1024 else 256
-    else:
-        cap = 512 if B <= 512 else (256 if B <= 1024 else 128)
+    budget (Mosaic's buffering costs ~2x the naive block arithmetic,
+    so the schedule is deliberately conservative). One schedule for
+    both A-build variants: 512 tiles at B=1024 or 256 at B=2048 ask
+    the v5e compiler for 18.2 MB of scoped VMEM against its 16 MB."""
+    cap = 512 if B <= 512 else (256 if B <= 1024 else 128)
     td = min(cap, _PL_TD if rows_cap % _PL_TD == 0 else _PL_TD // 2)
     tu = min(cap, 512 if u_cap % 512 == 0 else 256, u_cap)
     return td, tu
@@ -367,27 +367,22 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
                        n_uniq: jax.Array,    # i32 scalar (traced)
                        qc_ext: jax.Array,    # f32 [B, U_cap+1]
                        n_rows: jax.Array | None = None,  # i32 scalar
-                       *, a_build: str = "v3",
-                       vocab_cap: int = 0) -> jax.Array:
+                       *, a_build: str = "v3") -> jax.Array:
     """Fused ELL-block scoring on TPU: ``[B, rows_cap]`` scores.
 
     ``n_rows`` (traced) is the block's live row count: doc tiles wholly
     past it skip the A-build and contraction (their scores are zeroed by
     the unconditional init, exactly what all-pad rows would score).
 
-    ``a_build`` selects the A-build variant (see the notes above);
-    ``vocab_cap`` (static; 0 = unknown) arms the v4 packed-compare
-    sub-variant when every term id fits in i16. Both variants are
-    bit-identical to each other; the XLA reduce-fusion path is the
-    oracle (``kernel_parity.py``).
+    ``a_build`` selects the A-build variant (see the notes above).
+    Both variants are bit-identical to each other; the XLA
+    reduce-fusion path is the oracle (``kernel_parity.py``).
     """
-    import functools
-
     check_a_build(a_build)
     rows_cap, width = impact.shape
     B, _ = qc_ext.shape
     u_cap = uniq.shape[0]
-    td, tu = _pl_tiles(rows_cap, B, u_cap, a_build)
+    td, tu = _pl_tiles(rows_cap, B, u_cap)
     # the grid floor-divides: a non-multiple capacity would silently
     # drop the trailing tile (callers route through _pallas_eligible,
     # but direct callers must fail loudly, not score wrong)
@@ -399,12 +394,6 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     qc = qc_ext[:, :u_cap]                           # drop the zero column
     imp_t = impact.T                                 # [W, rows] width-major
     term_t = term.T
-    packed = (a_build == "v4" and 0 < vocab_cap <= _PACKED_VOCAB_MAX)
-    if packed:
-        # ids (and the -1 pad sentinel) fit i16: the compare runs at
-        # two lanes per 32-bit vreg lane, and the term tile halves
-        uniq_col = uniq_col.astype(jnp.int16)
-        term_t = term_t.astype(jnp.int16)
     if n_rows is None:
         n_rows = jnp.int32(rows_cap)
     lims = jnp.stack([jnp.asarray(n_uniq, jnp.int32),
@@ -430,11 +419,9 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rows_cap), jnp.float32),
-        compiler_params=_TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        # non-TPU backends (CPU tests, hypothetically GPU) run the
-        # reference interpreter instead of lowering a Mosaic program
-        interpret=jax.default_backend() != "tpu",
+        interpret=pallas_interpret(),
     )(lims, uniq_col, qc, term_t, imp_t)
 
 
@@ -444,8 +431,8 @@ def _pallas_eligible(rows_cap: int, B: int, u_cap: int,
     are cheap. u_cap is unbounded (uniq tiles past ``n_uniq`` are
     skipped, so capacity padding is free); B is VMEM-bounded. The
     envelope is shared by both A-build variants (v4's odd-width tail
-    row and packed sub-variant change the schedule, not the shapes the
-    kernel accepts), so a config flip can never silently change WHICH
+    row changes the loop, not the shapes the kernel accepts), so a
+    config flip can never silently change WHICH
     blocks ride the kernel — only how the A is built. An UNKNOWN
     variant raises (``check_a_build``) rather than quietly failing
     eligibility."""
@@ -590,8 +577,7 @@ def score_ell_impl(impacts,            # tuple of f32 [rows_cap_i, width_i]
     qc_t = qc_ext.T                                   # [U_cap+1, B]
     u_cap = q.uniq.shape[0]
     parts = [score_block_pallas(imp, term, q.uniq, q.n_uniq, qc_ext,
-                                block_live[i], a_build=a_build,
-                                vocab_cap=vocab_cap)
+                                block_live[i], a_build=a_build)
              if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap,
                                                 a_build)
              else _score_block(imp, term, slot_of, qc_t, doc_chunk)

@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tfidf_tpu.ops.topk import merge_topk, pack_topk
-from tfidf_tpu.parallel._compat import shard_map as _shard_map
 
 
 def shard_dense_column(mesh: Mesh, rows_per_shard: list,
@@ -80,7 +79,7 @@ def make_mesh_dense_search(mesh: Mesh, *, k: int):
         top_vals, top_ids = merge_topk(all_vals, all_ids)
         return pack_topk(top_vals, top_ids)
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(None, None), P("docs", None), P("docs"), P("docs")),
         out_specs=P(None, None), check_vma=False)

@@ -39,8 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tfidf_tpu.parallel._compat import shard_map as _shard_map
-
 from tfidf_tpu.ops.csr import next_capacity
 from tfidf_tpu.ops.ell import (_pallas_eligible, _score_block,
                                score_block_pallas, _rearrange_to_real)
@@ -269,7 +267,7 @@ def make_impact_refresh(mesh: Mesh, *, model: str = "bm25",
     def refresh(arrays: MeshEllArrays, df_g, n_docs, avgdl):
         import dataclasses
         k = arrays.n_buckets
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             step, mesh=mesh, in_specs=n_in(k),
             out_specs=(P("docs", None, "terms"),) * k,
             check_vma=False)
@@ -302,9 +300,9 @@ def make_mesh_ell_search(mesh: Mesh,
     them at commit), so the step needs no df psum.
 
     ``packed=True`` returns ONE i32 ``[B, 2k]`` array (values bitcast) so
-    the caller fetches values and ids in a single device->host transfer
-    — on high-latency links (remote-TPU tunnels) the second fetch costs
-    a full RTT, which at k=10 dwarfs the payload.
+    the caller fetches values and ids in a single device->host
+    transfer: at k=10 the fixed cost of a second fetch dwarfs the
+    payload.
     """
 
     def step(df_g, n_docs, avgdl, base_live, block_live,
@@ -343,7 +341,7 @@ def make_mesh_ell_search(mesh: Mesh,
                                                a_build):
                 parts.append(score_block_pallas(
                     imp, term, q.uniq, q.n_uniq, qc_ext, block_live[i],
-                    a_build=a_build, vocab_cap=vocab_cap))
+                    a_build=a_build))
             else:
                 parts.append(_score_block(imp, term, slot_of, qc_t, 2048))
         ell_scores = _rearrange_to_real(
@@ -392,7 +390,7 @@ def make_mesh_ell_search(mesh: Mesh,
     def search(base: MeshEllArrays, delta, df_g, n_docs, avgdl,
                q: QueryBatch):
         nb = base.n_buckets
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             step, mesh=mesh, in_specs=in_specs(nb),
             out_specs=(P(), P()), check_vma=False)
         vals, gids = sharded(

@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tfidf_tpu.ops.csr import CooShard, next_capacity
 from tfidf_tpu.ops.dfdelta import DfDeltaApplier
+from tfidf_tpu.ops.ell import _pallas_eligible
 from tfidf_tpu.parallel.mesh_ell import (MeshEllArrays, build_mesh_ell,
                                          make_impact_refresh,
                                          make_mesh_ell_search,
@@ -521,6 +522,17 @@ class MeshEllSearcher(MeshSearcher):
         if cached is not None and (snap is None
                                    or cached[0] != snap.version):
             self._unbounded_cache = None
+
+    def posting_blocks(self) -> list[tuple]:
+        # per-device block rows are dim 1 of the [D, rows_cap, W] base
+        # arrays; make_mesh_ell_search dispatches on the same predicate
+        snap = self.index.snapshot
+        if snap is None:
+            return []
+        return [(imp, _pallas_eligible(imp.shape[1], self.query_batch,
+                                       self._u_floor,
+                                       self.kernel_a_build))
+                for imp in snap.base.impact]
 
     def _dispatch_chunk(self, snap, qb, k: int):
         kk = min(k, snap.stride)

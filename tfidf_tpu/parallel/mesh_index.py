@@ -195,11 +195,16 @@ class MeshIndex:
         return int(n)
 
     def size_bytes(self) -> int:
-        n = sum(d.term_ids.nbytes + d.tfs.nbytes
-                for d in self._pending.values())
-        for sd in self._shard_docs:
-            n += sum(d.term_ids.nbytes + d.tfs.nbytes
-                     for d in sd if d.live)
+        # under the write lock: the leader polls /worker/index-size
+        # while upload handlers are adding to _pending, and a dict that
+        # grows mid-iteration raises — which the leader reads as a sick
+        # worker ("no reachable workers" in the middle of an ingest)
+        with self._write_lock:
+            n = sum(d.term_ids.nbytes + d.tfs.nbytes
+                    for d in self._pending.values())
+            for sd in self._shard_docs:
+                n += sum(d.term_ids.nbytes + d.tfs.nbytes
+                         for d in sd if d.live)
         return int(n)
 
     def live_entries(self) -> list[DocEntry]:
@@ -462,6 +467,12 @@ class MeshSearcher(QueryVectorizerMixin):
     def _on_snapshot(self, snap) -> None:
         """Layout hook: called with the snapshot each search (lets
         subclasses drop per-snapshot caches when the version moves)."""
+
+    def posting_blocks(self) -> list[tuple]:
+        """Layout hook, as :meth:`Searcher.posting_blocks`: the COO
+        scatter step never rides the Pallas kernel."""
+        snap = self.index.snapshot
+        return [] if snap is None else [(snap.arrays.tf, False)]
 
     def _dispatch_chunk(self, snap, qb, k: int):
         """Layout hook: launch one chunk's packed top-k (not fetched)."""

@@ -36,8 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tfidf_tpu.parallel._compat import shard_map as _shard_map
-
 from tfidf_tpu.ops.csr import CooShard, next_capacity
 from tfidf_tpu.ops.scoring import (QueryBatch, cosine_norms,
                                    score_coo_impl)
@@ -290,7 +288,7 @@ def make_sharded_search(mesh: Mesh,
         top_vals, top_ids = merge_topk(all_vals, all_ids)
         return top_vals, top_ids
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P("docs", "terms", None), P("docs", "terms", None),
@@ -312,8 +310,7 @@ def make_sharded_search(mesh: Mesh,
             jnp.asarray(q.slots), jnp.asarray(q.weights))
         if packed:
             # one [B, 2k] i32 buffer: bitcast values + ids fetched in a
-            # single device->host transfer (the second fetch costs a full
-            # RTT on tunneled links)
+            # single device->host transfer
             return pack_topk(vals, gids)
         return vals, gids
 
@@ -371,7 +368,7 @@ def make_sharded_scores(mesh: Mesh,
         scores = jax.lax.psum(partial, "terms")
         return (scores * live[None, :])[None]           # [1, B, doc_cap]
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P("docs", "terms", None), P("docs", "terms", None),
@@ -551,7 +548,7 @@ def make_sharded_ingest(mesh: Mesh):
                 used2[None, None], live2[None],
                 (len_sum + new_len_sum)[None])
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P("docs", "terms", None), P("docs", "terms", None),

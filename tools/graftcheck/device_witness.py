@@ -10,8 +10,9 @@ graph:
 
 * **compile events** — one module-level ``jax.monitoring`` listener
   counts ``/jax/core/compile/backend_compile_duration`` events (fires on
-  every backend compile INCLUDING recompiles; silent on executable-cache
-  hits — verified against jax 0.4.x).  ``end_warmup()`` snapshots the
+  every backend compile INCLUDING recompiles and loads from the
+  persistent cache; silent on in-memory executable-cache hits —
+  verified against jax 0.9).  ``end_warmup()`` snapshots the
   count; any later compile is a post-warmup recompile and fails
   ``check()``.  ``jax.monitoring`` has no per-listener unregister, so
   ONE process-wide listener feeds a monotonic counter and witnesses read
@@ -179,11 +180,8 @@ class DeviceWitness:
             proxy = _NumpyProxy(self, short, _real_np)
             self._saved.append((mod.__dict__, binding))
             mod.__dict__["np"] = proxy
-        try:
-            self._guard_cm = jax.transfer_guard(self.guard)
-            self._guard_cm.__enter__()
-        except Exception:
-            self._guard_cm = None   # older jax: proxy-only observation
+        self._guard_cm = jax.transfer_guard(self.guard)
+        self._guard_cm.__enter__()
         self._installed = True
         return self
 

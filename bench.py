@@ -2216,7 +2216,7 @@ def bench_cluster_tpu(rng) -> dict:
                     _json.loads(_http_get(urls[1] + "/api/metrics")))
 
         # per-stage breakdown of one served query (VERDICT r4 #1):
-        # leader linger/RPC/decode/merge from the leader process, batch
+        # leader queue wait/RPC/decode/merge from the leader process, batch
         # search/pack from the TPU worker, windowed per sweep point
         def window_breakdown(ml0, mw0, ml1, mw1):
             n_sb = (ml1.get("scatter_batches", 0)
@@ -2225,8 +2225,8 @@ def bench_cluster_tpu(rng) -> dict:
                     - ml0.get("scatter_items", 0))
             return {
                 "mean_scatter_batch": round(n_si / max(n_sb, 1), 1),
-                "leader_linger_ms": _delta_timing(ml0, ml1,
-                                                  "scatter_linger"),
+                "leader_queue_wait_ms": _delta_timing(
+                    ml0, ml1, "scatter_queue_wait"),
                 "leader_rpc_ms": _delta_timing(ml0, ml1, "scatter_rpc"),
                 "leader_decode_ms": _delta_timing(ml0, ml1,
                                                   "scatter_decode"),

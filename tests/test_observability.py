@@ -838,3 +838,150 @@ class TestChaosTrace:
                        for e in chrome["traceEvents"])
         finally:
             _stop_all(nodes)
+
+
+# ---------------------------------------------------------------------------
+# The stage timer (ISSUE 24): every stage and wait of the query path under
+# one name in /api/metrics, on the Dapper span and in a profiler trace
+# ---------------------------------------------------------------------------
+
+def _stage_engine(tmp_path, mode):
+    from tfidf_tpu.engine import Engine
+    from tfidf_tpu.utils.config import Config
+    e = Engine(Config(documents_path=str(tmp_path / "docs"),
+                      min_doc_capacity=8, min_nnz_capacity=256,
+                      min_vocab_capacity=64, query_batch=4,
+                      max_query_terms=8, search_pipeline_mode=mode))
+    for i in range(12):
+        e.ingest_text(f"d{i}", f"common word{i} term{i % 3} extra{i % 5}")
+    e.commit()
+    return e
+
+
+def _counts(*keys):
+    snap = global_metrics.snapshot()
+    return {k: snap.get(k, 0) for k in keys}
+
+
+def _grew(before, after):
+    return {k: after[k] - before[k] for k in before}
+
+
+class TestStageTimer:
+    FETCH = ("phase_device_wait_count", "phase_d2h_count",
+             "phase_assemble_count")
+    CHUNKS = ("dispatch_chunks", "dispatch_queries", "dispatch_slots")
+    WAITS = ("phase_dispatch_wait_count", "phase_fetch_wait_count")
+    QUERIES = [f"common word{i}" for i in range(10)]    # 3 chunks of <= 4
+
+    @pytest.mark.parametrize("arrays", [False, True])
+    def test_every_chunk_is_timed_and_counted(self, tmp_path, arrays):
+        e = _stage_engine(tmp_path, "inline")
+        keys = self.FETCH + self.CHUNKS + self.WAITS
+        before = _counts(*keys)
+        if arrays:
+            vals, _ids, _kk, _names = e.search_batch_arrays(self.QUERIES)
+            assert vals.shape[0] == len(self.QUERIES)
+        else:
+            assert all(e.search_batch(self.QUERIES))
+        d = _grew(before, _counts(*keys))
+        assert d["dispatch_chunks"] == 3
+        assert [d[k] for k in self.FETCH] == [3, 3, 3]
+        assert d["dispatch_queries"] == 10
+        assert d["dispatch_slots"] == 4 + 4 + 2      # buckets 4, 4, 2
+        assert d["dispatch_queries"] <= d["dispatch_slots"]
+        # the inline path has no thread hand-off to wait for
+        assert [d[k] for k in self.WAITS] == [0, 0]
+
+    def test_executor_records_both_waits_on_the_span(self, tmp_path):
+        e = _stage_engine(tmp_path, "executor")
+        keys = self.FETCH + self.WAITS + ("dispatch_chunks",)
+        before = _counts(*keys)
+        with global_tracer.span("req") as sp:
+            assert all(e.search_batch(self.QUERIES))
+        d = _grew(before, _counts(*keys))
+        assert d == {k: 3 for k in keys}
+        evs = [ev["name"] for ev in sp.to_dict()["events"]]
+        for name in ("phase.dispatch_wait", "phase.vectorize",
+                     "phase.score", "phase.topk", "phase.fetch_wait",
+                     "phase.device_wait", "phase.d2h", "phase.assemble"):
+            assert evs.count(name) == 3, (name, evs)
+        assert not [n for n in evs if n.startswith("pipeline.")]
+
+    def test_coalescer_queue_wait_and_wake(self):
+        """Two one-item batches through ONE dispatcher whose batch_fn
+        sleeps: the second waits in the queue at least as long as the
+        first is in flight, and that is the queue wait — not a linger."""
+        hold = 0.15
+
+        def slow(items):
+            time.sleep(hold)
+            return items
+
+        co = Coalescer(slow, max_batch=1, linger_s=0.0, pipeline=1,
+                       name="stg")
+        try:
+            import concurrent.futures
+            with concurrent.futures.ThreadPoolExecutor(2) as pool:
+                first = pool.submit(co.submit, 1)
+                time.sleep(0.03)       # the first batch is in flight
+                second = pool.submit(co.submit, 2)
+                assert (first.result(10), second.result(10)) == (1, 2)
+        finally:
+            co.stop()
+        snap = global_metrics.snapshot()
+        assert snap["stg_queue_wait_count"] == 2
+        assert snap["stg_queue_wait_max_ms"] >= (hold - 0.05) * 1e3
+        assert snap["stg_wake_count"] == 2
+        assert snap["stg_wake_sum_ms"] >= 0
+        assert not [k for k in snap if k.startswith("stg_linger")]
+
+    @pytest.mark.parametrize("under_span", [True, False])
+    def test_host_span_carries_the_trace_id(self, monkeypatch,
+                                            under_span):
+        """No profiler session in tier-1 (slow under xdist): the
+        annotation is a recording stub."""
+        from tfidf_tpu.utils import tracing
+        opened = []
+
+        class Stub:
+            def __init__(self, name, **kw):
+                opened.append((name, kw))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(tracing._jprof, "TraceAnnotation", Stub)
+        if under_span:
+            with global_tracer.span("req") as sp:
+                with trace_phase("assemble"):
+                    pass
+            assert opened == [("assemble", {"trace_id": sp.trace_id})]
+            global_tracer.configure(sample_rate=0.0)
+            with global_tracer.span("unsampled"):
+                with trace_phase("assemble"):
+                    pass
+            assert opened[1] == ("assemble", {})
+        else:
+            with trace_phase("assemble"):
+                pass
+            assert opened == [("assemble", {})]
+
+    @pytest.mark.parametrize("a_build", ["v3", "v4"])
+    def test_kernel_has_a_fixed_name(self, a_build):
+        import jax
+        import jax.numpy as jnp
+
+        from tfidf_tpu.ops.ell import score_block_pallas
+        rows, width, B, u_cap = 256, 8, 4, 256
+        jaxpr = jax.make_jaxpr(
+            lambda imp, term, uniq, qc: score_block_pallas(
+                imp, term, uniq, jnp.int32(3), qc, a_build=a_build))(
+            jnp.zeros((rows, width), jnp.float32),
+            jnp.zeros((rows, width), jnp.int32),
+            jnp.zeros((u_cap,), jnp.int32),
+            jnp.zeros((B, u_cap + 1), jnp.float32))
+        assert f"ell_score_{a_build}" in str(jaxpr)

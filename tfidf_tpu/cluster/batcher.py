@@ -24,21 +24,23 @@ from collections import deque
 
 from tfidf_tpu.utils.logging import get_logger
 from tfidf_tpu.utils.metrics import global_metrics
-from tfidf_tpu.utils.tracing import current_span, global_tracer
+from tfidf_tpu.utils.tracing import (current_span, global_tracer,
+                                     trace_wait, wait_stamp)
 
 log = get_logger("cluster.batcher")
 
 
 class _Waiter:
-    __slots__ = ("query", "event", "result", "error", "t0", "key",
-                 "lane", "span")
+    __slots__ = ("query", "event", "result", "error", "t0", "t_set",
+                 "key", "lane", "span")
 
     def __init__(self, query, lane: int = 0) -> None:
         self.query = query   # the submitted item (any shape)
         self.event = threading.Event()
         self.result = None
         self.error: BaseException | None = None
-        self.t0 = 0.0   # submit time (linger accounting)
+        self.t0 = 0.0   # submit time (queue-wait accounting)
+        self.t_set = 0.0  # the dispatcher's stamp just before event.set()
         self.key = None  # group key, stamped at SUBMIT time
         self.lane = lane  # 0 = interactive, 1 = bulk (weighted dequeue)
         self.span = None  # the submitter's active trace span (if any)
@@ -49,9 +51,12 @@ class Coalescer:
     into batches handed to ``batch_fn(items) -> results`` (positional,
     same length). The leader's scatter path uses this to turn N
     concurrent ``/leader/start`` requests into ONE batched RPC per
-    worker; the per-item linger wait is recorded as the
-    ``{name}_linger`` timing so the serving-path breakdown can attribute
-    queueing delay separately from RPC time.
+    worker. Each item's wait from ``submit()`` to the start of its
+    batch's dispatch — the queue for a free dispatcher plus the linger —
+    is recorded as the ``{name}_queue_wait`` timing, and its wait from
+    the dispatcher's ``event.set()`` to the submitter running again as
+    ``{name}_wake``, so the serving-path breakdown can attribute
+    queueing and wake-up delay separately from RPC time.
 
     ``pipeline`` dispatcher threads let one batch's RPC round trip
     overlap the next batch's formation.
@@ -110,7 +115,7 @@ class Coalescer:
 
     def submit(self, item, lane: int = 0):
         w = _Waiter(item, lane=1 if lane else 0)
-        w.t0 = time.perf_counter()
+        w.t0 = wait_stamp()
         # trace linkage: the batch this item lands in runs on a
         # dispatcher thread with no request context — capture the
         # submitter's span so the dispatched batch can LINK (not
@@ -152,6 +157,8 @@ class Coalescer:
                         + ("stopped" if self._stopping
                            else "dispatchers died"))
                 break
+        if w.t_set:   # unset where stop() or a dying dispatcher woke us
+            trace_wait(f"{self.name}_wake", w.t_set)
         if w.error is not None:
             raise w.error
         return w.result
@@ -316,7 +323,7 @@ class Coalescer:
                         waited: float) -> None:
         t0 = time.perf_counter()
         for w in batch:   # queueing delay, attributed separately
-            global_metrics.observe(f"{self.name}_linger", t0 - w.t0)
+            trace_wait(f"{self.name}_queue_wait", w.t0, w.span)
         # gauge the wait that actually happened: at saturation the
         # sleep is skipped, and reporting the computed linger there
         # would misattribute latency exactly where none was added
@@ -359,6 +366,7 @@ class Coalescer:
             with self._lock:
                 self._dispatching -= 1
         for w in batch:
+            w.t_set = wait_stamp()
             w.event.set()
         global_metrics.observe(f"{self.name}_batch_total",
                                time.perf_counter() - t0)

@@ -84,7 +84,7 @@ from tfidf_tpu.utils.faults import global_injector
 from tfidf_tpu.utils.logging import get_logger
 from tfidf_tpu.utils.metrics import global_metrics
 from tfidf_tpu.utils.tracing import (global_tracer, propagation_headers,
-                                     span_event)
+                                     span_event, trace_phase)
 
 log = get_logger("cluster.node")
 
@@ -2714,6 +2714,126 @@ class _NodeHandler(_HttpHandlerBase):
         except Exception as e:
             self._fail_500(u, e)
 
+    def _serve_process_batch(self) -> None:
+        """The ``/worker/process-batch`` branch, from the body read to
+        the reply written: what ``phase_handle_batch`` times."""
+        node = self.node
+        # batched scatter RPC (leader-internal; packed reply —
+        # see cluster/wire.py). The per-query endpoint above
+        # keeps the reference-compatible JSON shape. With
+        # "names" the request is an ownership SLICE (failover /
+        # hedged re-issue): score only those documents, exact
+        # within the slice.
+        global_injector.check("worker.process")
+        # propagated scatter budget: the leader's remaining
+        # milliseconds at dispatch; a batch whose budget is
+        # already gone is refused with a 504 the resilience
+        # layer treats as non-retryable — scoring it would
+        # burn device time nobody will merge (the deadline is
+        # re-checked after the NRT commit in
+        # _search_batch_guarded)
+        if self._past_deadline():
+            return
+        deadline = self._deadline_header()
+        req = json.loads(self._body().decode("utf-8"))
+        queries = [str(q) for q in req.get("queries", ())]
+        k = req.get("k")
+        names = req.get("names")
+        # hybrid plan (wire v3): "mode" selects which scoring
+        # stages run. Absent -> sparse, so v2 leaders are
+        # untouched; a v2 WORKER ignoring the field replies n
+        # lists where the leader expects 2n and the leader's
+        # slot-count check degrades honestly (never merges a
+        # misaligned reply).
+        mode = str(req.get("mode", "sparse"))
+        # continues the leader's scatter trace (propagated
+        # headers); the engine's trace_phase events and the
+        # pipeline stage events land inside this span — and so
+        # do the REPLIES (200, 500, and the 504 deadline
+        # refusal): _send stamps X-Trace-Id from the active
+        # span, so the reply the leader logs on a failed
+        # scatter leg joins the trace (graftcheck protocol
+        # finding, fixed — replies used to be emitted after
+        # the span closed and were never stamped; the runtime
+        # protocol witness pins this)
+        with self._worker_span(
+                "worker.process_batch",
+                queries=len(queries),
+                slice=len(names) if names is not None
+                else 0):
+            try:
+                if names is not None and mode != "sparse":
+                    body = pack_hit_lists(
+                        node.worker_search_slice_staged(
+                            queries, [str(n) for n in names],
+                            mode, deadline=deadline))
+                elif names is not None:
+                    body = pack_hit_lists(
+                        node.worker_search_slice(
+                            queries, [str(n) for n in names],
+                            deadline=deadline))
+                elif mode != "sparse":
+                    body = node.worker_search_staged_wire(
+                        queries,
+                        k=int(k) if k is not None else None,
+                        mode=mode, deadline=deadline)
+                else:
+                    body = node.worker_search_batch_wire(
+                        queries,
+                        k=int(k) if k is not None else None,
+                        deadline=deadline)
+            except WorkerDeadline as e:
+                span_event("worker_deadline_refused")
+                self._send(504, f"{e}".encode(),
+                           "text/plain; charset=utf-8",
+                           headers={"X-Deadline-Exceeded": "1"})
+                return
+            except Exception as e:
+                # honest failure propagation (ADVICE r5): an
+                # engine failure must surface as a 5xx the
+                # leader counts in scatter_failures — NOT as an
+                # HTTP 200 all-empty reply it would merge as a
+                # valid zero-hit result. (The per-query
+                # /worker/process endpoint above keeps the
+                # reference's []-on-failure parity shape,
+                # Worker.java:183; this endpoint is
+                # leader-internal.) A classified compute fault
+                # rides X-Compute-Fault so the leader's retry
+                # gate and quarantine see the taxonomy instead
+                # of string-matching the repr; a poisoned
+                # output additionally names the guilty query
+                # rows (X-Poison-Fingerprints) so the
+                # quarantine never blames innocent cohort
+                # queries that merely shared the batch.
+                global_metrics.inc("worker_batch_failures")
+                span_event("worker_batch_failed",
+                           err=repr(e)[:120])
+                log.warning("batch search failed", err=repr(e))
+                eh: dict[str, str] = {}
+                fault = classify_compute_fault(e)
+                if fault is not None:
+                    eh["X-Compute-Fault"] = fault
+                    qrows = getattr(e, "queries", ())
+                    if fault == "poison" and qrows:
+                        eh["X-Poison-Fingerprints"] = ",".join(
+                            poison_fingerprint(q, mode)
+                            for q in qrows)
+                self._send(
+                    500,
+                    f"batch search failed: {e!r}".encode(),
+                    "text/plain; charset=utf-8", headers=eh)
+                return
+            # host-fallback honesty: when the engine served
+            # this batch from the numpy mirror (degraded, not
+            # wrong — scores are bit-exact), say so on the
+            # wire so the leader can surface X-Compute-Degraded
+            # end-to-end instead of silently presenting sick
+            # hardware as healthy
+            dh = ({"X-Compute-Degraded": "1"}
+                  if node.engine.pop_fallback_served() else None)
+            self._send(200, body, "application/octet-stream",
+                       headers=dh)
+
     def do_POST(self) -> None:
         u = urllib.parse.urlparse(self.path)
         node = self.node
@@ -2754,121 +2874,8 @@ class _NodeHandler(_HttpHandlerBase):
                                  "score": h.score} for h in hits],
                                headers=dh)
             elif u.path == "/worker/process-batch":
-                # batched scatter RPC (leader-internal; packed reply —
-                # see cluster/wire.py). The per-query endpoint above
-                # keeps the reference-compatible JSON shape. With
-                # "names" the request is an ownership SLICE (failover /
-                # hedged re-issue): score only those documents, exact
-                # within the slice.
-                global_injector.check("worker.process")
-                # propagated scatter budget: the leader's remaining
-                # milliseconds at dispatch; a batch whose budget is
-                # already gone is refused with a 504 the resilience
-                # layer treats as non-retryable — scoring it would
-                # burn device time nobody will merge (the deadline is
-                # re-checked after the NRT commit in
-                # _search_batch_guarded)
-                if self._past_deadline():
-                    return
-                deadline = self._deadline_header()
-                req = json.loads(self._body().decode("utf-8"))
-                queries = [str(q) for q in req.get("queries", ())]
-                k = req.get("k")
-                names = req.get("names")
-                # hybrid plan (wire v3): "mode" selects which scoring
-                # stages run. Absent -> sparse, so v2 leaders are
-                # untouched; a v2 WORKER ignoring the field replies n
-                # lists where the leader expects 2n and the leader's
-                # slot-count check degrades honestly (never merges a
-                # misaligned reply).
-                mode = str(req.get("mode", "sparse"))
-                # continues the leader's scatter trace (propagated
-                # headers); the engine's trace_phase events and the
-                # pipeline stage events land inside this span — and so
-                # do the REPLIES (200, 500, and the 504 deadline
-                # refusal): _send stamps X-Trace-Id from the active
-                # span, so the reply the leader logs on a failed
-                # scatter leg joins the trace (graftcheck protocol
-                # finding, fixed — replies used to be emitted after
-                # the span closed and were never stamped; the runtime
-                # protocol witness pins this)
-                with self._worker_span(
-                        "worker.process_batch",
-                        queries=len(queries),
-                        slice=len(names) if names is not None
-                        else 0):
-                    try:
-                        if names is not None and mode != "sparse":
-                            body = pack_hit_lists(
-                                node.worker_search_slice_staged(
-                                    queries, [str(n) for n in names],
-                                    mode, deadline=deadline))
-                        elif names is not None:
-                            body = pack_hit_lists(
-                                node.worker_search_slice(
-                                    queries, [str(n) for n in names],
-                                    deadline=deadline))
-                        elif mode != "sparse":
-                            body = node.worker_search_staged_wire(
-                                queries,
-                                k=int(k) if k is not None else None,
-                                mode=mode, deadline=deadline)
-                        else:
-                            body = node.worker_search_batch_wire(
-                                queries,
-                                k=int(k) if k is not None else None,
-                                deadline=deadline)
-                    except WorkerDeadline as e:
-                        span_event("worker_deadline_refused")
-                        self._send(504, f"{e}".encode(),
-                                   "text/plain; charset=utf-8",
-                                   headers={"X-Deadline-Exceeded": "1"})
-                        return
-                    except Exception as e:
-                        # honest failure propagation (ADVICE r5): an
-                        # engine failure must surface as a 5xx the
-                        # leader counts in scatter_failures — NOT as an
-                        # HTTP 200 all-empty reply it would merge as a
-                        # valid zero-hit result. (The per-query
-                        # /worker/process endpoint above keeps the
-                        # reference's []-on-failure parity shape,
-                        # Worker.java:183; this endpoint is
-                        # leader-internal.) A classified compute fault
-                        # rides X-Compute-Fault so the leader's retry
-                        # gate and quarantine see the taxonomy instead
-                        # of string-matching the repr; a poisoned
-                        # output additionally names the guilty query
-                        # rows (X-Poison-Fingerprints) so the
-                        # quarantine never blames innocent cohort
-                        # queries that merely shared the batch.
-                        global_metrics.inc("worker_batch_failures")
-                        span_event("worker_batch_failed",
-                                   err=repr(e)[:120])
-                        log.warning("batch search failed", err=repr(e))
-                        eh: dict[str, str] = {}
-                        fault = classify_compute_fault(e)
-                        if fault is not None:
-                            eh["X-Compute-Fault"] = fault
-                            qrows = getattr(e, "queries", ())
-                            if fault == "poison" and qrows:
-                                eh["X-Poison-Fingerprints"] = ",".join(
-                                    poison_fingerprint(q, mode)
-                                    for q in qrows)
-                        self._send(
-                            500,
-                            f"batch search failed: {e!r}".encode(),
-                            "text/plain; charset=utf-8", headers=eh)
-                        return
-                    # host-fallback honesty: when the engine served
-                    # this batch from the numpy mirror (degraded, not
-                    # wrong — scores are bit-exact), say so on the
-                    # wire so the leader can surface X-Compute-Degraded
-                    # end-to-end instead of silently presenting sick
-                    # hardware as healthy
-                    dh = ({"X-Compute-Degraded": "1"}
-                          if node.engine.pop_fallback_served() else None)
-                    self._send(200, body, "application/octet-stream",
-                               headers=dh)
+                with trace_phase("handle_batch"):
+                    self._serve_process_batch()
             elif u.path == "/worker/upload":
                 name, data = self._read_upload(u)
                 if self._fence_check():   # after the body read: the

@@ -44,13 +44,13 @@ from __future__ import annotations
 
 import atexit
 import threading
-import time
 import weakref
 from collections import deque
 from concurrent.futures import Future
 
 from tfidf_tpu.utils.metrics import global_metrics
-from tfidf_tpu.utils.tracing import current_span, global_tracer
+from tfidf_tpu.utils.tracing import (current_span, global_tracer,
+                                     trace_wait, wait_stamp)
 
 # Every live executor, stopped at interpreter exit: a daemon thread
 # reaped DURING finalization while inside XLA's C++ fetch path dies via
@@ -73,7 +73,7 @@ atexit.register(_stop_all_executors)
 
 
 class _Job:
-    __slots__ = ("dispatch", "fetch", "future", "span")
+    __slots__ = ("dispatch", "fetch", "future", "span", "since")
 
     def __init__(self, dispatch, fetch, future: Future,
                  span=None) -> None:
@@ -82,10 +82,15 @@ class _Job:
         self.future = future
         # the SUBMITTER's active trace span: the stage threads have no
         # request context of their own, so each stage re-activates this
-        # span while running — pipeline.dispatch/fetch events (and the
-        # engine's trace_phase events inside dispatch) land on the
+        # span while running — the engine's trace_phase events inside
+        # dispatch and fetch, and the two hand-off waits, land on the
         # request timeline they belong to
         self.span = span
+        # start of the hand-off wait in progress: submit() until the
+        # dispatch thread takes the job (phase_dispatch_wait), then
+        # dispatch() returning until the fetch thread takes the chunk
+        # (phase_fetch_wait: the hold for room plus the fetch queue)
+        self.since = wait_stamp()
 
 
 class PipelineExecutor:
@@ -193,13 +198,10 @@ class PipelineExecutor:
             if not job.future.set_running_or_notify_cancel():
                 continue   # cancelled (an earlier sibling failed)
             try:
-                t0 = time.perf_counter()
+                trace_wait("phase_dispatch_wait", job.since, job.span)
                 with global_tracer.activate(job.span):
                     state = job.dispatch()
-                if job.span is not None:
-                    job.span.event(
-                        "pipeline.dispatch", stage=self.name,
-                        ms=round((time.perf_counter() - t0) * 1e3, 3))
+                job.since = wait_stamp()
             except BaseException as e:
                 global_metrics.inc(f"{self.name}_dispatch_failures")
                 job.future.set_exception(e)
@@ -237,13 +239,9 @@ class PipelineExecutor:
                 job, state = self._fetch_q.popleft()
                 self._fetch_busy = 1
             try:
-                t0 = time.perf_counter()
+                trace_wait("phase_fetch_wait", job.since, job.span)
                 with global_tracer.activate(job.span):
                     job.future.set_result(job.fetch(*state))
-                if job.span is not None:
-                    job.span.event(
-                        "pipeline.fetch", stage=self.name,
-                        ms=round((time.perf_counter() - t0) * 1e3, 3))
             except BaseException as e:
                 global_metrics.inc(f"{self.name}_fetch_failures")
                 job.future.set_exception(e)

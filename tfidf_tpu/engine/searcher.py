@@ -104,6 +104,16 @@ class QueryVectorizerMixin:
         self._u_floor = max(self._u_floor, qb.uniq.shape[0])
         return qb, widest
 
+    @staticmethod
+    def _count_chunk(n_queries: int, cap: int) -> None:
+        """One dispatched chunk: its real queries and the padded bucket
+        they ride in. ``dispatch_queries / dispatch_slots`` is the share
+        of every ``[B, rows]`` fusion, copy and top-k row that holds a
+        real query."""
+        global_metrics.inc("dispatch_chunks")
+        global_metrics.inc("dispatch_queries", n_queries)
+        global_metrics.inc("dispatch_slots", cap)
+
     def _pipeline(self) -> PipelineExecutor:
         """The searcher's SHARED dispatch/fetch executor (lazy). One per
         searcher, shared by every concurrent search call: chunks from
@@ -368,6 +378,7 @@ class Searcher(QueryVectorizerMixin):
                         k: int):
         """Launch one chunk's device work; returns (packed, kk) with the
         packed top-k still ON DEVICE (not fetched)."""
+        self._count_chunk(len(queries), self._batch_cap(len(queries)))
         if isinstance(snap, SegmentedSnapshot) and snap.tier is not None \
                 and not self.tier_bypass:
             return self._dispatch_tiered(snap, queries, k)
@@ -539,8 +550,9 @@ class Searcher(QueryVectorizerMixin):
         # (fetch_packed: ONE transfer for values+ids — high-latency
         # host<->device links make per-fetch cost dominate); this runs
         # on the caller's thread and only splits views + builds hits
-        vals, ids = unpack_topk(packed)
-        return self._assemble(snap, queries, vals, ids, kk)
+        with trace_phase("assemble"):
+            vals, ids = unpack_topk(packed)
+            return self._assemble(snap, queries, vals, ids, kk)
 
     def _search_unbounded(self, snap: Snapshot,
                           queries: list[str]) -> list[list[SearchHit]]:
@@ -557,9 +569,10 @@ class Searcher(QueryVectorizerMixin):
         return self._assemble(snap, queries, vals, ids, rank_n)
 
     def _checked_unpack(self, chunk: list[str], arr):
-        vals, ids = unpack_topk(arr[:len(chunk)])
-        self._poison_check(chunk, vals)
-        return vals, ids
+        with trace_phase("assemble"):
+            vals, ids = unpack_topk(arr[:len(chunk)])
+            self._poison_check(chunk, vals)
+            return vals, ids
 
     @staticmethod
     def _poison_check(queries: list[str], vals) -> None:

@@ -417,6 +417,10 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     )
     return pl.pallas_call(
         kernel,
+        # a fixed name that carries the A-build variant: the profiler's
+        # event and the HLO instruction are named from it, not from
+        # whichever jit happens to enclose the call
+        name=f"ell_score_{a_build}",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rows_cap), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -576,14 +580,17 @@ def score_ell_impl(impacts,            # tuple of f32 [rows_cap_i, width_i]
     slot_of, qc_ext = _compile_queries(q, vocab_cap)
     qc_t = qc_ext.T                                   # [U_cap+1, B]
     u_cap = q.uniq.shape[0]
-    parts = [score_block_pallas(imp, term, q.uniq, q.n_uniq, qc_ext,
-                                block_live[i], a_build=a_build)
-             if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap,
-                                                a_build)
-             else _score_block(imp, term, slot_of, qc_t, doc_chunk)
-             for i, (imp, term) in enumerate(zip(impacts, terms))]
-    return _rearrange_to_real(parts, [imp.shape[0] for imp in impacts],
-                              block_live, doc_cap, B)
+    with jax.named_scope("ell_blocks"):
+        parts = [score_block_pallas(imp, term, q.uniq, q.n_uniq, qc_ext,
+                                    block_live[i], a_build=a_build)
+                 if use_pallas and _pallas_eligible(imp.shape[0], B,
+                                                    u_cap, a_build)
+                 else _score_block(imp, term, slot_of, qc_t, doc_chunk)
+                 for i, (imp, term) in enumerate(zip(impacts, terms))]
+    with jax.named_scope("rearrange_to_real"):
+        return _rearrange_to_real(
+            parts, [imp.shape[0] for imp in impacts], block_live,
+            doc_cap, B)
 
 
 def score_ell_with_residual(impacts, terms, block_live,
@@ -606,10 +613,11 @@ def score_ell_with_residual(impacts, terms, block_live,
                             q, vocab_cap, doc_chunk=doc_chunk,
                             use_pallas=use_pallas, a_build=a_build)
     if res_tf is not None:
-        scores = scores + score_coo_impl(
-            res_tf, res_term, res_doc, doc_len, df, q,
-            n_docs, avgdl, doc_norms, model=model, k1=k1, b=b,
-            chunk=min(res_chunk, res_tf.shape[0]))
+        with jax.named_scope("coo_residual"):
+            scores = scores + score_coo_impl(
+                res_tf, res_term, res_doc, doc_len, df, q,
+                n_docs, avgdl, doc_norms, model=model, k1=k1, b=b,
+                chunk=min(res_chunk, res_tf.shape[0]))
     return scores
 
 

@@ -16,6 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from tfidf_tpu.utils.tracing import trace_phase
+
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def exact_topk(scores: jax.Array,     # f32 [B, doc_cap]
@@ -82,15 +84,22 @@ def packed_topk(scores: jax.Array, num_docs: jax.Array,
 
 
 def fetch_packed(packed):
-    """The serving pipeline's FETCH stage: one device->host transfer of
-    the packed ``[..., 2k]`` top-k buffer, nothing else. Kept as a named
-    function so the single d2h per chunk lives in exactly one place —
-    the pipeline executor's fetch thread must do only this (hit
-    assembly/unpacking happens later, on the caller's thread, so it
-    never blocks the fetch stream)."""
+    """The serving pipeline's FETCH stage: wait for the device to finish
+    the packed ``[..., 2k]`` top-k buffer, then one device->host
+    transfer of it, nothing else. Kept as a named function so the
+    single d2h per chunk lives in exactly one place — the pipeline
+    executor's fetch thread must do only this (hit assembly/unpacking
+    happens later, on the caller's thread, so it never blocks the fetch
+    stream). Wait and copy are two stages of the one timer
+    (``device_wait``, ``d2h``): a lone ``np.asarray`` is both, unsplit.
+    A host buffer (the tiered path's) is ready already and passes
+    through both at no cost."""
     import numpy as np
 
-    return np.asarray(packed)
+    with trace_phase("device_wait"):
+        jax.block_until_ready(packed)
+    with trace_phase("d2h"):
+        return np.asarray(packed)
 
 
 def unpack_topk(packed) -> tuple:
@@ -118,32 +127,33 @@ def packed_topk_chunked(scores: jax.Array, num_docs: jax.Array,
     bounds the temporaries at O(B * chunk) and merges per-chunk winners
     (exact: the global top-k is contained in the union of chunk top-ks).
     """
-    B, doc_cap = scores.shape
-    c = min(chunk, doc_cap)
-    n = -(-doc_cap // c)        # ceil: the tail chunk is clamped, not ragged
-    if n == 1:
-        return packed_topk(scores, num_docs, k=k)
+    with jax.named_scope("topk_chunked"):
+        B, doc_cap = scores.shape
+        c = min(chunk, doc_cap)
+        n = -(-doc_cap // c)    # ceil: the tail chunk is clamped, not ragged
+        if n == 1:
+            return packed_topk(scores, num_docs, k=k)
 
-    def body(_, off):
-        # dynamic_slice, NOT a [B, n, c] reshape+transpose: that would
-        # materialize a second doc_cap-sized copy of the scores, which
-        # at 1M docs and wide batches is the difference between fitting
-        # HBM and not.
-        # The last chunk's start is clamped to doc_cap - c so every slice
-        # is full-width regardless of doc_cap % c; columns the clamp makes
-        # overlap the previous chunk (idx < off) are masked out so no doc
-        # can win twice in the merge.
-        start = jnp.minimum(off, doc_cap - c)
-        x = jax.lax.dynamic_slice_in_dim(scores, start, c, axis=1)
-        idx = jnp.arange(c, dtype=jnp.int32)[None, :] + start
-        masked = jnp.where((idx >= off) & (idx < num_docs), x, -jnp.inf)
-        v, i = jax.lax.top_k(masked, k)
-        return None, (v, i.astype(jnp.int32) + start)
+        def body(_, off):
+            # dynamic_slice, NOT a [B, n, c] reshape+transpose: that would
+            # materialize a second doc_cap-sized copy of the scores, which
+            # at 1M docs and wide batches is the difference between fitting
+            # HBM and not.
+            # The last chunk's start is clamped to doc_cap - c so every slice
+            # is full-width regardless of doc_cap % c; columns the clamp makes
+            # overlap the previous chunk (idx < off) are masked out so no doc
+            # can win twice in the merge.
+            start = jnp.minimum(off, doc_cap - c)
+            x = jax.lax.dynamic_slice_in_dim(scores, start, c, axis=1)
+            idx = jnp.arange(c, dtype=jnp.int32)[None, :] + start
+            masked = jnp.where((idx >= off) & (idx < num_docs), x, -jnp.inf)
+            v, i = jax.lax.top_k(masked, k)
+            return None, (v, i.astype(jnp.int32) + start)
 
-    offs = jnp.arange(n, dtype=jnp.int32) * c
-    _, (vals, ids) = jax.lax.scan(body, None, offs)    # [n, B, k]
-    top_vals, top_ids = merge_topk(vals, ids)
-    return pack_topk(top_vals, top_ids)
+        offs = jnp.arange(n, dtype=jnp.int32) * c
+        _, (vals, ids) = jax.lax.scan(body, None, offs)    # [n, B, k]
+        top_vals, top_ids = merge_topk(vals, ids)
+        return pack_topk(top_vals, top_ids)
 
 
 def full_ranking(scores: jax.Array, num_docs: int) -> tuple[jax.Array, jax.Array]:

@@ -55,6 +55,7 @@ from tfidf_tpu.parallel.sharded import (ShardedArrays, build_ingest_batch,
                                         make_sharded_search, with_live_mask)
 from tfidf_tpu.utils.logging import get_logger
 from tfidf_tpu.utils.metrics import global_metrics
+from tfidf_tpu.utils.tracing import trace_phase
 
 log = get_logger("parallel.mesh_index")
 
@@ -448,8 +449,9 @@ class MeshSearcher(QueryVectorizerMixin):
         cap = self._batch_cap(len(queries))
 
         def dispatch(chunk):
-            qb, _widest = self._vectorize(chunk,
-                                          self._batch_cap(len(chunk)))
+            chunk_cap = self._batch_cap(len(chunk))
+            self._count_chunk(len(chunk), chunk_cap)
+            qb, _widest = self._vectorize(chunk, chunk_cap)
             return (chunk,) + self._dispatch_chunk(snap, qb, k)
 
         from tfidf_tpu.ops.topk import fetch_packed
@@ -483,8 +485,9 @@ class MeshSearcher(QueryVectorizerMixin):
         # packed already crossed device->host in the fetch stage; this
         # runs on the caller's thread (views + hit assembly only)
         from tfidf_tpu.ops.topk import unpack_topk
-        vals, gids = unpack_topk(packed)
-        return self._assemble_hits(snap, chunk, vals, gids, kk)
+        with trace_phase("assemble"):
+            vals, gids = unpack_topk(packed)
+            return self._assemble_hits(snap, chunk, vals, gids, kk)
 
     def _search_unbounded(self, snap, queries, k):
         """Layout hook: the reference's unbounded (parity) results."""

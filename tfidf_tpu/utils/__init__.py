@@ -1,7 +1,7 @@
 from tfidf_tpu.utils.config import Config, load_config
 from tfidf_tpu.utils.logging import get_logger
 from tfidf_tpu.utils.metrics import Metrics, global_metrics
-from tfidf_tpu.utils.tracing import trace_phase, phase_timings
+from tfidf_tpu.utils.tracing import trace_phase
 from tfidf_tpu.utils.faults import FaultInjector, fault_point
 
 __all__ = [
@@ -11,7 +11,6 @@ __all__ = [
     "Metrics",
     "global_metrics",
     "trace_phase",
-    "phase_timings",
     "FaultInjector",
     "fault_point",
 ]

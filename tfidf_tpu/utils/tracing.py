@@ -37,10 +37,10 @@ ring, and never propagates headers — with ``trace_sample_rate=0`` the
 per-request cost is one object allocation and two contextvar
 operations.
 
-``trace_phase`` keeps its original contract: it records wall time into
-the global metrics and opens a ``jax.profiler.TraceAnnotation`` so
-phases show up named in TensorBoard/Perfetto captures — and now ALSO
-stamps a ``phase.<name>`` event on the active span.
+``trace_phase`` is the ONE timer of a stage: wall time into the global
+metrics, a ``jax.profiler.TraceAnnotation`` so the stage shows up named
+in a profiler capture, and a ``phase.<name>`` event on the active span.
+``trace_wait`` is its companion for a wait that crosses threads.
 """
 
 from __future__ import annotations
@@ -468,26 +468,56 @@ def render_trace_tree(spans: list[dict]) -> str:
     return "\n".join(out)
 
 
-# ---- phase timing hooks (original API, now span-aware) ----
+# ---- the stage timer: one stage, three views ----
 
 @contextlib.contextmanager
 def trace_phase(name: str) -> Iterator[None]:
+    """Time a stage that starts and ends on ONE thread, into all three
+    views at once: the windowed timing ``phase_<name>`` in
+    ``/api/metrics``, a ``phase.<name>`` event on the active Dapper
+    span, and a host span ``<name>`` in a running profiler trace (on
+    the device's clock, which is what puts idle gaps beside host work).
+    Under a sampled span the annotation carries that span's
+    ``trace_id``, so a host span in an ``.xplane.pb`` joins to
+    ``/api/trace/<id>``; the keyword is only encoded while a profiler
+    session is running."""
     t0 = time.perf_counter()
-    ann = (_jprof.TraceAnnotation(name) if _jprof is not None
-           else contextlib.nullcontext())
+    # whatever span is active: the worker's process-batch span, or a
+    # pipeline stage's activated submit-time span
+    sp = global_tracer.current()
+    if _jprof is None:
+        ann = contextlib.nullcontext()
+    elif sp is not None and sp.sampled:
+        ann = _jprof.TraceAnnotation(name, trace_id=sp.trace_id)
+    else:
+        ann = _jprof.TraceAnnotation(name)
     with ann:
         try:
             yield
         finally:
             dt = time.perf_counter() - t0
             global_metrics.observe(f"phase_{name}", dt)
-            # fold the engine phase into the request timeline: lands on
-            # whatever span is active (the worker's process-batch span,
-            # or a pipeline stage's activated submit-time span)
-            span_event(f"phase.{name}", ms=round(dt * 1e3, 3))
+            if sp is not None:   # fold the stage into the request timeline
+                sp.event(f"phase.{name}", ms=round(dt * 1e3, 3))
 
 
-def phase_timings() -> dict[str, float]:
-    """Snapshot of per-phase timing stats (phase_* keys only)."""
-    return {k: v for k, v in global_metrics.snapshot().items()
-            if k.startswith("phase_")}
+# the clock of a wait that ends on another thread: take the stamp where
+# the wait starts, hand it to trace_wait where it ends
+wait_stamp = time.perf_counter
+
+
+def trace_wait(key: str, since: float, span: Span | None = None) -> None:
+    """``trace_phase``'s companion for a WAIT that starts on one thread
+    and ends on another (a queue hand-off, an ``Event`` wake-up): call
+    it where the wait ends with the ``wait_stamp()`` taken where it
+    began. Records the timing ``key`` (the full ``/api/metrics`` key,
+    so per-instance names stay ``<name>_queue_wait``) and an event
+    named ``key`` with its first ``_`` as a ``.`` (``scatter.wake``,
+    ``phase.fetch_wait``) on ``span``, or on the active span. No
+    profiler span: a ``TraceAnnotation`` cannot cross threads."""
+    dt = time.perf_counter() - since
+    global_metrics.observe(key, dt)
+    if span is None:
+        span = global_tracer.current()
+    if span is not None:
+        span.event(key.replace("_", ".", 1), ms=round(dt * 1e3, 3))

@@ -40,7 +40,7 @@ def main():
     from tfidf_tpu.engine import Engine
     from tfidf_tpu.engine.searcher import vectorize_queries
     from tfidf_tpu.ops.ell import score_ell_with_residual
-    from tfidf_tpu.ops.topk import packed_topk, unpack_topk
+    from tfidf_tpu.ops.topk import packed_topk_chunked, unpack_topk
     from tfidf_tpu.utils.config import Config
     import jax.numpy as jnp
 
@@ -81,7 +81,7 @@ def main():
                       snap.res_tf, snap.res_term, snap.res_doc,
                       snap.doc_len, snap.df, qb, snap.n_docs, snap.avgdl,
                       snap.doc_norms, **kw)
-                s.block_until_ready()
+                jax.block_until_ready(s)     # the tuple of blocks
                 return s
 
             dt = t(scores_only, n=2)
@@ -91,12 +91,12 @@ def main():
         s = scores_only()
 
         def topk_only():
-            p = packed_topk(s, snap.num_docs, k=10)
+            p = packed_topk_chunked(s, snap.ell_live, k=10)
             p.block_until_ready()
         log(f"  topk_only: {t(topk_only, n=3)*1e3:.0f}ms")
 
         def topk_and_fetch():
-            unpack_topk(packed_topk(s, snap.num_docs, k=10))
+            unpack_topk(packed_topk_chunked(s, snap.ell_live, k=10))
         log(f"  topk+fetch: {t(topk_and_fetch, n=3)*1e3:.0f}ms")
 
         def full():

@@ -58,7 +58,7 @@ def main():
         import jax
         from tfidf_tpu.engine.searcher import vectorize_queries
         from tfidf_tpu.ops.ell import score_ell_with_residual
-        from tfidf_tpu.ops.topk import packed_topk, unpack_topk
+        from tfidf_tpu.ops.topk import packed_topk_chunked, unpack_topk
 
         kw = engine.model.score_kwargs()
         B = int(os.environ.get("PROBE_B", 512))
@@ -75,7 +75,7 @@ def main():
                    snap.res_tf, snap.res_term, snap.res_doc,
                    snap.doc_len, snap.df, qb, snap.n_docs, snap.avgdl,
                    snap.doc_norms)
-            np.asarray(s[:1, :8])
+            np.asarray(s[0][:1, :8])
             return s
 
         def timeit(f, n=3):
@@ -90,12 +90,12 @@ def main():
         s = scores_only()
 
         def topk_and_fetch():
-            unpack_topk(packed_topk(s, snap.num_docs, k=10))
+            unpack_topk(packed_topk_chunked(s, snap.ell_live, k=10))
         dt = timeit(topk_and_fetch)
         log(f"[pieces] topk+packed fetch: {dt*1e3:.0f}ms")
 
         def fetch8():
-            np.asarray(s[:1, :8])
+            np.asarray(s[0][:1, :8])
         dt = timeit(fetch8)
         log(f"[pieces] bare fetch of 8 floats: {dt*1e3:.0f}ms")
         return

@@ -10,7 +10,7 @@ interpret-mode test and were refused here).
 
 Run as a script (``tests/test_kernel_compile.py`` does, in a subprocess:
 the compile-only client is process-global state); prints one JSON line
-``{"failures": [...], "compiled": N}``.
+``{"failures": [...], "compiled": N, "cells": M}``.
 """
 
 from __future__ import annotations
@@ -105,6 +105,56 @@ def compile_mesh_step(devices) -> None:
                              packed=True).lower(*args).compile()
 
 
+# The benchmark cells' committed snapshots: (rows_cap, width) of every
+# ELL block and the document capacity, computed from each
+# configuration's corpus (benchmarks/configs/*.json through
+# benchmarks/lib/data.make_corpus, the ladder and next_capacity: live
+# rows 3310 / 1047691 / 870316 / 78448 / 233 / 2 and 1 / 347971 /
+# 631529 / 20498 / 1), with the batch buckets the cells dispatch.
+CELL_STEPS = {
+    "msmarco2m": (((4096, 64), (1048576, 48), (1048576, 32),
+                   (131072, 24), (256, 16), (256, 12)),
+                  1 << 21, (128, 256, 512)),
+    "wiki1m": (((256, 128), (524288, 96), (1048576, 64), (32768, 48),
+                (256, 32)), 1 << 20, (512,)),
+}
+
+
+def compile_cell_step(dev, blocks, doc_cap: int, B: int) -> None:
+    """The served device step at a cell's real shapes: the scoring
+    program, then the top-k over the blocks it returns. Neither may
+    hold a ``[B, padded rows + 1]`` or ``[B, doc_cap]`` f32 array — the
+    score space exists once, where the kernel wrote it."""
+    from tfidf_tpu.ops.scoring import QueryBatch
+    from tfidf_tpu.ops.topk import packed_topk_chunked
+    sh = SingleDeviceSharding(dev)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    f32, i32 = jnp.float32, jnp.int32
+    q = QueryBatch(uniq=s((1024,), i32), n_uniq=s((), i32),
+                   slots=s((B, 32), i32), weights=s((B, 32), f32))
+    live = s((len(blocks),), i32)
+    score = ell._score_ell_batch_jit.lower(
+        tuple(s(b, f32) for b in blocks), tuple(s(b, i32) for b in blocks),
+        live, None, None, None, s((doc_cap,), f32), s((1 << 19,), f32), q,
+        s((), f32), s((), f32), s((doc_cap,), f32),
+        model="bm25", use_pallas=True, a_build="v4").compile()
+    topk = packed_topk_chunked.lower(
+        tuple(s((B, rows), f32) for rows, _ in blocks), live,
+        k=10).compile()
+    assert score.as_text().count("tpu_custom_call") >= 4
+    rows = [r for r, _ in blocks]
+    gone = [f"f32[{B},{sum(rows) + 1}]", f"f32[{doc_cap},{B}]"]
+    if doc_cap not in rows:     # wiki1m: a block is as wide as doc_cap
+        gone.append(f"f32[{B},{doc_cap}]")
+    for program in (score, topk):
+        text = program.as_text()
+        for shape in gone:
+            assert shape not in text, f"{shape} is back in the step"
+
+
 def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -130,7 +180,17 @@ def main() -> int:
     except Exception as e:
         failures.append(f"mesh (4,1) step: {type(e).__name__}: "
                         f"{str(e)[:600]}")
-    print(json.dumps({"failures": failures, "compiled": compiled}))
+    cells = 0
+    for name, (blocks, doc_cap, batches) in CELL_STEPS.items():
+        for B in batches:
+            try:
+                compile_cell_step(topo.devices[0], blocks, doc_cap, B)
+                cells += 1
+            except Exception as e:
+                failures.append(f"cell {name} B={B}: {type(e).__name__}: "
+                                f"{str(e)[:600]}")
+    print(json.dumps({"failures": failures, "compiled": compiled,
+                      "cells": cells}))
     return 1 if failures else 0
 
 

@@ -523,6 +523,37 @@ class TestClusterEndToEnd:
         snap = json.loads(http_get(leader.url + "/api/metrics"))
         assert snap.get("uploads_placed", 0) >= 1
 
+    def test_topk_chunk_counters_exposed(self, cluster):
+        """Every dispatched chunk adds its snapshot's padded top-k chunk
+        count to ``topk_chunks`` (and the dead ones among them to
+        ``topk_chunks_skipped``): read from a worker's /api/metrics."""
+        from tfidf_tpu.ops.topk import topk_chunk_counts
+        leader, workers = cluster[0], cluster[1:]
+        for i in range(12):     # three widths: several blocks a worker
+            words = " ".join(f"w{i}x{j}" for j in range((2, 10, 20)[i % 3]))
+            http_post(leader.url + f"/leader/upload?name=t{i:02d}.txt",
+                      f"chunky {words}".encode(),
+                      content_type="application/octet-stream")
+        http_post(leader.url + "/leader/start", b"chunky")   # commits
+        before = json.loads(http_get(workers[0].url + "/api/metrics"))
+        hits = json.loads(http_post(leader.url + "/leader/start",
+                                    b"chunky w0x0"))   # not the cached one
+        after = json.loads(http_get(workers[0].url + "/api/metrics"))
+        assert len(hits) == 10
+        want = [0, 0]
+        for w in workers:       # one process: the workers share counters
+            snap = w.engine.index.snapshot
+            assert len(snap.ell_impacts) >= 2
+            chunks, skipped = topk_chunk_counts(
+                [imp.shape[0] for imp in snap.ell_impacts],
+                snap.ell_live_host)
+            want[0] += chunks
+            want[1] += skipped
+        assert after["dispatch_chunks"] - before["dispatch_chunks"] == 2
+        assert after["topk_chunks"] - before["topk_chunks"] == want[0]
+        assert after["topk_chunks_skipped"] \
+            - before["topk_chunks_skipped"] == want[1]
+
 
 class TestBoundedClusterSearch:
     """r2: /worker/process serves exact top-k by default; the reference's

@@ -188,24 +188,35 @@ class TestDeviceNemesis:
         with pytest.raises(DeviceOOMError):
             n.check("score_ell", batch=8)
 
-    def test_poison_rule_and_row_targeting(self):
+    @pytest.mark.parametrize("widths", [(4,), (4, 2, 8)],
+                             ids=["matrix", "ell_blocks"])
+    def test_poison_rule_and_row_targeting(self, widths):
+        """One ``[B, n]`` matrix, or the ELL scorer's tuple of blocks:
+        the same query rows turn NaN in every block."""
         import jax.numpy as jnp
 
         from tfidf_tpu.utils.device_nemesis import poison_scores
         n = DeviceNemesis(env="score_ell:poison:1.0:min_uniq=2")
         rule = n.check("score_ell")
         assert rule is not None and rule.kind == "poison"
-        scores = jnp.ones((3, 4), jnp.float32)
+        scores = tuple(jnp.ones((3, w), jnp.float32) for w in widths)
+        if len(scores) == 1:
+            scores = scores[0]
         weights = jnp.asarray([[1.0, 1.0, 0.0],    # 2 uniq -> poisoned
                                [1.0, 0.0, 0.0],    # 1 uniq -> intact
                                [1.0, 2.0, 3.0]],   # 3 uniq -> poisoned
                               jnp.float32)
-        out = np.asarray(poison_scores(scores, weights, rule.min_uniq))
+
+        def flat(out):      # blocks side by side: [3, sum(widths)]
+            return np.concatenate(
+                [np.asarray(o) for o in
+                 (out if isinstance(out, tuple) else (out,))], axis=1)
+        out = flat(poison_scores(scores, weights, rule.min_uniq))
+        assert out.shape == (3, sum(widths))
         assert np.isnan(out[0]).all() and np.isnan(out[2]).all()
         assert (out[1] == 1.0).all()
         # min_uniq=0 poisons everything
-        out0 = np.asarray(poison_scores(scores, weights, 0))
-        assert np.isnan(out0).all()
+        assert np.isnan(flat(poison_scores(scores, weights, 0))).all()
 
     def test_fire_emits_metric(self):
         before = global_metrics.snapshot().get("device_nemesis_fired", 0)

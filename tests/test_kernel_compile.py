@@ -4,7 +4,8 @@ Runs ``tests/kernel_compile_worker.py`` (compile-only Mosaic through
 libtpu's topology client — no chip, seconds) in a subprocess: both
 A-build variants x batch buckets on every step of the tile schedule x
 widths from the ELL ladder incl. 12, a mesh-split width of 1 and an odd
-one, plus the (4, 1) ``make_mesh_ell_search`` program. Interpret-mode
+one, plus the (4, 1) ``make_mesh_ell_search`` program and the served
+device step at the benchmark cells' shapes. Interpret-mode
 parity (``tests/test_kernel_parity.py``) cannot see what this sees: a
 kernel the interpreter runs happily and Mosaic rejects.
 """
@@ -18,10 +19,10 @@ import sys
 import pytest
 
 
-@pytest.mark.skipif(importlib.util.find_spec("libtpu") is None,
-                    reason="libtpu (the compile-only TPU client) is "
-                           "not installed")
-def test_every_eligible_shape_compiles_for_v5e():
+@pytest.fixture(scope="module")
+def report():
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu (the compile-only TPU client) is not installed")
     worker = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "kernel_compile_worker.py")
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
@@ -30,7 +31,25 @@ def test_every_eligible_shape_compiles_for_v5e():
     lines = p.stdout.strip().splitlines()
     assert lines, f"no report (rc={p.returncode}):\n{p.stderr[-2000:]}"
     report = json.loads(lines[-1])
-    assert not report["failures"], "\n".join(report["failures"])
-    assert p.returncode == 0
+    assert p.returncode == (1 if report["failures"] else 0)
+    return report
+
+
+def _failures(report, of_cells: bool) -> str:
+    return "\n".join(f for f in report["failures"]
+                     if f.startswith("cell ") == of_cells)
+
+
+def test_every_eligible_shape_compiles_for_v5e(report):
+    assert not _failures(report, of_cells=False)
     # a run that compiled nothing proves nothing
     assert report["compiled"] >= 70, report
+
+
+def test_cells_device_step_compiles_for_v5e(report):
+    """The scoring program and the top-k over its blocks, at the block
+    lists of the benchmark's two corpora and the batch buckets its cells
+    dispatch (``CELL_STEPS`` in the worker) — and neither program holds
+    a second copy of the score space."""
+    assert not _failures(report, of_cells=True)
+    assert report["cells"] == 4, report

@@ -118,6 +118,9 @@ class Snapshot:
     # live rows per block — TRACED so commits within the same capacity
     # buckets never retrace the query path
     ell_live: jax.Array | None = None     # i32 [n_blocks]
+    # the same counts as host integers (both set by _ell_live_fields):
+    # the per-dispatch top-k chunk counters read these, never the device
+    ell_live_host: tuple = ()
     res_tf: jax.Array | None = None       # f32 [res_cap] (None: no spill)
     res_term: jax.Array | None = None     # i32 [res_cap]
     res_doc: jax.Array | None = None      # i32 [res_cap]
@@ -142,8 +145,16 @@ jax.tree_util.register_dataclass(
     data_fields=["tf", "term", "doc", "doc_len", "df", "doc_norms",
                  "n_docs", "avgdl", "num_docs", "ell_impacts", "ell_terms",
                  "ell_live", "res_tf", "res_term", "res_doc"],
-    meta_fields=["doc_names", "version", "nnz", "host_coo"],
+    meta_fields=["doc_names", "version", "nnz", "host_coo",
+                 "ell_live_host"],
 )
+
+
+def _ell_live_fields(live) -> dict:
+    """A snapshot's per-block live row counts, device and host copy."""
+    live = np.asarray(live, np.int32)
+    return dict(ell_live=jnp.asarray(live),
+                ell_live_host=tuple(int(n) for n in live))
 
 
 class ShardIndex:
@@ -409,7 +420,7 @@ class ShardIndex:
             tf = term = doc = None
             ell_kw: dict = dict(
                 ell_impacts=tuple(impacts), ell_terms=tuple(terms),
-                ell_live=jnp.asarray(np.asarray(live, np.int32)))
+                **_ell_live_fields(live))
             if ell.res_nnz:   # no spill -> no residual scoring pass at all
                 ell_kw.update(
                     res_tf=jnp.asarray(ell.res_tf),
@@ -515,7 +526,7 @@ class ShardIndex:
                                   for i in range(nb)),
                 ell_terms=tuple(jnp.asarray(data[f"ell_term_{i}"])
                                 for i in range(nb)),
-                ell_live=jnp.asarray(data["ell_live"]))
+                **_ell_live_fields(data["ell_live"]))
             if "res_tf" in data:
                 ell_kw.update(res_tf=jnp.asarray(data["res_tf"]),
                               res_term=jnp.asarray(data["res_term"]),

@@ -27,12 +27,13 @@ from tfidf_tpu.models.base import ScoringModel
 from tfidf_tpu.ops.analyzer import Analyzer
 from tfidf_tpu.ops.blockmax import query_upper_bounds, skip_mask
 from tfidf_tpu.ops.csr import next_capacity
-from tfidf_tpu.ops.ell import (_pallas_eligible, score_ell_batch,
-                               score_segments_batch)
+from tfidf_tpu.ops.ell import (_pallas_eligible, ell_scores_to_real,
+                               score_ell_batch, score_segments_batch)
 from tfidf_tpu.ops.scoring import (QueryBatch, make_query_batch,
                                    score_coo_batch)
 from tfidf_tpu.ops.topk import (fetch_packed, full_ranking, packed_topk,
-                                packed_topk_chunked, unpack_topk)
+                                packed_topk_chunked, topk_chunk_counts,
+                                unpack_topk)
 from tfidf_tpu.utils.metrics import global_metrics
 from tfidf_tpu.utils.tracing import trace_phase
 
@@ -337,6 +338,11 @@ class Searcher(QueryVectorizerMixin):
                 for imp in snap.ell_impacts]
 
     def _score_chunk(self, snap: Snapshot, queries: list[str]):
+        """``(blocks, live, live_host)``: the chunk's scores as the
+        tuple of ``[B, cap_i]`` blocks ``packed_topk_chunked`` takes (the
+        ELL layout's own; one block for the others), the blocks' live
+        column counts on the device, and the same counts as the host
+        integers the commit had."""
         cap = self._batch_cap(len(queries))
         with trace_phase("vectorize"):
             qb, _widest = self._vectorize(queries, cap)
@@ -351,10 +357,12 @@ class Searcher(QueryVectorizerMixin):
                 scores = score_segments_batch(
                     views, snap.df, qb, snap.n_docs, snap.avgdl,
                     **self.model.score_kwargs())
-            elif snap.is_ell:
+                # the whole padded space is live: pads score 0
+                return (scores,), snap.num_docs, (scores.shape[1],)
+            if snap.is_ell:
                 # gather fast path: impacts precomputed at commit;
                 # big blocks ride the fused compare/MXU Pallas kernel
-                scores = score_ell_batch(
+                blocks = score_ell_batch(
                     snap.ell_impacts, snap.ell_terms, snap.ell_live,
                     snap.res_tf, snap.res_term, snap.res_doc,
                     snap.doc_len, snap.df, qb,
@@ -362,12 +370,12 @@ class Searcher(QueryVectorizerMixin):
                     use_pallas=self.use_pallas,
                     a_build=self.kernel_a_build,
                     **self.model.score_kwargs())
-            else:
-                scores = score_coo_batch(
-                    snap.tf, snap.term, snap.doc, snap.doc_len, snap.df,
-                    qb, snap.n_docs, snap.avgdl, snap.doc_norms,
-                    **self.model.score_kwargs())
-        return scores
+                return blocks, snap.ell_live, snap.ell_live_host
+            scores = score_coo_batch(
+                snap.tf, snap.term, snap.doc, snap.doc_len, snap.df,
+                qb, snap.n_docs, snap.avgdl, snap.doc_norms,
+                **self.model.score_kwargs())
+            return (scores,), snap.num_docs, (snap.num_names,)
 
     # oracle switch: True forces tiered snapshots through the untiered
     # scoring path (every segment faulted + scored) — the parity
@@ -382,10 +390,16 @@ class Searcher(QueryVectorizerMixin):
         if isinstance(snap, SegmentedSnapshot) and snap.tier is not None \
                 and not self.tier_bypass:
             return self._dispatch_tiered(snap, queries, k)
-        scores = self._score_chunk(snap, queries)
+        blocks, live, live_host = self._score_chunk(snap, queries)
         with trace_phase("topk"):
             kk = min(k, snap.num_names)
-            return packed_topk_chunked(scores, snap.num_docs, k=kk), kk
+            # the top-k's chunks over this dispatch's padded score space,
+            # and those of them wholly in dead tails, which it skips
+            chunks, skipped = topk_chunk_counts(
+                [blk.shape[1] for blk in blocks], live_host)
+            global_metrics.inc("topk_chunks", chunks)
+            global_metrics.inc("topk_chunks_skipped", skipped)
+            return packed_topk_chunked(blocks, live, k=kk), kk
 
     def _dispatch_tiered(self, snap: SegmentedSnapshot,
                          queries: list[str], k: int):
@@ -556,9 +570,14 @@ class Searcher(QueryVectorizerMixin):
 
     def _search_unbounded(self, snap: Snapshot,
                           queries: list[str]) -> list[list[SearchHit]]:
-        scores = self._score_chunk(snap, queries)
+        blocks, live, _ = self._score_chunk(snap, queries)
         segmented = isinstance(snap, SegmentedSnapshot)
         with trace_phase("rank_all"):
+            # ELL blocks -> document order; the other layouts' one
+            # block is in it already
+            scores = (blocks[0] if segmented or not snap.is_ell
+                      else ell_scores_to_real(blocks, live,
+                                              snap.doc_len.shape[0]))
             # segmented doc ids interleave padding, so rank the whole
             # padded space (pads score 0 and are filtered below)
             rank_n = (scores.shape[-1] if segmented

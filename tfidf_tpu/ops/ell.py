@@ -140,6 +140,12 @@ def build_ell_from_coo(coo: CooShard,
         res_tf[:res_nnz] = coo.tf[:nnz][spill]
         res_term[:res_nnz] = coo.term[:nnz][spill]
         res_doc[:res_nnz] = doc_ids[spill]
+        # rows are sorted by length descending and only rows longer than
+        # the top rung spill, so every spilled row lies in block 0, where
+        # local column == real row: score_ell_with_residual adds the
+        # residual to that block alone
+        assert int(res_doc[:res_nnz].max()) < blocks[0].n_rows, \
+            "COO residual rows must all lie in ELL block 0"
     return EllShard(blocks=blocks, res_tf=res_tf, res_term=res_term,
                     res_doc=res_doc, res_nnz=res_nnz)
 
@@ -275,8 +281,8 @@ def _pallas_kernel(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
 
     # tiles wholly past the live unique terms (zero qc columns) or past
     # the block's live rows (all-pad postings; power-of-two row caps
-    # leave up to 2x dead rows, and their scores are never gathered by
-    # _rearrange_to_real) contribute nothing — skip them
+    # leave up to 2x dead rows, which the top-k masks and
+    # _rearrange_to_real never gathers) contribute nothing — skip them
     @pl.when(jnp.logical_and(u * tu < lims_ref[0],
                              d * td < lims_ref[1]))
     def _tile():
@@ -560,37 +566,37 @@ def _rearrange_to_real(parts, block_caps, block_live, doc_cap: int,
 def score_ell_impl(impacts,            # tuple of f32 [rows_cap_i, width_i]
                    terms,              # tuple of i32 [rows_cap_i, width_i]
                    block_live,         # i32 [n_blocks] — live rows (TRACED)
-                   doc_cap: int,
                    q: QueryBatch,
                    vocab_cap: int,
                    *, doc_chunk: int = 2048,
                    use_pallas: bool = False,
-                   a_build: str = "v3") -> jax.Array:
-    """Gather-based scoring over all blocks: ``scores [B, doc_cap]``.
+                   a_build: str = "v3") -> tuple:
+    """Gather-based scoring over all blocks: a tuple of per-block scores
+    ``[B, rows_cap_i]``, each in its block's padded row space.
 
-    Blocks are scored in their padded row space ``[B, sum(rows_cap_i)]``
-    and rearranged into the shard's real doc-id space with a device
-    gather. Live row counts are TRACED, so growing the corpus within the
-    same capacity buckets reuses the executable — only the (static) block
-    shapes key the compile cache. ``use_pallas`` routes big blocks
-    through the fused compare/MXU kernel; the rest stay on the XLA path.
-    ``a_build`` picks the kernel's A-build variant.
+    Real doc id d lives in block i at column ``d - row0_i``, row0_i the
+    sum of the live counts before block i; columns at or past
+    ``block_live[i]`` are dead. The top-k reads the blocks in place
+    (``ops.topk.packed_topk_chunked``); :func:`ell_scores_to_real` builds
+    the ``[B, doc_cap]`` matrix for callers off the hot path. Live row
+    counts are TRACED, so growing the corpus within the same capacity
+    buckets reuses the executable — only the (static) block shapes key
+    the compile cache. ``use_pallas`` routes big blocks through the
+    fused compare/MXU kernel; the rest stay on the XLA path. ``a_build``
+    picks the kernel's A-build variant.
     """
     B = q.slots.shape[0]
     slot_of, qc_ext = _compile_queries(q, vocab_cap)
     qc_t = qc_ext.T                                   # [U_cap+1, B]
     u_cap = q.uniq.shape[0]
     with jax.named_scope("ell_blocks"):
-        parts = [score_block_pallas(imp, term, q.uniq, q.n_uniq, qc_ext,
-                                    block_live[i], a_build=a_build)
-                 if use_pallas and _pallas_eligible(imp.shape[0], B,
-                                                    u_cap, a_build)
-                 else _score_block(imp, term, slot_of, qc_t, doc_chunk)
-                 for i, (imp, term) in enumerate(zip(impacts, terms))]
-    with jax.named_scope("rearrange_to_real"):
-        return _rearrange_to_real(
-            parts, [imp.shape[0] for imp in impacts], block_live,
-            doc_cap, B)
+        return tuple(
+            score_block_pallas(imp, term, q.uniq, q.n_uniq, qc_ext,
+                               block_live[i], a_build=a_build)
+            if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap,
+                                               a_build)
+            else _score_block(imp, term, slot_of, qc_t, doc_chunk)
+            for i, (imp, term) in enumerate(zip(impacts, terms)))
 
 
 def score_ell_with_residual(impacts, terms, block_live,
@@ -601,24 +607,38 @@ def score_ell_with_residual(impacts, terms, block_live,
                             b: float = 0.75, doc_chunk: int = 2048,
                             res_chunk: int = 1 << 10,
                             use_pallas: bool = False,
-                            a_build: str = "v3") -> jax.Array:
-    """Full shard scores: blocked ELL + COO residual (overlong docs).
+                            a_build: str = "v3") -> tuple:
+    """Full shard scores, per block (see :func:`score_ell_impl`): blocked
+    ELL + COO residual (overlong docs).
 
     Pass ``res_tf=None`` when nothing spilled — the residual pass is
-    skipped entirely instead of scanning guaranteed-zero padding.
+    skipped entirely instead of scanning guaranteed-zero padding. Every
+    spilled row lies in block 0 (``build_ell_from_coo`` asserts it),
+    whose columns ARE real rows, so the residual adds to that block
+    alone.
     """
-    doc_cap = doc_len.shape[0]
-    vocab_cap = df.shape[0]
-    scores = score_ell_impl(impacts, terms, block_live, doc_cap,
-                            q, vocab_cap, doc_chunk=doc_chunk,
-                            use_pallas=use_pallas, a_build=a_build)
+    parts = score_ell_impl(impacts, terms, block_live, q, df.shape[0],
+                           doc_chunk=doc_chunk, use_pallas=use_pallas,
+                           a_build=a_build)
     if res_tf is not None:
         with jax.named_scope("coo_residual"):
-            scores = scores + score_coo_impl(
+            residual = score_coo_impl(
                 res_tf, res_term, res_doc, doc_len, df, q,
                 n_docs, avgdl, doc_norms, model=model, k1=k1, b=b,
-                chunk=min(res_chunk, res_tf.shape[0]))
-    return scores
+                chunk=min(res_chunk, res_tf.shape[0]))    # [B, doc_cap]
+            rows_cap0 = parts[0].shape[1]
+            assert rows_cap0 <= residual.shape[1], (rows_cap0,
+                                                    residual.shape)
+            parts = (parts[0] + residual[:, :rows_cap0],) + parts[1:]
+    return parts
+
+
+def ell_scores_to_real(parts, block_live, doc_cap: int) -> jax.Array:
+    """The ``[B, doc_cap]`` matrix in real doc-id order from per-block
+    scores — for parity mode, probes and tests; the serving path never
+    builds it."""
+    return _rearrange_to_real(list(parts), [p.shape[1] for p in parts],
+                              block_live, doc_cap, parts[0].shape[0])
 
 
 _score_ell_batch_jit = jax.jit(
@@ -629,12 +649,12 @@ _score_ell_batch_jit = jax.jit(
 
 def score_ell_batch(impacts, terms, block_live, res_tf, res_term,
                     res_doc, doc_len, df, q: QueryBatch, n_docs, avgdl,
-                    doc_norms=None, **kw) -> jax.Array:
+                    doc_norms=None, **kw) -> tuple:
     """The ELL dispatch seam: the jitted scorer behind the device
     nemesis guard (``device.score_ell``). Unarmed cost is one attribute
     read; under an armed nemesis this is where injected OOM / compile /
     transient / sick faults surface and where a fired poison rule's NaN
-    rows enter the output buffer (on device — detection happens at the
+    rows enter the output blocks (on device — detection happens at the
     fetch seam)."""
     from tfidf_tpu.utils.device_nemesis import device_guard, poison_scores
     rule = device_guard("score_ell", batch=int(q.slots.shape[0]),

@@ -116,44 +116,117 @@ def unpack_topk(packed) -> tuple:
     return vals, ids
 
 
-@functools.partial(jax.jit, static_argnames=("k", "chunk"))
-def packed_topk_chunked(scores: jax.Array, num_docs: jax.Array,
-                        *, k: int, chunk: int = 1 << 17) -> jax.Array:
-    """:func:`packed_topk` with the doc axis scanned in chunks.
+TOPK_CHUNK = 1 << 17    # doc columns one ``lax.top_k`` call sees
 
-    ``lax.top_k`` over a [B, doc_cap] matrix allocates value+index
-    temporaries proportional to the whole input — at 1M docs and B≥1024
-    that (with the scores themselves) exceeds HBM. Scanning doc chunks
-    bounds the temporaries at O(B * chunk) and merges per-chunk winners
-    (exact: the global top-k is contained in the union of chunk top-ks).
+
+def _chunk_starts(cap: int, chunk: int) -> tuple[int, list[int]]:
+    """``(width, nominal chunk starts)`` of a ``cap``-column block. The
+    device scan and :func:`topk_chunk_counts` share it, so the host's
+    count of skipped chunks is the device's."""
+    c = min(chunk, cap)
+    return c, [j * c for j in range(-(-cap // c))]   # ceil: tail is clamped
+
+
+def topk_chunk_counts(block_caps, block_live,
+                      chunk: int = TOPK_CHUNK) -> tuple[int, int]:
+    """``(chunks, skipped)`` of one :func:`packed_topk_chunked` call over
+    blocks of ``block_caps`` columns holding ``block_live`` live ones —
+    host integers only (what a commit already knows), no device read.
+    A chunk is skipped when it starts at or past its block's live
+    count."""
+    total = skipped = 0
+    for cap, live in zip(block_caps, block_live):
+        starts = _chunk_starts(int(cap), chunk)[1]
+        total += len(starts)
+        skipped += sum(off >= int(live) for off in starts)
+    return total, skipped
+
+
+def _block_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
+                live: jax.Array,   # i32 scalar — its live columns (TRACED)
+                *, k: int, chunk: int) -> tuple[jax.Array, jax.Array]:
+    """Per-chunk winners of one block: ``[n, B, k]`` values and their
+    columns IN THE BLOCK, columns at or past ``live`` masked to -inf."""
+    B, cap = x.shape
+    c, starts = _chunk_starts(cap, chunk)
+    kc = min(k, c)
+
+    def scan_chunk(off):
+        # dynamic_slice, NOT a [B, n, c] reshape+transpose: that would
+        # materialize a second copy of the block, which at 1M docs and
+        # wide batches is the difference between fitting HBM and not.
+        # The last chunk's start is clamped to cap - c so every slice is
+        # full-width regardless of cap % c; columns the clamp makes
+        # overlap the previous chunk (idx < off) are masked out so no doc
+        # can win twice in the merge.
+        start = jnp.minimum(off, cap - c)
+        xc = jax.lax.dynamic_slice_in_dim(x, start, c, axis=1)
+        idx = jnp.arange(c, dtype=jnp.int32)[None, :] + start
+        masked = jnp.where((idx >= off) & (idx < live), xc, -jnp.inf)
+        v, i = jax.lax.top_k(masked, kc)
+        return v, i.astype(jnp.int32) + start
+
+    def dead_chunk(off):
+        # what scan_chunk yields when every column is masked: -inf at
+        # the chunk's first kc columns
+        first = jnp.arange(kc, dtype=jnp.int32) + jnp.minimum(off, cap - c)
+        return (jnp.full((B, kc), -jnp.inf, x.dtype),
+                jnp.broadcast_to(first[None, :], (B, kc)))
+
+    def one_chunk(off):
+        return jax.lax.cond(off < live, scan_chunk, dead_chunk, off)
+
+    if len(starts) == 1:
+        vals, ids = (a[None] for a in one_chunk(jnp.int32(0)))
+    else:
+        _, (vals, ids) = jax.lax.scan(
+            lambda _, off: (None, one_chunk(off)), None,
+            jnp.asarray(starts, jnp.int32))
+    if kc < k:   # a block narrower than k: the pad lanes never win
+        pad = ((0, 0), (0, 0), (0, k - kc))
+        vals = jnp.pad(vals, pad, constant_values=-jnp.inf)
+        ids = jnp.pad(ids, pad)
+    return vals, ids
+
+
+@functools.partial(jax.jit, static_argnames=("k", "chunk"))
+def packed_topk_chunked(scores, num_docs: jax.Array,
+                        *, k: int, chunk: int = TOPK_CHUNK) -> jax.Array:
+    """:func:`packed_topk` over score BLOCKS, read where the scorer wrote
+    them, the doc axis scanned in chunks.
+
+    ``scores`` is a tuple of ``[B, cap_i]`` blocks and ``num_docs`` their
+    ``[n_blocks]`` live counts (traced): block ``i`` holds real rows
+    ``row0_i .. row0_i + live_i`` (``row0`` the running sum of the live
+    counts) in its first ``live_i`` columns. Its dead tail is masked to
+    -inf and a winner's id is ``row0_i + column``, so no ``[B, doc_cap]``
+    matrix in document order is ever built. One ``[B, n]`` array with a
+    scalar ``num_docs`` is the one-block case. Block-then-column order
+    IS real-row order, ``lax.top_k`` breaks ties toward the lower column
+    and :func:`merge_topk` toward the earlier chunk, so ties resolve to
+    the lower document id whatever the blocking.
+
+    ``lax.top_k`` over a whole [B, doc_cap] row allocates value+index
+    temporaries proportional to its input — at 1M docs and B>=1024 that
+    (with the scores themselves) exceeds HBM. Chunks bound the
+    temporaries at O(B * chunk); per-chunk winners merge exactly (the
+    global top-k is contained in the union of chunk top-ks). A chunk
+    that starts at or past its block's live count is SKIPPED (the padded
+    space is up to 1.5x the live one; :func:`topk_chunk_counts`).
     """
     with jax.named_scope("topk_chunked"):
-        B, doc_cap = scores.shape
-        c = min(chunk, doc_cap)
-        n = -(-doc_cap // c)    # ceil: the tail chunk is clamped, not ragged
-        if n == 1:
-            return packed_topk(scores, num_docs, k=k)
-
-        def body(_, off):
-            # dynamic_slice, NOT a [B, n, c] reshape+transpose: that would
-            # materialize a second doc_cap-sized copy of the scores, which
-            # at 1M docs and wide batches is the difference between fitting
-            # HBM and not.
-            # The last chunk's start is clamped to doc_cap - c so every slice
-            # is full-width regardless of doc_cap % c; columns the clamp makes
-            # overlap the previous chunk (idx < off) are masked out so no doc
-            # can win twice in the merge.
-            start = jnp.minimum(off, doc_cap - c)
-            x = jax.lax.dynamic_slice_in_dim(scores, start, c, axis=1)
-            idx = jnp.arange(c, dtype=jnp.int32)[None, :] + start
-            masked = jnp.where((idx >= off) & (idx < num_docs), x, -jnp.inf)
-            v, i = jax.lax.top_k(masked, k)
-            return None, (v, i.astype(jnp.int32) + start)
-
-        offs = jnp.arange(n, dtype=jnp.int32) * c
-        _, (vals, ids) = jax.lax.scan(body, None, offs)    # [n, B, k]
-        top_vals, top_ids = merge_topk(vals, ids)
-        return pack_topk(top_vals, top_ids)
+        blocks = scores if isinstance(scores, (tuple, list)) else (scores,)
+        lives = jnp.reshape(num_docs, (-1,)).astype(jnp.int32)
+        row0s = jnp.cumsum(lives) - lives
+        vals, ids = [], []
+        for i, x in enumerate(blocks):
+            v, local = _block_topk(x, lives[i], k=k, chunk=chunk)
+            vals.append(v)
+            ids.append(local + row0s[i])
+        vals, ids = jnp.concatenate(vals), jnp.concatenate(ids)
+        if vals.shape[0] > 1:       # [n_chunks, B, k], real-row order
+            return pack_topk(*merge_topk(vals, ids))
+        return pack_topk(vals[0], ids[0])
 
 
 def full_ranking(scores: jax.Array, num_docs: int) -> tuple[jax.Array, jax.Array]:

@@ -294,14 +294,18 @@ def device_guard(site: str, *, batch: int = 0,
 
 def poison_scores(scores, weights, min_uniq: int):
     """Corrupt a fired poison rule's target rows with NaN — entirely ON
-    DEVICE (a ``jnp.where`` over the score matrix), so the injection
-    itself never adds a host<->device transfer the device witness would
-    have to explain. Rows with at least ``min_uniq`` nonzero term
-    weights are poisoned (``min_uniq`` 0 poisons every row), modelling
-    a query shape that deterministically breaks the kernel while its
-    batch cohort scores fine."""
+    DEVICE (a ``jnp.where`` over the score matrix, or over each block
+    of the ELL scorer's tuple), so the injection itself never adds a
+    host<->device transfer the device witness would have to explain.
+    Rows with at least ``min_uniq`` nonzero term weights are poisoned
+    (``min_uniq`` 0 poisons every row), modelling a query shape that
+    deterministically breaks the kernel while its batch cohort scores
+    fine."""
+    import jax
     import jax.numpy as jnp
     if min_uniq <= 0:
-        return jnp.full_like(scores, jnp.nan)
+        return jax.tree.map(lambda s: jnp.full_like(s, jnp.nan), scores)
     mask = (weights > 0).sum(axis=1) >= min_uniq       # [B] on device
-    return jnp.where(mask[:, None], jnp.float32(jnp.nan), scores)
+    return jax.tree.map(
+        lambda s: jnp.where(mask[:, None], jnp.float32(jnp.nan), s),
+        scores)

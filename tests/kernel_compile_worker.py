@@ -10,7 +10,7 @@ interpret-mode test and were refused here).
 
 Run as a script (``tests/test_kernel_compile.py`` does, in a subprocess:
 the compile-only client is process-global state); prints one JSON line
-``{"failures": [...], "compiled": N, "cells": M}``.
+``{"failures": [...], "compiled": N, "cells": M, "mesh_cells": [...]}``.
 """
 
 from __future__ import annotations
@@ -70,13 +70,27 @@ def compile_block(dev, rows: int, width: int, B: int, u_cap: int,
              s((B, u_cap + 1), jnp.float32), s((), jnp.int32)).compile()
 
 
-def compile_mesh_step(devices) -> None:
+# The mesh cells: configuration -> the batch buckets its cell dispatches.
+# The committed snapshot's shapes per docs-shard of the (4, 1) mesh are
+# the configuration file's ``layout.shard_blocks`` (rows_cap of each
+# ELL_WIDTHS bucket, the same for every seed:
+# tests/test_mesh_block_capacities.py).
+MESH_CELL_STEPS = {"msmarco4m-mesh": (128, 256, 512)}
+
+
+def compile_mesh_step(devices) -> list[dict]:
     """The served mesh program — ``make_mesh_ell_search`` on a (4, 1)
     ("docs", "terms") mesh: a small index is built for real on four
     virtual CPU devices, then every array is replaced by its shape on
-    the same mesh of v5e devices."""
+    the same mesh of v5e devices — and then by the shapes of the mesh
+    cell's snapshot (``MESH_CELL_STEPS``), whose per-device
+    ``memory_analysis()`` is returned, a dict a batch bucket."""
+    import dataclasses
+
     from tfidf_tpu.engine import Engine
-    from tfidf_tpu.parallel.mesh_ell import make_mesh_ell_search
+    from tfidf_tpu.ops.scoring import QueryBatch
+    from tfidf_tpu.parallel.mesh_ell import (ELL_WIDTHS,
+                                             make_mesh_ell_search)
     from tfidf_tpu.utils.config import Config
 
     engine = Engine(Config(engine_mode="mesh", query_batch=32,
@@ -92,17 +106,63 @@ def compile_mesh_step(devices) -> None:
     qb, _ = engine.searcher._vectorize(["x"] * 32, 32)
     tpu_mesh = Mesh(np.asarray(devices).reshape(4, 1), ("docs", "terms"))
 
-    def abstract(x):
+    def abstract(x, shape=None):
         x = jnp.asarray(x)   # host-side query arrays: replicated
         spec = getattr(x.sharding, "spec", PartitionSpec())
         return jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=NamedSharding(tpu_mesh, spec))
+            x.shape if shape is None else shape, x.dtype,
+            sharding=NamedSharding(tpu_mesh, spec))
 
     args = jax.tree.map(abstract, (snap.base, snap.delta, snap.df_g,
                                    snap.n_docs, snap.avgdl, qb))
     for a_build in ell.A_BUILD_VARIANTS:
         make_mesh_ell_search(tpu_mesh, k=10, a_build=a_build,
                              packed=True).lower(*args).compile()
+
+    def each(arrays, shapes):
+        return tuple(abstract(a, shape) for a, shape in zip(arrays, shapes))
+
+    out = []
+    for name, batches in MESH_CELL_STEPS.items():
+        with open(os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "benchmarks", "configs",
+                name + ".json")) as f:
+            shard = json.load(f)["layout"]["shard_blocks"]
+        assert tuple(shard["widths"]) == ELL_WIDTHS
+        rows, doc_cap = shard["rows"], shard["doc_cap"]
+        vocab_cap = shard["vocab_cap"]
+        blocks = tuple([4, r, w] for r, w in zip(rows, ELL_WIDTHS))
+        by_rows = tuple([4, r] for r in rows)
+        base = dataclasses.replace(
+            jax.tree.map(abstract, snap.base),
+            tf=each(snap.base.tf, blocks), term=each(snap.base.term, blocks),
+            impact=each(snap.base.impact, blocks),
+            dl=each(snap.base.dl, by_rows),
+            live=abstract(snap.base.live, [4, doc_cap]),
+            res_dl=abstract(snap.base.res_dl, [4, doc_cap]),
+            doc_cap=doc_cap)
+        delta = dataclasses.replace(
+            jax.tree.map(abstract, snap.delta),
+            df=abstract(snap.delta.df, [4, 1, vocab_cap]),
+            vocab_cap=vocab_cap)
+        for B in batches:
+            q = QueryBatch(
+                uniq=abstract(qb.uniq, [1024]), n_uniq=abstract(qb.n_uniq),
+                slots=abstract(qb.slots, [B, qb.slots.shape[1]]),
+                weights=abstract(qb.weights, [B, qb.weights.shape[1]]))
+            step = make_mesh_ell_search(
+                tpu_mesh, k=10, a_build="v4", packed=True).lower(
+                base, delta, abstract(snap.df_g, [vocab_cap]),
+                abstract(snap.n_docs), abstract(snap.avgdl), q).compile()
+            text = step.as_text()
+            assert text.count("tpu_custom_call") >= 4 \
+                and "all-gather" in text, "kernels or gather missing"
+            m = step.memory_analysis()
+            out.append({"cell": name, "B": B,
+                        "temp_bytes": m.temp_size_in_bytes,
+                        "argument_bytes": m.argument_size_in_bytes,
+                        "output_bytes": m.output_size_in_bytes})
+    return out
 
 
 # The benchmark cells' committed snapshots: (rows_cap, width) of every
@@ -174,8 +234,9 @@ def main() -> int:
                         failures.append(
                             f"{what}: {type(e).__name__}: "
                             f"{str(e)[:300]}")
+    mesh_cells: list[dict] = []
     try:
-        compile_mesh_step(topo.devices)
+        mesh_cells = compile_mesh_step(topo.devices)
         compiled += len(ell.A_BUILD_VARIANTS)
     except Exception as e:
         failures.append(f"mesh (4,1) step: {type(e).__name__}: "
@@ -190,7 +251,7 @@ def main() -> int:
                 failures.append(f"cell {name} B={B}: {type(e).__name__}: "
                                 f"{str(e)[:600]}")
     print(json.dumps({"failures": failures, "compiled": compiled,
-                      "cells": cells}))
+                      "cells": cells, "mesh_cells": mesh_cells}))
     return 1 if failures else 0
 
 

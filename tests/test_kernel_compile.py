@@ -53,3 +53,18 @@ def test_cells_device_step_compiles_for_v5e(report):
     a second copy of the score space."""
     assert not _failures(report, of_cells=True)
     assert report["cells"] == 4, report
+
+
+@pytest.mark.parametrize("B", (128, 256, 512))
+def test_mesh_cell_step_compiles_for_v5e(report, B):
+    """The (4, 1) ``jit_mesh_ell_search`` step at the ten per-shard block
+    capacities of ``msmarco4m-mesh`` (``MESH_CELL_STEPS`` in the worker),
+    at each batch bucket the cell dispatches: kernels and the all_gather
+    are in it, and a chip's share of it (``memory_analysis()`` is per
+    device) fits beside the 0.53 GB of a shard's index."""
+    assert not _failures(report, of_cells=False)
+    mine = [m for m in report["mesh_cells"]
+            if (m["cell"], m["B"]) == ("msmarco4m-mesh", B)]
+    assert len(mine) == 1, report["mesh_cells"]
+    print(f"mesh step memory_analysis, B={B}: {mine[0]}")
+    assert mine[0]["temp_bytes"] + mine[0]["argument_bytes"] < 8e9

@@ -1,0 +1,129 @@
+"""``msmarco4m-mesh``'s ``docs`` keeps every seed on ONE compiled program.
+
+On the mesh a run's seed decides which documents fall in which docs-shard
+(round-robin over the seed's order), and every ELL block's row capacity is
+the power of two above its fullest shard. At 4,000,000 passages the
+33-48-term block's fullest shard came within 67 rows of 524,288 in the
+worst of 64 seeds: the next unlucky seed would have doubled the block,
+compiled another program and run a quarter more kernel work (the fault PR
+23 found for one shard). The configuration holds 3,900,000 for that
+reason; this is the arithmetic, on the host alone (numpy over
+``benchmarks/lib/data.py``'s corpus law, no device), so that a later
+change of ``docs`` cannot walk back into the trap unnoticed.
+"""
+
+import importlib.util
+import json
+import os
+import sys
+from concurrent.futures import ThreadPoolExecutor
+
+import numpy as np
+import pytest
+
+from tfidf_tpu.ops.csr import next_capacity
+from tfidf_tpu.parallel.mesh_ell import ELL_WIDTHS
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def bench_lib(name: str):
+    """``benchmarks/lib/<name>.py`` by its path (``tests/`` has a ``data``
+    and an ``oracle`` of its own on ``sys.path``)."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{name}", os.path.join(ROOT, "benchmarks", "lib",
+                                      name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = module     # dataclasses look the module up
+    spec.loader.exec_module(module)
+    return module
+
+
+data = bench_lib("data")
+
+SEEDS = [2147483659 + 7919 * i for i in range(32)]
+MIN_ROWS = 256          # build_mesh_ell's floor of a block's rows
+CLEAR = 0.02            # of its capacity, every block's fullest shard
+
+
+def _spec() -> dict:
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "msmarco4m-mesh.json")) as f:
+        return json.load(f)
+
+
+def _distinct_terms_by_chunk(args: dict) -> list[np.ndarray]:
+    """Distinct terms of every document, chunk by chunk of the corpus in
+    ``corpus_seed``'s own order (a run's seed only reorders them)."""
+    bounds = np.linspace(0, args["docs"],
+                         data.CORPUS_CHUNKS + 1).astype(np.int64)
+    sizes = np.diff(bounds)
+    with ThreadPoolExecutor(4) as ex:
+        return list(ex.map(
+            lambda c: data._corpus_chunk(
+                args["corpus_seed"], c, int(sizes[c]), args["vocab"],
+                args["doc_len_mean"], args["doc_len_min"], args["zipf_a"],
+                0)[0], range(data.CORPUS_CHUNKS)))
+
+
+def _in_seed_order(per_chunk: list[np.ndarray], seed: int) -> np.ndarray:
+    """``make_corpus``'s order of the documents for ``seed`` (its chunk
+    permutation and rotations), applied to the per-document counts."""
+    rng = np.random.default_rng([seed, data._TAG_ORDER])
+    order = rng.permutation(data.CORPUS_CHUNKS)
+    rotate = [int(rng.integers(0, max(len(per_chunk[c]), 1)))
+              for c in range(data.CORPUS_CHUNKS)]
+    return np.concatenate([np.roll(per_chunk[c], -rotate[c])
+                           for c in order])
+
+
+def _fullest_shard(distinct: np.ndarray, shards: int) -> np.ndarray:
+    """Documents of the fullest docs-shard in every ``ELL_WIDTHS`` bucket
+    (``_bucket_of``: the narrowest bucket that holds the document; wider
+    than the widest rides the widest), dealt ``i % shards``."""
+    asc = np.asarray(sorted(ELL_WIDTHS))
+    bucket = np.minimum(np.searchsorted(asc, distinct, side="left"),
+                        len(asc) - 1)
+    need = np.stack([np.bincount(bucket[s::shards], minlength=len(asc))
+                     for s in range(shards)])
+    return need.max(axis=0)[::-1]           # ELL_WIDTHS runs wide to narrow
+
+
+def test_seed_order_is_make_corpus_order():
+    """The shortcut above (per-document counts reordered, not the corpus
+    redrawn) gives what ``make_corpus`` gives, at a size a test can draw
+    twice."""
+    args = {**data.corpus_args(_spec()), "docs": 20000, "vocab": 20000}
+    per_chunk = _distinct_terms_by_chunk(args)
+    for seed in SEEDS[:2]:
+        corpus = data.make_corpus(seed, **args)
+        assert np.array_equal(_in_seed_order(per_chunk, seed),
+                              np.diff(corpus.offsets))
+
+
+@pytest.fixture(scope="module")
+def fullest():
+    spec = _spec()
+    assert spec["engine_config"]["mesh_shape"] == [4, 1]
+    per_chunk = _distinct_terms_by_chunk(data.corpus_args(spec))
+    return np.stack([_fullest_shard(_in_seed_order(per_chunk, seed), 4)
+                     for seed in SEEDS])
+
+
+def test_every_seed_compiles_the_same_ten_blocks(fullest):
+    """... those the configuration states, which the compile-only test
+    (``tests/kernel_compile_worker.py``) builds its shapes from."""
+    blocks = _spec()["layout"]["shard_blocks"]
+    assert tuple(blocks["widths"]) == ELL_WIDTHS
+    for per_seed in fullest:
+        assert [next_capacity(int(n) or 1, MIN_ROWS)
+                for n in per_seed] == blocks["rows"]
+
+
+def test_fullest_shard_clears_its_capacity_by_2_percent(fullest):
+    rows = np.asarray(_spec()["layout"]["shard_blocks"]["rows"])
+    worst = fullest.max(axis=0)
+    assert (worst <= rows * (1 - CLEAR)).all(), \
+        dict(zip(ELL_WIDTHS, zip(worst.tolist(), rows.tolist())))
+    # the block the trap was in: 33-48 distinct terms
+    assert 500_000 < worst[ELL_WIDTHS.index(48)] < 524_288 * (1 - CLEAR)

@@ -845,13 +845,23 @@ class TestChaosTrace:
 # one name in /api/metrics, on the Dapper span and in a profiler trace
 # ---------------------------------------------------------------------------
 
-def _stage_engine(tmp_path, mode):
+def _stage_engine(tmp_path, mode, mesh_layout=None):
+    """``mesh_layout``: "ell" / "coo" builds the engine as one mesh
+    worker over a (4, 1) mesh of the virtual devices instead."""
     from tfidf_tpu.engine import Engine
     from tfidf_tpu.utils.config import Config
-    e = Engine(Config(documents_path=str(tmp_path / "docs"),
-                      min_doc_capacity=8, min_nnz_capacity=256,
-                      min_vocab_capacity=64, query_batch=4,
-                      max_query_terms=8, search_pipeline_mode=mode))
+    cfg = Config(documents_path=str(tmp_path / "docs"),
+                 min_doc_capacity=8, min_nnz_capacity=256,
+                 min_vocab_capacity=64, query_batch=4,
+                 max_query_terms=8, search_pipeline_mode=mode)
+    mesh = None
+    if mesh_layout:
+        import jax
+
+        from tfidf_tpu.parallel.mesh import make_mesh
+        cfg = cfg.replace(engine_mode="mesh", mesh_layout=mesh_layout)
+        mesh = make_mesh((4, 1), devices=jax.devices()[:4])
+    e = Engine(cfg, mesh=mesh)
     for i in range(12):
         e.ingest_text(f"d{i}", f"common word{i} term{i % 3} extra{i % 5}")
     e.commit()
@@ -907,6 +917,75 @@ class TestStageTimer:
                      "phase.device_wait", "phase.d2h", "phase.assemble"):
             assert evs.count(name) == 3, (name, evs)
         assert not [n for n in evs if n.startswith("pipeline.")]
+
+    MESH = ("phase_vectorize_count", "phase_score_count") + FETCH \
+        + CHUNKS + ("mesh_steps",)
+
+    @pytest.mark.parametrize("layout,mode", [
+        ("ell", "inline"), ("coo", "inline"), ("ell", "executor")])
+    def test_mesh_chunks_are_timed_and_counted(self, tmp_path, layout,
+                                               mode):
+        """The mesh searcher's stages, as the local searcher's: per
+        dispatched chunk one ``vectorize``, one ``score`` (the enqueue of
+        the shard_map program), one each of the fetch stages, and one
+        ``mesh_steps``."""
+        e = _stage_engine(tmp_path, mode, mesh_layout=layout)
+        assert type(e.searcher).__name__ == {
+            "ell": "MeshEllSearcher", "coo": "MeshSearcher"}[layout]
+        before = _counts(*self.MESH)
+        with global_tracer.span("req") as sp:
+            assert all(e.search_batch(self.QUERIES))
+        d = _grew(before, _counts(*self.MESH))
+        assert d.pop("dispatch_queries") == 10
+        assert d.pop("dispatch_slots") == 4 + 4 + 2
+        assert d == {k: 3 for k in d}, d
+        assert d["mesh_steps"] == d["dispatch_chunks"]
+        evs = [ev["name"] for ev in sp.to_dict()["events"]]
+        for name in ("phase.vectorize", "phase.score", "phase.device_wait",
+                     "phase.d2h", "phase.assemble"):
+            assert evs.count(name) == 3, (name, evs)
+        assert "phase.topk" not in evs      # the step takes its own top-k
+
+    @pytest.mark.parametrize("layout", ["ell", "coo"])
+    def test_mesh_commit_gauges_and_phases(self, tmp_path, layout):
+        """12 documents dealt round-robin over four docs-shards: three
+        slots a shard; the rows a shard's step scores are the layout's."""
+        e = _stage_engine(tmp_path, "inline", mesh_layout=layout)
+        snap = global_metrics.snapshot()
+        assert [snap[k] for k in (
+            "mesh_docs_shards", "mesh_terms_shards", "mesh_shard_docs_min",
+            "mesh_shard_docs_max")] == [4, 1, 3, 3]
+        index = e.index.snapshot
+        rows = sum(imp.shape[1] for imp in index.base.impact) \
+            if layout == "ell" else index.arrays.doc_cap
+        assert snap["mesh_shard_rows_padded"] == rows >= 3
+        if layout == "ell":     # the rebuild's parts, timed
+            assert snap["phase_mesh_build_host_count"] == 2
+            assert snap["phase_mesh_build_upload_count"] == 1
+            assert snap["phase_mesh_impact_refresh_count"] == 1
+        e.ingest_text("d12", "common extra word12")
+        e.commit()              # an append: slots move, no rebuild
+        snap = global_metrics.snapshot()
+        assert (snap["mesh_shard_docs_min"],
+                snap["mesh_shard_docs_max"]) == (3, 4)
+        if layout == "ell":
+            assert snap["phase_mesh_build_upload_count"] == 1
+            assert snap["phase_mesh_impact_refresh_count"] == 2
+
+    def test_mesh_program_has_a_fixed_name(self, tmp_path):
+        """``jit_mesh_ell_search`` is what ``mesh_step_ms.mesh`` looks for
+        on a trace's ``XLA Modules`` line; the scopes name the step's
+        parts in the compiled HLO."""
+        e = _stage_engine(tmp_path, "inline", mesh_layout="ell")
+        snap = e.index.snapshot
+        qb, _ = e.searcher._vectorize(self.QUERIES[:4], 4)
+        lowered = e.searcher._get_search_fn(3).lower(
+            snap.base, snap.delta, snap.df_g, snap.n_docs, snap.avgdl, qb)
+        assert "@jit_mesh_ell_search" in lowered.as_text()
+        text = lowered.as_text(debug_info=True)
+        for scope in ("ell_blocks", "rearrange_to_real", "coo_residual",
+                      "delta", "shard_topk", "gather_merge"):
+            assert f'loc("{scope}/' in text, scope
 
     def test_coalescer_queue_wait_and_wake(self):
         """Two one-item batches through ONE dispatcher whose batch_fn

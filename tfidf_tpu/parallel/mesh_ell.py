@@ -33,6 +33,7 @@ id so the searcher can translate top-k ids back to names.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +92,23 @@ jax.tree_util.register_dataclass(
 )
 
 
+class MeshEllHost(NamedTuple):
+    """:class:`MeshEllArrays` as :func:`build_mesh_ell` leaves it on the
+    host: numpy arrays of the same names and shapes, ``impact`` left out
+    (zeros of ``tf``'s shapes)."""
+
+    tf: list
+    term: list
+    dl: list
+    block_live: np.ndarray
+    live: np.ndarray
+    res_tf: np.ndarray
+    res_term: np.ndarray
+    res_doc: np.ndarray
+    res_dl: np.ndarray
+    doc_cap: int
+
+
 def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
                    mesh: Mesh,
                    transform_len,                   # model.transform_doc_len
@@ -98,11 +116,13 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
                    width_cap: int = 256,
                    min_rows: int = 256,
                    min_res_cap: int = 1 << 10
-                   ) -> tuple[MeshEllArrays, list[np.ndarray]]:
+                   ) -> tuple[MeshEllHost, list[np.ndarray]]:
     """Host-side build: per-shard blocked ELL with uniform buckets.
 
-    Returns ``(arrays, perm)`` where ``perm[s][ell_row] = insertion-local
-    id`` in shard s (for name lookup). Impacts are left zero — call
+    Returns ``(host, perm)``: ``host`` for :func:`place_mesh_ell` to put
+    on the mesh — two steps, so that a commit times the host's loops
+    and the upload apart; ``perm[s][ell_row] = insertion-local id`` in
+    shard s (for name lookup). Impacts are left zero — call
     :func:`make_impact_refresh` after placing the arrays.
     """
     D = mesh.shape["docs"]
@@ -199,27 +219,36 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
                  + g_res_term.nbytes + g_res_doc.nbytes
                  + g_res_dl.nbytes)
     global_metrics.set_gauge("mesh_ell_device_bytes", float(dev_bytes))
+    return MeshEllHost(tf=g_tf, term=g_term, dl=g_dl, block_live=g_bl,
+                       live=g_live, res_tf=g_res_tf, res_term=g_res_term,
+                       res_doc=g_res_doc, res_dl=g_res_dl,
+                       doc_cap=doc_cap), perms
+
+
+def place_mesh_ell(host: MeshEllHost, mesh: Mesh) -> MeshEllArrays:
+    """:func:`build_mesh_ell`'s arrays onto the mesh (``jax.device_put``
+    returns before the copy ends; a caller that times the upload waits
+    for the arrays)."""
 
     def put(x, spec):
         return jax.device_put(x, NamedSharding(mesh, spec))
 
     # width columns shard over "terms": entries of one row split across
     # terms-devices; contributions are additive, like the COO split
-    arrays = MeshEllArrays(
-        tf=tuple(put(a, P("docs", None, "terms")) for a in g_tf),
-        term=tuple(put(a, P("docs", None, "terms")) for a in g_term),
+    return MeshEllArrays(
+        tf=tuple(put(a, P("docs", None, "terms")) for a in host.tf),
+        term=tuple(put(a, P("docs", None, "terms")) for a in host.term),
         impact=tuple(put(np.zeros_like(a), P("docs", None, "terms"))
-                     for a in g_tf),
-        dl=tuple(put(a, P("docs", None)) for a in g_dl),
-        block_live=put(g_bl, P("docs", None)),
-        live=put(g_live, P("docs", None)),
-        res_tf=put(g_res_tf, P("docs", "terms", None)),
-        res_term=put(g_res_term, P("docs", "terms", None)),
-        res_doc=put(g_res_doc, P("docs", "terms", None)),
-        res_dl=put(g_res_dl, P("docs", None)),
-        doc_cap=doc_cap,
+                     for a in host.tf),
+        dl=tuple(put(a, P("docs", None)) for a in host.dl),
+        block_live=put(host.block_live, P("docs", None)),
+        live=put(host.live, P("docs", None)),
+        res_tf=put(host.res_tf, P("docs", "terms", None)),
+        res_term=put(host.res_term, P("docs", "terms", None)),
+        res_doc=put(host.res_doc, P("docs", "terms", None)),
+        res_dl=put(host.res_dl, P("docs", None)),
+        doc_cap=host.doc_cap,
     )
-    return arrays, perms
 
 
 def _bucket_of(k: int, widths: list[int]) -> int:
@@ -334,46 +363,55 @@ def make_mesh_ell_search(mesh: Mesh,
         qc_t = qc_ext.T
         u_cap = q.uniq.shape[0]
 
+        # the scopes name the step's parts in the compiled HLO's
+        # ``op_name`` (a device trace's events carry the HLO text only)
         # --- ELL base: same per-block scorers as single-device ---
         parts = []
-        for i, (imp, term) in enumerate(zip(impacts, terms)):
-            if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap,
-                                               a_build):
-                parts.append(score_block_pallas(
-                    imp, term, q.uniq, q.n_uniq, qc_ext, block_live[i],
-                    a_build=a_build))
-            else:
-                parts.append(_score_block(imp, term, slot_of, qc_t, 2048))
-        ell_scores = _rearrange_to_real(
-            parts, [imp.shape[0] for imp in impacts], block_live,
-            doc_cap_ell, B)
-        ell_scores = ell_scores + score_coo_compiled(
-            res_tf, res_term, res_doc, res_dl, df_g, slot_of, qc_ext,
-            n_docs, avgdl, None, model=model, k1=k1, b=b,
-            chunk=min(1 << 10, res_tf.shape[0]))
+        with jax.named_scope("ell_blocks"):
+            for i, (imp, term) in enumerate(zip(impacts, terms)):
+                if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap,
+                                                   a_build):
+                    parts.append(score_block_pallas(
+                        imp, term, q.uniq, q.n_uniq, qc_ext,
+                        block_live[i], a_build=a_build))
+                else:
+                    parts.append(_score_block(imp, term, slot_of, qc_t,
+                                              2048))
+        with jax.named_scope("rearrange_to_real"):
+            ell_scores = _rearrange_to_real(
+                parts, [imp.shape[0] for imp in impacts], block_live,
+                doc_cap_ell, B)
+        with jax.named_scope("coo_residual"):
+            ell_scores = ell_scores + score_coo_compiled(
+                res_tf, res_term, res_doc, res_dl, df_g, slot_of, qc_ext,
+                n_docs, avgdl, None, model=model, k1=k1, b=b,
+                chunk=min(1 << 10, res_tf.shape[0]))
         ell_scores = jax.lax.psum(ell_scores, "terms")
         ell_scores = ell_scores * base_live[None, :]
 
         # --- COO delta (appends since the last re-shard) ---
-        delta_scores = score_coo_compiled(
-            d_tf, d_term, d_doc, d_len, df_g, slot_of, qc_ext,
-            n_docs, avgdl, None, model=model, k1=k1, b=b,
-            chunk=min(delta_chunk, d_tf.shape[0]))
-        delta_scores = jax.lax.psum(delta_scores, "terms")
-        delta_scores = delta_scores * d_live[None, :]
+        with jax.named_scope("delta"):
+            delta_scores = score_coo_compiled(
+                d_tf, d_term, d_doc, d_len, df_g, slot_of, qc_ext,
+                n_docs, avgdl, None, model=model, k1=k1, b=b,
+                chunk=min(delta_chunk, d_tf.shape[0]))
+            delta_scores = jax.lax.psum(delta_scores, "terms")
+            delta_scores = delta_scores * d_live[None, :]
 
-        scores = jnp.concatenate([ell_scores, delta_scores], axis=1)
-        n_local = jnp.int32(doc_cap_ell) + d_n
-        # mask via per-position liveness, not a row-count prefix: the
-        # ELL space is permuted, so exact_topk's prefix mask is wrong —
-        # dead positions already score 0 and top_k handles the rest
-        vals, ids = exact_topk(scores, n_local, k=k)
-        shard_idx = jax.lax.axis_index("docs").astype(jnp.int32)
-        gids = (shard_idx * jnp.int32(doc_cap_ell + doc_cap_delta)
-                + ids)
-        all_vals = jax.lax.all_gather(vals, "docs")
-        all_ids = jax.lax.all_gather(gids, "docs")
-        return merge_topk(all_vals, all_ids)
+        with jax.named_scope("shard_topk"):
+            scores = jnp.concatenate([ell_scores, delta_scores], axis=1)
+            n_local = jnp.int32(doc_cap_ell) + d_n
+            # mask via per-position liveness, not a row-count prefix: the
+            # ELL space is permuted, so exact_topk's prefix mask is wrong
+            # — dead positions already score 0 and top_k handles the rest
+            vals, ids = exact_topk(scores, n_local, k=k)
+        with jax.named_scope("gather_merge"):
+            shard_idx = jax.lax.axis_index("docs").astype(jnp.int32)
+            gids = (shard_idx * jnp.int32(doc_cap_ell + doc_cap_delta)
+                    + ids)
+            all_vals = jax.lax.all_gather(vals, "docs")
+            all_ids = jax.lax.all_gather(gids, "docs")
+            return merge_topk(all_vals, all_ids)
 
     def in_specs(nb):
         return ((P(None), P(), P(),
@@ -386,9 +424,11 @@ def make_mesh_ell_search(mesh: Mesh,
                  P(None), P(), P(None, None), P(None, None))
                 + (P("docs", None, "terms"),) * nb * 2)
 
+    # the function's name is the program's in a device trace
+    # (``jit_mesh_ell_search(<fingerprint>)`` on the ``XLA Modules`` line)
     @jax.jit
-    def search(base: MeshEllArrays, delta, df_g, n_docs, avgdl,
-               q: QueryBatch):
+    def mesh_ell_search(base: MeshEllArrays, delta, df_g, n_docs, avgdl,
+                        q: QueryBatch):
         nb = base.n_buckets
         sharded = jax.shard_map(
             step, mesh=mesh, in_specs=in_specs(nb),
@@ -405,7 +445,7 @@ def make_mesh_ell_search(mesh: Mesh,
             return pack_topk(vals, gids)
         return vals, gids
 
-    return search
+    return mesh_ell_search
 
 
 def with_ell_live(mesh: Mesh, arrays: MeshEllArrays,

@@ -31,13 +31,14 @@ from tfidf_tpu.ops.ell import _pallas_eligible
 from tfidf_tpu.parallel.mesh_ell import (MeshEllArrays, build_mesh_ell,
                                          make_impact_refresh,
                                          make_mesh_ell_search,
-                                         with_ell_live)
+                                         place_mesh_ell, with_ell_live)
 from tfidf_tpu.parallel.mesh_index import MeshIndex, MeshSearcher
 from tfidf_tpu.parallel.sharded import (ShardedArrays,
                                         build_sharded_arrays,
                                         with_live_mask)
 from tfidf_tpu.utils.logging import get_logger
 from tfidf_tpu.utils.metrics import global_metrics
+from tfidf_tpu.utils.tracing import trace_phase
 
 log = get_logger("parallel.mesh_ell_index")
 
@@ -263,7 +264,10 @@ class MeshEllIndex(MeshIndex):
                 self._refresh_fn = make_impact_refresh(
                     self.mesh, model=kw["model"], k1=kw.get("k1", 1.2),
                     b=kw.get("b", 0.75))
-            base = self._refresh_fn(self._base, df_g, n_docs, avgdl)
+            # the ENQUEUE only (the first commit's holds its compile): a
+            # writer does not wait out the devices under the write lock
+            with trace_phase("mesh_impact_refresh"):
+                base = self._refresh_fn(self._base, df_g, n_docs, avgdl)
             # liveness only changes on delete/upsert (appends never touch
             # it, rebuilds drop tombstones and build a fresh all-live
             # mask) — rebuilding the masks every commit was an O(corpus)
@@ -287,6 +291,8 @@ class MeshEllIndex(MeshIndex):
             self._committed_gen = gen0
         global_metrics.set_gauge("index_docs", snap.total_live)
         global_metrics.set_gauge("index_nnz", snap.nnz)
+        self._publish_shard_gauges(
+            snap.shard_docs, sum(imp.shape[1] for imp in base.impact))
         log.info("committed mesh-ell snapshot", version=snap.version,
                  docs=snap.total_live, nnz=snap.nnz,
                  mesh=dict(self.mesh.shape))
@@ -337,26 +343,33 @@ class MeshEllIndex(MeshIndex):
     def _rebuild_ell_locked(self, pending, vocab_cap: int) -> None:
         """Fold everything (base + delta + pending) into a fresh ELL
         base with round-robin placement; drops tombstones."""
-        entries = []
-        for sd in self._shard_docs:
-            entries.extend(d for d in sd if d.live)
-        entries.extend(pending)
-        per_shard = [[] for _ in range(self.D)]
-        shard_docs = [[] for _ in range(self.D)]
-        placed = {}
-        for i, e in enumerate(entries):
-            e.live = True
-            s = i % self.D
-            placed[e.name] = (s, len(shard_docs[s]))
-            shard_docs[s].append(e)
-            per_shard[s].append(e)
+        # the rebuild's parts as stages of the one timer: the host's
+        # loops over every document (``mesh_build_host``, here and at
+        # the stats resync below) and the copy onto the devices
+        with trace_phase("mesh_build_host"):
+            entries = []
+            for sd in self._shard_docs:
+                entries.extend(d for d in sd if d.live)
+            entries.extend(pending)
+            per_shard = [[] for _ in range(self.D)]
+            shard_docs = [[] for _ in range(self.D)]
+            placed = {}
+            for i, e in enumerate(entries):
+                e.live = True
+                s = i % self.D
+                placed[e.name] = (s, len(shard_docs[s]))
+                shard_docs[s].append(e)
+                per_shard[s].append(e)
+            host, perms = build_mesh_ell(
+                per_shard, self.mesh, self.model.transform_doc_len,
+                width_cap=self.ell_width_cap,
+                min_rows=min(256, self.min_doc_cap))
         # build FIRST; install the new placement only once the device
         # build succeeded — a failed build (OOM) must not leave _placed
         # pointing into arrays that were never installed (ADVICE r2)
-        base, perms = build_mesh_ell(
-            per_shard, self.mesh, self.model.transform_doc_len,
-            width_cap=self.ell_width_cap,
-            min_rows=min(256, self.min_doc_cap))
+        with trace_phase("mesh_build_upload"):
+            base = jax.block_until_ready(place_mesh_ell(host, self.mesh))
+        del host
         self._shard_docs = shard_docs
         self._placed = placed
         self._base = base
@@ -367,9 +380,10 @@ class MeshEllIndex(MeshIndex):
         # (pending was just merged into the shard lists above) — the
         # one O(corpus nnz) pass steady commits never take (witness)
         self.df_full_recomputes += 1
-        df, n, len_sum = self._live_stats_scratch(
-            max(vocab_cap, self._df_live.shape[0], 1),
-            include_pending=False)
+        with trace_phase("mesh_build_host"):
+            df, n, len_sum = self._live_stats_scratch(
+                max(vocab_cap, self._df_live.shape[0], 1),
+                include_pending=False)
         self._df_live = df.astype(np.float64)
         self._n_live_stat = n
         self._len_sum_stat = len_sum

@@ -254,10 +254,24 @@ class MeshIndex:
         global_metrics.set_gauge("index_docs", snap.total_live)
         global_metrics.set_gauge("index_nnz", snap.nnz)
         global_metrics.set_gauge("mesh_rebuilds", self.rebuilds)
+        self._publish_shard_gauges(snap.shard_docs, arrays.doc_cap)
         log.info("committed mesh snapshot", version=snap.version,
                  docs=snap.total_live, nnz=snap.nnz,
                  mesh=dict(self.mesh.shape))
         return snap
+
+    def _publish_shard_gauges(self, shard_docs: list,
+                              rows_padded: int) -> None:
+        """What a sharded step waits on, as gauges: the mesh's shape, the
+        document slots (tombstones included: they are scored too) of the
+        emptiest and the fullest docs-shard, and the rows a shard's step
+        scores with their padding (equal on every shard)."""
+        slots = [len(sd) for sd in shard_docs]
+        global_metrics.set_gauge("mesh_docs_shards", self.D)
+        global_metrics.set_gauge("mesh_terms_shards", self.T)
+        global_metrics.set_gauge("mesh_shard_docs_min", min(slots))
+        global_metrics.set_gauge("mesh_shard_docs_max", max(slots))
+        global_metrics.set_gauge("mesh_shard_rows_padded", rows_padded)
 
     def _host_mask(self, doc_cap: int) -> np.ndarray:
         mask = np.zeros((self.D, doc_cap), np.float32)
@@ -451,8 +465,13 @@ class MeshSearcher(QueryVectorizerMixin):
         def dispatch(chunk):
             chunk_cap = self._batch_cap(len(chunk))
             self._count_chunk(len(chunk), chunk_cap)
-            qb, _widest = self._vectorize(chunk, chunk_cap)
-            return (chunk,) + self._dispatch_chunk(snap, qb, k)
+            global_metrics.inc("mesh_steps")
+            with trace_phase("vectorize"):
+                qb, _widest = self._vectorize(chunk, chunk_cap)
+            # jax's ENQUEUE of the shard_map program, not the devices'
+            # work (that is ``device_wait``, in the fetch stage)
+            with trace_phase("score"):
+                return (chunk,) + self._dispatch_chunk(snap, qb, k)
 
         from tfidf_tpu.ops.topk import fetch_packed
 

@@ -13,6 +13,8 @@ fails CI on a CPU box. Whether Mosaic ACCEPTS the kernel is
 import os
 import sys
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -21,8 +23,12 @@ if _root not in sys.path:
     sys.path.insert(0, _root)
 
 from kernel_parity import INTERPRET_CASES as T1_CASES  # noqa: E402
-from kernel_parity import run_case  # noqa: E402
-from tfidf_tpu.ops.ell import _pallas_eligible, _pl_tiles  # noqa: E402
+from kernel_parity import TOP_K, make_case, run_case  # noqa: E402
+from tfidf_tpu.ops import ell  # noqa: E402
+from tfidf_tpu.ops.ell import (_pallas_eligible, _pl_tiles,  # noqa: E402
+                               _score_block, score_block_pallas)
+from tfidf_tpu.ops.scoring import (QueryBatch,  # noqa: E402
+                                   _compile_queries)
 
 @pytest.mark.parametrize("i", range(len(T1_CASES)))
 def test_interpret_parity(i):
@@ -85,3 +91,87 @@ def test_tile_schedule_divides_capacities():
                 td, tu = _pl_tiles(rows_cap, B, u_cap)
                 assert rows_cap % td == 0 and u_cap % tu == 0, \
                     (rows_cap, B, u_cap, td, tu)
+
+
+# ---- the sub-tile nest (PR 27): work follows n_uniq -----------------
+#
+# One block of an ODD width (v4's tail row) under query batches whose
+# distinct-term count sits on every edge of the nest: the 8-row sublane
+# grain, the sub-tile (_PL_SU), the 128-row contraction chunk, the
+# 512-lane uniq tile and the whole capacity.
+
+_SU = ell._PL_SU
+N_UNIQS = (1, 7, 8, 9, _SU - 1, _SU, _SU + 1, 127, 128, 129, 511, 512,
+           513, 1024)
+_ROWS, _WIDTH, _LIVE_ROWS, _B, _U_CAP, _VOCAB = 512, 33, 400, 16, 1024, 4000
+
+
+def _subtile_case(n_uniq: int):
+    """(impact, term, QueryBatch) with exactly ``n_uniq`` distinct query
+    terms in a capacity of 1,024; half of them occur in the block."""
+    rng = np.random.default_rng(1000 + n_uniq)
+    imp, term, _qb = make_case(rng, rows_cap=_ROWS, width=_WIDTH,
+                               n_rows=_LIVE_ROWS, B=_B, n_terms=4,
+                               u_req=_U_CAP, vocab=_VOCAB, ragged=True)
+    in_block = np.unique(term[:_LIVE_ROWS][imp[:_LIVE_ROWS] > 0])
+    take = rng.permutation(in_block)[:(n_uniq + 1) // 2]
+    rest = np.setdiff1d(np.arange(_VOCAB), take)
+    uniq = np.sort(np.concatenate(
+        [take, rng.permutation(rest)[:n_uniq - take.shape[0]]]))
+    assert uniq.shape[0] == n_uniq
+    uniq_pad = np.zeros(_U_CAP, np.int32)
+    uniq_pad[:n_uniq] = uniq
+    slots = rng.integers(0, n_uniq, size=(_B, 8)).astype(np.int32)
+    weights = (1.0 + rng.random((_B, 8))).astype(np.float32)
+    dead = rng.random((_B, 8)) < 0.3          # padded query slots
+    slots[dead], weights[dead] = _U_CAP, 0.0
+    return imp, term, QueryBatch(uniq_pad, np.int32(n_uniq), slots,
+                                 weights)
+
+
+def _compiled(q):
+    """``(slot_of, qc_ext)`` of the batch, as the scorers take them."""
+    return _compile_queries(jax.tree.map(jnp.asarray, q), _VOCAB)
+
+
+def _kernel_scores(imp, term, q, a_build, *, poison=False):
+    """The kernel's ``[B, rows_cap]`` for ``q``; ``poison`` puts term
+    ids that DO occur in the block into ``uniq``'s pad rows and a
+    non-zero weight into their ``qc`` columns."""
+    _slot_of, qc_ext = _compiled(q)
+    uniq, n = np.array(q.uniq), int(q.n_uniq)
+    if poison:
+        uniq[n:] = np.resize(term[:_LIVE_ROWS, :4].ravel(), _U_CAP - n)
+        qc_ext = qc_ext.at[:, n:_U_CAP].set(7.0)
+    return np.asarray(score_block_pallas(
+        jnp.asarray(imp), jnp.asarray(term), jnp.asarray(uniq),
+        jnp.int32(n), qc_ext, jnp.int32(_LIVE_ROWS), a_build=a_build))
+
+
+@pytest.mark.parametrize("a_build", ell.A_BUILD_VARIANTS)
+@pytest.mark.parametrize("n_uniq", N_UNIQS)
+def test_subtile_nest_matches_xla(n_uniq, a_build):
+    """Kernel against the XLA path within the harness's tolerance, for
+    every edge of the nest; and what lies in ``uniq``'s pad rows and
+    their ``qc`` columns never reaches a score."""
+    imp, term, q = _subtile_case(n_uniq)
+    got = _kernel_scores(imp, term, q, a_build)
+    slot_of, qc_ext = _compiled(q)
+    want = np.asarray(_score_block(jnp.asarray(imp), jnp.asarray(term),
+                                   slot_of, qc_ext.T, 2048))
+    assert np.abs(want).max() > 0
+    assert np.abs(got - want)[:, :_LIVE_ROWS].max() < 1e-4
+    assert not got[:, _LIVE_ROWS:].any()       # all-pad rows
+    k = min(TOP_K, _LIVE_ROWS)
+    assert np.array_equal(
+        np.argsort(-got[:, :_LIVE_ROWS], axis=1, kind="stable")[:, :k],
+        np.argsort(-want[:, :_LIVE_ROWS], axis=1, kind="stable")[:, :k])
+    assert np.array_equal(
+        got, _kernel_scores(imp, term, q, a_build, poison=True))
+
+
+@pytest.mark.parametrize("n_uniq", N_UNIQS)
+def test_subtile_nest_variants_bitwise_equal(n_uniq):
+    imp, term, q = _subtile_case(n_uniq)
+    assert np.array_equal(_kernel_scores(imp, term, q, "v3"),
+                          _kernel_scores(imp, term, q, "v4"))

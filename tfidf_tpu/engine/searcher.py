@@ -28,7 +28,8 @@ from tfidf_tpu.ops.analyzer import Analyzer
 from tfidf_tpu.ops.blockmax import query_upper_bounds, skip_mask
 from tfidf_tpu.ops.csr import next_capacity
 from tfidf_tpu.ops.ell import (_pallas_eligible, ell_scores_to_real,
-                               score_ell_batch, score_segments_batch)
+                               kernel_uniq_lanes, score_ell_batch,
+                               score_segments_batch)
 from tfidf_tpu.ops.scoring import (QueryBatch, make_query_batch,
                                    score_coo_batch)
 from tfidf_tpu.ops.topk import (fetch_packed, full_ranking, packed_topk,
@@ -114,6 +115,20 @@ class QueryVectorizerMixin:
         global_metrics.inc("dispatch_chunks")
         global_metrics.inc("dispatch_queries", n_queries)
         global_metrics.inc("dispatch_slots", cap)
+
+    @staticmethod
+    def _count_kernel_uniq(qb) -> None:
+        """One chunk dispatched to the fused kernel: its distinct terms
+        (``kernel_uniq_live``), the uniq lanes of A the kernel builds
+        for them (``_built``) and what whole uniq tiles would hold
+        (``_tiled``). ``live / built`` is the share of the A-build's
+        compare/select work on lanes a query uses."""
+        n_uniq = int(qb.n_uniq)
+        built, tiled = kernel_uniq_lanes(
+            n_uniq, qb.slots.shape[0], qb.uniq.shape[0])
+        global_metrics.inc("kernel_uniq_live", n_uniq)
+        global_metrics.inc("kernel_uniq_built", built)
+        global_metrics.inc("kernel_uniq_tiled", tiled)
 
     def _pipeline(self) -> PipelineExecutor:
         """The searcher's SHARED dispatch/fetch executor (lazy). One per
@@ -346,6 +361,9 @@ class Searcher(QueryVectorizerMixin):
         cap = self._batch_cap(len(queries))
         with trace_phase("vectorize"):
             qb, _widest = self._vectorize(queries, cap)
+        if (self.use_pallas and not isinstance(snap, SegmentedSnapshot)
+                and snap.is_ell):
+            self._count_kernel_uniq(qb)
         with trace_phase("score"):
             if isinstance(snap, SegmentedSnapshot):
                 # tiered snapshots publish no eager views; materialize

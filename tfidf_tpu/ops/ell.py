@@ -38,6 +38,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -203,34 +204,42 @@ ell_impacts = jax.jit(ell_impacts, static_argnames=("model", "k1", "b"))
 # the MXU. Everything lives in VMEM per tile; HBM traffic is postings in
 # (8 bytes/entry) and scores out.
 #
-# Cost model per batch: nnz_padded * ceil(n_uniq/TU)*TU compare/select
-# lane-ops for A plus 2*B*U1*rows MXU flops — vs the gather path's
+# Cost model per batch: nnz_padded * ceil(n_uniq/SU)*SU compare/select
+# lane-ops for A plus 2*B*ceil(n_uniq/128)*128*rows MXU flops (six bf16
+# passes each, ``Precision.HIGHEST``) — vs the gather path's
 # nnz_padded * B slow gathers. Wins whenever the batch's unique-term
 # count is small relative to B * (gather-op slowdown ~40-100x), i.e.
 # always for real query batches.
 #
 # The grid is (doc_tiles, uniq_tiles): for each doc tile the output
 # block stays resident in VMEM while uniq tiles accumulate into it, and
-# ``n_uniq`` arrives by scalar prefetch so tiles past the batch's live
-# unique terms are SKIPPED — work scales with the actual unique count,
-# not the padded capacity, and arbitrarily large u_cap costs nothing.
+# ``n_uniq`` arrives by scalar prefetch. INSIDE a grid step the loop
+# nest is uniq sub-tiles outer, width inner (``_pallas_kernel``): a
+# sub-tile of ``_PL_SU`` = 32 unique terms by TD documents is 16 vregs,
+# lives in registers through the whole width loop and is stored once;
+# the trip count of the sub-tile loop and the 128-row chunks the MXU
+# contracts both come from ``n_uniq``. So work scales with the actual
+# unique count, in steps of 32 lanes of A-build and 128 rows of
+# contraction, not with the padded capacity: arbitrarily large u_cap
+# costs nothing, and neither does the rest of a 512-lane tile that a
+# batch only starts (PR 27; before it whole [TU, TD] tiles were built,
+# their accumulator of 256 vregs read and written every width step).
 #
-# A-build variants (``a_build``, PERF.md r2 item 2 — the remaining
-# kernel headroom after the r3 uniq-tiling):
+# A-build variants (``a_build``): two inner bodies of that ONE nest.
 #
-# * ``"v3"`` — one width row per loop iteration: per padded entry per
-#   uniq lane the A-build costs 1 compare + 1 select + 1 accumulate
-#   add, all on i32/f32 vregs (3 vreg-ops/entry).
-# * ``"v4"`` — TWO width rows per iteration. Within one document row
-#   the live term ids are DISTINCT (the ELL layout stores one posting
-#   per distinct term; pads are trailing and carry impact 0), so at
-#   most one compare of a (w, w+1) pair can select a non-zero impact:
-#   the pair folds into ONE nested select chain and ONE accumulate add
-#   — the loop-carried add chain halves (width/2 deep instead of
-#   width), and because +0.0 is exact in f32 the result is
-#   BIT-IDENTICAL to v3. Cost per 2 entries: 2 cmp + 2 sel + 1 add
-#   = 2.5 vreg-ops/entry vs v3's 3.0 (an op-count model, ``bench.py
-#   --kernel``; the on-chip speed of v4 against v3 is not measured).
+# * ``"v3"`` — one width row per step: per padded entry per uniq lane
+#   1 compare + 1 select + 1 accumulate add, all on i32/f32 vregs
+#   (3 vreg-ops/entry).
+# * ``"v4"`` — TWO width rows per step. Within one document row the
+#   live term ids are DISTINCT (the ELL layout stores one posting per
+#   distinct term; pads are trailing and carry impact 0), so at most
+#   one compare of a (w, w+1) pair can select a non-zero impact: the
+#   pair folds into ONE nested select chain and ONE accumulate add,
+#   and because +0.0 is exact in f32 the result is BIT-IDENTICAL to
+#   v3. Cost per 2 entries: 2 cmp + 2 sel + 1 add = 2.5 vreg-ops/entry
+#   vs v3's 3.0 (``a_build_ops_model``). On the v5e, in this nest, v4
+#   builds a live lane 8-10% faster than v3; in the nest before it,
+#   whose accumulator traffic v4 halved, 1.8x (PERF.md §6, PR 27).
 #   Term ids stay i32 even where the vocabulary fits 15 bits: Mosaic
 #   for v5e refuses a dynamic sublane load from an i16 tile ("cannot
 #   statically prove that index in dimension 0 is a multiple of 8")
@@ -245,6 +254,9 @@ ell_impacts = jax.jit(ell_impacts, static_argnames=("model", "k1", "b"))
 
 _PL_TD = 512          # docs per grid tile (256 for small blocks)
 _PL_MAX_B = 2048      # VMEM: qc [B, TU] + out [B, TD] stay ~8MB
+_PL_SU = 32           # uniq rows a register-resident A sub-tile holds
+_PL_ROWS = 8          # width rows an A-build loop iteration loads: a sublane tile
+_PL_TK = 128          # uniq rows an MXU contraction chunk holds
 A_BUILD_VARIANTS = ("v3", "v4")
 
 
@@ -271,91 +283,134 @@ def check_a_build(a_build: str) -> str:
 
 
 def _pallas_kernel(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
-                   out_ref, *, width: int, td: int, tu: int):
-    d = pl.program_id(0)
-    u = pl.program_id(1)
+                   out_ref, a_ref, *, width: int, td: int, tu: int,
+                   a_build: str):
+    """One (doc tile, uniq tile) grid step: the ONE loop nest of both
+    A-build variants. Uniq SUB-TILES outer (``_PL_SU`` rows, trip count
+    from ``n_uniq``), width inner: a sub-tile's accumulator
+    ``[_PL_SU, td]`` stays in vector registers across the whole width loop
+    and is written ONCE to the VMEM scratch ``a_ref [tu, td]``; then
+    the MXU contracts the 128-row chunks of ``a_ref`` that hold a live
+    term. Sub-tiles and chunks past the live unique terms are never
+    built, compared or contracted.
 
-    @pl.when(u == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    # tiles wholly past the live unique terms (zero qc columns) or past
-    # the block's live rows (all-pad postings; power-of-two row caps
-    # leave up to 2x dead rows, which the top-k masks and
-    # _rearrange_to_real never gathers) contribute nothing — skip them
-    @pl.when(jnp.logical_and(u * tu < lims_ref[0],
-                             d * td < lims_ref[1]))
-    def _tile():
-        uniq_col = uniq_ref[:]                       # [TU, 1] i32
-
-        def body(w, a):                              # a [TU, Td]
-            term_row = term_ref[w, :][None, :]       # [1, Td] i32
-            imp_row = imp_ref[w, :][None, :]         # [1, Td] f32
-            eq = uniq_col == term_row                # [TU, Td]
-            return a + jnp.where(eq, imp_row, 0.0)
-
-        a = jax.lax.fori_loop(0, width, body,
-                              jnp.zeros((tu, td), jnp.float32))
-        # the contraction rides the MXU: [B, TU] @ [TU, Td]. HIGHEST
-        # keeps f32-equivalent accumulation (the default bf16 passes
-        # cost ~0.4% relative error — enough to flip top-k near-ties);
-        # the matmul is not the kernel's bottleneck, the A build is.
-        out_ref[:] += jnp.dot(qc_ref[:], a,
-                              preferred_element_type=jnp.float32,
-                              precision=jax.lax.Precision.HIGHEST)
-
-
-def _pallas_kernel_v4(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
-                      out_ref, *, width: int, td: int, tu: int):
-    """A-build v4: two width rows per iteration (see the variant notes
-    above). CONTRACT: within a document row the live term ids are
-    distinct and pads (impact 0) are trailing — both guaranteed by
-    every ELL builder in this tree (``build_ell_from_coo`` lays out one
-    entry per distinct term left-to-right; ``build_mesh_ell`` fills
+    v4 CONTRACT: within a document row the live term ids are distinct
+    and pads (impact 0) are trailing — both guaranteed by every ELL
+    builder in this tree (``build_ell_from_coo`` lays out one entry per
+    distinct term left-to-right; ``build_mesh_ell`` fills
     ``e.term_ids``, distinct by construction, and the terms-axis width
     shard is a contiguous column slice, so pads stay trailing). A row
     violating it would double-select where v3 double-adds."""
     d = pl.program_id(0)
     u = pl.program_id(1)
+    su = _PL_SU
 
     @pl.when(u == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    @pl.when(jnp.logical_and(u * tu < lims_ref[0],
-                             d * td < lims_ref[1]))
+    # live unique terms of THIS uniq tile (<= 0: the tile is wholly
+    # past them, its qc columns are zero). Bare lax primitives from
+    # here down: a jnp wrapper is a nested jit to trace and lower, and
+    # a worker traces this body for every block of every batch bucket
+    # it warms up.
+    n_live = lax.min(lims_ref[0] - u * tu, tu)
+
+    # tiles past the live unique terms or past the block's live rows
+    # (all-pad postings; power-of-two row caps leave up to 2x dead
+    # rows, which the top-k masks) contribute nothing — skip them
+    @pl.when(lax.bitwise_and(n_live > 0, d * td < lims_ref[1]))
     def _tile():
-        uniq_col = uniq_ref[:]                       # [TU, 1] i32
+        zeros = lax.full((su, td), 0.0, jnp.float32)
 
-        def pair(w, a):                              # a [TU, Td]
-            t0 = term_ref[w, :][None, :]             # [1, Td]
-            t1 = term_ref[w + 1, :][None, :]
-            i0 = imp_ref[w, :][None, :]
-            i1 = imp_ref[w + 1, :][None, :]
-            # at most one branch selects non-zero (distinct live ids;
-            # a pad match selects its 0.0 impact) — one add per pair,
-            # bit-identical to v3's add-of-0.0 for the missed branch
-            return a + jnp.where(uniq_col == t0, i0,
-                                 jnp.where(uniq_col == t1, i1, 0.0))
+        def build(s, carry):
+            r0 = pl.multiple_of(s * su, su)
+            # [su, 1] term ids across the lanes once a sub-tile, not
+            # once a width step
+            uniq = lax.broadcast_in_dim(uniq_ref[pl.ds(r0, su), :],
+                                        (su, td), (0, 1))
 
-        def pair_at(p, a):
-            return pair(2 * p, a)
+            def rows(w0, n, a):
+                """Width rows ``w0 .. w0 + n`` (n static) onto ``a``, in
+                order: ONE load of the n rows of each array (a ref
+                access is the dearest thing here to trace and lower:
+                ~2 ms of a worker's warm-up each), every row then
+                spread over the sub-tile's sublanes. v3 adds each row's
+                select; v4 folds a pair into one select chain and one
+                add, a last odd row alone."""
+                terms = term_ref[pl.ds(w0, n), :]    # [n, Td] i32
+                imps = imp_ref[pl.ds(w0, n), :]      # [n, Td] f32
 
-        a = jax.lax.fori_loop(0, width // 2, pair_at,
-                              jnp.zeros((tu, td), jnp.float32))
-        if width % 2:                                # static tail row
-            t = term_ref[width - 1, :][None, :]
-            i = imp_ref[width - 1, :][None, :]
-            a = a + jnp.where(uniq_col == t, i, 0.0)
-        out_ref[:] += jnp.dot(qc_ref[:], a,
-                              preferred_element_type=jnp.float32,
-                              precision=jax.lax.Precision.HIGHEST)
+                def over_sublanes(x, j):             # row j as [su, Td]
+                    return lax.broadcast_in_dim(
+                        lax.slice_in_dim(x, j, j + 1), (su, td), (0, 1))
+
+                def chain(j0, j1):
+                    """select(u == t[j0], imp[j0], select(u == t[j0+1],
+                    ..., 0)): at most one branch selects non-zero
+                    (distinct live ids; a pad match selects its 0.0
+                    impact), so adding the chain is bit-identical to
+                    adding its rows one by one (+0.0 is exact)."""
+                    x = zeros
+                    for j in reversed(range(j0, j1)):
+                        x = lax.select(
+                            lax.eq(uniq, over_sublanes(terms, j)),
+                            over_sublanes(imps, j), x)
+                    return x
+
+                step = 2 if a_build == "v4" else 1
+                for j in range(0, n, step):
+                    a = a + chain(j, min(j + step, n))
+                return a
+
+            # _PL_ROWS width rows a loop iteration (Mosaic unrolls a
+            # loop wholly or not at all; wholly would grow with the
+            # width), the rest of the width as a static tail
+            a = zeros
+            if width >= _PL_ROWS:    # (a zero-trip loop is still traced)
+                a = lax.fori_loop(
+                    0, width // _PL_ROWS,
+                    lambda g, a: rows(
+                        pl.multiple_of(g * _PL_ROWS, _PL_ROWS), _PL_ROWS, a),
+                    a)
+            if width % _PL_ROWS:
+                a = rows(width - width % _PL_ROWS, width % _PL_ROWS, a)
+            a_ref[pl.ds(r0, su), :] = a
+            return carry
+
+        n_sub = lax.div(n_live + (su - 1), su)
+        lax.fori_loop(0, n_sub, build, 0)
+
+        # rows of the last live chunk past the last built sub-tile:
+        # zeroed, not built (the scratch holds whatever ran before, and
+        # 0 * NaN is not 0)
+        def zero(s, carry):
+            a_ref[pl.ds(pl.multiple_of(s * su, su), su), :] = zeros
+            return carry
+
+        n_chunks = lax.div(n_live + (_PL_TK - 1), _PL_TK)
+        lax.fori_loop(n_sub, n_chunks * (_PL_TK // su), zero, 0)
+
+        # the contraction rides the MXU at its own grain: [B, 128] @
+        # [128, Td] per live chunk (qc arrives chunk-major, so a chunk
+        # is a leading-axis index). HIGHEST keeps f32-equivalent
+        # accumulation (the default bf16 pass costs ~0.4% relative
+        # error — enough to flip top-k near-ties).
+        def contract(k, carry):
+            out_ref[:] += jnp.dot(
+                qc_ref[k],
+                a_ref[pl.ds(pl.multiple_of(k * _PL_TK, _PL_TK), _PL_TK), :],
+                preferred_element_type=jnp.float32,
+                precision=lax.Precision.HIGHEST)
+            return carry
+
+        lax.fori_loop(0, n_chunks, contract, 0)
 
 
 def _pl_tiles(rows_cap: int, B: int, u_cap: int) -> tuple[int, int]:
     """(doc tile, uniq tile) for a block/batch shape. Bigger tiles
     amortize grid overhead; both tiles shrink as B grows so the
-    multi-buffered qc [B, TU] / out [B, TD] blocks plus the A
+    multi-buffered qc [TU/128, B, 128] / out [B, TD] blocks plus the A
     accumulator and MXU temporaries stay inside the 16MB scoped-VMEM
     budget (Mosaic's buffering costs ~2x the naive block arithmetic,
     so the schedule is deliberately conservative). One schedule for
@@ -365,6 +420,17 @@ def _pl_tiles(rows_cap: int, B: int, u_cap: int) -> tuple[int, int]:
     td = min(cap, _PL_TD if rows_cap % _PL_TD == 0 else _PL_TD // 2)
     tu = min(cap, 512 if u_cap % 512 == 0 else 256, u_cap)
     return td, tu
+
+
+def kernel_uniq_lanes(n_uniq: int, B: int, u_cap: int) -> tuple[int, int]:
+    """``(built, tiled)``: the uniq lanes of A the kernel builds for a
+    batch of ``n_uniq`` distinct terms — sub-tiles of ``_PL_SU`` — and
+    what whole uniq tiles would hold (the kernel before PR 27 built
+    those). Host arithmetic for the ``kernel_uniq_*`` counters; the
+    same for every block of a dispatch (the uniq tile does not depend
+    on a block's rows)."""
+    _td, tu = _pl_tiles(_PL_TD, B, u_cap)
+    return -(-n_uniq // _PL_SU) * _PL_SU, -(-n_uniq // tu) * tu
 
 
 def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
@@ -387,17 +453,24 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     check_a_build(a_build)
     rows_cap, width = impact.shape
     B, _ = qc_ext.shape
-    u_cap = uniq.shape[0]
+    # the kernel contracts whole 128-row chunks: a capacity that is not
+    # a multiple (no eligible shape; small direct callers) is padded
+    # with more never-matching ids and zero weights
+    u_cap = -(-uniq.shape[0] // _PL_TK) * _PL_TK
+    grow = u_cap - uniq.shape[0]
     td, tu = _pl_tiles(rows_cap, B, u_cap)
     # the grid floor-divides: a non-multiple capacity would silently
     # drop the trailing tile (callers route through _pallas_eligible,
     # but direct callers must fail loudly, not score wrong)
-    assert rows_cap % td == 0 and u_cap % tu == 0, \
+    assert rows_cap % td == 0 and u_cap % tu == 0 and tu % _PL_TK == 0, \
         (rows_cap, td, u_cap, tu)
     # pad entries of uniq must never match a real term id
-    uniq_col = jnp.where(jnp.arange(u_cap) < n_uniq, uniq,
+    uniq_col = jnp.where(jnp.arange(u_cap) < n_uniq, jnp.pad(uniq, (0, grow)),
                          jnp.int32(-1))[:, None]     # [U1, 1]
-    qc = qc_ext[:, :u_cap]                           # drop the zero column
+    # drop the zero column; chunk-major [U1/128, B, 128], so the kernel
+    # takes a contraction chunk by its leading index
+    qc = jnp.pad(qc_ext[:, :uniq.shape[0]], ((0, 0), (0, grow))).reshape(
+        B, u_cap // _PL_TK, _PL_TK).swapaxes(0, 1)
     imp_t = impact.T                                 # [W, rows] width-major
     term_t = term.T
     if n_rows is None:
@@ -405,8 +478,8 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     lims = jnp.stack([jnp.asarray(n_uniq, jnp.int32),
                       jnp.asarray(n_rows, jnp.int32)])
 
-    kern = _pallas_kernel_v4 if a_build == "v4" else _pallas_kernel
-    kernel = functools.partial(kern, width=width, td=td, tu=tu)
+    kernel = functools.partial(_pallas_kernel, width=width, td=td, tu=tu,
+                               a_build=a_build)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         # u is the INNER axis: the output block for a doc tile stays in
@@ -415,11 +488,13 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
         grid=(rows_cap // td, u_cap // tu),
         in_specs=[
             pl.BlockSpec((tu, 1), lambda d, u, n: (u, 0)),    # uniq ids
-            pl.BlockSpec((B, tu), lambda d, u, n: (0, u)),    # query w
+            pl.BlockSpec((tu // _PL_TK, B, _PL_TK),
+                         lambda d, u, n: (u, 0, 0)),          # query w
             pl.BlockSpec((width, td), lambda d, u, n: (0, d)),  # terms
             pl.BlockSpec((width, td), lambda d, u, n: (0, d)),  # impacts
         ],
         out_specs=pl.BlockSpec((B, td), lambda d, u, n: (0, d)),
+        scratch_shapes=[pltpu.VMEM((tu, td), jnp.float32)],   # A
     )
     return pl.pallas_call(
         kernel,

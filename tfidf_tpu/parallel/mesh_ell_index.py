@@ -550,6 +550,7 @@ class MeshEllSearcher(MeshSearcher):
 
     def _dispatch_chunk(self, snap, qb, k: int):
         kk = min(k, snap.stride)
+        self._count_kernel_uniq(qb)
         return self._get_search_fn(kk)(
             snap.base, snap.delta, snap.df_g, snap.n_docs,
             snap.avgdl, qb), kk

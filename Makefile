@@ -86,65 +86,17 @@
 #                     should be validated against this — see
 #                     utils/faults.py)
 
-#   make probe-overlap  fetch/compute overlap isolation experiment
-#                     (VERDICT r5 Weak #3): two independently fetchable
-#                     device programs + the pipeline executor on a fake
-#                     workload
-#   make bench-overload  zipfian closed-loop overload bench (1x and 2x
-#                     saturating concurrency, per-lane p50/p99 latency,
-#                     shed rate, cache hit rate)
-#   make bench-routers  multi-router scale-out bench: the same zipfian
-#                     closed loop at equal offered load through 1, 2,
-#                     and 4 stateless routers; admitted interactive
-#                     q/s must scale (2 routers >= 1.6x the 1-router
-#                     baseline)
-#   make bench-kernel  r14 kernel-headroom bench: A-build v3 vs v4 vs
-#                     the XLA oracle (parity gated in-run), the
-#                     analytic A-build op-count model, and steady
-#                     commit cost incremental-df vs full-recompute
-#                     across a 4x corpus sweep on the mesh-ELL and
-#                     segments indexes (df_full_recomputes witness
-#                     asserted zero)
-#   make bench-replay  r16 capture/replay bench: a zipfian closed loop
-#                     through a router with the durable request log
-#                     (capture) enabled, then the SAME traffic re-driven
-#                     open-loop at recorded offsets against a fresh
-#                     router — fidelity gated in-run (every captured
-#                     admitted request must replay admitted)
-#   make bench-hybrid r17 hybrid-retrieval bench: batched dense q/s
-#                     (with the achieved model-flop rate) beside the
-#                     sparse plane on the same engine/stream, a
-#                     sparse/dense/hybrid latency table, and
-#                     fused-vs-sparse relevance deltas (MRR@10 /
-#                     recall@10) on the synthetic MS MARCO-style
-#                     slice; backend stamped honestly; writes
-#                     BENCH_r11.json
 #   make chaos-hybrid slow hybrid chaos job: zipfian hybrid/dense
 #                     load with a worker's data plane killed
 #                     mid-scatter — every reply exact or honestly
 #                     X-Scatter-Degraded, never silently partial
 #                     (tests/test_hybrid.py -m slow)
-#   make bench-tier   r18 tiered-postings bench: a synthetic corpus
-#                     provably larger than the hot-set HBM budget,
-#                     phased zipfian search with cold-segment
-#                     skip rate, hot-tier hit rate, upload-ring stall
-#                     time, flat steady-state ingest dps
-#                     (df_full_recomputes asserted zero), and exact
-#                     top-k parity vs the untiered oracle gated on
-#                     every phase; writes BENCH_r12.json
 #   make chaos-tier   slow tiered-storage chaos job: the disk nemesis
 #                     flips bytes in a cold spill file mid-query — the
 #                     rotten spill must be quarantined, repaired from
 #                     the host replica, and every search stays in
 #                     exact untiered-oracle parity
 #                     (tests/test_tiering.py -m slow)
-#   make bench-compute  r20 degraded-mode bench: the same measured
-#                     search loop on the healthy device path and on
-#                     the host-fallback path (device forced sick via
-#                     the nemesis), q/s + p50/p99 side by side with
-#                     in-run bit-parity gating and the steady-state
-#                     zero-recompile witness on the healthy leg;
-#                     writes BENCH_r13.json
 #   make chaos-compute  slow compute-plane chaos job: zipfian load
 #                     over a subprocess fleet while the device nemesis
 #                     OOMs one worker's every dispatch (host-fallback
@@ -203,9 +155,7 @@ PYTEST_FLAGS := -q --continue-on-collection-errors -p no:cacheprovider
         chaos-overload chaos-partition chaos-autopilot chaos-router \
         chaos-powerloss chaos-upgrade chaos-hybrid chaos-tier \
         chaos-compute scrub \
-        faults bench bench-overload bench-routers bench-kernel \
-        bench-replay bench-hybrid bench-tier bench-compute \
-        probe-overlap \
+        faults \
         graftcheck lockdep protocol-witness devicecheck \
         device-witness check trace-demo
 
@@ -314,30 +264,3 @@ scrub:
 
 faults:
 	python -m tfidf_tpu faults list
-
-bench:
-	python bench.py
-
-probe-overlap:
-	python probe_overlap.py
-
-bench-overload:
-	python bench.py --overload
-
-bench-routers:
-	python bench.py --routers
-
-bench-kernel:
-	python bench.py --kernel
-
-bench-replay:
-	python bench.py --replay
-
-bench-hybrid:
-	BENCH_OUT=BENCH_r11.json python bench.py --hybrid
-
-bench-tier:
-	BENCH_OUT=BENCH_r12.json python bench.py --tier
-
-bench-compute:
-	BENCH_OUT=BENCH_r13.json python bench.py --compute

@@ -65,8 +65,8 @@ BM25_K1, BM25_B = 1.2, 0.75          # Config defaults (Lucene's)
 BUDGET_S = 1150.0                    # the contract allows 1200
 STOP_TIMEOUT_S = 60.0                # SIGTERM -> exit, per process
 
-# BASELINE.json configs 2 and 3; corpus shapes are bench.py's
-# (make_texts / make_doc_arrays: Zipf 1.25 tokens, Poisson lengths)
+# BASELINE.json configs 2 and 3; corpus shapes: Zipf 1.25 tokens,
+# Poisson lengths
 FULL = dict(
     served_docs=100_000, served_vocab=200_000, served_len=80,
     served_queries=8192, client_procs=4, clients_per_proc=128,
@@ -192,7 +192,7 @@ class Oracle:
         return out
 
     def mismatch(self, qi: int, hits: list[tuple[str, float]]) -> str | None:
-        """bench.py's parity rule: the returned score SET equals the
+        """The parity rule: the returned score SET equals the
         oracle's top-k positive scores (tie order free), and every
         returned document scores what the oracle says it scores — at
         f32-vs-f64 tolerance (real bugs are orders of magnitude)."""

@@ -2,11 +2,9 @@
 
 Asserts that ``score_block_pallas`` matches the XLA reduce-fusion path
 bit-closely across the eligibility envelope — block shapes, batch
-widths, u_cap sizes, dead-row/dead-uniq tile skipping — for EVERY
-A-build variant (v3 single-row; v4 paired rows, including the
-odd-width tail row), that v3 and v4 are bit-identical to each other on
-the same inputs, and that the top-10 ranking is stable against the XLA
-path. Three callers share ``run_case``:
+widths, u_cap sizes, dead-row/dead-uniq tile skipping, odd widths (the
+pair fold's lone last row) — and that the top-10 ranking is stable
+against the XLA path. Three callers share ``run_case``:
 
 * ``chip_smoke.py``'s engine stage runs ``CASES`` on the TPU, where the
   kernels are Mosaic programs — the on-chip record;
@@ -28,9 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tfidf_tpu.ops.ell import (A_BUILD_VARIANTS, _pallas_eligible,
-                               _score_block, pallas_interpret,
-                               score_block_pallas)
+from tfidf_tpu.ops.ell import (_pallas_eligible, _score_block,
+                               pallas_interpret, score_block_pallas)
 from tfidf_tpu.ops.scoring import _compile_queries, make_query_batch
 
 TOP_K = 10
@@ -44,7 +41,7 @@ def make_case(rng, *, rows_cap, width, n_rows, B, n_terms, u_req,
               vocab=500_000, ragged=False):
     """Random ELL block + query batch. Term ids are DISTINCT within
     each row (the layout contract every ELL builder guarantees and the
-    v4 paired A-build relies on: stride-offset construction — position
+    kernel's pair fold relies on: stride-offset construction — position
     w draws from the congruence class w mod width). Pad rows
     (>= n_rows) are zeroed like the real build; ``ragged`` additionally
     zeroes a random per-row tail (within-row trailing pads, the shape
@@ -78,18 +75,14 @@ def make_case(rng, *, rows_cap, width, n_rows, B, n_terms, u_req,
     return imp, term, qb
 
 
-def run_case(name, rng, *, a_builds=A_BUILD_VARIANTS, **kw):
-    """One case, every requested A-build variant on the SAME inputs:
-    each variant vs the XLA oracle, plus cross-variant bitwise
-    identity (v4's pair fold adds 0.0 exactly where v3 adds it, so the
-    variants must agree to the BIT, not just a tolerance)."""
+def run_case(name, rng, **kw):
+    """One case: the kernel against the XLA oracle on the same inputs,
+    scores within 1e-4 and the top-10 ranking identical."""
     imp, term, qb = make_case(rng, **kw)
     vocab = kw.get("vocab", 500_000)
     rows_cap, B = kw["rows_cap"], kw["B"]
     u_cap = qb.uniq.shape[0]
-    for a_build in a_builds:
-        assert _pallas_eligible(rows_cap, B, u_cap, a_build), \
-            (name, a_build, rows_cap, B, u_cap)
+    assert _pallas_eligible(rows_cap, B, u_cap), (name, rows_cap, B, u_cap)
     imp_d = jnp.asarray(imp)
     term_d = jnp.asarray(term)
     n_rows = jnp.int32(kw["n_rows"])
@@ -99,46 +92,28 @@ def run_case(name, rng, *, a_builds=A_BUILD_VARIANTS, **kw):
         from tfidf_tpu.ops.scoring import QueryBatch
         q = QueryBatch(uniq, n_uniq, slots, weights)
         slot_of, qc_ext = _compile_queries(q, vocab)
-        outs = tuple(
-            score_block_pallas(imp_d, term_d, q.uniq, q.n_uniq, qc_ext,
-                               n_rows, a_build=a)
-            for a in a_builds)
+        out = score_block_pallas(imp_d, term_d, q.uniq, q.n_uniq, qc_ext,
+                                 n_rows)
         ref = _score_block(imp_d, term_d, slot_of, qc_ext.T, 2048)
-        return outs, ref
+        return out, ref
 
-    outs, ref = run(jnp.asarray(qb.uniq), jnp.asarray(qb.n_uniq),
-                    jnp.asarray(qb.slots), jnp.asarray(qb.weights))
+    out, ref = run(jnp.asarray(qb.uniq), jnp.asarray(qb.n_uniq),
+                   jnp.asarray(qb.slots), jnp.asarray(qb.weights))
     live = slice(None), slice(None, kw["n_rows"])  # dead rows: both 0
+    a = np.asarray(out)[live]
     b = np.asarray(ref)[live]
     k = min(TOP_K, kw["n_rows"])
-    tb = np.argsort(-b, axis=1, kind="stable")[:, :k]
-    variants = {}
-    cross_equal = True
-    first = None
-    for a_build, out in zip(a_builds, outs):
-        a = np.asarray(out)[live]
-        if first is None:
-            first = a
-        else:
-            cross_equal = cross_equal and bool(np.array_equal(first, a))
-        max_abs = float(np.max(np.abs(a - b))) if a.size else 0.0
-        denom = np.maximum(np.abs(b), 1e-6)
-        max_rel = float(np.max(np.abs(a - b) / denom)) if a.size else 0.0
-        ta = np.argsort(-a, axis=1, kind="stable")[:, :k]
-        topk_equal = bool((ta == tb).all())
-        variants[a_build] = {
-            "max_abs_delta": max_abs, "max_rel_delta": max_rel,
-            "topk_identical": topk_equal,
-            "ok": max_abs < 1e-4 and topk_equal,
-        }
-    ok = cross_equal and all(v["ok"] for v in variants.values())
-    log(f"[{name}] " + " ".join(
-        f"{ab}: max|d|={v['max_abs_delta']:.2e} "
-        f"topk={v['topk_identical']}" for ab, v in variants.items())
-        + f" cross_bitwise={cross_equal} ok={ok}")
-    return {"name": name, "variants": variants,
-            "cross_variant_bitwise_equal": cross_equal,
-            "ok": ok, **{k2: v for k2, v in kw.items()}}
+    max_abs = float(np.max(np.abs(a - b))) if a.size else 0.0
+    denom = np.maximum(np.abs(b), 1e-6)
+    max_rel = float(np.max(np.abs(a - b) / denom)) if a.size else 0.0
+    topk_equal = bool(
+        (np.argsort(-a, axis=1, kind="stable")[:, :k]
+         == np.argsort(-b, axis=1, kind="stable")[:, :k]).all())
+    ok = max_abs < 1e-4 and topk_equal
+    log(f"[{name}] max|d|={max_abs:.2e} topk={topk_equal} ok={ok}")
+    return {"name": name, "max_abs_delta": max_abs,
+            "max_rel_delta": max_rel, "topk_identical": topk_equal,
+            "ok": ok, **kw}
 
 
 # the hardware matrix: north-star-like shapes + every eligibility edge
@@ -165,8 +140,8 @@ CASES = [
     # heavy dead-tile skipping: few live rows / few live uniq
     dict(rows_cap=65536, width=64, n_rows=700, B=256, n_terms=4,
          u_req=4096),
-    # v4 edges: ODD width (tail row), within-row ragged pads, a small
-    # vocabulary (dense term-id collisions between rows)
+    # pair-fold edges: ODD width (lone last row), within-row ragged
+    # pads, a small vocabulary (dense term-id collisions between rows)
     dict(rows_cap=4096, width=33, n_rows=4000, B=256, n_terms=4,
          u_req=512),
     dict(rows_cap=4096, width=48, n_rows=4000, B=256, n_terms=4,
@@ -181,7 +156,7 @@ CASES = [
 
 # the same edges at a scale the Pallas interpreter can run: small block
 # floor, rows_cap not a multiple of 512, the U1=1024 boundary, odd
-# widths (v4 tail row), within-row ragged pads, a small vocabulary
+# widths (lone last row), within-row ragged pads, a small vocabulary
 INTERPRET_CASES = [
     dict(rows_cap=256, width=16, n_rows=200, B=64, n_terms=4,
          u_req=256),
@@ -212,7 +187,6 @@ def run_matrix(seed: int = 7) -> dict:
         "mosaic_compiled": not pallas_interpret(),
         "device_kind": dev.device_kind,
         "jax": jax.__version__,
-        "a_builds": list(A_BUILD_VARIANTS),
         "all_ok": all(r["ok"] for r in results),
         "cases": results,
     }

@@ -41,7 +41,7 @@ ell.pallas_interpret = lambda: False
 
 BATCHES = (32, 512, 1024, 2048)
 # the ladder's ends and its 1.5x rungs, plus what a terms-axis split
-# leaves of a rung (8/8 = 1) and an odd width (v4's tail row)
+# leaves of a rung (8/8 = 1) and an odd width (the lone last row)
 WIDTHS = (1, 8, 12, 33, 64, 256)
 assert set(WIDTHS) & set(ell.ELL_WIDTH_LADDER) >= {8, 12, 64, 256}
 
@@ -55,16 +55,15 @@ def block_shapes(B: int):
         yield 768, 256
 
 
-def compile_block(dev, rows: int, width: int, B: int, u_cap: int,
-                  a_build: str) -> None:
+def compile_block(dev, rows: int, width: int, B: int,
+                  u_cap: int) -> None:
     sh = SingleDeviceSharding(dev)
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
-    assert ell._pallas_eligible(rows, B, u_cap, a_build)
-    fn = jax.jit(lambda imp, term, uniq, nu, qc, nr: ell.score_block_pallas(
-        imp, term, uniq, nu, qc, nr, a_build=a_build))
+    assert ell._pallas_eligible(rows, B, u_cap)
+    fn = jax.jit(ell.score_block_pallas)
     fn.lower(s((rows, width), jnp.float32), s((rows, width), jnp.int32),
              s((u_cap,), jnp.int32), s((), jnp.int32),
              s((B, u_cap + 1), jnp.float32), s((), jnp.int32)).compile()
@@ -115,9 +114,8 @@ def compile_mesh_step(devices) -> list[dict]:
 
     args = jax.tree.map(abstract, (snap.base, snap.delta, snap.df_g,
                                    snap.n_docs, snap.avgdl, qb))
-    for a_build in ell.A_BUILD_VARIANTS:
-        make_mesh_ell_search(tpu_mesh, k=10, a_build=a_build,
-                             packed=True).lower(*args).compile()
+    make_mesh_ell_search(tpu_mesh, k=10,
+                         packed=True).lower(*args).compile()
 
     def each(arrays, shapes):
         return tuple(abstract(a, shape) for a, shape in zip(arrays, shapes))
@@ -151,7 +149,7 @@ def compile_mesh_step(devices) -> list[dict]:
                 slots=abstract(qb.slots, [B, qb.slots.shape[1]]),
                 weights=abstract(qb.weights, [B, qb.weights.shape[1]]))
             step = make_mesh_ell_search(
-                tpu_mesh, k=10, a_build="v4", packed=True).lower(
+                tpu_mesh, k=10, packed=True).lower(
                 base, delta, abstract(snap.df_g, [vocab_cap]),
                 abstract(snap.n_docs), abstract(snap.avgdl), q).compile()
             text = step.as_text()
@@ -200,7 +198,7 @@ def compile_cell_step(dev, blocks, doc_cap: int, B: int) -> None:
         tuple(s(b, f32) for b in blocks), tuple(s(b, i32) for b in blocks),
         live, None, None, None, s((doc_cap,), f32), s((1 << 19,), f32), q,
         s((), f32), s((), f32), s((doc_cap,), f32),
-        model="bm25", use_pallas=True, a_build="v4").compile()
+        model="bm25", use_pallas=True).compile()
     topk = packed_topk_chunked.lower(
         tuple(s((B, rows), f32) for rows, _ in blocks), live,
         k=10).compile()
@@ -220,24 +218,20 @@ def main() -> int:
                                         topology_name="v5e:2x2")
     failures: list[str] = []
     compiled = 0
-    for a_build in ell.A_BUILD_VARIANTS:
-        for B in BATCHES:
-            for rows, u_cap in block_shapes(B):
-                for width in WIDTHS:
-                    what = (f"{a_build} rows={rows} width={width} "
-                            f"B={B} u_cap={u_cap}")
-                    try:
-                        compile_block(topo.devices[0], rows, width, B,
-                                      u_cap, a_build)
-                        compiled += 1
-                    except Exception as e:   # reported, all of them
-                        failures.append(
-                            f"{what}: {type(e).__name__}: "
-                            f"{str(e)[:300]}")
+    for B in BATCHES:
+        for rows, u_cap in block_shapes(B):
+            for width in WIDTHS:
+                what = f"rows={rows} width={width} B={B} u_cap={u_cap}"
+                try:
+                    compile_block(topo.devices[0], rows, width, B, u_cap)
+                    compiled += 1
+                except Exception as e:   # reported, all of them
+                    failures.append(
+                        f"{what}: {type(e).__name__}: {str(e)[:300]}")
     mesh_cells: list[dict] = []
     try:
         mesh_cells = compile_mesh_step(topo.devices)
-        compiled += len(ell.A_BUILD_VARIANTS)
+        compiled += 1
     except Exception as e:
         failures.append(f"mesh (4,1) step: {type(e).__name__}: "
                         f"{str(e)[:600]}")

@@ -9,7 +9,7 @@ TPU pod runs (SURVEY.md §5.8). Every process executes the identical
 program on identical inputs and must get the identical (and
 local-engine-equivalent) results.
 
-Invoked by tests/test_multihost.py and probe_multihost.py; not a test
+Invoked by tests/test_multihost.py; not a test
 module itself.
 """
 

@@ -5,7 +5,7 @@ Two pins per index family (ISSUE 15 tentpole b):
 * **witness**: steady-state commits never invoke the O(corpus) full
   stat recompute — the ``df_full_recomputes`` counter moves only on
   the documented exceptional paths (first commit / vocab growth /
-  mesh rebuild / the ``df_incremental=false`` control path);
+  mesh rebuild);
 * **exact parity**: after randomized upsert → delete → merge → commit
   sequences, the incrementally maintained device df and the N/avgdl
   scalars equal a full recompute BIT-EXACTLY (df counts are integer-
@@ -91,16 +91,6 @@ class TestSegmentsWitness:
         e.ingest_text("big.txt", " ".join(f"x{i}" for i in range(80)))
         e.commit()
         assert e.index.df_full_recomputes == base + 1
-        assert_segment_stats_exact(e)
-
-    def test_control_path_counts_every_commit(self, tmp_path):
-        e = make_engine(tmp_path, "ctl", "segments",
-                        df_incremental=False)
-        rng = np.random.default_rng(1)
-        for i in range(3):
-            e.ingest_text(f"d{i}.txt", rand_text(rng))
-            e.commit()
-        assert e.index.df_full_recomputes == 3
         assert_segment_stats_exact(e)
 
 
@@ -212,27 +202,3 @@ class TestMeshWitness:
         mesh_stats_exact(e)
         # witness only ever tracks rebuilds, never steady commits
         assert e.index.df_full_recomputes == e.index.rebuilds
-
-    def test_control_path_counts_every_commit(self, tmp_path):
-        e = make_engine(tmp_path, "mc", "mesh", df_incremental=False)
-        rng = np.random.default_rng(6)
-        for i in range(4):
-            e.ingest_text(f"d{i}.txt", rand_text(rng))
-        e.commit()
-        e.ingest_text("x.txt", rand_text(rng))
-        e.commit()
-        # rebuild resync + one control recompute PER commit
-        assert e.index.df_full_recomputes >= 3
-        mesh_stats_exact(e)
-        # control and incremental engines agree end to end
-        e2 = make_engine(tmp_path, "mi", "mesh")
-        rng = np.random.default_rng(6)
-        for i in range(4):
-            e2.ingest_text(f"d{i}.txt", rand_text(rng))
-        e2.commit()
-        e2.ingest_text("x.txt", rand_text(rng))
-        e2.commit()
-        q = WORDS[2] + " " + WORDS[9]
-        got = [(h.name, round(h.score, 5)) for h in e.search(q)]
-        want = [(h.name, round(h.score, 5)) for h in e2.search(q)]
-        assert got == want

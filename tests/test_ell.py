@@ -248,7 +248,7 @@ class TestPallasKernel:
         imp = rng.random((rows_cap, width), dtype=np.float32)
         # distinct term ids within each row — the layout contract every
         # ELL builder guarantees (one posting per distinct term) and
-        # the v4 paired A-build relies on: position w draws from the
+        # the kernel's pair fold relies on: position w draws from the
         # congruence class w mod width
         base = rng.integers(0, max(vocab // width, 1),
                             size=(rows_cap, width))
@@ -260,11 +260,10 @@ class TestPallasKernel:
         term[-rows_cap // 4:] = 0
         return jnp.asarray(imp), jnp.asarray(term)
 
-    @pytest.mark.parametrize("a_build", ["v3", "v4"])
     @pytest.mark.parametrize("vocab", [1 << 12, 1 << 17])
-    def test_matches_xla_block_path(self, rng, a_build, vocab):
-        """Both A-build variants vs the XLA oracle, on a small and a
-        large vocabulary."""
+    def test_matches_xla_block_path(self, rng, vocab):
+        """The kernel vs the XLA oracle, on a small and a large
+        vocabulary."""
         from tfidf_tpu.ops.ell import _score_block, score_block_pallas
         from tfidf_tpu.ops.scoring import (_compile_queries,
                                            make_query_batch)
@@ -276,17 +275,15 @@ class TestPallasKernel:
         slot_of, qc_ext = _compile_queries(qb, vocab)
         ref = _score_block(imp, term, slot_of, qc_ext.T, 256)
         out = score_block_pallas(imp, term, jnp.asarray(qb.uniq),
-                                 jnp.asarray(qb.n_uniq), qc_ext,
-                                 a_build=a_build)
+                                 jnp.asarray(qb.n_uniq), qc_ext)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
 
     @pytest.mark.parametrize("width", [7, 16, 33])
-    def test_v4_bitwise_equals_v3(self, rng, width):
-        """The pair fold adds 0.0 exactly where v3 adds it, so v4
-        (odd widths included — the static tail row) must agree with v3
-        to the BIT."""
-        from tfidf_tpu.ops.ell import score_block_pallas
+    def test_matches_xla_at_width(self, rng, width):
+        """The pair fold at widths with and without the static tail and
+        its lone last row (7, 33), against the XLA oracle."""
+        from tfidf_tpu.ops.ell import _score_block, score_block_pallas
         from tfidf_tpu.ops.scoring import (_compile_queries,
                                            make_query_batch)
         for vocab in (1 << 14, 1 << 16):
@@ -296,13 +293,14 @@ class TestPallasKernel:
             q_terms[0, 0] = int(np.asarray(term)[0, 0])   # force a hit
             q_weights = (rng.random((B, 4), dtype=np.float32) + 0.1)
             qb = make_query_batch(q_terms, q_weights, min_slots=256)
-            _slot_of, qc_ext = _compile_queries(qb, vocab)
-            outs = [np.asarray(score_block_pallas(
+            slot_of, qc_ext = _compile_queries(qb, vocab)
+            ref = np.asarray(_score_block(imp, term, slot_of, qc_ext.T,
+                                          256))
+            out = np.asarray(score_block_pallas(
                 imp, term, jnp.asarray(qb.uniq), jnp.asarray(qb.n_uniq),
-                qc_ext, a_build=a))
-                for a in ("v3", "v4")]
-            assert np.abs(outs[0]).max() > 0
-            np.testing.assert_array_equal(outs[0], outs[1])
+                qc_ext))
+            assert np.abs(ref).max() > 0
+            np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
     def test_pad_uniq_never_matches_term_zero(self, rng):
         """uniq is zero-padded but term id 0 is real: pad entries must
@@ -324,9 +322,9 @@ class TestPallasKernel:
         assert np.asarray(out).max() == 0.0
 
     def test_end_to_end_engine_equivalence(self, tmp_path):
-        """Engine with use_pallas on eligible shapes == engine without,
-        for BOTH A-build variants. min_doc_capacity=512 makes every
-        block eligible (rows_cap 512)."""
+        """Engine with use_pallas on eligible shapes == engine without.
+        min_doc_capacity=512 makes every block eligible (rows_cap
+        512)."""
         from tfidf_tpu.engine.engine import Engine
         from tfidf_tpu.utils.config import Config
 
@@ -336,12 +334,11 @@ class TestPallasKernel:
             words = rng.integers(0, 200, size=int(rng.integers(3, 30)))
             texts[f"d{i}.txt"] = " ".join(f"w{w}" for w in words)
 
-        def build(use_pallas, a_build="v4"):
-            cfg = Config(documents_path=str(
-                             tmp_path / f"{use_pallas}-{a_build}"),
+        def build(use_pallas):
+            cfg = Config(documents_path=str(tmp_path / f"{use_pallas}"),
                          min_doc_capacity=512, min_vocab_capacity=256,
                          query_batch=8, max_query_terms=8,
-                         use_pallas=use_pallas, kernel_a_build=a_build)
+                         use_pallas=use_pallas)
             e = Engine(cfg)
             for n, t in texts.items():
                 e.ingest_text(n, t)
@@ -352,8 +349,7 @@ class TestPallasKernel:
         queries = ["w3 w17", "w100 w5 w9", "w42"]
         hx = [[(h.name, round(h.score, 5)) for h in ex.search(q)]
               for q in queries]
-        for a_build in ("v3", "v4"):
-            ep = build(True, a_build)
-            for q, want in zip(queries, hx):
-                hp = [(h.name, round(h.score, 5)) for h in ep.search(q)]
-                assert hp == want, (a_build, q, hp, want)
+        ep = build(True)
+        for q, want in zip(queries, hx):
+            hp = [(h.name, round(h.score, 5)) for h in ep.search(q)]
+            assert hp == want, (q, hp, want)

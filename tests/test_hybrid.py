@@ -585,6 +585,12 @@ class TestHybridFailover:
                 json.dumps({"worker": victim.url}).encode()))
             assert resp["draining"] is True
 
+            # Known (ROADMAP D12): on a loaded host about one run in
+            # five fails a ``during:`` check below, documents or one
+            # stage's hits missing — a search planned before the
+            # ownership flip reaches the drained worker after the
+            # post-flip /worker/delete. The product's race, not this
+            # wait's: the checks stay as they are.
             def drained():
                 for q in QUERIES:   # exact parity DURING the drain
                     got, _ = _post_search(leader, q, mode="hybrid",

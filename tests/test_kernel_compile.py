@@ -1,10 +1,9 @@
 """Nothing ``_pallas_eligible`` admits may be refused by the v5e compiler.
 
 Runs ``tests/kernel_compile_worker.py`` (compile-only Mosaic through
-libtpu's topology client — no chip, seconds) in a subprocess: both
-A-build variants x batch buckets on every step of the tile schedule x
-widths from the ELL ladder incl. 12, a mesh-split width of 1 and an odd
-one, plus the (4, 1) ``make_mesh_ell_search`` program and the served
+libtpu's topology client — no chip, seconds) in a subprocess: batch
+buckets on every step of the tile schedule x widths from the ELL ladder
+incl. 12, a mesh-split width of 1 and an odd one, plus the (4, 1) ``make_mesh_ell_search`` program and the served
 device step at the benchmark cells' shapes. Interpret-mode
 parity (``tests/test_kernel_parity.py``) cannot see what this sees: a
 kernel the interpreter runs happily and Mosaic rejects.
@@ -43,7 +42,7 @@ def _failures(report, of_cells: bool) -> str:
 def test_every_eligible_shape_compiles_for_v5e(report):
     assert not _failures(report, of_cells=False)
     # a run that compiled nothing proves nothing
-    assert report["compiled"] >= 70, report
+    assert report["compiled"] >= 37, report
 
 
 def test_cells_device_step_compiles_for_v5e(report):

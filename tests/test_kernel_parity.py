@@ -2,9 +2,8 @@
 
 Drives the SAME ``kernel_parity.py`` case machinery the hardware
 harness uses, on CPU-scaled shapes in Pallas interpret mode — so every
-tier-1 run exercises BOTH A-build variants (v3 single-row; v4 paired
-rows incl. the odd-width tail) against the XLA reduce-fusion oracle
-plus the v3==v4 bitwise-identity contract, and a kernel regression
+tier-1 run holds the kernel (paired rows incl. the lone last row of an
+odd width) to the XLA reduce-fusion oracle, and a kernel regression
 fails CI on a CPU box. Whether Mosaic ACCEPTS the kernel is
 ``tests/test_kernel_compile.py``; its results on a chip are
 ``chip_smoke.py``'s engine stage.
@@ -35,26 +34,10 @@ def test_interpret_parity(i):
     rng = np.random.default_rng(100 + i)
     r = run_case(f"t1-case{i}", rng, **T1_CASES[i])
     assert r["ok"], r
-    assert r["cross_variant_bitwise_equal"], r
-
-
-def test_eligibility_envelope_shared_across_variants():
-    """A config flip between A-build variants must never change WHICH
-    blocks ride the kernel — only how A is built (the gate contract)."""
-    for rows_cap in (128, 256, 768, 4096, 4097):
-        for B in (64, 2048, 4096):
-            for u_cap in (256, 512, 640):
-                assert (_pallas_eligible(rows_cap, B, u_cap, "v3")
-                        == _pallas_eligible(rows_cap, B, u_cap, "v4")), \
-                    (rows_cap, B, u_cap)
-    # an unknown variant fails LOUDLY — returning False would silently
-    # route the whole engine to the XLA path on a config typo
-    with pytest.raises(ValueError, match="kernel_a_build"):
-        _pallas_eligible(512, 64, 256, "v9")
 
 
 def test_ingest_rejects_duplicate_or_unsorted_ids():
-    """The layout contract the v4 pair fold relies on (distinct term
+    """The layout contract the kernel's pair fold relies on (distinct term
     ids per row) is enforced at the ingest seam: a raw-array caller
     passing duplicate or unsorted ids must fail loudly there, not
     score differently on the kernel vs the XLA path."""
@@ -86,7 +69,7 @@ def test_tile_schedule_divides_capacities():
     for rows_cap in (256, 768, 1024, 4096, 65536):
         for B in (64, 512, 1024, 2048):
             for u_cap in (256, 512, 1024, 4096):
-                if not _pallas_eligible(rows_cap, B, u_cap, "v4"):
+                if not _pallas_eligible(rows_cap, B, u_cap):
                     continue
                 td, tu = _pl_tiles(rows_cap, B, u_cap)
                 assert rows_cap % td == 0 and u_cap % tu == 0, \
@@ -95,22 +78,25 @@ def test_tile_schedule_divides_capacities():
 
 # ---- the sub-tile nest (PR 27): work follows n_uniq -----------------
 #
-# One block of an ODD width (v4's tail row) under query batches whose
-# distinct-term count sits on every edge of the nest: the 8-row sublane
-# grain, the sub-tile (_PL_SU), the 128-row contraction chunk, the
-# 512-lane uniq tile and the whole capacity.
+# One block under query batches whose distinct-term count sits on every
+# edge of the nest: the 8-row sublane grain, the sub-tile (_PL_SU), the
+# 128-row contraction chunk, the 512-lane uniq tile and the whole
+# capacity. Its WIDTH takes the four shapes one sub-tile's build can
+# have: no ``_PL_ROWS``-row loop at all (7), the loop alone (32), the
+# loop plus a lone last row (33), the loop plus a tail of pairs (38).
 
 _SU = ell._PL_SU
 N_UNIQS = (1, 7, 8, 9, _SU - 1, _SU, _SU + 1, 127, 128, 129, 511, 512,
            513, 1024)
-_ROWS, _WIDTH, _LIVE_ROWS, _B, _U_CAP, _VOCAB = 512, 33, 400, 16, 1024, 4000
+WIDTHS = (7, 32, 33, 38)
+_ROWS, _LIVE_ROWS, _B, _U_CAP, _VOCAB = 512, 400, 16, 1024, 4000
 
 
-def _subtile_case(n_uniq: int):
+def _subtile_case(n_uniq: int, width: int):
     """(impact, term, QueryBatch) with exactly ``n_uniq`` distinct query
     terms in a capacity of 1,024; half of them occur in the block."""
     rng = np.random.default_rng(1000 + n_uniq)
-    imp, term, _qb = make_case(rng, rows_cap=_ROWS, width=_WIDTH,
+    imp, term, _qb = make_case(rng, rows_cap=_ROWS, width=width,
                                n_rows=_LIVE_ROWS, B=_B, n_terms=4,
                                u_req=_U_CAP, vocab=_VOCAB, ragged=True)
     in_block = np.unique(term[:_LIVE_ROWS][imp[:_LIVE_ROWS] > 0])
@@ -134,7 +120,12 @@ def _compiled(q):
     return _compile_queries(jax.tree.map(jnp.asarray, q), _VOCAB)
 
 
-def _kernel_scores(imp, term, q, a_build, *, poison=False):
+# one compile a width: ``n_uniq`` and the block's contents are traced
+_kernel = jax.jit(score_block_pallas)
+_xla = jax.jit(_score_block, static_argnums=4)
+
+
+def _kernel_scores(imp, term, q, *, poison=False):
     """The kernel's ``[B, rows_cap]`` for ``q``; ``poison`` puts term
     ids that DO occur in the block into ``uniq``'s pad rows and a
     non-zero weight into their ``qc`` columns."""
@@ -143,22 +134,22 @@ def _kernel_scores(imp, term, q, a_build, *, poison=False):
     if poison:
         uniq[n:] = np.resize(term[:_LIVE_ROWS, :4].ravel(), _U_CAP - n)
         qc_ext = qc_ext.at[:, n:_U_CAP].set(7.0)
-    return np.asarray(score_block_pallas(
+    return np.asarray(_kernel(
         jnp.asarray(imp), jnp.asarray(term), jnp.asarray(uniq),
-        jnp.int32(n), qc_ext, jnp.int32(_LIVE_ROWS), a_build=a_build))
+        jnp.int32(n), qc_ext, jnp.int32(_LIVE_ROWS)))
 
 
-@pytest.mark.parametrize("a_build", ell.A_BUILD_VARIANTS)
+@pytest.mark.parametrize("width", WIDTHS)
 @pytest.mark.parametrize("n_uniq", N_UNIQS)
-def test_subtile_nest_matches_xla(n_uniq, a_build):
+def test_subtile_nest_matches_xla(n_uniq, width):
     """Kernel against the XLA path within the harness's tolerance, for
     every edge of the nest; and what lies in ``uniq``'s pad rows and
     their ``qc`` columns never reaches a score."""
-    imp, term, q = _subtile_case(n_uniq)
-    got = _kernel_scores(imp, term, q, a_build)
+    imp, term, q = _subtile_case(n_uniq, width)
+    got = _kernel_scores(imp, term, q)
     slot_of, qc_ext = _compiled(q)
-    want = np.asarray(_score_block(jnp.asarray(imp), jnp.asarray(term),
-                                   slot_of, qc_ext.T, 2048))
+    want = np.asarray(_xla(jnp.asarray(imp), jnp.asarray(term),
+                           slot_of, qc_ext.T, 2048))
     assert np.abs(want).max() > 0
     assert np.abs(got - want)[:, :_LIVE_ROWS].max() < 1e-4
     assert not got[:, _LIVE_ROWS:].any()       # all-pad rows
@@ -167,11 +158,4 @@ def test_subtile_nest_matches_xla(n_uniq, a_build):
         np.argsort(-got[:, :_LIVE_ROWS], axis=1, kind="stable")[:, :k],
         np.argsort(-want[:, :_LIVE_ROWS], axis=1, kind="stable")[:, :k])
     assert np.array_equal(
-        got, _kernel_scores(imp, term, q, a_build, poison=True))
-
-
-@pytest.mark.parametrize("n_uniq", N_UNIQS)
-def test_subtile_nest_variants_bitwise_equal(n_uniq):
-    imp, term, q = _subtile_case(n_uniq)
-    assert np.array_equal(_kernel_scores(imp, term, q, "v3"),
-                          _kernel_scores(imp, term, q, "v4"))
+        got, _kernel_scores(imp, term, q, poison=True))

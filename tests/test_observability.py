@@ -1049,8 +1049,9 @@ class TestStageTimer:
                 pass
             assert opened == [("assemble", {})]
 
-    @pytest.mark.parametrize("a_build", ["v3", "v4"])
-    def test_kernel_has_a_fixed_name(self, a_build):
+    def test_kernel_has_a_fixed_name(self):
+        """``ell_score_v4`` is the label the device trace's reduction
+        and PERF.md §3 know the kernel by."""
         import jax
         import jax.numpy as jnp
 
@@ -1058,9 +1059,9 @@ class TestStageTimer:
         rows, width, B, u_cap = 256, 8, 4, 256
         jaxpr = jax.make_jaxpr(
             lambda imp, term, uniq, qc: score_block_pallas(
-                imp, term, uniq, jnp.int32(3), qc, a_build=a_build))(
+                imp, term, uniq, jnp.int32(3), qc))(
             jnp.zeros((rows, width), jnp.float32),
             jnp.zeros((rows, width), jnp.int32),
             jnp.zeros((u_cap,), jnp.int32),
             jnp.zeros((B, u_cap + 1), jnp.float32))
-        assert f"ell_score_{a_build}" in str(jaxpr)
+        assert "ell_score_v4" in str(jaxpr)

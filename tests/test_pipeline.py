@@ -160,27 +160,57 @@ def test_concurrent_callers_share_one_executor():
 
 
 def test_executor_smoke_fake_two_program_workload():
-    """Tier-1-safe CPU smoke of the overlap machinery: the committed
-    probe's executor experiment at tiny cost, asserting correctness,
-    FIFO fetch order, and the deterministic overlap witness (chunk 0's
-    fetch observed chunk 1's dispatch in flight)."""
-    import os
-    import sys
+    """Tier-1-safe CPU smoke of the overlap machinery at tiny cost: a
+    synthetic 2-program-shaped workload (dispatch sleeps like a device
+    queue, fetch like the d2h link) gives the serial loop's results in
+    FIFO fetch order, and a DETERMINISTIC overlap witness: chunk 0's
+    fetch blocks until chunk 1's dispatch has started, which can only
+    complete if dispatch and fetch genuinely run concurrently (a
+    serialized pipeline runs into the timeout and fails the
+    handshake)."""
+    n_chunks, cost_s = 4, 0.002
+    record: list = []
 
-    # probe_overlap.py lives at the repo root, which only `python -m
-    # pytest` from the root puts on sys.path — console-script pytest
-    # (or an IDE runner with another cwd) needs it added explicitly
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    from probe_overlap import executor_workload
+    def dispatch(i):
+        time.sleep(cost_s)
+        record.append(("d", i))
+        return (i,)
 
-    res = executor_workload(n_chunks=4, compute_s=0.002, rtt_s=0.002,
-                            depth=2)
-    assert res["results_ok"]
-    assert res["fetch_order_fifo"]
-    assert res["overlap_witnessed"], \
-        "dispatch and fetch never overlapped — pipeline serialized"
+    def fetch(i):
+        time.sleep(cost_s)
+        record.append(("f", i))
+        return i * i
+
+    serial_out = [fetch(*dispatch(i)) for i in range(n_chunks)]
+    del record[:]
+    ex = PipelineExecutor(depth=2, name="probe")
+    try:
+        futures = [ex.submit(lambda i=i: dispatch(i), fetch)
+                   for i in range(n_chunks)]
+        pipe_out = [f.result() for f in futures]
+        assert serial_out == pipe_out == [i * i for i in range(n_chunks)]
+        assert [i for s, i in record if s == "f"] == list(range(n_chunks))
+
+        # the witness: an event handshake, no timing
+        started_d1 = threading.Event()
+        witnessed = threading.Event()
+
+        def d(i):
+            if i == 1:
+                started_d1.set()
+            return (i,)
+
+        def f(i):
+            if i == 0 and started_d1.wait(timeout=5.0):
+                witnessed.set()
+            return i
+
+        for w in [ex.submit(lambda i=i: d(i), f) for i in range(2)]:
+            w.result()
+        assert witnessed.is_set(), \
+            "dispatch and fetch never overlapped — pipeline serialized"
+    finally:
+        ex.stop()
 
 
 def test_stop_fails_pending_and_rejects_new():

@@ -17,7 +17,6 @@ The reference ships one Spring Boot fat jar that every node runs
     drain        client: migrate a worker empty before decommission
     trace        client: fetch + render a distributed request trace
     autopilot    client: SLO-autopilot state, decision audit, kill switch
-    bench        run the TPU benchmark
     faults       chaos tooling: list registered fault points
 
 Config resolution (lowest to highest): dataclass defaults, --config JSON
@@ -993,20 +992,6 @@ def cmd_scrub(args) -> int:
     return 1 if ckpt_bad or store_bad else 0
 
 
-def cmd_bench(args) -> int:
-    # bench.py lives at the repo root, not inside the package
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if not os.path.exists(os.path.join(root, "bench.py")):
-        print("bench.py not found (requires a repo checkout)",
-              file=sys.stderr)
-        return 1
-    sys.path.insert(0, root)
-    import bench
-
-    bench.main()
-    return 0
-
-
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="tfidf_tpu",
@@ -1144,9 +1129,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="raw JSON instead of the rendered table")
     s.set_defaults(fn=cmd_autopilot)
 
-    s = sub.add_parser("bench", help="run the TPU benchmark")
-    s.set_defaults(fn=cmd_bench)
-
     s = sub.add_parser("scrub",
                        help="storage-integrity verification: checkpoint "
                             "manifests + placed-docs CRC ledger")
@@ -1198,7 +1180,7 @@ def _apply_platform_override() -> None:
 def main(argv: list[str] | None = None) -> int:
     _apply_platform_override()
     args = build_parser().parse_args(argv)
-    if args.fn in (cmd_serve, cmd_ingest, cmd_search, cmd_bench):
+    if args.fn in (cmd_serve, cmd_ingest, cmd_search):
         # the commands that compile: a restarted node finds its
         # executables instead of recompiling every start
         from tfidf_tpu.utils.compile_cache import configure_compile_cache

@@ -413,7 +413,7 @@ class SearchNode(ScatterReadPlane):
                              else None)
         # traffic-capture tap (utils/storage.py RequestLog): admitted
         # /leader/start requests land in a durable replayable log when
-        # the knob names a path — bench.py --replay drives load from it
+        # the knob names a path (``RequestLog.read`` gives it back)
         self.request_log = (storage.RequestLog(
             self.config.replay_capture_path,
             self.config.replay_capture_max)

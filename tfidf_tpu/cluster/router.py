@@ -1325,7 +1325,7 @@ class _HttpHandlerBase(BaseHTTPRequestHandler):
                                 "circuit_open")}))
             dt = time.perf_counter() - t0
             # live front-door latency histogram: the p50/p99
-            # operators (and bench.py's cross-validation) read
+            # operators read
             global_metrics.observe("leader_search", dt)
             slow_ms = node.config.trace_slow_query_ms
             if slow_ms > 0 and dt * 1e3 >= slow_ms:
@@ -1708,7 +1708,7 @@ class QueryRouter(ScatterReadPlane):
                              else None)
         # traffic-capture tap (utils/storage.py RequestLog): admitted
         # /leader/start requests land in a durable replayable log when
-        # the knob names a path — bench.py --replay drives load from it
+        # the knob names a path (``RequestLog.read`` gives it back)
         self.request_log = (_storage.RequestLog(
             self.config.replay_capture_path,
             self.config.replay_capture_max)

@@ -111,14 +111,12 @@ class Engine:
                     self.model, mesh=mesh,
                     min_doc_cap=c.min_doc_capacity,
                     min_chunk_cap=min_chunk,
-                    ell_width_cap=c.ell_width_cap,
-                    incremental_stats=c.df_incremental)
+                    ell_width_cap=c.ell_width_cap)
                 self.searcher = MeshEllSearcher(
                     self.index, self.analyzer, self.vocab, self.model,
                     query_batch=c.query_batch,
                     max_query_terms=c.max_query_terms,
                     top_k=c.top_k, result_order=c.result_order,
-                    kernel_a_build=c.kernel_a_build,
                     pipeline_depth=c.search_pipeline_depth,
                     pipeline_mode=c.search_pipeline_mode)
                 return
@@ -159,7 +157,6 @@ class Engine:
                 sync_merge_nnz=c.sync_merge_nnz,
                 merge_upload_pace=c.merge_upload_pace,
                 merge_workers=c.merge_workers,
-                incremental_stats=c.df_incremental,
                 tier=self.tier)
         else:
             self.index = ShardIndex(
@@ -173,7 +170,6 @@ class Engine:
             query_batch=c.query_batch, max_query_terms=c.max_query_terms,
             top_k=c.top_k, result_order=c.result_order,
             use_pallas=c.use_pallas,
-            kernel_a_build=c.kernel_a_build,
             pipeline_depth=c.search_pipeline_depth,
             pipeline_mode=c.search_pipeline_mode)
         # host-fallback degraded scoring rides only the local Searcher

@@ -184,8 +184,7 @@ class QueryVectorizerMixin:
         hand-off queue). The r5 drain-before-dispatch variant (depth
         chunks total, depth-1 overlapped) measured ~2x slower on
         RTT-bound configs, so the extra in-flight buffer is kept
-        deliberately — HBM sizing must budget depth+1 packed buffers
-        (see probe_msmarco's B cap)."""
+        deliberately — HBM sizing must budget depth+1 packed buffers."""
         if not self._use_executor():
             return self._run_inline(chunks, dispatch, fetch, assemble)
         pipe = self._pipeline()
@@ -225,7 +224,6 @@ class Searcher(QueryVectorizerMixin):
                  *, query_batch: int = 32, max_query_terms: int = 32,
                  top_k: int = 10, result_order: str = "score",
                  use_pallas: bool = False,
-                 kernel_a_build: str = "v4",
                  pipeline_depth: int = 2,
                  pipeline_mode: str = "auto") -> None:
         self.index = index
@@ -239,12 +237,6 @@ class Searcher(QueryVectorizerMixin):
         # (Leader.java:80-91 sorts the merged map by document name)
         self.result_order = result_order
         self.use_pallas = use_pallas
-        # A-build variant for the fused kernel (ops/ell.py): scores are
-        # bit-identical across variants; the knob exists so a kernel
-        # regression can be isolated live (and benched old-vs-new).
-        # Validated at construction so a typo fails before any query.
-        from tfidf_tpu.ops.ell import check_a_build
-        self.kernel_a_build = check_a_build(kernel_a_build)
         # in-flight chunks: on small corpora the device step is far
         # shorter than the device->host fetch RTT, so serial execution
         # caps throughput at ~1 chunk per RTT; depth D keeps D fetches
@@ -348,8 +340,7 @@ class Searcher(QueryVectorizerMixin):
         if not snap.is_ell:
             return [(snap.tf, False)]
         return [(imp, self.use_pallas and _pallas_eligible(
-                    imp.shape[0], self.query_batch, self._u_floor,
-                    self.kernel_a_build))
+                    imp.shape[0], self.query_batch, self._u_floor))
                 for imp in snap.ell_impacts]
 
     def _score_chunk(self, snap: Snapshot, queries: list[str]):
@@ -386,7 +377,6 @@ class Searcher(QueryVectorizerMixin):
                     snap.doc_len, snap.df, qb,
                     snap.n_docs, snap.avgdl, snap.doc_norms,
                     use_pallas=self.use_pallas,
-                    a_build=self.kernel_a_build,
                     **self.model.score_kwargs())
                 return blocks, snap.ell_live, snap.ell_live_host
             scores = score_coo_batch(
@@ -397,7 +387,7 @@ class Searcher(QueryVectorizerMixin):
 
     # oracle switch: True forces tiered snapshots through the untiered
     # scoring path (every segment faulted + scored) — the parity
-    # baseline bench/chaos runs compare the skipping path against
+    # baseline tests and chaos runs compare the skipping path against
     tier_bypass = False
 
     def _dispatch_chunk(self, snap: Snapshot, queries: list[str],

@@ -259,7 +259,6 @@ class SegmentedIndex:
                  sync_merge_nnz: int = 1 << 20,
                  merge_upload_pace: float = 1.0,
                  merge_workers: int = 2,
-                 incremental_stats: bool = True,
                  tier=None) -> None:
         self.model = model
         # tiered residency (engine/tiering.py): None = everything stays
@@ -320,9 +319,7 @@ class SegmentedIndex:
         # adds + an O(vocab) dense df re-upload per commit. The device
         # df advances by one journaled sparse scatter; totals INCLUDE
         # tombstones until merge (Lucene docFreq/docCount semantics,
-        # same as the full recompute below). False = the pre-r14
-        # control path for bench.py --kernel, never the default.
-        self.incremental_stats = incremental_stats
+        # same as the full recompute below).
         self._df_total = np.zeros(0, np.float64)   # tombstone-inclusive
         self._count_total = 0
         self._len_total = 0.0
@@ -330,7 +327,7 @@ class SegmentedIndex:
         self._df_delta = DfDeltaApplier()
         self._df_device = None        # committed [vocab_cap] device df
         # witness: commits that paid the full O(segments x vocab) stat
-        # recompute (first commit / vocab growth / control path) —
+        # recompute (first commit / vocab growth) —
         # steady-state streaming commits must leave it untouched
         # (tests/test_commit_stats.py)
         self.df_full_recomputes = 0
@@ -457,7 +454,7 @@ class SegmentedIndex:
         """Full recompute of the global stats (df summed over every
         segment, tombstone-inclusive doc count and length sum, live
         count) — the pre-r14 per-commit pass, now the resync belt
-        (first commit, vocab growth, ``incremental_stats=False``) and
+        (first commit, vocab growth) and
         the test oracle for the incremental accumulators."""
         df = np.zeros(vocab_cap, np.float32)
         total_count = 0
@@ -907,12 +904,11 @@ class SegmentedIndex:
                 # idf negative for heavily-deleted terms. Steady state
                 # reads the incrementally maintained totals and advances
                 # the device df by ONE journaled sparse scatter
-                # (O(new-segment nnz)); only the first commit, vocab
-                # growth, and the incremental_stats=False control path
-                # pay the full O(segments x vocab) recompute + dense df
-                # upload — counted by the df_full_recomputes witness.
-                if (self.incremental_stats
-                        and self._df_device is not None
+                # (O(new-segment nnz)); only the first commit and vocab
+                # growth pay the full O(segments x vocab) recompute +
+                # dense df upload — counted by the df_full_recomputes
+                # witness.
+                if (self._df_device is not None
                         and self._df_device.shape[0] == vocab_cap):
                     df_dev = self._df_delta.apply(self._df_device)
                     total_count = self._count_total
@@ -1138,17 +1134,6 @@ class SegmentedIndex:
         key_box.append(id(fut))
         self._merge_jobs[id(fut)] = sources
         self._merge_futs[id(fut)] = fut
-
-    @property
-    def _merge_future(self):
-        """Any in-flight background merge future (compat surface for
-        probes/benches that poll ``_merge_future is None``). Locked: a
-        merge thread popping its entry mid-iteration would otherwise
-        raise "dictionary changed size during iteration". Only external
-        callers use this property — locked internal paths read
-        ``_merge_futs`` directly."""
-        with self._write_lock:
-            return next(iter(self._merge_futs.values()), None)
 
     def wait_for_merges(self, timeout: float | None = None) -> None:
         """Block until every in-flight background merge has spliced

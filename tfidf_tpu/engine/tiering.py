@@ -102,7 +102,7 @@ class TierManager:
         self.budget_bytes = max(0, int(budget_bytes))
         self.ring_depth = max(1, int(ring_depth))
         self.skip_margin = float(skip_margin)
-        # kill switch for the block-max cut (oracle/bench control: with
+        # kill switch for the block-max cut (the tests' oracle: with
         # skipping off every cold segment is faulted and scored, which
         # is the untiered computation — the parity baseline)
         self.skip_enabled = True

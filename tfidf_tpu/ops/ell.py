@@ -225,39 +225,31 @@ ell_impacts = jax.jit(ell_impacts, static_argnames=("model", "k1", "b"))
 # batch only starts (PR 27; before it whole [TU, TD] tiles were built,
 # their accumulator of 256 vregs read and written every width step).
 #
-# A-build variants (``a_build``): two inner bodies of that ONE nest.
-#
-# * ``"v3"`` — one width row per step: per padded entry per uniq lane
-#   1 compare + 1 select + 1 accumulate add, all on i32/f32 vregs
-#   (3 vreg-ops/entry).
-# * ``"v4"`` — TWO width rows per step. Within one document row the
-#   live term ids are DISTINCT (the ELL layout stores one posting per
-#   distinct term; pads are trailing and carry impact 0), so at most
-#   one compare of a (w, w+1) pair can select a non-zero impact: the
-#   pair folds into ONE nested select chain and ONE accumulate add,
-#   and because +0.0 is exact in f32 the result is BIT-IDENTICAL to
-#   v3. Cost per 2 entries: 2 cmp + 2 sel + 1 add = 2.5 vreg-ops/entry
-#   vs v3's 3.0 (``a_build_ops_model``). On the v5e, in this nest, v4
-#   builds a live lane 8-10% faster than v3; in the nest before it,
-#   whose accumulator traffic v4 halved, 1.8x (PERF.md §6, PR 27).
-#   Term ids stay i32 even where the vocabulary fits 15 bits: Mosaic
-#   for v5e refuses a dynamic sublane load from an i16 tile ("cannot
-#   statically prove that index in dimension 0 is a multiple of 8")
-#   and, with the rows unrolled, an i16 compare mask feeding an f32
-#   select ("Invalid relayout"), so packed compares cannot be built
-#   this way.
+# The A-build takes TWO width rows a step. CONTRACT: within one document
+# row the live term ids are DISTINCT (the ELL layout stores one posting
+# per distinct term; ingest rejects duplicate or unsorted ids) and pads
+# carry impact 0. So at most one compare of a (w, w+1) pair can select
+# a non-zero impact: the pair folds into ONE nested select chain and
+# ONE accumulate add, and because +0.0 is exact in f32 that is
+# bit-identical to adding the rows one by one. Cost per 2 entries:
+# 2 cmp + 2 sel + 1 add = 2.5 vreg-ops an entry. Term ids stay i32 even
+# where the vocabulary fits 15 bits: Mosaic for v5e refuses a dynamic
+# sublane load from an i16 tile ("cannot statically prove that index in
+# dimension 0 is a multiple of 8") and, with the rows unrolled, an i16
+# compare mask feeding an f32 select ("Invalid relayout").
 #
 # The XLA reduce-fusion path (``_score_block``) stays untouched as the
-# oracle for both. ``tests/test_kernel_compile.py`` compiles every
-# shape class ``_pallas_eligible`` admits for v5e (compile-only, no
-# chip needed).
+# oracle. ``tests/test_kernel_compile.py`` compiles every shape class
+# ``_pallas_eligible`` admits for v5e (compile-only, no chip needed).
 
 _PL_TD = 512          # docs per grid tile (256 for small blocks)
 _PL_MAX_B = 2048      # VMEM: qc [B, TU] + out [B, TD] stay ~8MB
 _PL_SU = 32           # uniq rows a register-resident A sub-tile holds
 _PL_ROWS = 8          # width rows an A-build loop iteration loads: a sublane tile
 _PL_TK = 128          # uniq rows an MXU contraction chunk holds
-A_BUILD_VARIANTS = ("v3", "v4")
+# the Pallas call's fixed name: the device trace's event and the HLO
+# instruction are named from it (PERF.md §3), whatever jit encloses it
+KERNEL_NAME = "ell_score_v4"
 
 
 def pallas_interpret() -> bool:
@@ -269,38 +261,22 @@ def pallas_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def check_a_build(a_build: str) -> str:
-    """The ONE validator for the kernel_a_build knob (searchers call it
-    at construction, the kernel entry points at trace time): an unknown
-    variant must fail loudly everywhere — quietly failing eligibility
-    would silently route every block to the slow XLA path on a config
-    typo."""
-    if a_build not in A_BUILD_VARIANTS:
-        raise ValueError(
-            f"kernel_a_build={a_build!r}: expected one of "
-            f"{A_BUILD_VARIANTS}")
-    return a_build
-
-
 def _pallas_kernel(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
-                   out_ref, a_ref, *, width: int, td: int, tu: int,
-                   a_build: str):
-    """One (doc tile, uniq tile) grid step: the ONE loop nest of both
-    A-build variants. Uniq SUB-TILES outer (``_PL_SU`` rows, trip count
-    from ``n_uniq``), width inner: a sub-tile's accumulator
-    ``[_PL_SU, td]`` stays in vector registers across the whole width loop
-    and is written ONCE to the VMEM scratch ``a_ref [tu, td]``; then
-    the MXU contracts the 128-row chunks of ``a_ref`` that hold a live
-    term. Sub-tiles and chunks past the live unique terms are never
+                   out_ref, a_ref, *, width: int, td: int, tu: int):
+    """One (doc tile, uniq tile) grid step. Uniq SUB-TILES outer
+    (``_PL_SU`` rows, trip count from ``n_uniq``), width inner: a
+    sub-tile's accumulator ``[_PL_SU, td]`` stays in vector registers
+    across the whole width loop and is written ONCE to the VMEM scratch
+    ``a_ref [tu, td]``; then the MXU contracts the 128-row chunks of
+    ``a_ref`` that hold a live term. Sub-tiles and chunks past the live unique terms are never
     built, compared or contracted.
 
-    v4 CONTRACT: within a document row the live term ids are distinct
-    and pads (impact 0) are trailing — both guaranteed by every ELL
-    builder in this tree (``build_ell_from_coo`` lays out one entry per
-    distinct term left-to-right; ``build_mesh_ell`` fills
-    ``e.term_ids``, distinct by construction, and the terms-axis width
-    shard is a contiguous column slice, so pads stay trailing). A row
-    violating it would double-select where v3 double-adds."""
+    CONTRACT (the pair fold's, see the notes above): within a document
+    row the live term ids are distinct and pads carry impact 0 — every
+    ELL builder in this tree lays out one entry per distinct term
+    (``build_ell_from_coo``; ``build_mesh_ell`` fills ``e.term_ids``,
+    and the terms-axis width shard is a contiguous column slice). A row
+    violating it would select once where the XLA path adds twice."""
     d = pl.program_id(0)
     u = pl.program_id(1)
     su = _PL_SU
@@ -335,9 +311,10 @@ def _pallas_kernel(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
                 order: ONE load of the n rows of each array (a ref
                 access is the dearest thing here to trace and lower:
                 ~2 ms of a worker's warm-up each), every row then
-                spread over the sub-tile's sublanes. v3 adds each row's
-                select; v4 folds a pair into one select chain and one
-                add, a last odd row alone."""
+                spread over the sub-tile's sublanes. A pair of rows
+                folds into one select chain and one add (at most one
+                branch selects non-zero; a pad match selects its 0.0
+                impact), a last odd row goes alone."""
                 terms = term_ref[pl.ds(w0, n), :]    # [n, Td] i32
                 imps = imp_ref[pl.ds(w0, n), :]      # [n, Td] f32
 
@@ -345,22 +322,13 @@ def _pallas_kernel(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
                     return lax.broadcast_in_dim(
                         lax.slice_in_dim(x, j, j + 1), (su, td), (0, 1))
 
-                def chain(j0, j1):
-                    """select(u == t[j0], imp[j0], select(u == t[j0+1],
-                    ..., 0)): at most one branch selects non-zero
-                    (distinct live ids; a pad match selects its 0.0
-                    impact), so adding the chain is bit-identical to
-                    adding its rows one by one (+0.0 is exact)."""
+                for j in range(0, n, 2):
                     x = zeros
-                    for j in reversed(range(j0, j1)):
+                    for i in reversed(range(j, min(j + 2, n))):
                         x = lax.select(
-                            lax.eq(uniq, over_sublanes(terms, j)),
-                            over_sublanes(imps, j), x)
-                    return x
-
-                step = 2 if a_build == "v4" else 1
-                for j in range(0, n, step):
-                    a = a + chain(j, min(j + step, n))
+                            lax.eq(uniq, over_sublanes(terms, i)),
+                            over_sublanes(imps, i), x)
+                    a = a + x
                 return a
 
             # _PL_ROWS width rows a loop iteration (Mosaic unrolls a
@@ -413,9 +381,9 @@ def _pl_tiles(rows_cap: int, B: int, u_cap: int) -> tuple[int, int]:
     multi-buffered qc [TU/128, B, 128] / out [B, TD] blocks plus the A
     accumulator and MXU temporaries stay inside the 16MB scoped-VMEM
     budget (Mosaic's buffering costs ~2x the naive block arithmetic,
-    so the schedule is deliberately conservative). One schedule for
-    both A-build variants: 512 tiles at B=1024 or 256 at B=2048 ask
-    the v5e compiler for 18.2 MB of scoped VMEM against its 16 MB."""
+    so the schedule is deliberately conservative): 512 tiles at B=1024
+    or 256 at B=2048 ask the v5e compiler for 18.2 MB of scoped VMEM
+    against its 16 MB."""
     cap = 512 if B <= 512 else (256 if B <= 1024 else 128)
     td = min(cap, _PL_TD if rows_cap % _PL_TD == 0 else _PL_TD // 2)
     tu = min(cap, 512 if u_cap % 512 == 0 else 256, u_cap)
@@ -439,18 +407,14 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
                        n_uniq: jax.Array,    # i32 scalar (traced)
                        qc_ext: jax.Array,    # f32 [B, U_cap+1]
                        n_rows: jax.Array | None = None,  # i32 scalar
-                       *, a_build: str = "v3") -> jax.Array:
+                       ) -> jax.Array:
     """Fused ELL-block scoring on TPU: ``[B, rows_cap]`` scores.
 
     ``n_rows`` (traced) is the block's live row count: doc tiles wholly
     past it skip the A-build and contraction (their scores are zeroed by
     the unconditional init, exactly what all-pad rows would score).
-
-    ``a_build`` selects the A-build variant (see the notes above).
-    Both variants are bit-identical to each other; the XLA
-    reduce-fusion path is the oracle (``kernel_parity.py``).
+    The XLA reduce-fusion path is the oracle (``kernel_parity.py``).
     """
-    check_a_build(a_build)
     rows_cap, width = impact.shape
     B, _ = qc_ext.shape
     # the kernel contracts whole 128-row chunks: a capacity that is not
@@ -478,8 +442,7 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     lims = jnp.stack([jnp.asarray(n_uniq, jnp.int32),
                       jnp.asarray(n_rows, jnp.int32)])
 
-    kernel = functools.partial(_pallas_kernel, width=width, td=td, tu=tu,
-                               a_build=a_build)
+    kernel = functools.partial(_pallas_kernel, width=width, td=td, tu=tu)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         # u is the INNER axis: the output block for a doc tile stays in
@@ -498,10 +461,7 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     )
     return pl.pallas_call(
         kernel,
-        # a fixed name that carries the A-build variant: the profiler's
-        # event and the HLO instruction are named from it, not from
-        # whichever jit happens to enclose the call
-        name=f"ell_score_{a_build}",
+        name=KERNEL_NAME,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rows_cap), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -510,18 +470,10 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     )(lims, uniq_col, qc, term_t, imp_t)
 
 
-def _pallas_eligible(rows_cap: int, B: int, u_cap: int,
-                     a_build: str = "v3") -> bool:
+def _pallas_eligible(rows_cap: int, B: int, u_cap: int) -> bool:
     """Big blocks only — small blocks stay on the XLA path where they
     are cheap. u_cap is unbounded (uniq tiles past ``n_uniq`` are
-    skipped, so capacity padding is free); B is VMEM-bounded. The
-    envelope is shared by both A-build variants (v4's odd-width tail
-    row changes the loop, not the shapes the kernel accepts), so a
-    config flip can never silently change WHICH
-    blocks ride the kernel — only how the A is built. An UNKNOWN
-    variant raises (``check_a_build``) rather than quietly failing
-    eligibility."""
-    check_a_build(a_build)
+    skipped, so capacity padding is free); B is VMEM-bounded."""
     return (rows_cap % (_PL_TD // 2) == 0 and rows_cap >= _PL_TD // 2
             and B <= _PL_MAX_B and u_cap % 256 == 0)
 
@@ -644,8 +596,7 @@ def score_ell_impl(impacts,            # tuple of f32 [rows_cap_i, width_i]
                    q: QueryBatch,
                    vocab_cap: int,
                    *, doc_chunk: int = 2048,
-                   use_pallas: bool = False,
-                   a_build: str = "v3") -> tuple:
+                   use_pallas: bool = False) -> tuple:
     """Gather-based scoring over all blocks: a tuple of per-block scores
     ``[B, rows_cap_i]``, each in its block's padded row space.
 
@@ -657,8 +608,7 @@ def score_ell_impl(impacts,            # tuple of f32 [rows_cap_i, width_i]
     counts are TRACED, so growing the corpus within the same capacity
     buckets reuses the executable — only the (static) block shapes key
     the compile cache. ``use_pallas`` routes big blocks through the
-    fused compare/MXU kernel; the rest stay on the XLA path. ``a_build``
-    picks the kernel's A-build variant.
+    fused compare/MXU kernel; the rest stay on the XLA path.
     """
     B = q.slots.shape[0]
     slot_of, qc_ext = _compile_queries(q, vocab_cap)
@@ -667,9 +617,8 @@ def score_ell_impl(impacts,            # tuple of f32 [rows_cap_i, width_i]
     with jax.named_scope("ell_blocks"):
         return tuple(
             score_block_pallas(imp, term, q.uniq, q.n_uniq, qc_ext,
-                               block_live[i], a_build=a_build)
-            if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap,
-                                               a_build)
+                               block_live[i])
+            if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap)
             else _score_block(imp, term, slot_of, qc_t, doc_chunk)
             for i, (imp, term) in enumerate(zip(impacts, terms)))
 
@@ -681,8 +630,7 @@ def score_ell_with_residual(impacts, terms, block_live,
                             *, model: str = "bm25", k1: float = 1.2,
                             b: float = 0.75, doc_chunk: int = 2048,
                             res_chunk: int = 1 << 10,
-                            use_pallas: bool = False,
-                            a_build: str = "v3") -> tuple:
+                            use_pallas: bool = False) -> tuple:
     """Full shard scores, per block (see :func:`score_ell_impl`): blocked
     ELL + COO residual (overlong docs).
 
@@ -693,8 +641,7 @@ def score_ell_with_residual(impacts, terms, block_live,
     alone.
     """
     parts = score_ell_impl(impacts, terms, block_live, q, df.shape[0],
-                           doc_chunk=doc_chunk, use_pallas=use_pallas,
-                           a_build=a_build)
+                           doc_chunk=doc_chunk, use_pallas=use_pallas)
     if res_tf is not None:
         with jax.named_scope("coo_residual"):
             residual = score_coo_impl(
@@ -719,7 +666,7 @@ def ell_scores_to_real(parts, block_live, doc_cap: int) -> jax.Array:
 _score_ell_batch_jit = jax.jit(
     score_ell_with_residual,
     static_argnames=("model", "k1", "b", "doc_chunk", "res_chunk",
-                     "use_pallas", "a_build"))
+                     "use_pallas"))
 
 
 def score_ell_batch(impacts, terms, block_live, res_tf, res_term,

@@ -315,7 +315,6 @@ def make_mesh_ell_search(mesh: Mesh,
                          k1: float = 1.2,
                          b: float = 0.75,
                          use_pallas: bool = True,
-                         a_build: str = "v4",
                          packed: bool = False):
     """Distributed search over ELL base + COO delta.
 
@@ -369,11 +368,10 @@ def make_mesh_ell_search(mesh: Mesh,
         parts = []
         with jax.named_scope("ell_blocks"):
             for i, (imp, term) in enumerate(zip(impacts, terms)):
-                if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap,
-                                                   a_build):
+                if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap):
                     parts.append(score_block_pallas(
                         imp, term, q.uniq, q.n_uniq, qc_ext,
-                        block_live[i], a_build=a_build))
+                        block_live[i]))
                 else:
                     parts.append(_score_block(imp, term, slot_of, qc_t,
                                               2048))

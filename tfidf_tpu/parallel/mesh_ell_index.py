@@ -86,19 +86,13 @@ class MeshEllIndex(MeshIndex):
     def __init__(self, model, mesh=None, min_doc_cap: int = 1024,
                  min_chunk_cap: int = 1 << 14,
                  ell_width_cap: int = 256,
-                 delta_rebuild_frac: float = 0.5,
-                 incremental_stats: bool = True) -> None:
+                 delta_rebuild_frac: float = 0.5) -> None:
         super().__init__(model, mesh=mesh, min_doc_cap=min_doc_cap,
                          min_chunk_cap=min_chunk_cap)
         self.ell_width_cap = ell_width_cap
         # fold the delta into the base when it exceeds this fraction of
         # the corpus (the merge policy)
         self.delta_rebuild_frac = delta_rebuild_frac
-        # False = the pre-incremental control path: every commit
-        # recomputes df/N/avgdl from the live host postings (O(corpus
-        # nnz)) and re-uploads the dense df — kept as the bench.py
-        # --kernel old-vs-new lever, never the default
-        self.incremental_stats = incremental_stats
         self._base: MeshEllArrays | None = None
         self._perms: list[np.ndarray] = []
         self._base_counts: list[int] = []
@@ -239,16 +233,7 @@ class MeshEllIndex(MeshIndex):
             # goes stale). After a rebuild the replicated df is uploaded
             # whole; otherwise the journaled changes land as one sparse
             # on-device scatter (O(touched terms), not O(vocab)).
-            if not self.incremental_stats:
-                # control path: full O(corpus nnz) recompute + dense
-                # re-upload every commit (the pre-r14 cost model)
-                df_host, n_live, len_sum = self._live_stats_scratch(
-                    vocab_cap, include_pending=False)
-                self.df_full_recomputes += 1
-                df_g = jax.device_put(
-                    df_host, NamedSharding(self.mesh, P(None)))
-                self._df_delta.clear()
-            elif need_rebuild or self.snapshot is None:
+            if need_rebuild or self.snapshot is None:
                 df_host, n_live, len_sum = self._live_stats(vocab_cap)
                 df_g = jax.device_put(
                     df_host, NamedSharding(self.mesh, P(None)))
@@ -523,7 +508,6 @@ class MeshEllSearcher(MeshSearcher):
             fn = make_mesh_ell_search(
                 self.index.mesh, k=k,
                 model=self.model.score_kwargs()["model"],
-                a_build=self.kernel_a_build,
                 packed=True, **self._model_kwargs())
             self._search_fns[k] = fn
         return fn
@@ -544,8 +528,7 @@ class MeshEllSearcher(MeshSearcher):
         if snap is None:
             return []
         return [(imp, _pallas_eligible(imp.shape[1], self.query_batch,
-                                       self._u_floor,
-                                       self.kernel_a_build))
+                                       self._u_floor))
                 for imp in snap.base.impact]
 
     def _dispatch_chunk(self, snap, qb, k: int):

@@ -394,7 +394,6 @@ class MeshSearcher(QueryVectorizerMixin):
                  *, query_batch: int = 32, max_query_terms: int = 32,
                  top_k: int = 10, result_order: str = "score",
                  global_idf: bool = True,
-                 kernel_a_build: str = "v4",
                  pipeline_depth: int = 2,
                  pipeline_mode: str = "auto") -> None:
         self.index = index
@@ -408,11 +407,6 @@ class MeshSearcher(QueryVectorizerMixin):
         self.pipeline_depth = max(1, pipeline_depth)
         # "auto" | "executor" | "inline" — see QueryVectorizerMixin
         self.pipeline_mode = pipeline_mode
-        # A-build variant for the fused kernel (ELL layout only; the
-        # COO scatter step never touches it). Validated at
-        # construction so a config typo fails before any query.
-        from tfidf_tpu.ops.ell import check_a_build
-        self.kernel_a_build = check_a_build(kernel_a_build)
         # global_idf=False reproduces the reference's per-worker statistics
         # (each Lucene shard scores against local df/N, Worker.java:222-241)
         self.global_idf = global_idf

@@ -1,6 +1,6 @@
 """The one place that says where XLA's persistent compile cache lives.
 
-A serving node, the bench and every probe start in a fresh process; with
+A serving node and every benchmark worker start in a fresh process; with
 no persistent cache each start pays every compile again (tens of seconds
 at 1M documents). The directory is part of the cache key, so it must not
 move between runs: it is ``$JAX_COMPILATION_CACHE_DIR`` when the

@@ -181,20 +181,6 @@ class Config:
     # path's [rows, width, B] HBM materialization — the gather-bound
     # bottleneck at 1M-doc scale). Small blocks always use the XLA path.
     use_pallas: bool = True
-    # A-build variant inside the fused kernel (ops/ell.py): "v4"
-    # processes two width rows per grid iteration (one accumulate add
-    # per pair) — bit-identical scores to "v3" at 2.5 instead of 3.0
-    # A-build vreg-ops per entry by an op count; which is faster on a
-    # chip is not measured (ROADMAP D1). Parity matrix:
-    # kernel_parity.py. "v3" is the single-row build.
-    kernel_a_build: str = "v4"
-    # Maintain global df/N/avgdl incrementally on mutation so
-    # steady-state commits are O(batch nnz) with the device df advanced
-    # by one sparse scatter (segments + mesh-ELL indexes; the
-    # df_full_recomputes witness counts the exceptional full passes).
-    # False = recompute from the live corpus every commit (the pre-r14
-    # control path, kept for bench.py --kernel old-vs-new runs).
-    df_incremental: bool = True
 
     # --- index mode ---
     # "rebuild": every commit re-lays-out the whole corpus (static corpora)
@@ -549,9 +535,8 @@ class Config:
     # (query + arrival offset + lane + client id), written through the
     # storage seam's CRC-framed append log so a torn tail truncates
     # cleanly instead of corrupting the capture. Empty disables the
-    # tap. `bench.py --replay` replays a captured log with original
-    # inter-arrival spacing so perf claims run against production-
-    # shaped traffic instead of synthetic zipf.
+    # tap. `RequestLog.read(path)` returns the admitted stream with its
+    # original inter-arrival offsets, for a load generator to replay.
     replay_capture_path: str = ""
     # Bound on captured entries per log (memory- and disk-bounded like
     # the trace ring); the tap stops appending once reached.

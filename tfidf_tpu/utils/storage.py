@@ -40,8 +40,8 @@ This is the one seam every durable byte now goes through:
   integrity scrub verifies replicas against;
 - :class:`RequestLog` — the durable traffic-capture log (admitted
   ``/leader/start`` queries + arrival offsets + lanes), CRC-framed
-  per line so a torn tail truncates cleanly; ``bench.py --replay``
-  replays it as production-shaped load.
+  per line so a torn tail truncates cleanly; :meth:`RequestLog.read`
+  gives a load generator the stream back, arrival offsets included.
 
 Nemesis rules are scriptable in-process (``global_storage.arm(...)``)
 and via the ``TFIDF_STORAGE_NEMESIS`` env var (a JSON rule list) so
@@ -731,8 +731,8 @@ class RequestLog:
     """Durable, replayable capture of front-door search traffic: one
     record per ADMITTED ``/leader/start`` request — query text, arrival
     offset (monotonic seconds since the log opened), admission lane,
-    and client id — so perf claims can replay production-shaped
-    traffic instead of synthetic zipf (``bench.py --replay``).
+    and client id — so a load generator can replay production-shaped
+    traffic instead of synthetic zipf (:meth:`read`).
 
     Framing is the WAL's discipline applied to capture: each record is
     one ``<crc32-hex> <compact-json>\\n`` line over an append handle

@@ -1,8 +1,8 @@
 """Offline realistic-text corpus generator (VERDICT r3 #3).
 
-Every earlier bench corpus was synthetic ``t{i}`` integer tokens, which
-bypasses the analyzer's real work (Unicode rules, punctuation, the
-native ASCII fast path / Python fallback boundary, the extractors). The
+A corpus of synthetic ``t{i}`` integer tokens bypasses the analyzer's
+real work (Unicode rules, punctuation, the native ASCII fast path /
+Python fallback boundary, the extractors). The
 reference's workload is real text files run through Lucene's
 ``StandardAnalyzer`` + Tika (``Worker.java:190-220``). This module
 builds a realistic corpus **without network egress**:

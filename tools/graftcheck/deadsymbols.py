@@ -4,8 +4,8 @@ PR 11 extracted 1,000+ lines of ``node.py`` into ``router.py``; moves
 that big strand dead code (helpers whose last caller moved away). This
 pass walks the resolver's symbol table and flags every module-level
 function and every method across ``tfidf_tpu/`` whose NAME is never
-referenced anywhere else — package, tests, bench/probe scripts, or
-tools (``tools/graftcheck`` excluded: analyzers name symbols without
+referenced anywhere else — package, tests, or tools
+(``tools/graftcheck`` excluded: analyzers name symbols without
 calling them).
 
 Matching is name-based on purpose: any ``Name`` id, ``Attribute`` attr,
@@ -44,10 +44,6 @@ def _reference_files(root: str) -> list[str]:
             for fn in sorted(files):
                 if fn.endswith(".py"):
                     out.append(os.path.join(dirpath, fn))
-    for fn in ("bench.py", "probe_overlap.py"):
-        p = os.path.join(root, fn)
-        if os.path.isfile(p):
-            out.append(p)
     return out
 
 
@@ -99,7 +95,7 @@ def analyze(tree: SourceTree, root: str) -> list[Finding]:
             out.append(Finding(
                 "deadsymbols", f"deadsymbols:unreferenced:{fi.qual}",
                 f"{fi.qual} is referenced nowhere (package, tests, "
-                f"bench, tools) — dead code; delete it or allowlist "
+                f"tools) — dead code; delete it or allowlist "
                 f"the intentional entry point with a reason",
                 mi.relpath, fi.node.lineno))
     return out

@@ -10,7 +10,7 @@ hand. This module makes the contract a build gate, four passes:
 1. **endpoint drift** — every route literal dispatched in the
    ``do_GET``/``do_POST`` chains of the package's handler classes is
    cross-checked BOTH ways against every client-side path literal
-   (leader RPC legs, ``proxy_write``, the CLI, bench, the tests): a
+   (leader RPC legs, ``proxy_write``, the CLI, the tests): a
    path served but never called/tested, or called but never served,
    fails. The README "Wire contract" table is enforced two-directionally
    the same way the Config table is by registry_drift.
@@ -345,7 +345,7 @@ def served_routes(tree: SourceTree) -> list[Route]:
 
 def _extra_client_files(root: str) -> list[str]:
     """Files outside the package whose path literals count as callers:
-    the tests, bench/probe scripts, and tools — EXCLUDING
+    the tests and tools — EXCLUDING
     ``tools/graftcheck`` (the analyzers and their seeded fixtures name
     endpoints without calling them) and ``tests/test_graftcheck.py``
     (same reason)."""
@@ -360,17 +360,13 @@ def _extra_client_files(root: str) -> list[str]:
             for fn in sorted(files):
                 if fn.endswith(".py") and fn != "test_graftcheck.py":
                     out.append(os.path.join(dirpath, fn))
-    for fn in ("bench.py", "probe_overlap.py"):
-        p = os.path.join(root, fn)
-        if os.path.isfile(p):
-            out.append(p)
     return out
 
 
 def client_paths(tree: SourceTree,
                  root: str | None) -> dict[str, tuple[str, int]]:
     """Every client-side endpoint literal: package modules OUTSIDE the
-    handler classes, plus the tests/bench/tools callers."""
+    handler classes, plus the tests/tools callers."""
     handlers = handler_classes(tree)
     out: dict[str, tuple[str, int]] = {}
     for mi in tree.modules.values():

@@ -4,7 +4,11 @@ Asserts that ``score_block_pallas`` matches the XLA reduce-fusion path
 bit-closely across the eligibility envelope — block shapes, batch
 widths, u_cap sizes, dead-row/dead-uniq tile skipping, odd widths (the
 pair fold's lone last row) — and that the top-10 ranking is stable
-against the XLA path. Three callers share ``run_case``:
+against the XLA path. Every case runs twice, because the kernel
+contracts by what the batch's weights are: FRACTIONAL weights take the
+``Precision.HIGHEST`` dot, term MULTIPLICITIES (what the engine's
+queries carry; exact in bfloat16) the three bf16 passes. Three callers
+share ``run_case``:
 
 * ``chip_smoke.py``'s engine stage runs ``CASES`` on the TPU, where the
   kernels are Mosaic programs — the on-chip record;
@@ -26,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tfidf_tpu.ops.ell import (_pallas_eligible, _score_block,
+from tfidf_tpu.ops.ell import (_pallas_eligible, _score_block, bf16_exact,
                                pallas_interpret, score_block_pallas)
 from tfidf_tpu.ops.scoring import _compile_queries, make_query_batch
 
@@ -38,7 +42,7 @@ def log(msg):
 
 
 def make_case(rng, *, rows_cap, width, n_rows, B, n_terms, u_req,
-              vocab=500_000, ragged=False):
+              vocab=500_000, ragged=False, multiplicity=False):
     """Random ELL block + query batch. Term ids are DISTINCT within
     each row (the layout contract every ELL builder guarantees and the
     kernel's pair fold relies on: stride-offset construction — position
@@ -46,7 +50,8 @@ def make_case(rng, *, rows_cap, width, n_rows, B, n_terms, u_req,
     (>= n_rows) are zeroed like the real build; ``ragged`` additionally
     zeroes a random per-row tail (within-row trailing pads, the shape
     real width buckets produce); uniq capacity is driven via
-    min_slots."""
+    min_slots. Query weights are fractions in [1, 2), or with
+    ``multiplicity`` the counts 1..3 a query's repeated terms give."""
     slots = max(vocab // width, 1)
     base = rng.integers(0, slots, size=(rows_cap, width))
     term = (base * width
@@ -70,15 +75,19 @@ def make_case(rng, *, rows_cap, width, n_rows, B, n_terms, u_req,
             ids[0] = term[rng.integers(0, max(n_rows, 1)),
                           rng.integers(0, width)]
         q_terms[i, :k] = ids
-        q_weights[i, :k] = 1.0 + rng.random(k, dtype=np.float32)
+        q_weights[i, :k] = (rng.integers(1, 4, size=k) if multiplicity
+                            else 1.0 + rng.random(k, dtype=np.float32))
     qb = make_query_batch(q_terms, q_weights, min_slots=u_req)
     return imp, term, qb
 
 
 def run_case(name, rng, **kw):
     """One case: the kernel against the XLA oracle on the same inputs,
-    scores within 1e-4 and the top-10 ranking identical."""
+    scores within 1e-4 and the top-10 ranking identical. ``bf16x3``
+    reports which contraction the batch's weights select."""
     imp, term, qb = make_case(rng, **kw)
+    bf16x3 = bool(bf16_exact(qb.weights))
+    assert bf16x3 == kw.get("multiplicity", False), (name, bf16x3)
     vocab = kw.get("vocab", 500_000)
     rows_cap, B = kw["rows_cap"], kw["B"]
     u_cap = qb.uniq.shape[0]
@@ -110,8 +119,9 @@ def run_case(name, rng, **kw):
         (np.argsort(-a, axis=1, kind="stable")[:, :k]
          == np.argsort(-b, axis=1, kind="stable")[:, :k]).all())
     ok = max_abs < 1e-4 and topk_equal
-    log(f"[{name}] max|d|={max_abs:.2e} topk={topk_equal} ok={ok}")
-    return {"name": name, "max_abs_delta": max_abs,
+    log(f"[{name}] bf16x3={bf16x3} max|d|={max_abs:.2e} "
+        f"topk={topk_equal} ok={ok}")
+    return {"name": name, "bf16x3": bf16x3, "max_abs_delta": max_abs,
             "max_rel_delta": max_rel, "topk_identical": topk_equal,
             "ok": ok, **kw}
 
@@ -176,11 +186,14 @@ INTERPRET_CASES = [
 def run_matrix(seed: int = 7) -> dict:
     """Every case of the matrix on the attached backend, as the record
     ``KERNEL_PARITY.json`` holds: ``CASES`` where the kernel is a
-    Mosaic program, ``INTERPRET_CASES`` where it is interpreted."""
+    Mosaic program, ``INTERPRET_CASES`` where it is interpreted; each
+    with fractional weights (``caseN``) and with multiplicities
+    (``caseN-mult``)."""
     rng = np.random.default_rng(seed)
     cases = INTERPRET_CASES if pallas_interpret() else CASES
-    results = [run_case(f"case{i}", rng, **kw)
-               for i, kw in enumerate(cases)]
+    results = [run_case(f"case{i}{'-mult' if mult else ''}", rng,
+                        multiplicity=mult, **kw)
+               for i, kw in enumerate(cases) for mult in (False, True)]
     dev = jax.devices()[0]
     return {
         "backend": jax.default_backend(),

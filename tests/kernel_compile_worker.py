@@ -39,7 +39,9 @@ from tfidf_tpu.ops import ell  # noqa: E402
 # lower the Mosaic program, not the interpreter the CPU backend selects
 ell.pallas_interpret = lambda: False
 
-BATCHES = (32, 512, 1024, 2048)
+# 8: a bucket narrower than a bf16 sublane tile (16 rows), which the
+# three-pass contraction's packed query operand must survive
+BATCHES = (8, 32, 512, 1024, 2048)
 # the ladder's ends and its 1.5x rungs, plus what a terms-axis split
 # leaves of a rung (8/8 = 1) and an odd width (the lone last row)
 WIDTHS = (1, 8, 12, 33, 64, 256)

@@ -302,6 +302,110 @@ class TestPallasKernel:
             assert np.abs(ref).max() > 0
             np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
+    def _kernel_and_oracle(self, rng, width, n_uniq, weights, B=32,
+                           rows_cap=512, vocab=1 << 14):
+        """One block scored by the kernel and by the XLA oracle for a
+        batch of exactly ``n_uniq`` distinct terms, every query
+        holding ``len(weights)`` of them with those weights; also the
+        host's and the wrapper's verdicts on the batch's weights."""
+        from tfidf_tpu.ops.ell import (_score_block, bf16_exact,
+                                       score_block_pallas)
+        from tfidf_tpu.ops.scoring import (_compile_queries,
+                                           make_query_batch)
+        imp, term = self._block(rng, rows_cap, width, vocab)
+        T = len(weights)
+        # terms the block holds, so every query hits
+        ids = rng.choice(np.unique(np.asarray(term)[:rows_cap // 2]),
+                         size=n_uniq, replace=False)
+        # every id is used: walk the ids T at a time, wrapping
+        assert T < n_uniq <= B * T
+        q_terms = ids[(np.arange(B * T) % n_uniq)].reshape(B, T)
+        q_weights = np.tile(np.asarray(weights, np.float32), (B, 1))
+        qb = make_query_batch(q_terms.astype(np.int32), q_weights,
+                              min_slots=256)
+        assert int(qb.n_uniq) == n_uniq
+        slot_of, qc_ext = _compile_queries(qb, vocab)
+        ref = np.asarray(_score_block(imp, term, slot_of, qc_ext.T, 256))
+        out = np.asarray(score_block_pallas(
+            imp, term, jnp.asarray(qb.uniq), jnp.asarray(qb.n_uniq),
+            qc_ext))
+        assert np.abs(ref).max() > 0
+        return out, ref, bool(bf16_exact(qb.weights)), \
+            bool(bf16_exact(qc_ext))
+
+    @pytest.mark.parametrize("width", [7, 16, 33])
+    @pytest.mark.parametrize("n_uniq", [127, 128, 129])
+    def test_multiplicities_take_three_bf16_passes(self, rng, width,
+                                                   n_uniq):
+        """Term multiplicities (what the engine's queries carry) are
+        exact in bfloat16: the kernel contracts A's three exact bf16
+        pieces in one pass each, one under, at and one over a 128-row
+        chunk, and matches the f32 oracle tighter than HIGHEST's
+        tests ask."""
+        out, ref, host, device = self._kernel_and_oracle(
+            rng, width, n_uniq, [1, 2, 3, 256, 1, 2])
+        assert host and device
+        np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("weights", [
+        [1, 2, 0.37, 3, 1], [1, 257, 2, 3, 1]],
+        ids=["one-fraction", "257"])
+    @pytest.mark.parametrize("width", [7, 16, 33])
+    def test_inexact_weight_takes_highest(self, rng, width, weights):
+        """ONE weight that bfloat16 cannot hold (a fraction; 257) in an
+        otherwise integer batch: the whole batch takes the HIGHEST dot,
+        as every batch did before, and still matches."""
+        out, ref, host, device = self._kernel_and_oracle(
+            rng, width, 129, weights)
+        assert not host and not device
+        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+    def test_a_bf16_pass_alone_would_not_do(self, rng, monkeypatch):
+        """The control: with A's lower two pieces dropped the same
+        multiplicity batch misses the oracle by bfloat16's 2**-9, so
+        the tests above do see the pieces."""
+        from tfidf_tpu.ops import ell
+        monkeypatch.setattr(
+            ell, "split_bf16x3",
+            lambda a: (a.astype(jnp.bfloat16),) + (jnp.zeros_like(
+                a, jnp.bfloat16),) * 2)
+        out, ref, _host, _device = self._kernel_and_oracle(
+            rng, 16, 128, [1, 2, 3, 1, 2])
+        assert np.abs(out - ref).max() > 1e-4 * np.abs(ref).max()
+
+    @pytest.mark.parametrize("scale", [1e-6, 1e-3, 1.0, 1e3])
+    def test_split_bf16x3_rebuilds_f32_bit_for_bit(self, rng, scale):
+        """hi + mid + lo == a exactly: 8 + 8 + 8 significand bits, each
+        remainder exact in f32; both signs, zeros, and impacts from
+        1e-6 to 1e3."""
+        from tfidf_tpu.ops.ell import split_bf16x3
+        a = (rng.random((128, 512), dtype=np.float32) * 2 - 1) * scale
+        a[rng.random(a.shape) < 0.1] = 0.0
+        a = a.astype(np.float32)
+        hi, mid, lo = (np.asarray(p) for p in split_bf16x3(jnp.asarray(a)))
+        assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+        back = (hi.astype(np.float32) + mid.astype(np.float32)) \
+            + lo.astype(np.float32)
+        assert (back.view(np.uint32) == a.view(np.uint32)).all()
+        assert (np.abs(mid.astype(np.float32)) > 0).any()
+
+    @pytest.mark.parametrize("weights, exact", [
+        ([1.0, 2.0, 3.0, 256.0, 0.0], True), ([0.5, 0.25, 384.0], True),
+        ([257.0], False), ([1.0, 0.37], False), ([1 / 3], False)])
+    def test_bf16_exact_host_and_device_agree(self, weights, exact):
+        """The ONE predicate, on the host's numpy weights (the counter)
+        and traced on the device (the kernel's flag)."""
+        import jax
+
+        from tfidf_tpu.ops.ell import bf16_exact, kernel_contract_chunks
+        w = np.asarray(weights, np.float32)
+        assert bool(bf16_exact(w)) is exact
+        assert bool(jax.jit(bf16_exact)(jnp.asarray(w))) is exact
+        # ... and it is the round trip through bfloat16 it stands for
+        assert exact == bool(
+            (w.astype(jnp.bfloat16).astype(np.float32) == w).all())
+        assert kernel_contract_chunks(129, w) == (2, 2 if exact else 0)
+
     def test_pad_uniq_never_matches_term_zero(self, rng):
         """uniq is zero-padded but term id 0 is real: pad entries must
         not siphon term-0 impacts into the batch (the -1 mask)."""

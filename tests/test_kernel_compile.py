@@ -42,7 +42,7 @@ def _failures(report, of_cells: bool) -> str:
 def test_every_eligible_shape_compiles_for_v5e(report):
     assert not _failures(report, of_cells=False)
     # a run that compiled nothing proves nothing
-    assert report["compiled"] >= 37, report
+    assert report["compiled"] >= 49, report
 
 
 def test_cells_device_step_compiles_for_v5e(report):

@@ -29,11 +29,17 @@ from tfidf_tpu.ops.ell import (_pallas_eligible, _pl_tiles,  # noqa: E402
 from tfidf_tpu.ops.scoring import (QueryBatch,  # noqa: E402
                                    _compile_queries)
 
+@pytest.mark.parametrize("multiplicity", [False, True],
+                         ids=["fractional", "multiplicity"])
 @pytest.mark.parametrize("i", range(len(T1_CASES)))
-def test_interpret_parity(i):
+def test_interpret_parity(i, multiplicity):
+    """Both contractions: fractional weights take the HIGHEST dot,
+    multiplicities the three bf16 passes (``r["bf16x3"]``)."""
     rng = np.random.default_rng(100 + i)
-    r = run_case(f"t1-case{i}", rng, **T1_CASES[i])
+    r = run_case(f"t1-case{i}", rng, multiplicity=multiplicity,
+                 **T1_CASES[i])
     assert r["ok"], r
+    assert r["bf16x3"] is multiplicity
 
 
 def test_ingest_rejects_duplicate_or_unsorted_ids():

@@ -972,6 +972,40 @@ class TestStageTimer:
             assert snap["phase_mesh_build_upload_count"] == 1
             assert snap["phase_mesh_impact_refresh_count"] == 2
 
+    CONTRACT = ("dispatch_chunks", "kernel_contract_chunks",
+                "kernel_contract_chunks_bf16x3")
+
+    @pytest.mark.parametrize("mesh_layout", [None, "ell"],
+                             ids=["local", "mesh"])
+    def test_contraction_chunks_are_counted(self, tmp_path, mesh_layout):
+        """Per chunk dispatched to the kernel: the live 128-row chunks
+        of A it contracts, and those it contracts in three bf16 passes.
+        An engine's query weights are term multiplicities, exact in
+        bfloat16, so the two are equal, on both searchers."""
+        e = _stage_engine(tmp_path, "inline", mesh_layout=mesh_layout)
+        before = _counts(*self.CONTRACT)
+        assert all(e.search_batch(self.QUERIES + ["common common word1"]))
+        d = _grew(before, _counts(*self.CONTRACT))
+        # three dispatches of a handful of terms: one chunk each
+        assert d == {k: 3 for k in self.CONTRACT}, d
+
+    def test_contraction_counters_in_api_metrics(self, core, tmp_path):
+        """After a batch served through the front door a worker's
+        ``/api/metrics`` carries both counters, equal."""
+        nodes = _mk_cluster(core, tmp_path, n=3)
+        try:
+            leader = nodes[0]
+            _upload_docs(leader)
+            before = json.loads(http_get(nodes[1].url + "/api/metrics"))
+            for q in QUERIES:
+                _search(leader, q)
+            snap = json.loads(http_get(nodes[1].url + "/api/metrics"))
+            grew = [snap[k] - before.get(k, 0) for k in self.CONTRACT]
+            assert grew[0] > 0 and grew[1] >= grew[0], grew
+            assert grew[1] == grew[2], grew
+        finally:
+            _stop_all(nodes)
+
     def test_mesh_program_has_a_fixed_name(self, tmp_path):
         """``jit_mesh_ell_search`` is what ``mesh_step_ms.mesh`` looks for
         on a trace's ``XLA Modules`` line; the scopes name the step's

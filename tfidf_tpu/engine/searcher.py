@@ -28,8 +28,8 @@ from tfidf_tpu.ops.analyzer import Analyzer
 from tfidf_tpu.ops.blockmax import query_upper_bounds, skip_mask
 from tfidf_tpu.ops.csr import next_capacity
 from tfidf_tpu.ops.ell import (_pallas_eligible, ell_scores_to_real,
-                               kernel_uniq_lanes, score_ell_batch,
-                               score_segments_batch)
+                               kernel_contract_chunks, kernel_uniq_lanes,
+                               score_ell_batch, score_segments_batch)
 from tfidf_tpu.ops.scoring import (QueryBatch, make_query_batch,
                                    score_coo_batch)
 from tfidf_tpu.ops.topk import (fetch_packed, full_ranking, packed_topk,
@@ -122,13 +122,19 @@ class QueryVectorizerMixin:
         (``kernel_uniq_live``), the uniq lanes of A the kernel builds
         for them (``_built``) and what whole uniq tiles would hold
         (``_tiled``). ``live / built`` is the share of the A-build's
-        compare/select work on lanes a query uses."""
+        compare/select work on lanes a query uses. And the 128-row
+        chunks of A it contracts (``kernel_contract_chunks``), with
+        those that take three bf16 passes because the batch's weights
+        are exact in bfloat16 (``_bf16x3``: all of a batch or none)."""
         n_uniq = int(qb.n_uniq)
         built, tiled = kernel_uniq_lanes(
             n_uniq, qb.slots.shape[0], qb.uniq.shape[0])
+        chunks, bf16x3 = kernel_contract_chunks(n_uniq, qb.weights)
         global_metrics.inc("kernel_uniq_live", n_uniq)
         global_metrics.inc("kernel_uniq_built", built)
         global_metrics.inc("kernel_uniq_tiled", tiled)
+        global_metrics.inc("kernel_contract_chunks", chunks)
+        global_metrics.inc("kernel_contract_chunks_bf16x3", bf16x3)
 
     def _pipeline(self) -> PipelineExecutor:
         """The searcher's SHARED dispatch/fetch executor (lazy). One per

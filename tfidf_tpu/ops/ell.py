@@ -205,8 +205,10 @@ ell_impacts = jax.jit(ell_impacts, static_argnames=("model", "k1", "b"))
 # (8 bytes/entry) and scores out.
 #
 # Cost model per batch: nnz_padded * ceil(n_uniq/SU)*SU compare/select
-# lane-ops for A plus 2*B*ceil(n_uniq/128)*128*rows MXU flops (six bf16
-# passes each, ``Precision.HIGHEST``) — vs the gather path's
+# lane-ops for A plus 2*B*ceil(n_uniq/128)*128*rows MXU flops, THREE
+# bf16 passes each when the batch's weights are exact in bfloat16 (term
+# multiplicities: every batch the engine makes) and ``Precision.HIGHEST``'s
+# six otherwise — vs the gather path's
 # nnz_padded * B slow gathers. Wins whenever the batch's unique-term
 # count is small relative to B * (gather-op slowdown ~40-100x), i.e.
 # always for real query batches.
@@ -361,18 +363,78 @@ def _pallas_kernel(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
 
         # the contraction rides the MXU at its own grain: [B, 128] @
         # [128, Td] per live chunk (qc arrives chunk-major, so a chunk
-        # is a leading-axis index). HIGHEST keeps f32-equivalent
-        # accumulation (the default bf16 pass costs ~0.4% relative
-        # error — enough to flip top-k near-ties).
-        def contract(k, carry):
-            out_ref[:] += jnp.dot(
-                qc_ref[k],
-                a_ref[pl.ds(pl.multiple_of(k * _PL_TK, _PL_TK), _PL_TK), :],
-                preferred_element_type=jnp.float32,
-                precision=lax.Precision.HIGHEST)
-            return carry
+        # is a leading-axis index), f32-equivalent either way (ONE
+        # default bf16 pass of the f32 operands costs ~0.4% relative
+        # error, enough to flip top-k near-ties). The batch's own qc
+        # decides how (lims_ref[2], see ``bf16_exact``): multiplicities
+        # are exact in bfloat16, so of HIGHEST's six passes over the
+        # bf16 pieces of both operands the three that take a lower
+        # piece of qc multiply by zeros; the exact pieces of A against
+        # bf16(qc), one native pass each, are the other three. Any
+        # other weights take the HIGHEST dot.
+        def contract(dot):
+            def chunk(k, carry):
+                a = a_ref[pl.ds(pl.multiple_of(k * _PL_TK, _PL_TK),
+                                _PL_TK), :]
+                out_ref[:] += dot(qc_ref[k], a)
+                return carry
+            lax.fori_loop(0, n_chunks, chunk, 0)
 
-        lax.fori_loop(0, n_chunks, contract, 0)
+        @pl.when(lims_ref[2] != 0)
+        def _three_passes():
+            contract(_dot_bf16x3)
+
+        @pl.when(lims_ref[2] == 0)
+        def _six_passes():
+            contract(_dot_highest)
+
+
+def _mxu(q, a, precision=None):
+    """``q [B, K] @ a [K, Td]`` accumulated in f32."""
+    return lax.dot_general(q, a, (((1,), (0,)), ((), ())),
+                           precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+def split_bf16x3(a):
+    """f32 ``a`` as three bfloat16 pieces with ``hi + mid + lo == a``
+    bit for bit (summed in f32, in that order): each piece takes the
+    next 8 of the 24 significand bits, and each remainder is exact in
+    f32. ~7 VPU ops a vreg of ``a``, hidden under the MXU's passes.
+    For the kernel body: Mosaic keeps each round trip through bfloat16
+    (as XLA does on the CPU, where the tests run it), but XLA for the
+    TPU, allowed excess precision, folds ``a - f32(bf16(a))`` to zero
+    and hands back ``hi`` alone (on the chip, PR 29)."""
+    hi = lax.convert_element_type(a, jnp.bfloat16)
+    rest = a - lax.convert_element_type(hi, jnp.float32)
+    mid = lax.convert_element_type(rest, jnp.bfloat16)
+    rest = rest - lax.convert_element_type(mid, jnp.float32)
+    return hi, mid, lax.convert_element_type(rest, jnp.bfloat16)
+
+
+def _dot_bf16x3(q, a):
+    """``q @ a`` for a ``q`` that is exact in bfloat16: one native
+    bf16 x bf16 -> f32 pass for each exact piece of ``a``."""
+    q = lax.convert_element_type(q, jnp.bfloat16)
+    hi, mid, lo = split_bf16x3(a)
+    return _mxu(q, hi) + _mxu(q, mid) + _mxu(q, lo)
+
+
+def _dot_highest(q, a):
+    """``q @ a`` for any f32 ``q``: six passes over the pieces of both."""
+    return _mxu(q, a, lax.Precision.HIGHEST)
+
+
+def bf16_exact(x):
+    """Whether every entry of f32 ``x`` (numpy or jax) is exact in
+    bfloat16, as a term multiplicity up to 256 is and 0.37 or 257 is
+    not: a bfloat16 is the upper half of a float32, so the lower 16
+    bits are zero. On the bits, not ``x == f32(bf16(x))``: a compiler
+    allowed excess precision may drop that round trip. The ONE
+    predicate behind the kernel's three-pass contraction
+    (``score_block_pallas``) and the counter that says how often it
+    engages (``kernel_contract_chunks_bf16x3``)."""
+    return ((x.view(np.uint32) & 0xFFFF) == 0).all()
 
 
 def _pl_tiles(rows_cap: int, B: int, u_cap: int) -> tuple[int, int]:
@@ -399,6 +461,18 @@ def kernel_uniq_lanes(n_uniq: int, B: int, u_cap: int) -> tuple[int, int]:
     on a block's rows)."""
     _td, tu = _pl_tiles(_PL_TD, B, u_cap)
     return -(-n_uniq // _PL_SU) * _PL_SU, -(-n_uniq // tu) * tu
+
+
+def kernel_contract_chunks(n_uniq: int,
+                           weights: np.ndarray) -> tuple[int, int]:
+    """``(chunks, bf16x3)``: the live 128-row chunks of A the kernel
+    contracts for a batch of ``n_uniq`` distinct terms, and those it
+    contracts in three bf16 passes instead of HIGHEST's six: all of a
+    batch whose host ``weights`` are exact in bfloat16, none of any
+    other. Host arithmetic for the ``kernel_contract_chunks*``
+    counters, through the predicate the kernel's own flag is made of."""
+    chunks = -(-n_uniq // _PL_TK)
+    return chunks, chunks if bf16_exact(weights) else 0
 
 
 def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
@@ -439,8 +513,11 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     term_t = term.T
     if n_rows is None:
         n_rows = jnp.int32(rows_cap)
+    # the scalars a grid step reads: live unique terms, live rows, and
+    # whether this batch's weights let the contraction take three passes
     lims = jnp.stack([jnp.asarray(n_uniq, jnp.int32),
-                      jnp.asarray(n_rows, jnp.int32)])
+                      jnp.asarray(n_rows, jnp.int32),
+                      bf16_exact(qc_ext).astype(jnp.int32)])
 
     kernel = functools.partial(_pallas_kernel, width=width, td=td, tu=tu)
     grid_spec = pltpu.PrefetchScalarGridSpec(

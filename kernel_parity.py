@@ -161,6 +161,14 @@ CASES = [
     # the tile schedule's B steps (512 -> 256 -> 128 tiles)
     dict(rows_cap=4096, width=12, n_rows=4000, B=1024, n_terms=4,
          u_req=1024),
+    # the rungs past 256 (whole documents, PR 30), at the batch bucket
+    # and capacity the doc cell runs: doc tile 512 to width 1024, 256
+    # to 2048, 128 to 4096 (fewer rows: the XLA reference gathers
+    # rows x width x B elements)
+    *(dict(rows_cap=4096 if width <= 1024 else 1024, width=width,
+           n_rows=4000 if width <= 1024 else 1000, B=512, n_terms=4,
+           u_req=1024)
+      for width in (384, 512, 768, 1024, 1536, 2048, 3072, 4096)),
 ]
 
 
@@ -180,6 +188,11 @@ INTERPRET_CASES = [
          u_req=256, ragged=True),
     dict(rows_cap=512, width=31, n_rows=300, B=64, n_terms=4,
          u_req=256, vocab=20_000, ragged=True),
+    # past the 256 rung: the widths whole documents fill (PR 30)
+    dict(rows_cap=256, width=384, n_rows=200, B=64, n_terms=4,
+         u_req=256, ragged=True),
+    dict(rows_cap=512, width=512, n_rows=400, B=64, n_terms=4,
+         u_req=256),
 ]
 
 

@@ -13,8 +13,9 @@ import pytest
 from tests.oracle import random_corpus as oracle_random_corpus
 from tfidf_tpu.engine.engine import Engine
 from tfidf_tpu.ops.csr import build_coo
-from tfidf_tpu.ops.ell import (build_ell_from_coo, ell_impacts,
-                               ell_scores_to_real, score_ell_batch)
+from tfidf_tpu.ops.ell import (ELL_WIDTH_LADDER, build_ell_from_coo,
+                               ell_impacts, ell_scores_to_real,
+                               score_ell_batch)
 from tfidf_tpu.ops.scoring import make_query_batch, score_coo_batch
 from tfidf_tpu.utils.config import Config
 
@@ -101,18 +102,47 @@ class TestBuild:
         assert ell.res_nnz > 0
         assert (np.diff(ell.res_doc) >= 0).all()
 
-    def test_non_ladder_width_cap_conserves_entries(self, rng):
-        """width_cap values that are not ladder rungs (e.g. 100, 512)
-        must still conserve every posting between blocks and residual —
-        a regression guard for the ladder/spill boundary mismatch."""
-        docs = [{t: 1 for t in range(n)} for n in (300, 120, 90, 40, 3)]
-        total = sum(len(d) for d in docs)
-        for cap in (100, 512, 20):
-            coo = build_coo(docs, vocab_cap=512, min_nnz_cap=1 << 11,
-                            min_doc_cap=16)
-            ell = build_ell_from_coo(coo, width_cap=cap, min_rows=8)
-            main = sum(int((b.tf > 0).sum()) for b in ell.blocks)
-            assert main + ell.res_nnz == total, cap
+    @pytest.mark.parametrize("cap, top", [
+        (20, 16), (100, 96), (256, 256), (300, 256), (384, 384),
+        (512, 512), (600, 512), (4096, 4096), (5000, 4096)])
+    def test_non_ladder_width_cap_conserves_entries(self, cap, top):
+        """A ``width_cap`` on or off a rung, under or over the ladder's
+        top, conserves every posting between blocks and residual: the
+        blocks stop at the widest rung <= cap (``top``) and only what a
+        document holds past THAT spills (since the ladder runs to 4096
+        a cap of 512 is a rung, where it once fell back to 256)."""
+        sizes = (4300, 600, 450, 300, 120, 90, 40, 3)
+        docs = [{t: 1 for t in range(n)} for n in sizes]
+        coo = build_coo(docs, vocab_cap=8192, min_nnz_cap=1 << 13,
+                        min_doc_cap=16)
+        ell = build_ell_from_coo(coo, width_cap=cap, min_rows=8)
+        main = sum(int((b.tf > 0).sum()) for b in ell.blocks)
+        assert main + ell.res_nnz == sum(sizes)
+        assert ell.res_nnz == sum(max(n - top, 0) for n in sizes)
+        assert ell.blocks[0].width == top
+        assert all(b.width in ELL_WIDTH_LADDER for b in ell.blocks)
+
+    def test_default_cap_is_the_ladders_top(self):
+        """``Config.ell_width_cap`` defaults to None, no ceiling of its
+        own, and the builder then cuts at the top rung: a row one wider
+        than the ladder spills that one posting."""
+        from tfidf_tpu.utils.config import Config
+        assert Config().ell_width_cap is None
+        top = ELL_WIDTH_LADDER[-1]
+        docs = [{t: 1 for t in range(top + 1)}]
+        coo = build_coo(docs, vocab_cap=8192, min_nnz_cap=1 << 13,
+                        min_doc_cap=16)
+        ell = build_ell_from_coo(coo, width_cap=Config().ell_width_cap,
+                                 min_rows=8)
+        assert (ell.blocks[0].width, ell.res_nnz) == (top, 1)
+
+    @pytest.mark.parametrize("raw,want", [("512", 512), ("null", None)])
+    def test_cap_from_the_environment(self, raw, want):
+        """A field whose default is None reads its ``TFIDF_*`` variable
+        as JSON: an int stays an int, not a string."""
+        from tfidf_tpu.utils.config import load_config
+        assert load_config(env={"TFIDF_ELL_WIDTH_CAP": raw}) \
+            .ell_width_cap == want
 
     def test_unsorted_rows_rejected(self, rng):
         docs = [{1: 1}, {1: 1, 2: 1, 3: 1}]    # ascending length

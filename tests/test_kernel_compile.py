@@ -3,7 +3,8 @@
 Runs ``tests/kernel_compile_worker.py`` (compile-only Mosaic through
 libtpu's topology client — no chip, seconds) in a subprocess: batch
 buckets on every step of the tile schedule x widths from the ELL ladder
-incl. 12, a mesh-split width of 1 and an odd one, plus the (4, 1) ``make_mesh_ell_search`` program and the served
+incl. 12, the rungs past 256 on every doc tile they take, a mesh-split
+width of 1 and an odd one, plus the (4, 1) ``make_mesh_ell_search`` program and the served
 device step at the benchmark cells' shapes. Interpret-mode
 parity (``tests/test_kernel_parity.py``) cannot see what this sees: a
 kernel the interpreter runs happily and Mosaic rejects.
@@ -42,16 +43,16 @@ def _failures(report, of_cells: bool) -> str:
 def test_every_eligible_shape_compiles_for_v5e(report):
     assert not _failures(report, of_cells=False)
     # a run that compiled nothing proves nothing
-    assert report["compiled"] >= 49, report
+    assert report["compiled"] >= 89, report
 
 
 def test_cells_device_step_compiles_for_v5e(report):
     """The scoring program and the top-k over its blocks, at the block
-    lists of the benchmark's two corpora and the batch buckets its cells
+    lists of the benchmark's three corpora and the batch buckets its cells
     dispatch (``CELL_STEPS`` in the worker) — and neither program holds
     a second copy of the score space."""
     assert not _failures(report, of_cells=True)
-    assert report["cells"] == 4, report
+    assert report["cells"] == 5, report
 
 
 @pytest.mark.parametrize("B", (128, 256, 512))

@@ -10,6 +10,10 @@ compiled another program and run a quarter more kernel work (the fault PR
 reason; this is the arithmetic, on the host alone (numpy over
 ``benchmarks/lib/data.py``'s corpus law, no device), so that a later
 change of ``docs`` cannot walk back into the trap unnoticed.
+
+``msmarco-doc`` (one chip, whole documents) is held the same way at the
+end of the file: its two blocks, at the 384 and the 512 rung of
+``ops/ell.py``'s ladder, as its configuration states them.
 """
 
 import importlib.util
@@ -22,6 +26,7 @@ import numpy as np
 import pytest
 
 from tfidf_tpu.ops.csr import next_capacity
+from tfidf_tpu.ops.ell import ELL_WIDTH_LADDER
 from tfidf_tpu.parallel.mesh_ell import ELL_WIDTHS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -46,9 +51,9 @@ MIN_ROWS = 256          # build_mesh_ell's floor of a block's rows
 CLEAR = 0.02            # of its capacity, every block's fullest shard
 
 
-def _spec() -> dict:
+def _spec(name: str = "msmarco4m-mesh") -> dict:
     with open(os.path.join(ROOT, "benchmarks", "configs",
-                           "msmarco4m-mesh.json")) as f:
+                           name + ".json")) as f:
         return json.load(f)
 
 
@@ -127,3 +132,38 @@ def test_fullest_shard_clears_its_capacity_by_2_percent(fullest):
         dict(zip(ELL_WIDTHS, zip(worst.tolist(), rows.tolist())))
     # the block the trap was in: 33-48 distinct terms
     assert 500_000 < worst[ELL_WIDTHS.index(48)] < 524_288 * (1 - CLEAR)
+
+
+# ---- msmarco-doc: one chip, every row past the 256 rung ---------------
+
+@pytest.fixture(scope="module")
+def doc_rungs():
+    """Documents of ``msmarco-doc`` in every rung of the local ladder
+    (``build_ell_from_coo``: the narrowest rung that holds the document),
+    for 64 seeds' orders: ``[64, len(ELL_WIDTH_LADDER)]``."""
+    per_chunk = _distinct_terms_by_chunk(
+        data.corpus_args(_spec("msmarco-doc")))
+    ladder = np.asarray(ELL_WIDTH_LADDER)
+    assert max(int(c.max()) for c in per_chunk) <= ladder[-1]
+    return np.stack([
+        np.bincount(np.searchsorted(
+            ladder, _in_seed_order(per_chunk, 2147483659 + 7919 * i)),
+            minlength=len(ladder)) for i in range(64)])
+
+
+def test_doc_cell_blocks_are_the_configurations(doc_rungs):
+    """Every seed commits the two blocks ``layout.blocks`` states, each
+    2% clear of its power-of-two capacity, no row at or under the 256
+    rung, none past the top: no residual. (One chip packs by the
+    documents' multiset, which the seed only reorders: the 64 rows are
+    equal, and this says so.)"""
+    blocks = _spec("msmarco-doc")["layout"]["blocks"]
+    assert (doc_rungs == doc_rungs[0]).all()
+    live = {w: int(n) for w, n in zip(ELL_WIDTH_LADDER, doc_rungs[0]) if n}
+    assert sorted(live, reverse=True) == blocks["widths"] == [512, 384]
+    assert [live[w] for w in blocks["widths"]] == [57590, 342410]
+    for w, rows in zip(blocks["widths"], blocks["rows"]):
+        assert next_capacity(live[w], MIN_ROWS) == rows
+        assert live[w] <= rows * (1 - CLEAR), (w, live[w], rows)
+    assert next_capacity(sum(live.values()), MIN_ROWS) \
+        == blocks["doc_cap"]

@@ -33,10 +33,11 @@ import numpy as np
 from tfidf_tpu.models.base import ScoringModel
 from tfidf_tpu.ops.csr import CooShard, next_capacity
 from tfidf_tpu.ops.ell import (build_ell_from_coo, cosine_norms_host,
-                               ell_impacts)
+                               ell_impacts, ell_layout_gauges)
 from tfidf_tpu.ops.scoring import cosine_norms
 from tfidf_tpu.utils.logging import get_logger
 from tfidf_tpu.utils.metrics import global_metrics
+from tfidf_tpu.utils.tracing import trace_phase
 
 log = get_logger("engine.index")
 
@@ -124,6 +125,7 @@ class Snapshot:
     res_tf: jax.Array | None = None       # f32 [res_cap] (None: no spill)
     res_term: jax.Array | None = None     # i32 [res_cap]
     res_doc: jax.Array | None = None      # i32 [res_cap]
+    res_nnz: int = 0                      # live entries of the residual
 
     @property
     def is_ell(self) -> bool:
@@ -146,7 +148,7 @@ jax.tree_util.register_dataclass(
                  "n_docs", "avgdl", "num_docs", "ell_impacts", "ell_terms",
                  "ell_live", "res_tf", "res_term", "res_doc"],
     meta_fields=["doc_names", "version", "nnz", "host_coo",
-                 "ell_live_host"],
+                 "ell_live_host", "res_nnz"],
 )
 
 
@@ -157,13 +159,19 @@ def _ell_live_fields(live) -> dict:
                 ell_live_host=tuple(int(n) for n in live))
 
 
+def _publish_ell_gauges(shapes, live, res_doc: np.ndarray) -> None:
+    """The ``ell_*`` gauges of a snapshot going live."""
+    for name, value in ell_layout_gauges(shapes, live, res_doc).items():
+        global_metrics.set_gauge(name, value)
+
+
 class ShardIndex:
     def __init__(self, model: ScoringModel,
                  min_nnz_cap: int = 1 << 16,
                  min_doc_cap: int = 1024,
                  keep_host_coo: bool = False,
                  layout: str = "ell",
-                 ell_width_cap: int = 256) -> None:
+                 ell_width_cap: int | None = None) -> None:
         self.model = model
         self.min_nnz_cap = min_nnz_cap
         self.min_doc_cap = min_doc_cap
@@ -396,27 +404,30 @@ class ShardIndex:
             else:
                 norms_host = np.zeros(coo.doc_cap, np.float32)
             norms = jnp.asarray(norms_host)
-            ell = build_ell_from_coo(
-                coo, width_cap=self.ell_width_cap,
-                min_rows=min(256, self.min_doc_cap))
             impacts, terms, live = [], [], []
             kw = self.model.score_kwargs()
-            for blk in ell.blocks:
-                rows_cap = blk.tf.shape[0]
-                dl_blk = np.zeros(rows_cap, np.float32)
-                dl_blk[:blk.n_rows] = doc_len_host[
-                    blk.row0:blk.row0 + blk.n_rows]
-                nrm_blk = np.zeros(rows_cap, np.float32)
-                nrm_blk[:blk.n_rows] = norms_host[
-                    blk.row0:blk.row0 + blk.n_rows]
-                # impacts precomputed once per commit (query path = pure
-                # gather + contract, no per-query BM25 math)
-                impacts.append(ell_impacts(
-                    jnp.asarray(blk.tf), jnp.asarray(blk.term),
-                    jnp.asarray(dl_blk), df, n_docs, avgdl,
-                    jnp.asarray(nrm_blk), **kw))
-                terms.append(jnp.asarray(blk.term))
-                live.append(blk.n_rows)
+            # host layout + upload + impacts: the part of a commit that
+            # grows with the postings (``phase_ell_build``)
+            with trace_phase("ell_build"):
+                ell = build_ell_from_coo(
+                    coo, width_cap=self.ell_width_cap,
+                    min_rows=min(256, self.min_doc_cap))
+                for blk in ell.blocks:
+                    rows_cap = blk.tf.shape[0]
+                    dl_blk = np.zeros(rows_cap, np.float32)
+                    dl_blk[:blk.n_rows] = doc_len_host[
+                        blk.row0:blk.row0 + blk.n_rows]
+                    nrm_blk = np.zeros(rows_cap, np.float32)
+                    nrm_blk[:blk.n_rows] = norms_host[
+                        blk.row0:blk.row0 + blk.n_rows]
+                    # impacts precomputed once per commit (query path =
+                    # pure gather + contract, no per-query BM25 math)
+                    terms.append(jnp.asarray(blk.term))
+                    impacts.append(ell_impacts(
+                        jnp.asarray(blk.tf), terms[-1],
+                        jnp.asarray(dl_blk), df, n_docs, avgdl,
+                        jnp.asarray(nrm_blk), **kw))
+                    live.append(blk.n_rows)
             tf = term = doc = None
             ell_kw: dict = dict(
                 ell_impacts=tuple(impacts), ell_terms=tuple(terms),
@@ -425,7 +436,10 @@ class ShardIndex:
                 ell_kw.update(
                     res_tf=jnp.asarray(ell.res_tf),
                     res_term=jnp.asarray(ell.res_term),
-                    res_doc=jnp.asarray(ell.res_doc))
+                    res_doc=jnp.asarray(ell.res_doc),
+                    res_nnz=ell.res_nnz)
+            _publish_ell_gauges([b.tf.shape for b in ell.blocks], live,
+                                ell.res_doc[:ell.res_nnz])
         else:
             tf = jnp.asarray(coo.tf)
             term = jnp.asarray(coo.term)
@@ -527,10 +541,18 @@ class ShardIndex:
                 ell_terms=tuple(jnp.asarray(data[f"ell_term_{i}"])
                                 for i in range(nb)),
                 **_ell_live_fields(data["ell_live"]))
+            res_doc = np.zeros(0, np.int32)
             if "res_tf" in data:
+                # a live entry has tf > 0; the padding has none
+                res_doc = np.asarray(data["res_doc"])[
+                    np.asarray(data["res_tf"]) > 0]
                 ell_kw.update(res_tf=jnp.asarray(data["res_tf"]),
                               res_term=jnp.asarray(data["res_term"]),
-                              res_doc=jnp.asarray(data["res_doc"]))
+                              res_doc=jnp.asarray(data["res_doc"]),
+                              res_nnz=int(res_doc.shape[0]))
+            _publish_ell_gauges(
+                [data[f"ell_imp_{i}"].shape for i in range(nb)],
+                data["ell_live"], res_doc)
         else:
             tf = jnp.asarray(data["coo_tf"])
             term = jnp.asarray(data["coo_term"])

@@ -376,7 +376,9 @@ class Searcher(QueryVectorizerMixin):
                 return (scores,), snap.num_docs, (scores.shape[1],)
             if snap.is_ell:
                 # gather fast path: impacts precomputed at commit;
-                # big blocks ride the fused compare/MXU Pallas kernel
+                # big blocks ride the fused compare/MXU Pallas kernel;
+                # what spilled past the widest block, the scatter path
+                global_metrics.inc("residual_entries_scored", snap.res_nnz)
                 blocks = score_ell_batch(
                     snap.ell_impacts, snap.ell_terms, snap.ell_live,
                     snap.res_tf, snap.res_term, snap.res_doc,

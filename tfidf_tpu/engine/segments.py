@@ -254,7 +254,7 @@ class SegmentedIndex:
                  min_nnz_cap: int = 1 << 16,     # unused; API compat
                  min_doc_cap: int = 1024,
                  layout: str = "ell",            # segments are always ELL
-                 ell_width_cap: int = 256,
+                 ell_width_cap: int | None = None,
                  max_segments: int = 8,
                  sync_merge_nnz: int = 1 << 20,
                  merge_upload_pace: float = 1.0,

@@ -13,12 +13,17 @@ for the VPU/MXU, with the output indexed directly by document row:
 A single width would waste heavily on skewed corpora (a few long documents
 force every row to their width), so documents are **sorted by distinct-term
 count at commit** (``ShardIndex.to_coo``) and packed into width buckets
-from ``ELL_WIDTH_LADDER`` (1.5x steps, 8..width_cap — finer than powers of
-two because real corpora concentrate around their mean distinct count);
-each bucket is its own dense block. Total padded entries stay well within
-2x of nnz regardless of skew. Entries beyond the widest bucket in a row
-spill into a small COO *residual* scored by the existing chunked path; the
-partial score tensors add.
+from ``ELL_WIDTH_LADDER`` (1.5x steps, 8..4096, cut at ``width_cap`` —
+finer than powers of two because real corpora concentrate around their
+mean distinct count); each bucket is its own dense block, and a rung
+yields a block only where documents need it: passages fill rungs up to
+64 or 128, whole documents of ~1,100 tokens the 384 and 512 rungs. Total
+padded entries stay well within 2x of nnz regardless of skew. A residual
+exists only where a document holds more distinct terms than the top rung
+(4,096: a text of some tens of thousands of tokens) or than a lower
+``width_cap``, and on the mesh, whose buckets stop at 256
+(``parallel/mesh_ell.py``): those entries spill into a COO *residual*
+scored by the chunked scatter path, and the partial score tensors add.
 
 Row counts are power-of-two bucketed and widths come from the fixed
 ladder, so the set of block shapes — and therefore XLA executables — is
@@ -61,7 +66,8 @@ class EllBlock:
 class EllShard:
     """Host-side blocked-ELL build product."""
     blocks: list[EllBlock]
-    # residual COO for entries beyond width_cap per doc (often empty)
+    # residual COO for the entries of a document past the widest rung
+    # (empty unless a document outgrows the ladder or ``width_cap``)
     res_tf: np.ndarray    # f32 [res_cap]
     res_term: np.ndarray  # i32 [res_cap]
     res_doc: np.ndarray   # i32 [res_cap], non-decreasing
@@ -72,13 +78,16 @@ class EllShard:
 # two (the 1.5x intermediate steps): real corpora concentrate around
 # their mean distinct count, so pure power-of-two buckets waste ~13% of
 # the A-build in pad entries (measured on the 1M-doc Zipf corpus:
-# 86.2M -> 74.8M padded entries). The kernel takes any width.
-ELL_WIDTH_LADDER = (8, 12, 16, 24, 32, 48, 64, 96, 128, 192, 256)
+# 86.2M -> 74.8M padded entries). The kernel takes any width; the top
+# rung is the widest whose [width, td] posting blocks fit the kernel's
+# VMEM at a doc tile of 128 for every batch bucket (``_pl_tiles``).
+ELL_WIDTH_LADDER = (8, 12, 16, 24, 32, 48, 64, 96, 128, 192, 256, 384, 512,
+                    768, 1024, 1536, 2048, 3072, 4096)
 
 
 def build_ell_from_coo(coo: CooShard,
                        *,
-                       width_cap: int = 256,
+                       width_cap: int | None = None,
                        min_width: int = 8,
                        min_rows: int = 256,
                        min_res_cap: int = 1 << 10) -> EllShard:
@@ -87,7 +96,10 @@ def build_ell_from_coo(coo: CooShard,
     Requires the COO invariants from ``ShardIndex.to_coo``: entries grouped
     by doc in increasing row order, rows sorted by distinct-term count
     descending, padding pointing at ``doc_cap - 1`` with tf=0.
+    ``width_cap`` None: no ceiling under the ladder's top rung.
     """
+    if width_cap is None:
+        width_cap = ELL_WIDTH_LADDER[-1]
     nnz, n_live = coo.nnz, coo.num_docs
     doc_ids = coo.doc[:nnz]
     bounds = np.searchsorted(doc_ids, np.arange(n_live + 1))
@@ -245,12 +257,16 @@ ell_impacts = jax.jit(ell_impacts, static_argnames=("model", "k1", "b"))
 # ``_pallas_eligible`` admits for v5e (compile-only, no chip needed).
 
 _PL_TD = 512          # docs per grid tile (256 for small blocks)
+_PL_TD_MIN = 128      # ... and no narrower than a lane tile, however wide
+_PL_POSTINGS_VMEM = 10 << 20   # of the 16 MB, for the [width, td] blocks
+_PL_ENTRY_VMEM = 16   # bytes of it an entry: term + impact, double-buffered
 _PL_MAX_B = 2048      # VMEM: qc [B, TU] + out [B, TD] stay ~8MB
 _PL_SU = 32           # uniq rows a register-resident A sub-tile holds
 _PL_ROWS = 8          # width rows an A-build loop iteration loads: a sublane tile
 _PL_TK = 128          # uniq rows an MXU contraction chunk holds
-# the Pallas call's fixed name: the device trace's event and the HLO
-# instruction are named from it (PERF.md §3), whatever jit encloses it
+# the Pallas call's name starts with this constant, whatever jit encloses
+# it: the device trace's event and the HLO instruction are named from it
+# (PERF.md §3), followed by ``_w<block width>``
 KERNEL_NAME = "ell_score_v4"
 
 
@@ -437,7 +453,8 @@ def bf16_exact(x):
     return ((x.view(np.uint32) & 0xFFFF) == 0).all()
 
 
-def _pl_tiles(rows_cap: int, B: int, u_cap: int) -> tuple[int, int]:
+def _pl_tiles(rows_cap: int, B: int, u_cap: int,
+              width: int) -> tuple[int, int]:
     """(doc tile, uniq tile) for a block/batch shape. Bigger tiles
     amortize grid overhead; both tiles shrink as B grows so the
     multi-buffered qc [TU/128, B, 128] / out [B, TD] blocks plus the A
@@ -445,9 +462,25 @@ def _pl_tiles(rows_cap: int, B: int, u_cap: int) -> tuple[int, int]:
     budget (Mosaic's buffering costs ~2x the naive block arithmetic,
     so the schedule is deliberately conservative): 512 tiles at B=1024
     or 256 at B=2048 ask the v5e compiler for 18.2 MB of scoped VMEM
-    against its 16 MB."""
+    against its 16 MB.
+
+    The doc tile also halves, down to 128 (a lane tile), until the term
+    and impact blocks ``[width, td]`` fit ``_PL_POSTINGS_VMEM``: two
+    arrays of 4 bytes, double-buffered, 16 bytes an entry. What the v5e
+    compiler asked for where it refused (compile-only, PR 30) less
+    those 16 bytes an entry is everything else, by (B, td, tu): 4.2 MB
+    at (512, 512, 512), 1.5 at (512, 256, 512), 4.5 at (1024, 256,
+    256), 5.25 at (2048, 128, 128); so 10 MB of postings leave the
+    16 MB limit 0.75 MB at the worst. Widths to 256 (2 MB at td 512)
+    never shrink the tile; 384 / 512 / 768 / 1024 take 3 / 4 / 6 / 8 MB
+    at td 512; 1536 and 2048 run at td 256, 3072 and 4096 at 128 (at
+    B <= 512; the larger buckets start lower). 4096 is the last rung
+    every bucket to ``_PL_MAX_B`` compiles: 6144 fits at B <= 1024
+    alone."""
     cap = 512 if B <= 512 else (256 if B <= 1024 else 128)
     td = min(cap, _PL_TD if rows_cap % _PL_TD == 0 else _PL_TD // 2)
+    while td > _PL_TD_MIN and _PL_ENTRY_VMEM * width * td > _PL_POSTINGS_VMEM:
+        td //= 2
     tu = min(cap, 512 if u_cap % 512 == 0 else 256, u_cap)
     return td, tu
 
@@ -459,8 +492,31 @@ def kernel_uniq_lanes(n_uniq: int, B: int, u_cap: int) -> tuple[int, int]:
     those). Host arithmetic for the ``kernel_uniq_*`` counters; the
     same for every block of a dispatch (the uniq tile does not depend
     on a block's rows)."""
-    _td, tu = _pl_tiles(_PL_TD, B, u_cap)
+    _td, tu = _pl_tiles(_PL_TD, B, u_cap, 1)
     return -(-n_uniq // _PL_SU) * _PL_SU, -(-n_uniq // tu) * tu
+
+
+def ell_layout_gauges(shapes, live, res_doc: np.ndarray) -> dict[str, int]:
+    """A committed layout as the ``ell_*`` gauges: ``shapes`` the
+    blocks' ``(rows_cap, width)``, ``live`` their live rows, ``res_doc``
+    the document row of every live entry of the COO residual, in its
+    non-decreasing order.
+    ``ell_entries_padded`` is what the blocks hold, live or pad (sum of
+    rows_cap x width); ``ell_entries_live_tiles`` what a kernel call
+    streams of it: the doc tiles that hold a live row (the rest are
+    skipped), at the doc tile of a batch of up to 512 queries. Host
+    arithmetic on the commit's own counts."""
+    streamed = 0
+    for (rows_cap, width), n_rows in zip(shapes, live):
+        td, _tu = _pl_tiles(rows_cap, 1, _PL_TK, width)
+        streamed += min(rows_cap, -(-int(n_rows) // td) * td) * width
+    return {"ell_blocks": len(shapes),
+            "ell_width_max": max((w for _r, w in shapes), default=0),
+            "ell_entries_padded": sum(r * w for r, w in shapes),
+            "ell_entries_live_tiles": streamed,
+            "ell_residual_nnz": int(res_doc.shape[0]),
+            "ell_residual_docs":
+                int(np.count_nonzero(np.diff(res_doc))) + min(len(res_doc), 1)}
 
 
 def kernel_contract_chunks(n_uniq: int,
@@ -496,12 +552,14 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     # with more never-matching ids and zero weights
     u_cap = -(-uniq.shape[0] // _PL_TK) * _PL_TK
     grow = u_cap - uniq.shape[0]
-    td, tu = _pl_tiles(rows_cap, B, u_cap)
+    td, tu = _pl_tiles(rows_cap, B, u_cap, width)
     # the grid floor-divides: a non-multiple capacity would silently
     # drop the trailing tile (callers route through _pallas_eligible,
-    # but direct callers must fail loudly, not score wrong)
-    assert rows_cap % td == 0 and u_cap % tu == 0 and tu % _PL_TK == 0, \
-        (rows_cap, td, u_cap, tu)
+    # but direct callers must fail loudly, not score wrong); a width
+    # past the ladder's top would ask Mosaic for more VMEM than it has
+    assert rows_cap % td == 0 and u_cap % tu == 0 and tu % _PL_TK == 0 \
+        and _PL_ENTRY_VMEM * width * td <= _PL_POSTINGS_VMEM, \
+        (rows_cap, td, u_cap, tu, width)
     # pad entries of uniq must never match a real term id
     uniq_col = jnp.where(jnp.arange(u_cap) < n_uniq, jnp.pad(uniq, (0, grow)),
                          jnp.int32(-1))[:, None]     # [U1, 1]
@@ -538,7 +596,9 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     )
     return pl.pallas_call(
         kernel,
-        name=KERNEL_NAME,
+        # the block's width after the constant, so that a device trace
+        # tells a wide block's calls from a narrow one's
+        name=f"{KERNEL_NAME}_w{width}",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rows_cap), jnp.float32),
         compiler_params=pltpu.CompilerParams(

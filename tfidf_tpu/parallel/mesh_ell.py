@@ -113,7 +113,7 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
                    mesh: Mesh,
                    transform_len,                   # model.transform_doc_len
                    *,
-                   width_cap: int = 256,
+                   width_cap: int | None = 256,
                    min_rows: int = 256,
                    min_res_cap: int = 1 << 10
                    ) -> tuple[MeshEllHost, list[np.ndarray]]:
@@ -127,7 +127,8 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
     """
     D = mesh.shape["docs"]
     T = mesh.shape["terms"]
-    widths = [w for w in ELL_WIDTHS if w <= width_cap]
+    widths = [w for w in ELL_WIDTHS
+              if width_cap is None or w <= width_cap]
     assert T <= min(widths), "terms axis cannot exceed the narrowest bucket"
 
     # per shard: sort rows by distinct-count desc, assign to buckets

@@ -85,7 +85,7 @@ class MeshEllIndex(MeshIndex):
 
     def __init__(self, model, mesh=None, min_doc_cap: int = 1024,
                  min_chunk_cap: int = 1 << 14,
-                 ell_width_cap: int = 256,
+                 ell_width_cap: int | None = 256,
                  delta_rebuild_frac: float = 0.5) -> None:
         super().__init__(model, mesh=mesh, min_doc_cap=min_doc_cap,
                          min_chunk_cap=min_chunk_cap)

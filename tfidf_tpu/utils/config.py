@@ -176,7 +176,9 @@ class Config:
     # "ell": padded rows-by-document, gather/MXU scoring with precomputed
     #        impacts (TPU fast path). "coo": chunked scatter scoring.
     scoring_layout: str = "ell"
-    ell_width_cap: int = 256   # max ELL row width; longer docs spill to COO
+    # ceiling on an ELL row's width; a document's postings past it spill
+    # to COO. None: no ceiling under the top of ops.ell.ELL_WIDTH_LADDER
+    ell_width_cap: int | None = None
     # Fused Pallas gather kernel for big ELL blocks (avoids the XLA
     # path's [rows, width, B] HBM materialization — the gather-bound
     # bottleneck at 1M-doc scale). Small blocks always use the XLA path.
@@ -628,8 +630,9 @@ def load_config(path: str | None = None, env: dict[str, str] | None = None,
         key = _ENV_PREFIX + f_.name.upper()
         if key in env:
             base = Config.__dataclass_fields__[f_.name].default
-            ty = type(base) if base is not None and not isinstance(
-                base, dataclasses._MISSING_TYPE) else str
+            # a default of None (ell_width_cap) reads as JSON
+            ty = str if isinstance(base, dataclasses._MISSING_TYPE) \
+                else type(base)
             values[f_.name] = _coerce(env[key], ty)
     values.update(overrides)
     return Config(**values)

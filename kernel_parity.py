@@ -7,7 +7,10 @@ pair fold's lone last row) — and that the top-10 ranking is stable
 against the XLA path. Every case runs twice, because the kernel
 contracts by what the batch's weights are: FRACTIONAL weights take the
 ``Precision.HIGHEST`` dot, term MULTIPLICITIES (what the engine's
-queries carry; exact in bfloat16) the three bf16 passes. Three callers
+queries carry; exact in bfloat16) the three bf16 passes. Beside the
+kernel's matrix runs one case of the top-k that reads its blocks
+(``run_topk_case``): the two-stage selection of ``ops/topk.py`` against
+``lax.top_k`` of the masked block, ids as well as values. Three callers
 share ``run_case``:
 
 * ``chip_smoke.py``'s engine stage runs ``CASES`` on the TPU, where the
@@ -33,6 +36,8 @@ import numpy as np
 from tfidf_tpu.ops.ell import (_pallas_eligible, _score_block, bf16_exact,
                                pallas_interpret, score_block_pallas)
 from tfidf_tpu.ops.scoring import _compile_queries, make_query_batch
+from tfidf_tpu.ops.topk import (TOPK_CHUNK, packed_topk_chunked,
+                                topk_chunk_counts, unpack_topk)
 
 TOP_K = 10
 
@@ -126,6 +131,54 @@ def run_case(name, rng, **kw):
             "ok": ok, **kw}
 
 
+# the top-k beside the kernel: one block as wide as the cells' widest,
+# its live count inside a group of 128, at the batch bucket they run
+TOPK_CASE = dict(B=512, cap=1 << 20, live=870_316, k=TOP_K)
+TOPK_INTERPRET_CASE = dict(B=16, cap=3 << 14, live=40_037, k=TOP_K)
+# the msmarco2m cell's blocks (tests/kernel_compile_worker.py CELL_STEPS)
+TOPK_CELL_CAPS = (4096, 1048576, 1048576, 131072, 256, 256)
+
+
+def run_topk_case(rng, *, B, cap, live, k, chunk=TOPK_CHUNK):
+    """``packed_topk_chunked`` over one ``[B, cap]`` block of TIED scores
+    (integers 1..3 over 97% exact zeros; every row's ``live - 1`` column
+    a 3, so the live edge itself ranks) against ``lax.top_k`` of the same
+    block masked past ``live``: values and ids bit-equal. Also the
+    compiled program's temporaries at this bucket on the ``msmarco2m``
+    cell's blocks (``memory_analysis()``; the parent's read 2,842,112
+    bytes at B = 512: PERF.md)."""
+    x = rng.integers(1, 4, size=(B, cap), dtype=np.int8)
+    x[rng.random((B, cap), dtype=np.float32) < 0.97] = 0
+    x[:, live - 1] = 3
+    x_d = jnp.asarray(x, jnp.float32)
+    del x
+
+    @jax.jit
+    def oracle(x):
+        col = jnp.arange(cap, dtype=jnp.int32)[None, :]
+        return jax.lax.top_k(jnp.where(col < live, x, -jnp.inf), k)
+
+    want_v, want_i = (np.asarray(a) for a in oracle(x_d))
+    lives = jnp.asarray([live], jnp.int32)
+    got_v, got_i = unpack_topk(np.asarray(packed_topk_chunked(
+        (x_d,), lives, k=k, chunk=chunk)))
+    chunks, skipped, grouped = topk_chunk_counts([cap], [live], chunk, k=k)
+    structs = tuple(jax.ShapeDtypeStruct((B, c), jnp.float32)
+                    for c in TOPK_CELL_CAPS)
+    temp = packed_topk_chunked.lower(
+        structs, jax.ShapeDtypeStruct((len(structs),), jnp.int32),
+        k=k).compile().memory_analysis().temp_size_in_bytes
+    values_equal = bool(np.array_equal(got_v, want_v))
+    ids_equal = bool(np.array_equal(got_i, want_i))
+    ok = values_equal and ids_equal and grouped == chunks - skipped > 0
+    log(f"[topk] values={values_equal} ids={ids_equal} chunks={chunks} "
+        f"skipped={skipped} grouped={grouped} temp_bytes={temp} ok={ok}")
+    return {"name": "topk", "B": B, "cap": cap, "live": live, "k": k,
+            "values_equal": values_equal, "ids_equal": ids_equal,
+            "chunks": chunks, "skipped": skipped, "grouped": grouped,
+            "cell_temp_bytes": int(temp), "ok": ok}
+
+
 # the hardware matrix: north-star-like shapes + every eligibility edge
 # (the tier-1 interpret run uses scaled-down shapes of the same edges)
 CASES = [
@@ -201,20 +254,24 @@ def run_matrix(seed: int = 7) -> dict:
     ``KERNEL_PARITY.json`` holds: ``CASES`` where the kernel is a
     Mosaic program, ``INTERPRET_CASES`` where it is interpreted; each
     with fractional weights (``caseN``) and with multiplicities
-    (``caseN-mult``)."""
+    (``caseN-mult``); then the top-k's one case (``topk``)."""
     rng = np.random.default_rng(seed)
-    cases = INTERPRET_CASES if pallas_interpret() else CASES
+    interpret = pallas_interpret()
+    cases = INTERPRET_CASES if interpret else CASES
     results = [run_case(f"case{i}{'-mult' if mult else ''}", rng,
                         multiplicity=mult, **kw)
                for i, kw in enumerate(cases) for mult in (False, True)]
+    topk = run_topk_case(rng, **(TOPK_INTERPRET_CASE if interpret
+                                 else TOPK_CASE))
     dev = jax.devices()[0]
     return {
         "backend": jax.default_backend(),
-        "mosaic_compiled": not pallas_interpret(),
+        "mosaic_compiled": not interpret,
         "device_kind": dev.device_kind,
         "jax": jax.__version__,
-        "all_ok": all(r["ok"] for r in results),
+        "all_ok": all(r["ok"] for r in results) and topk["ok"],
         "cases": results,
+        "topk": topk,
     }
 
 

@@ -525,8 +525,9 @@ class TestClusterEndToEnd:
 
     def test_topk_chunk_counters_exposed(self, cluster):
         """Every dispatched chunk adds its snapshot's padded top-k chunk
-        count to ``topk_chunks`` (and the dead ones among them to
-        ``topk_chunks_skipped``): read from a worker's /api/metrics."""
+        count to ``topk_chunks`` (the dead ones among them to
+        ``topk_chunks_skipped``, those ranked by group maxima to
+        ``topk_chunks_grouped``): read from a worker's /api/metrics."""
         from tfidf_tpu.ops.topk import topk_chunk_counts
         leader, workers = cluster[0], cluster[1:]
         for i in range(12):     # three widths: several blocks a worker
@@ -540,19 +541,22 @@ class TestClusterEndToEnd:
                                     b"chunky w0x0"))   # not the cached one
         after = json.loads(http_get(workers[0].url + "/api/metrics"))
         assert len(hits) == 10
-        want = [0, 0]
+        want = [0, 0, 0]
         for w in workers:       # one process: the workers share counters
             snap = w.engine.index.snapshot
             assert len(snap.ell_impacts) >= 2
-            chunks, skipped = topk_chunk_counts(
+            counts = topk_chunk_counts(
                 [imp.shape[0] for imp in snap.ell_impacts],
-                snap.ell_live_host)
-            want[0] += chunks
-            want[1] += skipped
+                snap.ell_live_host, k=10)
+            want = [a + b for a, b in zip(want, counts)]
         assert after["dispatch_chunks"] - before["dispatch_chunks"] == 2
         assert after["topk_chunks"] - before["topk_chunks"] == want[0]
         assert after["topk_chunks_skipped"] \
             - before["topk_chunks_skipped"] == want[1]
+        # these blocks are narrower than eighty groups: none is grouped,
+        # and the counter is there all the same
+        assert after["topk_chunks_grouped"] \
+            - before.get("topk_chunks_grouped", 0) == want[2] == 0
 
 
 class TestBoundedClusterSearch:

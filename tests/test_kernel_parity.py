@@ -22,7 +22,8 @@ if _root not in sys.path:
     sys.path.insert(0, _root)
 
 from kernel_parity import INTERPRET_CASES as T1_CASES  # noqa: E402
-from kernel_parity import TOP_K, make_case, run_case  # noqa: E402
+from kernel_parity import (TOP_K, TOPK_INTERPRET_CASE,  # noqa: E402
+                           make_case, run_case, run_topk_case)
 from tfidf_tpu.ops import ell  # noqa: E402
 from tfidf_tpu.ops.ell import (_pallas_eligible, _pl_tiles,  # noqa: E402
                                _score_block, score_block_pallas)
@@ -45,6 +46,16 @@ def test_interpret_parity(i, multiplicity):
     # one reads 1.2e-7: Mosaic's HIGHEST is six bf16 passes there)
     if T1_CASES[i]["width"] > 256:
         assert r["max_abs_delta"] == 0.0, r
+
+
+def test_topk_case_of_the_matrix():
+    """The top-k case ``kernel_parity.py`` runs on the chip beside the
+    kernel's, at a CPU's scale: two-stage against ``lax.top_k``, ids as
+    well as values, every live chunk by group maxima."""
+    r = run_topk_case(np.random.default_rng(31), **TOPK_INTERPRET_CASE)
+    assert r["ok"], r
+    assert (r["chunks"], r["skipped"], r["grouped"]) == (1, 0, 1)
+    assert r["cell_temp_bytes"] > 0
 
 
 def test_ingest_rejects_duplicate_or_unsorted_ids():

@@ -9,6 +9,7 @@ never win, ties resolve to the lower real row across block and chunk
 boundaries, live zero-score rows still fill a short list.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -18,8 +19,9 @@ from tfidf_tpu.engine.engine import Engine
 from tfidf_tpu.ops.csr import build_coo
 from tfidf_tpu.ops.ell import ell_scores_to_real, score_ell_batch
 from tfidf_tpu.ops.scoring import make_query_batch
-from tfidf_tpu.ops.topk import (packed_topk, packed_topk_chunked,
-                                topk_chunk_counts)
+from tfidf_tpu.ops.topk import (TOPK_GROUP, exact_topk, packed_topk,
+                                packed_topk_chunked, topk_chunk_counts,
+                                topk_grouped, unpack_topk)
 from tfidf_tpu.utils.config import Config
 from tfidf_tpu.utils.metrics import global_metrics
 
@@ -78,7 +80,7 @@ def case_dead_tails(rng):
     # (a) four blocks with dead tails; block 1's second chunk and block
     # 0's last hold no live row; the 8-row block is narrower than k
     caps, live = (64, 32, 16, 8), (40, 5, 16, 3)
-    assert topk_chunk_counts(caps, live, CHUNK) == (8, 2)
+    assert topk_chunk_counts(caps, live, CHUNK, k=10) == (8, 2, 0)
     return synthetic_blocks(rng, caps, live) + (10,)
 
 
@@ -124,7 +126,8 @@ def case_small_and_skipped(rng):
         query_terms=lambda b, docs: [b, b + 1])
     caps = [blk.shape[1] for blk in blocks]
     assert caps == [128, 8, 8]
-    assert topk_chunk_counts(caps, np.asarray(live), CHUNK) == (10, 3)
+    assert topk_chunk_counts(caps, np.asarray(live), CHUNK,
+                             k=10) == (10, 3, 0)
     return blocks, live, 10
 
 
@@ -150,17 +153,125 @@ def test_topk_from_blocks_is_bit_equal_to_topk_of_real_matrix(rng, case):
             blocks, live, k=k, chunk=chunk)))
 
 
+# ---- the two-stage selection (PR 31): group maxima choose the k groups
+# that can hold a winner, and only those are ranked. The contract is
+# lax.top_k's own answer over the masked matrix, VALUES AND IDS: exact
+# ties are the common case here (zeros, equal tf and length).
+
+G = TOPK_GROUP
+WIDE = 96 * G       # the narrowest window that is grouped at k = 10 is 80 G
+
+
+def tied_scores(rng, B, cap, zeros=0.97):
+    """Integer scores 1..3 over ``zeros`` exact zeros: ties everywhere."""
+    x = rng.integers(1, 4, (B, cap)).astype(np.float32)
+    x[rng.random((B, cap)) < zeros] = 0.0
+    return x
+
+
+def lax_topk_of_masked(x, lo, hi, k):
+    """The oracle: ``lax.top_k`` of the matrix with the columns outside
+    ``[lo, hi)`` at -inf."""
+    col = np.arange(x.shape[1])[None, :]
+    masked = jnp.where((col >= lo) & (col < hi), jnp.asarray(x), -jnp.inf)
+    v, i = jax.lax.top_k(masked, k)
+    return np.asarray(v), np.asarray(i)
+
+
+# (B, cap, live, k, chunk, whether the block's chunks are grouped): a
+# width the groups do not divide goes straight (the commit's capacities
+# are powers of two: a pad would be a copy of the block)
+GROUPED_CASES = {
+    "ties_95pct_zeros": (16, WIDE, WIDE - 5, 10, WIDE, True),
+    "fewer_than_k_positive": (2, WIDE, WIDE, 10, WIDE, True),
+    "live_0": (2, WIDE, 0, 10, WIDE, True),
+    "live_inside_a_group": (16, WIDE, 40 * G + 37, 10, WIDE, True),
+    "live_on_a_group_edge": (16, WIDE, 40 * G, 10, WIDE, True),
+    "live_is_cap": (16, WIDE, WIDE, 10, WIDE, True),
+    "width_g_does_not_divide": (2, 87 * G + 1, 87 * G + 1, 10, 1 << 17,
+                                False),
+    "width_a_column_short": (2, 88 * G - 1, 87 * G + 3, 10, 1 << 17, False),
+    "under_the_threshold": (16, 79 * G, 79 * G - 3, 10, 1 << 17, False),
+    "k_1": (16, 8 * G, 5 * G + 1, 1, 8 * G, True),
+    "k_100": (2, 1600 * G, 1500 * G + 1, 100, 800 * G, True),
+    "k_over_g": (1, 8 * (G + 2) * G, 999 * G + 1, G + 2, 1 << 18, True),
+    "B_1": (1, WIDE, WIDE - G - 1, 10, WIDE, True),
+    "B_2": (2, WIDE, WIDE - G - 1, 10, WIDE, True),
+    "B_12": (12, WIDE, WIDE - G - 1, 10, WIDE, True),
+    "B_64": (64, WIDE, WIDE - G - 1, 10, WIDE, True),
+    "clamped_last_chunk": (16, 2 * WIDE + 3 * G, 2 * WIDE + G + 1, 10,
+                           WIDE, True),
+    "clamped_last_chunk_skipped": (2, 2 * WIDE + 3 * G, 2 * WIDE, 10, WIDE,
+                                   True),
+    "clamped_last_chunk_odd_cap": (2, 2 * WIDE + 3 * G + 5, 2 * WIDE + G,
+                                   10, WIDE, False),
+    "all_minus_inf_rows": (16, WIDE, WIDE - 9, 10, WIDE, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GROUPED_CASES))
+def test_grouped_topk_is_bit_equal_to_lax_topk(rng, name):
+    B, cap, live, k, chunk, grouped = GROUPED_CASES[name]
+    c = min(chunk, cap)
+    assert topk_grouped(cap, c, min(k, c)) == grouped
+    x = tied_scores(rng, B, cap)
+    if name == "fewer_than_k_positive":
+        x[:] = 0.0
+        x[0, [5, 40 * G, cap - 1]] = 2.0        # row 1: none at all
+    if name == "all_minus_inf_rows":
+        x[::2] = -np.inf
+        x[1, :cap // 2] = -np.inf
+    x[:, live:] = DEAD
+    want_v, want_i = lax_topk_of_masked(x, 0, live, k)
+
+    # the chunked form (a block with a traced live count) ...
+    got_v, got_i = unpack_topk(np.asarray(packed_topk_chunked(
+        (jnp.asarray(x),), jnp.asarray([live], jnp.int32), k=k,
+        chunk=chunk)))
+    assert np.array_equal(got_v, want_v)
+    assert np.array_equal(got_i, want_i)
+    # ... and the unchunked one the mesh's shards use (``lax.top_k``
+    # itself: PERF.md section 7)
+    v, i = exact_topk(jnp.asarray(x), jnp.int32(live), k=k)
+    assert np.array_equal(np.asarray(v), want_v)
+    assert np.array_equal(np.asarray(i), want_i)
+    n_chunks, skipped, n_grouped = topk_chunk_counts([cap], [live], chunk,
+                                                     k=k)
+    assert n_grouped == (n_chunks - skipped if grouped else 0)
+
+
+def test_grouped_topk_between_blocks_breaks_ties_to_the_lower_row(rng):
+    """Two grouped blocks and a narrow one, every score one of two
+    values: the merged top-10 of each query is its ten lowest real rows
+    of the higher value, whichever block holds them."""
+    caps, live = (WIDE, 2 * WIDE, 256), (WIDE - 77, WIDE + 5, 200)
+    blocks, lives = synthetic_blocks(rng, caps, live, B=4, levels=2)
+    real = ell_scores_to_real(blocks, lives, 4 * WIDE)
+    num_docs = jnp.int32(sum(live))
+    want = np.asarray(packed_topk(real, num_docs, k=10))
+    for chunk in (WIDE, 1 << 17):
+        assert np.array_equal(want, np.asarray(packed_topk_chunked(
+            blocks, lives, k=10, chunk=chunk)))
+    col = np.arange(4 * WIDE)[None, :]
+    masked = np.where(col < sum(live), np.asarray(real), -np.inf)
+    order = np.argsort(-masked, axis=1, kind="stable")[:, :10]
+    assert np.array_equal(want[:, 10:], order)
+
+
 def test_topk_chunk_counters_add_up_to_the_padded_space(tmp_path):
-    """``topk_chunks`` / ``topk_chunks_skipped`` count, per dispatched
-    chunk, the committed snapshot's padded chunk count and the dead ones
-    among them — from shapes and the commit's host integers
-    (``tests/test_cluster.py`` reads them from ``/api/metrics``)."""
+    """``topk_chunks`` / ``topk_chunks_skipped`` / ``topk_chunks_grouped``
+    count, per dispatched chunk, the committed snapshot's padded chunk
+    count, the dead ones among them and those ranked by group maxima —
+    from shapes and the commit's host integers (``tests/test_cluster.py``
+    reads them from ``/api/metrics``). grouped + skipped + straight =
+    chunks."""
     def counted(fn):
         before = global_metrics.snapshot()
         fn()
         after = global_metrics.snapshot()
         return [after.get(key, 0) - before.get(key, 0) for key in
-                ("dispatch_chunks", "topk_chunks", "topk_chunks_skipped")]
+                ("dispatch_chunks", "topk_chunks", "topk_chunks_skipped",
+                 "topk_chunks_grouped")]
 
     e = Engine(Config(documents_path=str(tmp_path), min_doc_capacity=8,
                       min_nnz_capacity=256, min_vocab_capacity=64,
@@ -174,10 +285,21 @@ def test_topk_chunk_counters_add_up_to_the_padded_space(tmp_path):
     assert len(caps) == 3
     assert snap.ell_live_host == tuple(np.asarray(snap.ell_live))
     # 3 dispatches of <= 4 queries; every block here is one live chunk
-    assert counted(lambda: e.search_batch(["shared"] * 9)) == [3, 9, 0]
+    # (narrower than eighty groups: straight through ``lax.top_k``)
+    assert counted(lambda: e.search_batch(["shared"] * 9)) == [3, 9, 0, 0]
 
     # the msmarco2m cell's blocks (a commit of its corpus): 20 chunks of
-    # 131072 columns, the third block's last one past its 870,316 rows
+    # 131072 columns, the third block's last one past its 870,316 rows;
+    # the 17 chunks of the three wide blocks go by group maxima less the
+    # skipped one, the 4096- and 256-column blocks straight
+    caps = (4096, 1048576, 1048576, 131072, 256, 256)
+    live = (3310, 1047691, 870316, 78448, 233, 2)
+    assert topk_chunk_counts(caps, live, k=10) == (20, 1, 16)
+    assert [topk_grouped(c, min(c, 1 << 17), 10)
+            for c in caps[::2]] == [False, True, False]
+    # wiki1m's: four dead chunks of fifteen, the 32768-column block grouped
     assert topk_chunk_counts(
-        (4096, 1048576, 1048576, 131072, 256, 256),
-        (3310, 1047691, 870316, 78448, 233, 2)) == (20, 1)
+        (256, 524288, 1048576, 32768, 256),
+        (201, 393204, 598231, 8311, 53), k=10) == (15, 4, 9)
+    # a caller's k in the thousands ranks every chunk straight
+    assert topk_chunk_counts(caps, live, k=2000) == (20, 1, 0)

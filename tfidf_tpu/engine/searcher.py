@@ -410,11 +410,13 @@ class Searcher(QueryVectorizerMixin):
         with trace_phase("topk"):
             kk = min(k, snap.num_names)
             # the top-k's chunks over this dispatch's padded score space,
-            # and those of them wholly in dead tails, which it skips
-            chunks, skipped = topk_chunk_counts(
-                [blk.shape[1] for blk in blocks], live_host)
+            # those of them wholly in dead tails, which it skips, and
+            # those it ranks by group maxima
+            chunks, skipped, grouped = topk_chunk_counts(
+                [blk.shape[1] for blk in blocks], live_host, k=kk)
             global_metrics.inc("topk_chunks", chunks)
             global_metrics.inc("topk_chunks_skipped", skipped)
+            global_metrics.inc("topk_chunks_grouped", grouped)
             return packed_topk_chunked(blocks, live, k=kk), kk
 
     def _dispatch_tiered(self, snap: SegmentedSnapshot,

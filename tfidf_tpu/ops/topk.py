@@ -7,11 +7,19 @@ produces an exact local top-k, shards are combined by concatenation +
 re-top-k (associative, so it composes under ``all_gather``), and a
 ``full_ranking`` path covers the reference's unbounded-result behavior for
 parity testing.
+
+The serving top-k (:func:`packed_topk_chunked`) reads the scorer's blocks
+where they lie, in chunks, and ranks a chunk in two stages: one reduce to
+the maxima of groups of 128 contiguous columns, then only the ``k`` groups
+that can hold a winner (:func:`_chunk_topk`, with the proof that it is
+``lax.top_k``'s own answer, ties included). :func:`exact_topk`, the
+unchunked form the mesh's shards use, is ``lax.top_k`` itself.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -116,7 +124,96 @@ def unpack_topk(packed) -> tuple:
     return vals, ids
 
 
-TOPK_CHUNK = 1 << 17    # doc columns one ``lax.top_k`` call sees
+TOPK_GROUP = 128        # columns a group: one lane tile
+
+
+def topk_grouped(cap: int, c: int, k: int) -> bool:
+    """Whether the top-``k`` of a ``c``-column window of a ``cap``-column
+    array goes by group maxima (:func:`_chunk_topk`): where the window
+    holds at least eight times ``k`` groups, so that the ``k`` groups it
+    ranks are well under it, and groups are whole lane tiles of the
+    array. A block narrower than that, or a caller's ``k`` in the
+    thousands, goes straight through ``lax.top_k``. Static shapes only:
+    the device path and :func:`topk_chunk_counts` share it, so the
+    host's count is the device's."""
+    g = TOPK_GROUP
+    return cap % g == 0 and c % g == 0 and c // g >= 8 * k
+
+
+def _chunk_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
+                off, live,         # i32 scalars (TRACED)
+                *, c: int, k: int) -> tuple[jax.Array, jax.Array]:
+    """Exact top-``k`` of the ``c`` columns of ``x`` from ``off``, those at
+    or past ``live`` masked to -inf: values and columns of ``x``, ties to
+    the lower column, as ``lax.top_k`` of the masked chunk gives them.
+    Where :func:`topk_grouped` says so, in two stages:
+
+    1. the maximum of each group of ``TOPK_GROUP`` CONTIGUOUS columns:
+       the only pass over the chunk, a plain reduce with the mask fused
+       into it, at the memory's rate;
+    2. ``lax.top_k`` of the group maxima chooses ``k`` groups, ties to
+       the lower group, and they are put in ascending order;
+    3. only those groups' columns are gathered and ranked.
+
+    Exact, ties included. Let element ``(v, i)`` lie in a group ``G``
+    that stage 2 did not choose. Then ``k`` chosen groups ``j`` each
+    order before ``G``: ``M_j > M_G``, or ``M_j = M_G`` and ``j < G``.
+    Each holds an element of value ``M_j >= M_G >= v``; where ``M_j = v``
+    it follows that ``M_j = M_G``, so ``j < G``, and because groups are
+    contiguous every column of ``j`` is lower than ``i``: that element
+    wins the tie. So ``k`` elements beat ``(v, i)`` and it is not in the
+    top ``k``; the candidates lie in ascending column order, so the last
+    ``lax.top_k`` breaks ties as one over the whole chunk would. (Strided
+    groups would not carry this: a tie between two groups' maxima says
+    nothing about the order of their columns. Scores that are exactly
+    equal are the common case here: zeros, equal tf and length.)
+
+    The chunk is a ``dynamic_slice``, NOT a ``[B, n, c]``
+    reshape+transpose: that would materialize a second copy of the
+    block, which at 1M docs and wide batches is the difference between
+    fitting HBM and not. The last chunk's start is clamped to
+    ``cap - c`` so every slice is full-width regardless of ``cap % c``;
+    columns the clamp makes overlap the previous chunk (``< off``) are
+    masked out so no doc can win twice in the merge. Likewise both the
+    reduce and the gather read ``x`` through a view in ITS OWN tiling —
+    ``[B/8, 8, cap/128, 128]`` is ``f32[B, cap]{T(8,128)}`` as it lies
+    in HBM — so neither makes XLA relay a chunk: written
+    ``reshape(B, c/128, 128).max(-1)`` the reduce costs two chunk-sized
+    copies, and a gather of ``[1, 128]`` slices of the 2-D array expands
+    to a ``while`` of ``B * k`` steps (PERF.md section 6, PR 31).
+    """
+    B, cap = x.shape
+    g = TOPK_GROUP
+    start = jnp.minimum(off, cap - c)
+
+    def mask(scores, col):
+        return jnp.where((col >= off) & (col < live), scores, -jnp.inf)
+
+    masked = mask(jax.lax.dynamic_slice_in_dim(x, start, c, axis=1),
+                  jnp.arange(c, dtype=jnp.int32)[None, :] + start)
+    if not topk_grouped(cap, c, k):
+        v, i = jax.lax.top_k(masked, k)
+        return v, i.astype(jnp.int32) + start
+    s = math.gcd(B, 8)      # rows a sublane tile
+    gmax = masked.reshape(B // s, s, c // g, g).max(-1).reshape(B, c // g)
+    _, grp = jax.lax.top_k(gmax, k)
+    grp = jnp.sort(grp.astype(jnp.int32), axis=-1) + start // g    # [B, k]
+    tiles = x.reshape(B // s, s, cap // g, g).transpose(0, 2, 1, 3)
+    row = jax.lax.broadcasted_iota(jnp.int32, (B, k), 0)
+    cand = jax.lax.gather(
+        tiles, jnp.stack([row // s, grp, row % s], axis=-1),
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(2,), collapsed_slice_dims=(0, 1, 2),
+            start_index_map=(0, 1, 2)),
+        (1, 1, 1, g), mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    cand = mask(cand, grp[:, :, None] * g + jnp.arange(g, dtype=jnp.int32))
+    v, p = jax.lax.top_k(cand.reshape(B, k * g), k)
+    # a winner's column, through its group: a [B, k, k] select, no gather
+    slot = (p // g)[:, :, None] == jnp.arange(k, dtype=jnp.int32)
+    return v, jnp.sum(jnp.where(slot, grp[:, None, :], 0), -1) * g + p % g
+
+
+TOPK_CHUNK = 1 << 17    # doc columns one step of the scan sees
 
 
 def _chunk_starts(cap: int, chunk: int) -> tuple[int, list[int]]:
@@ -127,19 +224,24 @@ def _chunk_starts(cap: int, chunk: int) -> tuple[int, list[int]]:
     return c, [j * c for j in range(-(-cap // c))]   # ceil: tail is clamped
 
 
-def topk_chunk_counts(block_caps, block_live,
-                      chunk: int = TOPK_CHUNK) -> tuple[int, int]:
-    """``(chunks, skipped)`` of one :func:`packed_topk_chunked` call over
-    blocks of ``block_caps`` columns holding ``block_live`` live ones —
-    host integers only (what a commit already knows), no device read.
-    A chunk is skipped when it starts at or past its block's live
-    count."""
-    total = skipped = 0
+def topk_chunk_counts(block_caps, block_live, chunk: int = TOPK_CHUNK,
+                      *, k: int) -> tuple[int, int, int]:
+    """``(chunks, skipped, grouped)`` of one :func:`packed_topk_chunked`
+    call for the top ``k`` over blocks of ``block_caps`` columns holding
+    ``block_live`` live ones — host integers only (what a commit already
+    knows), no device read. A chunk is skipped when it starts at or past
+    its block's live count; of the others, those wide enough for
+    :func:`topk_grouped` go by group maxima and the rest straight through
+    ``lax.top_k``."""
+    total = skipped = grouped = 0
     for cap, live in zip(block_caps, block_live):
-        starts = _chunk_starts(int(cap), chunk)[1]
+        c, starts = _chunk_starts(int(cap), chunk)
+        dead = sum(off >= int(live) for off in starts)
         total += len(starts)
-        skipped += sum(off >= int(live) for off in starts)
-    return total, skipped
+        skipped += dead
+        if topk_grouped(int(cap), c, min(k, c)):
+            grouped += len(starts) - dead
+    return total, skipped, grouped
 
 
 def _block_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
@@ -152,19 +254,7 @@ def _block_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
     kc = min(k, c)
 
     def scan_chunk(off):
-        # dynamic_slice, NOT a [B, n, c] reshape+transpose: that would
-        # materialize a second copy of the block, which at 1M docs and
-        # wide batches is the difference between fitting HBM and not.
-        # The last chunk's start is clamped to cap - c so every slice is
-        # full-width regardless of cap % c; columns the clamp makes
-        # overlap the previous chunk (idx < off) are masked out so no doc
-        # can win twice in the merge.
-        start = jnp.minimum(off, cap - c)
-        xc = jax.lax.dynamic_slice_in_dim(x, start, c, axis=1)
-        idx = jnp.arange(c, dtype=jnp.int32)[None, :] + start
-        masked = jnp.where((idx >= off) & (idx < live), xc, -jnp.inf)
-        v, i = jax.lax.top_k(masked, kc)
-        return v, i.astype(jnp.int32) + start
+        return _chunk_topk(x, off, live, c=c, k=kc)
 
     def dead_chunk(off):
         # what scan_chunk yields when every column is masked: -inf at
@@ -202,17 +292,22 @@ def packed_topk_chunked(scores, num_docs: jax.Array,
     -inf and a winner's id is ``row0_i + column``, so no ``[B, doc_cap]``
     matrix in document order is ever built. One ``[B, n]`` array with a
     scalar ``num_docs`` is the one-block case. Block-then-column order
-    IS real-row order, ``lax.top_k`` breaks ties toward the lower column
-    and :func:`merge_topk` toward the earlier chunk, so ties resolve to
-    the lower document id whatever the blocking.
+    IS real-row order, a chunk's top-k (:func:`_chunk_topk`) breaks ties
+    toward the lower column and :func:`merge_topk` toward the earlier
+    chunk, so ties resolve to the lower document id whatever the
+    blocking.
 
-    ``lax.top_k`` over a whole [B, doc_cap] row allocates value+index
-    temporaries proportional to its input — at 1M docs and B>=1024 that
-    (with the scores themselves) exceeds HBM. Chunks bound the
-    temporaries at O(B * chunk); per-chunk winners merge exactly (the
-    global top-k is contained in the union of chunk top-ks). A chunk
-    that starts at or past its block's live count is SKIPPED (the padded
-    space is up to 1.5x the live one; :func:`topk_chunk_counts`).
+    A chunk wide enough is read ONCE, by a reduce to its group maxima,
+    and only the ``k`` groups that can hold a winner are ranked
+    (:func:`topk_grouped`); a narrow block goes straight through
+    ``lax.top_k``, whose k-deep selection over every column costs ten
+    times the read. Either way the chunks bound the temporaries at
+    O(B * chunk / 128) — ``lax.top_k`` over a whole [B, doc_cap] row
+    allocates value+index temporaries proportional to its input — and
+    per-chunk winners merge exactly (the global top-k is contained in
+    the union of chunk top-ks). A chunk that starts at or past its
+    block's live count is SKIPPED (the padded space is up to 1.5x the
+    live one; :func:`topk_chunk_counts`).
     """
     with jax.named_scope("topk_chunked"):
         blocks = scores if isinstance(scores, (tuple, list)) else (scores,)

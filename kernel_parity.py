@@ -10,8 +10,11 @@ contracts by what the batch's weights are: FRACTIONAL weights take the
 queries carry; exact in bfloat16) the three bf16 passes. Beside the
 kernel's matrix runs one case of the top-k that reads its blocks
 (``run_topk_case``): the two-stage selection of ``ops/topk.py`` against
-``lax.top_k`` of the masked block, ids as well as values. Three callers
-share ``run_case``:
+``lax.top_k`` of the masked block, ids as well as values; and one of
+the stretched step (``run_stretch_case``): three blocks scored and ranked
+a block at a time and merged, against the one program pair over all
+three, ties on the stretch edges included. Three callers share
+``run_case``:
 
 * ``chip_smoke.py``'s engine stage runs ``CASES`` on the TPU, where the
   kernels are Mosaic programs — the on-chip record;
@@ -34,10 +37,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from tfidf_tpu.ops.ell import (_pallas_eligible, _score_block, bf16_exact,
-                               pallas_interpret, score_block_pallas)
-from tfidf_tpu.ops.scoring import _compile_queries, make_query_batch
-from tfidf_tpu.ops.topk import (TOPK_CHUNK, packed_topk_chunked,
-                                topk_chunk_counts, unpack_topk)
+                               pallas_interpret, plan_stretches,
+                               score_block_pallas, score_ell_batch)
+from tfidf_tpu.ops.scoring import (QueryBatch, _compile_queries,
+                                   make_query_batch)
+from tfidf_tpu.ops.topk import (TOPK_CHUNK, merge_packed,
+                                packed_topk_chunked, topk_chunk_counts,
+                                unpack_topk)
 
 TOP_K = 10
 
@@ -179,6 +185,86 @@ def run_topk_case(rng, *, B, cap, live, k, chunk=TOPK_CHUNK):
             "cell_temp_bytes": int(temp), "ok": ok}
 
 
+# three of msmarco-full's 1M-row blocks of the 33-48-term rung, the last
+# with a dead tail, at the batch the cell is answered at
+STRETCH_CASE = dict(rows_cap=1 << 20, width=48, B=512, n_blocks=3,
+                    last_live=700_481)
+STRETCH_INTERPRET_CASE = dict(rows_cap=512, width=24, B=16, n_blocks=3,
+                              last_live=300)
+TIE_IMPACT = 1000.0     # over any sum of four impacts under 1 times 3
+
+
+def run_stretch_case(rng, *, rows_cap, width, B, n_blocks, last_live,
+                     vocab=500_000):
+    """The stretched step against the whole one: ``n_blocks`` blocks
+    ``[rows_cap, width]`` (the last with ``last_live`` live rows), scored
+    and ranked ONE BLOCK A STRETCH — ``score_ell_batch`` on the block,
+    ``packed_topk_chunked`` with the stretch's base row, ``merge_packed``
+    — and once by the one program pair over every block. The packed
+    answers are bit-equal: values and ids. One term that no other row
+    holds sits, with the same impact, in the last five rows of every
+    block and the first five of all but the first, so query 0's ten
+    winners TIE exactly and
+    straddle the first stretch edge (rows ``rows_cap - 5 ..
+    rows_cap + 4``); queries 1 and 2 hold it beside other terms."""
+    tie_term = vocab - 1
+    lives = [rows_cap] * (n_blocks - 1) + [last_live]
+    imps, terms = [], []
+    q_terms = np.zeros((B, 8), np.int32)
+    q_weights = np.zeros((B, 8), np.float32)
+    for i, live in enumerate(lives):
+        imp, term, _qb = make_case(rng, rows_cap=rows_cap, width=width,
+                                   n_rows=live, B=1, n_terms=4, u_req=256,
+                                   vocab=vocab - width)
+        # a third of the queries draw their terms from this block's rows
+        for b in range(3 + i, B, n_blocks):
+            n = rng.integers(1, 5)
+            q_terms[b, :n] = term[rng.integers(0, live, n),
+                                  rng.integers(0, width, n)]
+            q_weights[b, :n] = rng.integers(1, 4, n)
+        edge = np.r_[0:5 if i else 0, live - 5:live]
+        term[edge, 0] = tie_term
+        imp[edge, 0] = TIE_IMPACT
+        imps.append(jnp.asarray(imp))
+        terms.append(jnp.asarray(term))
+    q_terms[:3, 0], q_weights[:3, 0] = tie_term, 1.0
+    q_terms[1:3, 1:3], q_weights[1:3, 1:3] = q_terms[3:5, :2], 1.0
+    qb = make_query_batch(q_terms, q_weights, min_slots=1024)
+    q = QueryBatch(*(jnp.asarray(a) for a in qb))
+    doc_cap = n_blocks * rows_cap
+    rest = (None, None, None, jnp.zeros(doc_cap, jnp.float32),
+            jnp.zeros(vocab, jnp.float32), q, jnp.float32(doc_cap),
+            jnp.float32(1.0), None)
+    kw = dict(model="bm25", use_pallas=True)
+    lives_d = jnp.asarray(lives, jnp.int32)
+
+    whole = np.asarray(packed_topk_chunked(
+        score_ell_batch(tuple(imps), tuple(terms), lives_d, *rest, **kw),
+        lives_d, k=TOP_K))
+    parts, base = [], 0
+    for i, live in enumerate(lives):
+        one = jnp.asarray([live], jnp.int32)
+        scores = score_ell_batch((imps[i],), (terms[i],), one, *rest, **kw)
+        parts.append(packed_topk_chunked(scores, one, jnp.int32(base),
+                                         k=TOP_K))
+        del scores
+        base += live
+    got = np.asarray(merge_packed(tuple(parts)))
+    vals, ids = unpack_topk(got)
+    first = rows_cap - 5
+    tied = bool((vals[0] == TIE_IMPACT).all()
+                and ids[0].tolist() == list(range(first, first + 10)))
+    equal = bool(np.array_equal(got, whole))
+    stretches = len(plan_stretches([rows_cap] * n_blocks, B,
+                                   4 * B * rows_cap))
+    ok = equal and tied and stretches == n_blocks
+    log(f"[stretch] packed_equal={equal} tie_straddles_edge={tied} "
+        f"stretches={stretches} ok={ok}")
+    return {"name": "stretch", "B": B, "rows_cap": rows_cap,
+            "width": width, "lives": lives, "stretches": stretches,
+            "packed_equal": equal, "tie_straddles_edge": tied, "ok": ok}
+
+
 # the hardware matrix: north-star-like shapes + every eligibility edge
 # (the tier-1 interpret run uses scaled-down shapes of the same edges)
 CASES = [
@@ -254,7 +340,8 @@ def run_matrix(seed: int = 7) -> dict:
     ``KERNEL_PARITY.json`` holds: ``CASES`` where the kernel is a
     Mosaic program, ``INTERPRET_CASES`` where it is interpreted; each
     with fractional weights (``caseN``) and with multiplicities
-    (``caseN-mult``); then the top-k's one case (``topk``)."""
+    (``caseN-mult``); then the top-k's one case (``topk``) and the
+    stretched step's (``stretch``)."""
     rng = np.random.default_rng(seed)
     interpret = pallas_interpret()
     cases = INTERPRET_CASES if interpret else CASES
@@ -263,15 +350,19 @@ def run_matrix(seed: int = 7) -> dict:
                for i, kw in enumerate(cases) for mult in (False, True)]
     topk = run_topk_case(rng, **(TOPK_INTERPRET_CASE if interpret
                                  else TOPK_CASE))
+    stretch = run_stretch_case(rng, **(STRETCH_INTERPRET_CASE if interpret
+                                       else STRETCH_CASE))
     dev = jax.devices()[0]
     return {
         "backend": jax.default_backend(),
         "mosaic_compiled": not interpret,
         "device_kind": dev.device_kind,
         "jax": jax.__version__,
-        "all_ok": all(r["ok"] for r in results) and topk["ok"],
+        "all_ok": all(r["ok"] for r in results) and topk["ok"]
+        and stretch["ok"],
         "cases": results,
         "topk": topk,
+        "stretch": stretch,
     }
 
 

@@ -10,13 +10,16 @@ interpret-mode test and were refused here).
 
 Run as a script (``tests/test_kernel_compile.py`` does, in a subprocess:
 the compile-only client is process-global state); prints one JSON line
-``{"failures": [...], "compiled": N, "cells": M, "mesh_cells": [...]}``.
+``{"failures": [...], "compiled": N, "cells": M, "mesh_cells": [...],
+"cell_digests": {...}, "stretches": [...]}``.
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
+import re
 import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -188,11 +191,29 @@ CELL_STEPS = {
 }
 
 
-def compile_cell_step(dev, blocks, doc_cap: int, B: int) -> None:
-    """The served device step at a cell's real shapes: the scoring
-    program, then the top-k over the blocks it returns. Neither may
-    hold a ``[B, padded rows + 1]`` or ``[B, doc_cap]`` f32 array — the
-    score space exists once, where the kernel wrote it."""
+def program_digest(program) -> str:
+    """A compiled program's HLO text less what moves when a source line
+    does: the tables of files, functions and stack frames, the
+    ``stack_frame_id`` of every instruction, and a Pallas call's
+    ``backend_config`` (the serialized Mosaic module carries its own
+    source locations; the kernel's body is held by the parity tests).
+    What is left is the program: instructions, shapes, layouts, fusion
+    and schedule."""
+    head, _, rest = program.as_text().partition("\n\nFileNames\n")
+    rest = "\n\n".join(
+        part for part in rest.split("\n\n") if not re.match(
+            r"\d+ |FunctionNames|FileLocations|StackFrames", part))
+    text = re.sub(r"stack_frame_id=\d+|backend_config=.*$", "",
+                  head + "\n\n" + rest, flags=re.M)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def compile_step_pair(dev, blocks, doc_cap: int, B: int,
+                      stretched: bool = False):
+    """``(score, topk)``: the two programs of a step over ``blocks``
+    compiled for ``dev`` — the whole block list of a snapshot, or with
+    ``stretched`` one stretch of it (the top-k then takes its base
+    row)."""
     from tfidf_tpu.ops.scoring import QueryBatch
     from tfidf_tpu.ops.topk import packed_topk_chunked
     sh = SingleDeviceSharding(dev)
@@ -211,7 +232,17 @@ def compile_cell_step(dev, blocks, doc_cap: int, B: int) -> None:
         model="bm25", use_pallas=True).compile()
     topk = packed_topk_chunked.lower(
         tuple(s((B, rows), f32) for rows, _ in blocks), live,
-        k=10).compile()
+        *((s((), i32),) if stretched else ()), k=10).compile()
+    return score, topk
+
+
+def compile_cell_step(dev, blocks, doc_cap: int, B: int) -> dict:
+    """The served device step at a cell's real shapes: the scoring
+    program, then the top-k over the blocks it returns. Neither may
+    hold a ``[B, padded rows + 1]`` or ``[B, doc_cap]`` f32 array — the
+    score space exists once, where the kernel wrote it. Returns the two
+    programs' digests (:func:`program_digest`)."""
+    score, topk = compile_step_pair(dev, blocks, doc_cap, B)
     assert score.as_text().count("tpu_custom_call") >= min(len(blocks), 4)
     rows = [r for r, _ in blocks]
     gone = [f"f32[{B},{sum(rows) + 1}]", f"f32[{doc_cap},{B}]"]
@@ -221,6 +252,49 @@ def compile_cell_step(dev, blocks, doc_cap: int, B: int) -> None:
         text = program.as_text()
         for shape in gone:
             assert shape not in text, f"{shape} is back in the step"
+    return {"score": program_digest(score), "topk": program_digest(topk)}
+
+
+# what a v5e chip reports as memory_stats()["bytes_limit"] (my chip run,
+# PR 32), and the stretches a served worker keeps in flight
+# (search_pipeline_depth 2, + 1)
+V5E_BYTES_LIMIT = 16_909_336_064
+IN_FLIGHT = 3
+
+
+def compile_full_stretches(dev, B: int = 512) -> list[dict]:
+    """``msmarco-full``'s step at bucket ``B``: the stretches its
+    eleven blocks (the configuration's ``layout.blocks``) are taken in
+    under the budget a v5e worker derives, and for each DISTINCT stretch
+    shape the two programs compiled, with ``memory_analysis()``: a
+    stretch's outputs and temporaries have to fit the budget the plan
+    was made under."""
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "msmarco-full.json")) as f:
+        layout = json.load(f)["layout"]["blocks"]
+    blocks = list(zip(layout["rows"], layout["widths"]))
+    doc_cap = layout["doc_cap"]
+    index = sum(8 * r * w for r, w in blocks) + 4 * doc_cap + 4 * (1 << 19)
+    budget = ell.stretch_budget(V5E_BYTES_LIMIT, index, IN_FLIGHT)
+    plan = ell.plan_stretches([r for r, _w in blocks], B, budget)
+    out, seen = [], {}
+    for first, stop in plan:
+        shape = tuple(blocks[first:stop])
+        if shape not in seen:
+            score, topk = compile_step_pair(dev, shape, doc_cap, B,
+                                            stretched=True)
+            assert score.as_text().count("tpu_custom_call") \
+                == sum(ell._pallas_eligible(r, B, 1024) for r, _w in shape)
+            ms, mt = score.memory_analysis(), topk.memory_analysis()
+            seen[shape] = {
+                "score_output_bytes": ms.output_size_in_bytes,
+                "score_temp_bytes": ms.temp_size_in_bytes,
+                "topk_temp_bytes": mt.temp_size_in_bytes,
+                "topk_output_bytes": mt.output_size_in_bytes}
+        out.append({"blocks": [first, stop], "B": B, "budget": budget,
+                    "index_bytes": index, **seen[shape]})
+    return out
 
 
 def main() -> int:
@@ -246,16 +320,25 @@ def main() -> int:
         failures.append(f"mesh (4,1) step: {type(e).__name__}: "
                         f"{str(e)[:600]}")
     cells = 0
+    digests: dict[str, dict] = {}
     for name, (blocks, doc_cap, batches) in CELL_STEPS.items():
         for B in batches:
             try:
-                compile_cell_step(topo.devices[0], blocks, doc_cap, B)
+                digests[f"{name}/{B}"] = compile_cell_step(
+                    topo.devices[0], blocks, doc_cap, B)
                 cells += 1
             except Exception as e:
                 failures.append(f"cell {name} B={B}: {type(e).__name__}: "
                                 f"{str(e)[:600]}")
+    stretches: list[dict] = []
+    try:
+        stretches = compile_full_stretches(topo.devices[0])
+    except Exception as e:
+        failures.append(f"cell msmarco-full stretches: "
+                        f"{type(e).__name__}: {str(e)[:600]}")
     print(json.dumps({"failures": failures, "compiled": compiled,
-                      "cells": cells, "mesh_cells": mesh_cells}))
+                      "cells": cells, "mesh_cells": mesh_cells,
+                      "cell_digests": digests, "stretches": stretches}))
     return 1 if failures else 0
 
 

@@ -4,8 +4,10 @@ Runs ``tests/kernel_compile_worker.py`` (compile-only Mosaic through
 libtpu's topology client — no chip, seconds) in a subprocess: batch
 buckets on every step of the tile schedule x widths from the ELL ladder
 incl. 12, the rungs past 256 on every doc tile they take, a mesh-split
-width of 1 and an odd one, plus the (4, 1) ``make_mesh_ell_search`` program and the served
-device step at the benchmark cells' shapes. Interpret-mode
+width of 1 and an odd one, plus the (4, 1) ``make_mesh_ell_search`` program, the served
+device step at the benchmark cells' shapes (held to the programs of the
+commit before the stretched step, by digest) and the stretches of
+``msmarco-full``'s step. Interpret-mode
 parity (``tests/test_kernel_parity.py``) cannot see what this sees: a
 kernel the interpreter runs happily and Mosaic rejects.
 """
@@ -68,3 +70,49 @@ def test_mesh_cell_step_compiles_for_v5e(report, B):
     assert len(mine) == 1, report["mesh_cells"]
     print(f"mesh step memory_analysis, B={B}: {mine[0]}")
     assert mine[0]["temp_bytes"] + mine[0]["argument_bytes"] < 8e9
+
+
+# ``program_digest`` of the two programs of every accepted one-chip
+# cell's step, compiled for the v5e from commit 2278ade (PR 31's, the
+# parent of the PR that brought the stretched step): a corpus that fits
+# the chip as ONE stretch must go on running exactly these. A PR that
+# MEANS to change the score or the top-k program of these cells reads
+# the new digests off ``python tests/kernel_compile_worker.py``
+# (``cell_digests``) and says in PERF.md what moved.
+PARENT_STEP_DIGESTS = {
+    "msmarco2m/128": ("3eb9eb06c0ce11fc", "26f7238d520d063e"),
+    "msmarco2m/256": ("eebf4a4db46e6100", "8e264a85a02781ef"),
+    "msmarco2m/512": ("73d8af08ecdff80e", "7f2c09dd10adad7f"),
+    "wiki1m/512": ("67342a45b6dbfc13", "81be580ebdfbf9d6"),
+    "msmarco-doc/512": ("0f36f4b85d1f8092", "785c0c8e26aaaee3"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_STEP_DIGESTS))
+def test_accepted_cell_step_is_the_unstretched_pair(report, cell):
+    """The score program and the top-k program of an accepted cell's
+    step, compiled for the v5e, are the parent's HLO instruction for
+    instruction, shapes, layouts and schedule included (what differs in
+    the text is where a source line sits: ``program_digest``)."""
+    assert not _failures(report, of_cells=True)
+    got = report["cell_digests"][cell]
+    assert (got["score"], got["topk"]) == PARENT_STEP_DIGESTS[cell]
+
+
+def test_full_collection_stretches_compile_for_v5e(report):
+    """``msmarco-full``'s B=512 step: six stretches over its eleven
+    blocks, each stretch's two programs accepted by the v5e compiler,
+    and what one stretch allocates (the score program's outputs and
+    temporaries, the top-k's) within the budget the plan was made
+    under."""
+    assert not _failures(report, of_cells=True)
+    stretches = report["stretches"]
+    assert [s["blocks"] for s in stretches] == [
+        [0, 2], [2, 3], [3, 5], [5, 6], [6, 7], [7, 11]]
+    held = [s["score_output_bytes"] + s["score_temp_bytes"]
+            + s["topk_temp_bytes"] + s["topk_output_bytes"]
+            for s in stretches]
+    print(f"stretch bytes: {held}, budget {stretches[0]['budget']}")
+    assert max(held) <= stretches[0]["budget"]
+    # the score space of the whole step would be 15.1 GB
+    assert sum(s["score_output_bytes"] for s in stretches) > 15e9

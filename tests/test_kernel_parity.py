@@ -22,8 +22,9 @@ if _root not in sys.path:
     sys.path.insert(0, _root)
 
 from kernel_parity import INTERPRET_CASES as T1_CASES  # noqa: E402
-from kernel_parity import (TOP_K, TOPK_INTERPRET_CASE,  # noqa: E402
-                           make_case, run_case, run_topk_case)
+from kernel_parity import (STRETCH_INTERPRET_CASE, TOP_K,  # noqa: E402
+                           TOPK_INTERPRET_CASE, make_case, run_case,
+                           run_stretch_case, run_topk_case)
 from tfidf_tpu.ops import ell  # noqa: E402
 from tfidf_tpu.ops.ell import (_pallas_eligible, _pl_tiles,  # noqa: E402
                                _score_block, score_block_pallas)
@@ -56,6 +57,17 @@ def test_topk_case_of_the_matrix():
     assert r["ok"], r
     assert (r["chunks"], r["skipped"], r["grouped"]) == (1, 0, 1)
     assert r["cell_temp_bytes"] > 0
+
+
+def test_stretch_case_of_the_matrix():
+    """The stretched step's case ``kernel_parity.py`` runs on the chip,
+    at a CPU's scale: three blocks a block a stretch against the one
+    program pair, packed answers bit-equal, the tied winners across the
+    first stretch edge."""
+    r = run_stretch_case(np.random.default_rng(32),
+                         **STRETCH_INTERPRET_CASE)
+    assert r["ok"], r
+    assert r["stretches"] == 3 and r["lives"] == [512, 512, 300]
 
 
 def test_ingest_rejects_duplicate_or_unsorted_ids():

@@ -13,7 +13,10 @@ change of ``docs`` cannot walk back into the trap unnoticed.
 
 ``msmarco-doc`` (one chip, whole documents) is held the same way at the
 end of the file: its two blocks, at the 384 and the 512 rung of
-``ops/ell.py``'s ladder, as its configuration states them.
+``ops/ell.py``'s ladder, as its configuration states them. And
+``msmarco-full`` (one chip, the passage collection in one index): its
+eleven blocks, the rungs over ``ELL_BLOCK_ROWS_MAX`` rows cut into full blocks
+and a last one, that last one clear of its power of two.
 """
 
 import importlib.util
@@ -26,7 +29,7 @@ import numpy as np
 import pytest
 
 from tfidf_tpu.ops.csr import next_capacity
-from tfidf_tpu.ops.ell import ELL_WIDTH_LADDER
+from tfidf_tpu.ops.ell import ELL_BLOCK_ROWS_MAX, ELL_WIDTH_LADDER
 from tfidf_tpu.parallel.mesh_ell import ELL_WIDTHS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -166,4 +169,41 @@ def test_doc_cell_blocks_are_the_configurations(doc_rungs):
         assert next_capacity(live[w], MIN_ROWS) == rows
         assert live[w] <= rows * (1 - CLEAR), (w, live[w], rows)
     assert next_capacity(sum(live.values()), MIN_ROWS) \
+        == blocks["doc_cap"]
+
+
+# ---- msmarco-full: one chip, rungs of several blocks --------------------
+
+def test_full_collection_blocks_are_the_configurations():
+    """``msmarco-full`` commits the eleven blocks its ``layout.blocks``
+    states (what ``build_ell_from_coo`` cuts: a rung's rows in full
+    blocks of ``ELL_BLOCK_ROWS_MAX`` and a last one of the rest), every
+    last block of a rung 2% clear of its power-of-two capacity, no row
+    past the 64 rung: no residual. One chip packs by the documents'
+    multiset, which a run's seed only reorders (``doc_rungs`` above
+    says so for 64 seeds), so the counts are drawn once."""
+    spec = _spec("msmarco-full")
+    assert spec["docs"] == 6_700_000 and list(spec["reduced"]) == ["docs"]
+    per_doc = np.concatenate(
+        _distinct_terms_by_chunk(data.corpus_args(spec)))
+    ladder = np.asarray(ELL_WIDTH_LADDER)
+    rungs = np.bincount(np.searchsorted(ladder, per_doc),
+                        minlength=len(ladder))
+    widths, rows, live = [], [], []
+    for w, n in sorted(zip(ELL_WIDTH_LADDER, rungs.tolist()),
+                       reverse=True):
+        while n:
+            take = min(n, ELL_BLOCK_ROWS_MAX)
+            widths.append(w)
+            live.append(take)
+            rows.append(next_capacity(take, MIN_ROWS))
+            n -= take
+        if live and widths[-1] == w:
+            assert live[-1] <= rows[-1] * (1 - CLEAR), (w, live[-1])
+    blocks = spec["layout"]["blocks"]
+    assert [blocks["widths"], blocks["rows"], blocks["live"]] \
+        == [widths, rows, live]
+    assert sum(r == ELL_BLOCK_ROWS_MAX for r in rows) == 6 \
+        and len(rows) == 11
+    assert next_capacity(int(per_doc.shape[0]), MIN_ROWS) \
         == blocks["doc_cap"]

@@ -40,6 +40,7 @@ import time
 import numpy as np
 
 from tfidf_tpu.engine.engine import Engine
+from tfidf_tpu.ops import ell
 from tfidf_tpu.utils import storage
 from tfidf_tpu.utils.config import Config
 from tfidf_tpu.utils.faults import fault_point
@@ -55,10 +56,12 @@ FORMAT_VERSION = 1
 def _score_signature(engine: Engine) -> list:
     """Everything the precomputed snapshot arrays depend on: restoring
     them under a different scoring config would silently serve wrong
-    scores, so load falls back to a full commit on any mismatch."""
+    scores, so load falls back to a full commit on any mismatch. The
+    ELL blocks' row ceiling belongs to it: blocks cut under another one
+    are not those a step's stretches are planned over."""
     c = engine.config
     return [engine.model.kind, c.bm25_k1, c.bm25_b, c.lucene_parity,
-            c.scoring_layout, c.ell_width_cap]
+            c.scoring_layout, c.ell_width_cap, ell.ELL_BLOCK_ROWS_MAX]
 
 
 def save_checkpoint(engine: Engine, directory: str) -> None:

@@ -15,8 +15,11 @@ terms. Unknown query terms are dropped (they can match nothing).
 from __future__ import annotations
 
 import threading
+from collections import deque
 from typing import NamedTuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from tfidf_tpu.engine.index import ShardIndex, Snapshot
@@ -29,12 +32,13 @@ from tfidf_tpu.ops.blockmax import query_upper_bounds, skip_mask
 from tfidf_tpu.ops.csr import next_capacity
 from tfidf_tpu.ops.ell import (_pallas_eligible, ell_scores_to_real,
                                kernel_contract_chunks, kernel_uniq_lanes,
-                               score_ell_batch, score_segments_batch)
+                               plan_stretches, score_ell_batch,
+                               score_segments_batch, stretch_budget)
 from tfidf_tpu.ops.scoring import (QueryBatch, make_query_batch,
                                    score_coo_batch)
-from tfidf_tpu.ops.topk import (fetch_packed, full_ranking, packed_topk,
-                                packed_topk_chunked, topk_chunk_counts,
-                                unpack_topk)
+from tfidf_tpu.ops.topk import (fetch_packed, full_ranking, merge_packed,
+                                packed_topk, packed_topk_chunked,
+                                topk_chunk_counts, unpack_topk)
 from tfidf_tpu.utils.metrics import global_metrics
 from tfidf_tpu.utils.tracing import trace_phase
 
@@ -42,6 +46,24 @@ from tfidf_tpu.utils.tracing import trace_phase
 class SearchHit(NamedTuple):
     name: str
     score: float
+
+
+class Stretch(NamedTuple):
+    """A run of whole ELL blocks one score program and one top-k program
+    take together (``ops.ell.plan_stretches``)."""
+    first: int             # blocks [first, stop) of the snapshot
+    stop: int
+    live: jax.Array        # i32 [stop - first] their live rows (device)
+    live_host: tuple       # the same, host integers
+    base: jax.Array | None  # i32 real row of its first column; None: 0
+    nbytes: int            # of its [B, rows] f32 scores
+
+
+def device_bytes_limit(array) -> int | None:
+    """``bytes_limit`` of the device that holds ``array``, or None where
+    the backend keeps no such count (the CPU)."""
+    stats = next(iter(array.devices())).memory_stats()
+    return stats.get("bytes_limit") if stats else None
 
 
 # guards lazy per-searcher PipelineExecutor construction (the mixin has
@@ -252,6 +274,12 @@ class Searcher(QueryVectorizerMixin):
         self.pipeline_depth = max(1, pipeline_depth)
         # "auto" | "executor" | "inline" — see _use_executor
         self.pipeline_mode = pipeline_mode
+        # the packed top-k of the stretches whose scores may still be
+        # allocated, oldest first (see _hold_back), and the stretch
+        # plans of the current snapshot by batch bucket
+        self._in_flight: deque = deque()
+        self._flight_lock = threading.Lock()
+        self._plans: dict[tuple[int, int], list[Stretch]] = {}
 
     def _batch_cap(self, n: int) -> int:
         return min(self.query_batch, next_capacity(max(n, 1), 1))
@@ -350,11 +378,13 @@ class Searcher(QueryVectorizerMixin):
                 for imp in snap.ell_impacts]
 
     def _score_chunk(self, snap: Snapshot, queries: list[str]):
-        """``(blocks, live, live_host)``: the chunk's scores as the
-        tuple of ``[B, cap_i]`` blocks ``packed_topk_chunked`` takes (the
-        ELL layout's own; one block for the others), the blocks' live
-        column counts on the device, and the same counts as the host
-        integers the commit had."""
+        """``(blocks, live, live_host)``: the chunk's WHOLE score space
+        as the tuple of ``[B, cap_i]`` blocks ``packed_topk_chunked``
+        takes (the ELL layout's own; one block for the others), the
+        blocks' live column counts on the device, and the same counts as
+        the host integers the commit had. The serving path of the ELL
+        layout takes that space a stretch at a time instead
+        (:meth:`_dispatch_ell`)."""
         cap = self._batch_cap(len(queries))
         with trace_phase("vectorize"):
             qb, _widest = self._vectorize(queries, cap)
@@ -375,23 +405,102 @@ class Searcher(QueryVectorizerMixin):
                 # the whole padded space is live: pads score 0
                 return (scores,), snap.num_docs, (scores.shape[1],)
             if snap.is_ell:
-                # gather fast path: impacts precomputed at commit;
-                # big blocks ride the fused compare/MXU Pallas kernel;
-                # what spilled past the widest block, the scatter path
-                global_metrics.inc("residual_entries_scored", snap.res_nnz)
-                blocks = score_ell_batch(
-                    snap.ell_impacts, snap.ell_terms, snap.ell_live,
-                    snap.res_tf, snap.res_term, snap.res_doc,
-                    snap.doc_len, snap.df, qb,
-                    snap.n_docs, snap.avgdl, snap.doc_norms,
-                    use_pallas=self.use_pallas,
-                    **self.model.score_kwargs())
-                return blocks, snap.ell_live, snap.ell_live_host
+                whole, = self._stretches(snap, cap, None)
+                return (self._score_ell(snap, qb, whole), whole.live,
+                        whole.live_host)
             scores = score_coo_batch(
                 snap.tf, snap.term, snap.doc, snap.doc_len, snap.df,
                 qb, snap.n_docs, snap.avgdl, snap.doc_norms,
                 **self.model.score_kwargs())
             return (scores,), snap.num_docs, (snap.num_names,)
+
+    def _score_ell(self, snap: Snapshot, qb, st: Stretch) -> tuple:
+        """Enqueue the score program over one stretch of the ELL
+        blocks: their ``[B, cap_i]`` scores. Gather fast path: impacts
+        precomputed at commit; big blocks ride the fused compare/MXU
+        Pallas kernel; what spilled past the widest block, the scatter
+        path — into block 0, so with the stretch that holds it."""
+        res = (None, None, None)
+        if st.first == 0:
+            res = (snap.res_tf, snap.res_term, snap.res_doc)
+            global_metrics.inc("residual_entries_scored", snap.res_nnz)
+        return score_ell_batch(
+            snap.ell_impacts[st.first:st.stop],
+            snap.ell_terms[st.first:st.stop], st.live, *res,
+            snap.doc_len, snap.df, qb,
+            snap.n_docs, snap.avgdl, snap.doc_norms,
+            use_pallas=self.use_pallas,
+            **self.model.score_kwargs())
+
+    def _stretches(self, snap: Snapshot, cap: int,
+                   budget: int | None) -> list[Stretch]:
+        """The snapshot's ELL blocks as the stretches a chunk of bucket
+        ``cap`` takes them in, each within ``budget`` bytes of scores
+        (``ops.ell.plan_stretches``). One stretch over every block
+        passes the snapshot's own live counts and no base: the program
+        pair of a corpus that fits is the unstretched one."""
+        rows = [imp.shape[0] for imp in snap.ell_impacts]
+        live = snap.ell_live_host
+        out = []
+        for first, stop in plan_stretches(rows, cap, budget):
+            whole = stop - first == len(rows)
+            out.append(Stretch(
+                first, stop,
+                snap.ell_live if whole
+                else jnp.asarray(live[first:stop], jnp.int32),
+                live[first:stop],
+                None if whole else jnp.int32(sum(live[:first])),
+                4 * cap * sum(rows[first:stop])))
+        return out
+
+    def _stretch_plan(self, snap: Snapshot, cap: int) -> list[Stretch]:
+        """:meth:`_stretches` under the budget this process observes —
+        what its device holds, less the committed index, shared by the
+        stretches kept in flight (``ops.ell.stretch_budget``) — once a
+        snapshot and bucket."""
+        key = (snap.version, cap)
+        plan = self._plans.get(key)
+        if plan is None:
+            budget = stretch_budget(
+                device_bytes_limit(snap.ell_impacts[0]),
+                snap.size_bytes(), self.pipeline_depth + 1)
+            plan = self._stretches(snap, cap, budget)
+            # (whole-dict swap: concurrent inline callers read it)
+            self._plans = {k: v for k, v in self._plans.items()
+                           if k[0] == snap.version} | {key: plan}
+        return plan
+
+    def _hold_back(self) -> None:
+        """Before a stretch's score program is enqueued: wait out the
+        oldest stretch in flight once ``pipeline_depth + 1`` of them
+        are. PjRt allocates a program's outputs when it is ENQUEUED and
+        frees them once their reader has run, so a host running ahead
+        of the device would allocate every stretch's scores at once;
+        held back, at most ``pipeline_depth + 1`` stretches' are — what
+        ``stretch_budget`` divided the device by. A chunk of one
+        stretch never waits here: the pipeline keeps no more chunks
+        than that unfetched. (Inline callers on several threads can
+        overshoot by one each; there the backend is the CPU.)"""
+        with self._flight_lock:
+            full = len(self._in_flight) > self.pipeline_depth
+            oldest = self._in_flight.popleft() if full else None
+        if oldest is not None:
+            with trace_phase("stretch_wait"):
+                jax.block_until_ready(oldest)
+
+    def _rank(self, blocks, live, live_host, base, kk: int):
+        """Enqueue the top-k over a stretch's score blocks (of every
+        layout: a non-ELL chunk is one block); the packed ``[B, 2kk]``
+        winners, still on the device."""
+        # the top-k's chunks over this padded score space, those of
+        # them wholly in dead tails, which it skips, and those it ranks
+        # by group maxima
+        chunks, skipped, grouped = topk_chunk_counts(
+            [blk.shape[1] for blk in blocks], live_host, k=kk)
+        global_metrics.inc("topk_chunks", chunks)
+        global_metrics.inc("topk_chunks_skipped", skipped)
+        global_metrics.inc("topk_chunks_grouped", grouped)
+        return packed_topk_chunked(blocks, live, base, k=kk)
 
     # oracle switch: True forces tiered snapshots through the untiered
     # scoring path (every segment faulted + scored) — the parity
@@ -403,21 +512,56 @@ class Searcher(QueryVectorizerMixin):
         """Launch one chunk's device work; returns (packed, kk) with the
         packed top-k still ON DEVICE (not fetched)."""
         self._count_chunk(len(queries), self._batch_cap(len(queries)))
-        if isinstance(snap, SegmentedSnapshot) and snap.tier is not None \
-                and not self.tier_bypass:
-            return self._dispatch_tiered(snap, queries, k)
+        kk = min(k, snap.num_names)
+        if isinstance(snap, SegmentedSnapshot):
+            if snap.tier is not None and not self.tier_bypass:
+                return self._dispatch_tiered(snap, queries, k)
+        elif snap.is_ell:
+            return self._dispatch_ell(snap, queries, kk), kk
         blocks, live, live_host = self._score_chunk(snap, queries)
         with trace_phase("topk"):
-            kk = min(k, snap.num_names)
-            # the top-k's chunks over this dispatch's padded score space,
-            # those of them wholly in dead tails, which it skips, and
-            # those it ranks by group maxima
-            chunks, skipped, grouped = topk_chunk_counts(
-                [blk.shape[1] for blk in blocks], live_host, k=kk)
-            global_metrics.inc("topk_chunks", chunks)
-            global_metrics.inc("topk_chunks_skipped", skipped)
-            global_metrics.inc("topk_chunks_grouped", grouped)
-            return packed_topk_chunked(blocks, live, k=kk), kk
+            return self._rank(blocks, live, live_host, None, kk), kk
+
+    def _dispatch_ell(self, snap: Snapshot, queries: list[str], kk: int):
+        """One chunk over the ELL blocks, a STRETCH at a time: score
+        program on the stretch, top-k program on its ``[B, cap_i]``
+        outputs with the stretch's base row, the scores dropped (their
+        last reference goes with this frame's ``blocks``), and the
+        per-stretch ``[B, kk]`` winners merged — exact, and a tie goes
+        to the earlier stretch, so still to the lower document id. The
+        live score space is a stretch's times those in flight, whatever
+        the corpus holds. ONE ``score`` and one ``topk`` span a chunk,
+        as every per-batch device metric divides by them: ``score``
+        holds every stretch but the last one's top-k (and the waits of
+        :meth:`_hold_back`), ``topk`` that and the merge — for a
+        corpus of one stretch, exactly the two enqueues."""
+        cap = self._batch_cap(len(queries))
+        with trace_phase("vectorize"):
+            qb, _widest = self._vectorize(queries, cap)
+        if self.use_pallas:
+            self._count_kernel_uniq(qb)
+        plan = self._stretch_plan(snap, cap)
+        global_metrics.inc("score_stretches", len(plan))
+        # the most score bytes this chunk can hold allocated at once
+        global_metrics.inc("score_space_bytes", sum(sorted(
+            st.nbytes for st in plan)[-(self.pipeline_depth + 1):]))
+        done = []
+
+        def rank(st: Stretch, blocks):
+            packed = self._rank(blocks, st.live, st.live_host, st.base, kk)
+            with self._flight_lock:
+                self._in_flight.append(packed)
+            return packed
+
+        with trace_phase("score"):
+            for st in plan[:-1]:
+                self._hold_back()
+                done.append(rank(st, self._score_ell(snap, qb, st)))
+            self._hold_back()
+            blocks = self._score_ell(snap, qb, plan[-1])
+        with trace_phase("topk"):
+            packed = rank(plan[-1], blocks)
+            return merge_packed((*done, packed)) if done else packed
 
     def _dispatch_tiered(self, snap: SegmentedSnapshot,
                          queries: list[str], k: int):

@@ -27,7 +27,11 @@ scored by the chunked scatter path, and the partial score tensors add.
 
 Row counts are power-of-two bucketed and widths come from the fixed
 ladder, so the set of block shapes — and therefore XLA executables — is
-reused as the shard grows.
+reused as the shard grows. A block holds at most ``ELL_BLOCK_ROWS_MAX``
+rows: a rung with more documents yields several blocks, so that a step
+can score and rank the row axis in STRETCHES of whole blocks
+(:func:`plan_stretches`) and its live score space is bounded by the
+chip, not by the corpus.
 
 Padding is inert: pad entries have impact 0 (tf=0); pad rows are all-pad.
 Replaces the posting-list traversal inside Lucene's ``searcher.search``
@@ -83,6 +87,12 @@ class EllShard:
 # VMEM at a doc tile of 128 for every batch bucket (``_pl_tiles``).
 ELL_WIDTH_LADDER = (8, 12, 16, 24, 32, 48, 64, 96, 128, 192, 256, 384, 512,
                     768, 1024, 1536, 2048, 3072, 4096)
+# Ceiling of a block's row capacity (a power of two). One ``[B, rows]``
+# f32 score block of a 512-query batch is 2 GB at this size; without a
+# ceiling a rung of 4.6M passages was ONE block of 8,388,608 rows, 17 GB
+# of scores and past int32 elements. A million rows leave every block of
+# a corpus to ~2M passages or ~1M documents as it was.
+ELL_BLOCK_ROWS_MAX = 1 << 20
 
 
 def build_ell_from_coo(coo: CooShard,
@@ -90,16 +100,24 @@ def build_ell_from_coo(coo: CooShard,
                        width_cap: int | None = None,
                        min_width: int = 8,
                        min_rows: int = 256,
-                       min_res_cap: int = 1 << 10) -> EllShard:
+                       min_res_cap: int = 1 << 10,
+                       max_rows: int | None = None) -> EllShard:
     """Vectorized COO → blocked ELL + residual (host side, commit time).
 
     Requires the COO invariants from ``ShardIndex.to_coo``: entries grouped
     by doc in increasing row order, rows sorted by distinct-term count
     descending, padding pointing at ``doc_cap - 1`` with tf=0.
     ``width_cap`` None: no ceiling under the ladder's top rung.
+    A rung of more than ``max_rows`` rows (a power of two; None:
+    ``ELL_BLOCK_ROWS_MAX``) yields full blocks of ``max_rows`` and a
+    last one of the rest, in row order: a real row is still the running
+    sum of the live counts before its block plus its column.
     """
     if width_cap is None:
         width_cap = ELL_WIDTH_LADDER[-1]
+    if max_rows is None:
+        max_rows = ELL_BLOCK_ROWS_MAX
+    assert max_rows >= min_rows and max_rows & (max_rows - 1) == 0, max_rows
     nnz, n_live = coo.nnz, coo.num_docs
     doc_ids = coo.doc[:nnz]
     bounds = np.searchsorted(doc_ids, np.arange(n_live + 1))
@@ -129,14 +147,19 @@ def build_ell_from_coo(coo: CooShard,
     row0 = 0
     while row0 < n_live:
         w = int(widths[row0])
-        hi = int(np.searchsorted(-widths, -w, side="right"))
+        hi = min(int(np.searchsorted(-widths, -w, side="right")),
+                 row0 + max_rows)
         n_rows = hi - row0
         rows_cap = next_capacity(n_rows, min_rows)
         tf = np.zeros((rows_cap, w), np.float32)
         term = np.zeros((rows_cap, w), np.int32)
-        sel = (doc_ids >= row0) & (doc_ids < hi) & (pos < w)
-        tf[doc_ids[sel] - row0, pos[sel]] = coo.tf[:nnz][sel]
-        term[doc_ids[sel] - row0, pos[sel]] = coo.term[:nnz][sel]
+        # the block's rows are one run of the COO (entries lie in row
+        # order), so only that run is read, not the whole shard a block
+        run = slice(int(bounds[row0]), int(bounds[hi]))
+        sel = pos[run] < w
+        at = (doc_ids[run][sel] - row0, pos[run][sel])
+        tf[at] = coo.tf[run][sel]
+        term[at] = coo.term[run][sel]
         blocks.append(EllBlock(tf=tf, term=term, row0=row0,
                                n_rows=n_rows, width=w))
         row0 = hi
@@ -501,8 +524,9 @@ def ell_layout_gauges(shapes, live, res_doc: np.ndarray) -> dict[str, int]:
     blocks' ``(rows_cap, width)``, ``live`` their live rows, ``res_doc``
     the document row of every live entry of the COO residual, in its
     non-decreasing order.
-    ``ell_entries_padded`` is what the blocks hold, live or pad (sum of
-    rows_cap x width); ``ell_entries_live_tiles`` what a kernel call
+    ``ell_rows_padded`` is the row axis of a step's score space (sum of
+    rows_cap); ``ell_entries_padded`` what the blocks hold, live or pad
+    (sum of rows_cap x width); ``ell_entries_live_tiles`` what a kernel call
     streams of it: the doc tiles that hold a live row (the rest are
     skipped), at the doc tile of a batch of up to 512 queries. Host
     arithmetic on the commit's own counts."""
@@ -512,11 +536,53 @@ def ell_layout_gauges(shapes, live, res_doc: np.ndarray) -> dict[str, int]:
         streamed += min(rows_cap, -(-int(n_rows) // td) * td) * width
     return {"ell_blocks": len(shapes),
             "ell_width_max": max((w for _r, w in shapes), default=0),
+            "ell_rows_padded": sum(r for r, _w in shapes),
             "ell_entries_padded": sum(r * w for r, w in shapes),
             "ell_entries_live_tiles": streamed,
             "ell_residual_nnz": int(res_doc.shape[0]),
             "ell_residual_docs":
                 int(np.count_nonzero(np.diff(res_doc))) + min(len(res_doc), 1)}
+
+
+def plan_stretches(block_rows, B: int,
+                   budget_bytes: int | None) -> list[tuple[int, int]]:
+    """The blocks of a step as STRETCHES ``(first, past-the-last)``:
+    each the longest run of whole blocks, in order, whose ``[B, rows]``
+    f32 scores fit ``budget_bytes`` (a block over the budget alone is a
+    stretch of its own; None: no bound, one stretch). A step scores and
+    ranks one stretch, drops its scores and goes on to the next, so its
+    live score space is a stretch's, whatever the corpus holds. Host
+    arithmetic on static shapes: the same blocks, batch bucket and
+    budget give the same stretches, and so the same compiled programs."""
+    out, first, held = [], 0, 0
+    for i, rows in enumerate(block_rows):
+        need = 4 * B * int(rows)
+        if budget_bytes is not None and i > first \
+                and held + need > budget_bytes:
+            out.append((first, i))
+            first, held = i, 0
+        held += need
+    return out + [(first, len(block_rows))]
+
+
+STRETCH_RESERVE = 8     # 1/8 of the device is left out of the budget
+
+
+def stretch_budget(bytes_limit: int | None, index_bytes: int,
+                   in_flight: int) -> int | None:
+    """Bytes ONE stretch's scores may take: what the device holds
+    (``memory_stats()["bytes_limit"]``; None where the backend does not
+    say: no bound) less the committed index and an eighth for what
+    this count leaves out (the programs' code and temporaries, query
+    arrays, the allocator's fragmentation: a stretch's blocks are
+    buffers of up to 2 GB each), shared by the ``in_flight`` stretches
+    the dispatch lets be allocated at once. On a v5e (16,909,336,064
+    bytes) that leaves 2M passages' whole 4.57 GB score space ONE
+    stretch beside their 0.7 GB index, by 2.7%."""
+    if bytes_limit is None:
+        return None
+    free = bytes_limit - bytes_limit // STRETCH_RESERVE - index_bytes
+    return max(free, 0) // max(in_flight, 1)
 
 
 def kernel_contract_chunks(n_uniq: int,

@@ -281,6 +281,7 @@ def _block_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk"))
 def packed_topk_chunked(scores, num_docs: jax.Array,
+                        base: jax.Array | None = None,
                         *, k: int, chunk: int = TOPK_CHUNK) -> jax.Array:
     """:func:`packed_topk` over score BLOCKS, read where the scorer wrote
     them, the doc axis scanned in chunks.
@@ -291,7 +292,11 @@ def packed_topk_chunked(scores, num_docs: jax.Array,
     counts) in its first ``live_i`` columns. Its dead tail is masked to
     -inf and a winner's id is ``row0_i + column``, so no ``[B, doc_cap]``
     matrix in document order is ever built. One ``[B, n]`` array with a
-    scalar ``num_docs`` is the one-block case. Block-then-column order
+    scalar ``num_docs`` is the one-block case. ``base`` (i32 scalar,
+    traced; None: 0, and no parameter of the program) is the real row of
+    the first block's first column where ``scores`` is a STRETCH of a
+    longer block list: the live rows of the blocks before it
+    (:func:`merge_packed` joins the stretches). Block-then-column order
     IS real-row order, a chunk's top-k (:func:`_chunk_topk`) breaks ties
     toward the lower column and :func:`merge_topk` toward the earlier
     chunk, so ties resolve to the lower document id whatever the
@@ -313,6 +318,8 @@ def packed_topk_chunked(scores, num_docs: jax.Array,
         blocks = scores if isinstance(scores, (tuple, list)) else (scores,)
         lives = jnp.reshape(num_docs, (-1,)).astype(jnp.int32)
         row0s = jnp.cumsum(lives) - lives
+        if base is not None:
+            row0s = row0s + base
         vals, ids = [], []
         for i, x in enumerate(blocks):
             v, local = _block_topk(x, lives[i], k=k, chunk=chunk)
@@ -322,6 +329,19 @@ def packed_topk_chunked(scores, num_docs: jax.Array,
         if vals.shape[0] > 1:       # [n_chunks, B, k], real-row order
             return pack_topk(*merge_topk(vals, ids))
         return pack_topk(vals[0], ids[0])
+
+
+@jax.jit
+def merge_packed(parts) -> jax.Array:
+    """The packed top-k ``[B, 2k]`` of a step from those of its
+    stretches (a tuple, in row order: each a
+    :func:`packed_topk_chunked` with its ``base``). Exact as
+    :func:`merge_topk` is, and a tie goes to the earlier stretch, whose
+    documents are the lower ones."""
+    stacked = jnp.stack(parts)                       # [n, B, 2k]
+    k = stacked.shape[-1] // 2
+    vals = jax.lax.bitcast_convert_type(stacked[..., :k], jnp.float32)
+    return pack_topk(*merge_topk(vals, stacked[..., k:]))
 
 
 def full_ranking(scores: jax.Array, num_docs: int) -> tuple[jax.Array, jax.Array]:

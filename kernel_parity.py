@@ -2,9 +2,9 @@
 
 Asserts that ``score_block_pallas`` matches the XLA reduce-fusion path
 bit-closely across the eligibility envelope — block shapes, batch
-widths, u_cap sizes, dead-row/dead-uniq tile skipping, odd widths (the
-pair fold's lone last row) — and that the top-10 ranking is stable
-against the XLA path. Every case runs twice, because the kernel
+widths, u_cap sizes, dead-row/dead-uniq tile skipping, widths with and
+without a static tail past the 8-row loop — and that the top-10 ranking
+is stable against the XLA path. Every case runs twice, because the kernel
 contracts by what the batch's weights are: FRACTIONAL weights take the
 ``Precision.HIGHEST`` dot, term MULTIPLICITIES (what the engine's
 queries carry; exact in bfloat16) the three bf16 passes. Beside the
@@ -13,8 +13,15 @@ kernel's matrix runs one case of the top-k that reads its blocks
 ``lax.top_k`` of the masked block, ids as well as values; and one of
 the stretched step (``run_stretch_case``): three blocks scored and ranked
 a block at a time and merged, against the one program pair over all
-three, ties on the stretch edges included. Three callers share
-``run_case``:
+three, ties on the stretch edges included; and the matrix's last kernel
+case is the TRAP the A-build's order is held by (``run_trap_case``: a
+live term 0 before trailing ``term 0`` pads, bit-equal to the oracle).
+``python kernel_parity.py --against <checkout>`` also runs every kernel
+case on the kernel of ANOTHER checkout's ``tfidf_tpu/ops/ell.py`` (a
+parent commit unpacked under a directory ``.gitignore`` lists) and
+reports whether the two outputs are BIT-EQUAL: what a PR that changes
+the kernel's body and not its arithmetic has to show on the chip. Three
+callers share ``run_case``:
 
 * ``chip_smoke.py``'s engine stage runs ``CASES`` on the TPU, where the
   kernels are Mosaic programs — the on-chip record;
@@ -28,6 +35,7 @@ three, ties on the stretch edges included. Three callers share
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 import sys
@@ -56,7 +64,7 @@ def make_case(rng, *, rows_cap, width, n_rows, B, n_terms, u_req,
               vocab=500_000, ragged=False, multiplicity=False):
     """Random ELL block + query batch. Term ids are DISTINCT within
     each row (the layout contract every ELL builder guarantees and the
-    kernel's pair fold relies on: stride-offset construction — position
+    kernel's select chain relies on: stride-offset construction — position
     w draws from the congruence class w mod width). Pad rows
     (>= n_rows) are zeroed like the real build; ``ragged`` additionally
     zeroes a random per-row tail (within-row trailing pads, the shape
@@ -92,36 +100,60 @@ def make_case(rng, *, rows_cap, width, n_rows, B, n_terms, u_req,
     return imp, term, qb
 
 
-def run_case(name, rng, **kw):
-    """One case: the kernel against the XLA oracle on the same inputs,
-    scores within 1e-4 and the top-10 ranking identical. ``bf16x3``
-    reports which contraction the batch's weights select."""
-    imp, term, qb = make_case(rng, **kw)
-    bf16x3 = bool(bf16_exact(qb.weights))
-    assert bf16x3 == kw.get("multiplicity", False), (name, bf16x3)
-    vocab = kw.get("vocab", 500_000)
-    rows_cap, B = kw["rows_cap"], kw["B"]
-    u_cap = qb.uniq.shape[0]
-    assert _pallas_eligible(rows_cap, B, u_cap), (name, rows_cap, B, u_cap)
-    imp_d = jnp.asarray(imp)
-    term_d = jnp.asarray(term)
-    n_rows = jnp.int32(kw["n_rows"])
+def kernel_of(tree: str):
+    """``score_block_pallas`` of the checkout at ``tree`` (a parent
+    commit unpacked beside this one), its ``ops/ell.py`` loaded under a
+    module name of its own: everything it imports is this tree's."""
+    spec = importlib.util.spec_from_file_location(
+        "tfidf_tpu.ops._ell_against",
+        os.path.join(tree, "tfidf_tpu", "ops", "ell.py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod.score_block_pallas
+
+
+def _kernel_and_oracle(imp, term, qb, vocab, n_rows, against=None):
+    """``(kernel, XLA oracle, same)``: the ``[B, rows_cap]`` numpy
+    scores of one block under one batch, from ONE jitted program, and
+    whether ``against``, another checkout's ``score_block_pallas``
+    (:func:`kernel_of`), gave the kernel's bits (None without one)."""
+    imp_d, term_d = jnp.asarray(imp), jnp.asarray(term)
+    n_rows = jnp.int32(n_rows)
 
     @jax.jit
     def run(uniq, n_uniq, slots, weights):
-        from tfidf_tpu.ops.scoring import QueryBatch
         q = QueryBatch(uniq, n_uniq, slots, weights)
         slot_of, qc_ext = _compile_queries(q, vocab)
-        out = score_block_pallas(imp_d, term_d, q.uniq, q.n_uniq, qc_ext,
-                                 n_rows)
-        ref = _score_block(imp_d, term_d, slot_of, qc_ext.T, 2048)
-        return out, ref
+        args = (imp_d, term_d, q.uniq, q.n_uniq, qc_ext, n_rows)
+        return (score_block_pallas(*args),
+                _score_block(imp_d, term_d, slot_of, qc_ext.T, 2048),
+                None if against is None else against(*args))
 
-    out, ref = run(jnp.asarray(qb.uniq), jnp.asarray(qb.n_uniq),
-                   jnp.asarray(qb.slots), jnp.asarray(qb.weights))
+    out, ref, other = run(*(jnp.asarray(a) for a in qb))
+    out = np.asarray(out)
+    return out, np.asarray(ref), (
+        None if other is None else bool(np.array_equal(out, other)))
+
+
+def run_case(name, rng, against=None, **kw):
+    """One case: the kernel against the XLA oracle on the same inputs,
+    scores within 1e-4 and the top-10 ranking identical. ``bf16x3``
+    reports which contraction the batch's weights select;
+    ``bit_equal_against`` whether ``against`` (another checkout's
+    kernel) gave the same bits, which the case then also needs (None
+    without one)."""
+    imp, term, qb = make_case(rng, **kw)
+    bf16x3 = bool(bf16_exact(qb.weights))
+    assert bf16x3 == kw.get("multiplicity", False), (name, bf16x3)
+    rows_cap, B = kw["rows_cap"], kw["B"]
+    u_cap = qb.uniq.shape[0]
+    assert _pallas_eligible(rows_cap, B, u_cap), (name, rows_cap, B, u_cap)
+    out, ref, same = _kernel_and_oracle(
+        imp, term, qb, kw.get("vocab", 500_000), kw["n_rows"], against)
     live = slice(None), slice(None, kw["n_rows"])  # dead rows: both 0
-    a = np.asarray(out)[live]
-    b = np.asarray(ref)[live]
+    a = out[live]
+    b = ref[live]
     k = min(TOP_K, kw["n_rows"])
     max_abs = float(np.max(np.abs(a - b))) if a.size else 0.0
     denom = np.maximum(np.abs(b), 1e-6)
@@ -129,12 +161,67 @@ def run_case(name, rng, **kw):
     topk_equal = bool(
         (np.argsort(-a, axis=1, kind="stable")[:, :k]
          == np.argsort(-b, axis=1, kind="stable")[:, :k]).all())
-    ok = max_abs < 1e-4 and topk_equal
+    ok = max_abs < 1e-4 and topk_equal and same is not False
     log(f"[{name}] bf16x3={bf16x3} max|d|={max_abs:.2e} "
-        f"topk={topk_equal} ok={ok}")
+        f"topk={topk_equal} bit_equal_against={same} ok={ok}")
     return {"name": name, "bf16x3": bf16x3, "max_abs_delta": max_abs,
             "max_rel_delta": max_rel, "topk_identical": topk_equal,
-            "ok": ok, **kw}
+            "bit_equal_against": same, "ok": ok, **kw}
+
+
+# the trap the A-build's ORDER is held by (PR 33), at the loop-plus-even-
+# tail width: on the chip a block of eight doc tiles, interpreted one
+TRAP_CASE = dict(rows_cap=4096, width=38, B=256, u_req=512)
+TRAP_INTERPRET_CASE = dict(rows_cap=256, width=38, B=16, u_req=256)
+
+
+def make_trap_case(rng, *, rows_cap, width, B, u_req, vocab=500_000):
+    """A block whose rows are SHORTER than its width: row ``r`` holds
+    ``1 + r % width`` live entries (every length from 1 to the width)
+    over trailing pads ``term 0, impact 0``, as every builder lays them
+    out, and every other row's first entry is a LIVE posting of term 0.
+    Query 0 is term 0 alone, every other query ONE term of the block,
+    weight 1 or 2: a score is one exact product whatever the order of
+    the sum, so kernel and oracle must agree BIT for bit. Returns
+    ``(imp, term, qb, rows)``, ``rows`` those that hold the live term
+    0: their pads match its lane too, and a chain that applied them
+    after the live entry would score 0 there."""
+    imp, term, _qb = make_case(rng, rows_cap=rows_cap, width=width,
+                               n_rows=rows_cap, B=1, n_terms=1,
+                               u_req=u_req, vocab=vocab)
+    imp += np.float32(0.5)                  # no live impact is 0
+    term[::2, 0] = 0
+    fill = 1 + np.arange(rows_cap) % width
+    dead = np.arange(width)[None, :] >= fill[:, None]
+    term[dead] = 0
+    imp[dead] = 0.0
+    q_terms = np.zeros((B, 8), np.int32)
+    q_weights = np.zeros((B, 8), np.float32)
+    q_weights[0, 0] = 1.0                   # query 0: term 0 alone
+    r = rng.integers(0, rows_cap, B - 1)
+    q_terms[1:, 0] = term[r, rng.integers(0, fill[r])]
+    q_weights[1:, 0] = rng.integers(1, 3, B - 1)
+    return (imp, term, make_query_batch(q_terms, q_weights, min_slots=u_req),
+            np.flatnonzero(term[:, 0] == 0))
+
+
+def run_trap_case(rng, against=None, *, vocab=500_000, **kw):
+    """:func:`make_trap_case` through the kernel and the XLA oracle:
+    BIT-EQUAL, and query 0's score of every row with a live term 0 its
+    impact, not the 0.0 of the pads behind it."""
+    imp, term, qb, rows = make_trap_case(rng, vocab=vocab, **kw)
+    assert qb.uniq[0] == 0 and rows.size
+    out, ref, same = _kernel_and_oracle(imp, term, qb, vocab,
+                                        kw["rows_cap"], against)
+    equal = bool(np.array_equal(out, ref))
+    term0_live = bool(np.array_equal(out[0, rows], imp[rows, 0])
+                      and out[0, rows].all())
+    ok = equal and term0_live and same is not False
+    log(f"[trap] width={kw['width']} oracle_bit_equal={equal} "
+        f"term0_live={term0_live} bit_equal_against={same} ok={ok}")
+    return {"name": "trap", "oracle_bit_equal": equal,
+            "term0_live": term0_live, "term0_rows": int(rows.size),
+            "bit_equal_against": same, "ok": ok, **kw}
 
 
 # the top-k beside the kernel: one block as wide as the cells' widest,
@@ -289,8 +376,9 @@ CASES = [
     # heavy dead-tile skipping: few live rows / few live uniq
     dict(rows_cap=65536, width=64, n_rows=700, B=256, n_terms=4,
          u_req=4096),
-    # pair-fold edges: ODD width (lone last row), within-row ragged
-    # pads, a small vocabulary (dense term-id collisions between rows)
+    # select-chain edges: ODD width (a static tail of one row), within-
+    # row ragged pads, a small vocabulary (dense term-id collisions
+    # between rows, and term 0 among them)
     dict(rows_cap=4096, width=33, n_rows=4000, B=256, n_terms=4,
          u_req=512),
     dict(rows_cap=4096, width=48, n_rows=4000, B=256, n_terms=4,
@@ -335,25 +423,30 @@ INTERPRET_CASES = [
 ]
 
 
-def run_matrix(seed: int = 7) -> dict:
+def run_matrix(seed: int = 7, against=None) -> dict:
     """Every case of the matrix on the attached backend, as the record
     ``KERNEL_PARITY.json`` holds: ``CASES`` where the kernel is a
     Mosaic program, ``INTERPRET_CASES`` where it is interpreted; each
     with fractional weights (``caseN``) and with multiplicities
-    (``caseN-mult``); then the top-k's one case (``topk``) and the
-    stretched step's (``stretch``)."""
+    (``caseN-mult``), then the pad trap (``trap``), the last of
+    ``cases``; then the top-k's one case (``topk``) and the stretched
+    step's (``stretch``). ``against``: another checkout's kernel
+    (:func:`kernel_of`), which every kernel case must then equal bit
+    for bit."""
     rng = np.random.default_rng(seed)
     interpret = pallas_interpret()
     cases = INTERPRET_CASES if interpret else CASES
-    results = [run_case(f"case{i}{'-mult' if mult else ''}", rng,
+    results = [run_case(f"case{i}{'-mult' if mult else ''}", rng, against,
                         multiplicity=mult, **kw)
                for i, kw in enumerate(cases) for mult in (False, True)]
+    results.append(run_trap_case(rng, against, **(
+        TRAP_INTERPRET_CASE if interpret else TRAP_CASE)))
     topk = run_topk_case(rng, **(TOPK_INTERPRET_CASE if interpret
                                  else TOPK_CASE))
     stretch = run_stretch_case(rng, **(STRETCH_INTERPRET_CASE if interpret
                                        else STRETCH_CASE))
     dev = jax.devices()[0]
-    return {
+    out = {
         "backend": jax.default_backend(),
         "mosaic_compiled": not interpret,
         "device_kind": dev.device_kind,
@@ -364,9 +457,13 @@ def run_matrix(seed: int = 7) -> dict:
         "topk": topk,
         "stretch": stretch,
     }
+    if against is not None:
+        out["bit_equal_against"] = sum(r["bit_equal_against"]
+                                       for r in results)
+    return out
 
 
-def main() -> int:
+def main(argv) -> int:
     from tfidf_tpu.utils.compile_cache import configure_compile_cache
     configure_compile_cache()
     if pallas_interpret():
@@ -374,13 +471,20 @@ def main() -> int:
             "would run in the Pallas interpreter, which is not a "
             "parity record — tests/test_kernel_parity.py covers that")
         return 1
-    out = run_matrix()
+    if argv and (len(argv) != 2 or argv[0] != "--against"):
+        log("usage: kernel_parity.py [--against <checkout>]")
+        return 2
+    out = run_matrix(against=kernel_of(argv[1]) if argv else None)
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "KERNEL_PARITY.json"), "w") as f:
         json.dump(out, f, indent=1)
-    log(f"[done] all_ok={out['all_ok']}")
+    n = len(out["cases"])
+    log(f"[done] all_ok={out['all_ok']}: "
+        f"{sum(r['ok'] for r in out['cases'])} of {n} kernel cases ok"
+        + (f", {out['bit_equal_against']} of {n} BIT-EQUAL to "
+           f"{argv[1]}'s kernel" if argv else ""))
     return 0 if out["all_ok"] else 1
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(sys.argv[1:]))

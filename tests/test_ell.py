@@ -278,7 +278,7 @@ class TestPallasKernel:
         imp = rng.random((rows_cap, width), dtype=np.float32)
         # distinct term ids within each row — the layout contract every
         # ELL builder guarantees (one posting per distinct term) and
-        # the kernel's pair fold relies on: position w draws from the
+        # the kernel's select chain relies on: position w draws from the
         # congruence class w mod width
         base = rng.integers(0, max(vocab // width, 1),
                             size=(rows_cap, width))
@@ -311,8 +311,8 @@ class TestPallasKernel:
 
     @pytest.mark.parametrize("width", [7, 16, 33])
     def test_matches_xla_at_width(self, rng, width):
-        """The pair fold at widths with and without the static tail and
-        its lone last row (7, 33), against the XLA oracle."""
+        """The select chain at widths with and without the static tail
+        (7, 33: all of it, one row of it), against the XLA oracle."""
         from tfidf_tpu.ops.ell import _score_block, score_block_pallas
         from tfidf_tpu.ops.scoring import (_compile_queries,
                                            make_query_batch)

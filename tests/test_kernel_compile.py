@@ -78,7 +78,11 @@ def test_mesh_cell_step_compiles_for_v5e(report, B):
 # the chip as ONE stretch must go on running exactly these. A PR that
 # MEANS to change the score or the top-k program of these cells reads
 # the new digests off ``python tests/kernel_compile_worker.py``
-# (``cell_digests``) and says in PERF.md what moved.
+# (``cell_digests``) and says in PERF.md what moved. PR 33 (5ef81a6's
+# child) rewrote the kernel's BODY, the A-build's select chain: that is
+# the Pallas call's ``backend_config``, which ``program_digest`` leaves
+# out, so all ten stood: the XLA programs around the kernel are still
+# 2278ade's, and the body is ``tests/test_kernel_parity.py``'s to hold.
 PARENT_STEP_DIGESTS = {
     "msmarco2m/128": ("3eb9eb06c0ce11fc", "26f7238d520d063e"),
     "msmarco2m/256": ("eebf4a4db46e6100", "8e264a85a02781ef"),

@@ -2,9 +2,9 @@
 
 Drives the SAME ``kernel_parity.py`` case machinery the hardware
 harness uses, on CPU-scaled shapes in Pallas interpret mode — so every
-tier-1 run holds the kernel (paired rows incl. the lone last row of an
-odd width) to the XLA reduce-fusion oracle, and a kernel regression
-fails CI on a CPU box. Whether Mosaic ACCEPTS the kernel is
+tier-1 run holds the kernel (the select chain at widths with and
+without a static tail, the pad trap its order is held by) to the XLA
+reduce-fusion oracle, and a kernel regression fails CI on a CPU box. Whether Mosaic ACCEPTS the kernel is
 ``tests/test_kernel_compile.py``; its results on a chip are
 ``chip_smoke.py``'s engine stage.
 """
@@ -24,10 +24,12 @@ if _root not in sys.path:
 from kernel_parity import INTERPRET_CASES as T1_CASES  # noqa: E402
 from kernel_parity import (STRETCH_INTERPRET_CASE, TOP_K,  # noqa: E402
                            TOPK_INTERPRET_CASE, make_case, run_case,
-                           run_stretch_case, run_topk_case)
+                           run_stretch_case, run_topk_case, run_trap_case)
 from tfidf_tpu.ops import ell  # noqa: E402
+from tfidf_tpu.ops.csr import build_coo  # noqa: E402
 from tfidf_tpu.ops.ell import (_pallas_eligible, _pl_tiles,  # noqa: E402
-                               _score_block, score_block_pallas)
+                               _score_block, build_ell_from_coo,
+                               score_block_pallas)
 from tfidf_tpu.ops.scoring import (QueryBatch,  # noqa: E402
                                    _compile_queries)
 
@@ -70,8 +72,131 @@ def test_stretch_case_of_the_matrix():
     assert r["stretches"] == 3 and r["lives"] == [512, 512, 300]
 
 
+# one sub-tile's build at each of its shapes: no ``_PL_ROWS``-row loop
+# (7), one trip and a tail (12), the loop alone (32), the loop and a
+# tail of one row (33), the loop and an even tail (38)
+TRAP_WIDTHS = (7, 12, 32, 33, 38)
+
+
+@pytest.mark.parametrize("width", TRAP_WIDTHS)
+def test_pad_trap(width):
+    """The A-build's ORDER. A pad is ``term 0, impact 0`` and term 0 is
+    a real term: in rows of every length from 1 to the width whose
+    first entry is a live posting of term 0, the trailing pads match
+    the lane of term 0 too, and a chain that walked the width upwards
+    would leave their 0.0 there. The kernel is bit-equal to the XLA
+    oracle and scores those rows by the live impact."""
+    r = run_trap_case(np.random.default_rng(330 + width), rows_cap=256,
+                      width=width, B=16, u_req=256)
+    assert r["ok"], r
+    assert r["oracle_bit_equal"] and r["term0_live"]
+    assert r["term0_rows"] >= 128
+
+
+def _all_eqns(jaxpr, out=None):
+    """Every equation under ``jaxpr``: the Pallas kernel's body, its
+    loops and branches included."""
+    out = [] if out is None else out
+    for e in jaxpr.eqns:
+        out.append(e)
+        for v in e.params.values():
+            for x in v if isinstance(v, (list, tuple)) else [v]:
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    _all_eqns(x, out)
+    return out
+
+
+def test_a_build_is_one_select_chain():
+    """What the kernel's body holds, read off its jaxpr at a width with
+    the loop and a tail (38 = 4 x 8 + 6): for each of the 8 + 6 traced
+    width rows ONE compare and ONE select of a ``[_PL_SU, td]``
+    sub-tile (a shape only the A-build has) and no add of it, and ONE
+    load of each posting array a ``rows()`` call: two calls, four
+    loads (a ref access is ~2.3 ms of Mosaic lowering a block a
+    bucket: PERF.md §6, PR 27)."""
+    width, rows_cap, td = 38, 512, 512
+    f32, i32 = jnp.float32, jnp.int32
+    jaxpr = jax.make_jaxpr(score_block_pallas)(
+        jnp.zeros((rows_cap, width), f32), jnp.zeros((rows_cap, width), i32),
+        jnp.zeros((_U_CAP,), i32), jnp.int32(5),
+        jnp.zeros((_B, _U_CAP + 1), f32), jnp.int32(rows_cap))
+    eqns = _all_eqns(jaxpr.jaxpr)
+
+    def count(name, shape):
+        return sum(e.primitive.name == name
+                   and e.outvars[0].aval.shape == shape for e in eqns)
+
+    traced = ell._PL_ROWS + width % ell._PL_ROWS
+    assert count("eq", (_SU, td)) == traced
+    assert count("select_n", (_SU, td)) == traced
+    assert count("add", (_SU, td)) == 0
+    posting_loads = [e.outvars[0].aval.shape for e in eqns
+                     if e.primitive.name == "get"
+                     and e.invars[0].aval.shape == (width, td)]
+    assert sorted(posting_loads) == sorted(
+        2 * [(ell._PL_ROWS, td), (width % ell._PL_ROWS, td)])
+
+
+def _mesh_shards(docs):
+    """``build_mesh_ell`` over a (2, 2) mesh of the CPU's devices, as
+    each device holds it: the ``tf`` of every bucket's every shard, the
+    terms axis's contiguous width slice included."""
+    from tfidf_tpu.engine.index import DocEntry
+    from tfidf_tpu.parallel.mesh import make_mesh
+    from tfidf_tpu.parallel.mesh_ell import build_mesh_ell, place_mesh_ell
+
+    mesh = make_mesh((2, 2), devices=jax.devices()[:4])
+    entries = [DocEntry(f"d{i}", np.asarray(sorted(d), np.int32),
+                        np.asarray([d[t] for t in sorted(d)], np.float32),
+                        float(sum(d.values())))
+               for i, d in enumerate(docs)]
+    host, _perm = build_mesh_ell([entries[0::2], entries[1::2]], mesh,
+                                 lambda x: x, min_rows=8)
+    arrays = place_mesh_ell(host, mesh)
+    assert any(sh.data.shape[-1] < a.shape[-1]
+               for a in arrays.tf for sh in a.addressable_shards)
+    return [np.asarray(sh.data) for a in arrays.tf
+            for sh in a.addressable_shards]
+
+
+def _coo_blocks(docs):
+    """``build_ell_from_coo``'s blocks over the same documents, longest
+    first as ``ShardIndex.to_coo`` hands them over."""
+    docs = sorted(docs, key=len, reverse=True)
+    coo = build_coo(docs, vocab_cap=512, min_nnz_cap=1 << 10,
+                    min_doc_cap=64)
+    return [b.tf for b in build_ell_from_coo(coo, width_cap=64,
+                                             min_rows=8).blocks]
+
+
+@pytest.mark.parametrize("blocks_of", [_coo_blocks, _mesh_shards],
+                         ids=["build_ell_from_coo", "build_mesh_ell"])
+def test_builders_trail_their_pads(blocks_of):
+    """The other half of the select chain's contract, at the two
+    builders that feed the kernel: in every row of every block (on the
+    mesh: of every device's slice of the width) the non-zero entries
+    all precede the first pad, so the chain, walking the width from its
+    last row down, applies a live entry after every pad of its row."""
+    rng = np.random.default_rng(33)
+    docs = []
+    for _ in range(120):
+        ids = rng.choice(400, size=rng.integers(1, 60), replace=False)
+        docs.append({int(t): float(rng.integers(1, 5)) for t in ids})
+    docs[3][0] = 2.0            # term 0 itself is live in some rows
+    docs[40][0] = 1.0
+    blocks = blocks_of(docs)
+    assert len(blocks) > 1
+    live = 0
+    for tf in blocks:
+        filled = tf != 0
+        assert (filled[..., 1:] <= filled[..., :-1]).all()
+        live += int(filled.sum())
+    assert live == sum(len(d) for d in docs)
+
+
 def test_ingest_rejects_duplicate_or_unsorted_ids():
-    """The layout contract the kernel's pair fold relies on (distinct term
+    """The layout contract the kernel's select chain relies on (distinct term
     ids per row) is enforced at the ingest seam: a raw-array caller
     passing duplicate or unsorted ids must fail loudly there, not
     score differently on the kernel vs the XLA path."""
@@ -124,7 +249,7 @@ def test_tile_schedule_divides_capacities():
 # 128-row contraction chunk, the 512-lane uniq tile and the whole
 # capacity. Its WIDTH takes the four shapes one sub-tile's build can
 # have: no ``_PL_ROWS``-row loop at all (7), the loop alone (32), the
-# loop plus a lone last row (33), the loop plus a tail of pairs (38).
+# loop plus a tail of one row (33), the loop plus a tail of six (38).
 
 _SU = ell._PL_SU
 N_UNIQS = (1, 7, 8, 9, _SU - 1, _SU, _SU + 1, 127, 128, 129, 511, 512,

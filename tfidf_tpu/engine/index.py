@@ -56,7 +56,7 @@ def check_sorted_unique_ids(name: str, ids: np.ndarray) -> None:
     ascending (sorted AND distinct) — at the ingest seam, where it is
     one vectorized diff per document. Everything downstream assumes it:
     the ELL layouts store one posting per distinct term, and the v4
-    A-build's pair fold selects AT MOST ONE match per pair, so a
+    A-build's select chain keeps AT MOST ONE match per row, so a
     duplicated id that slipped in here would score differently on the
     kernel vs the XLA path (silently, per block). The analyzer, native
     tokenizer, and dict ingest all produce conforming arrays; this

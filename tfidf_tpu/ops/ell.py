@@ -262,18 +262,28 @@ ell_impacts = jax.jit(ell_impacts, static_argnames=("model", "k1", "b"))
 # batch only starts (PR 27; before it whole [TU, TD] tiles were built,
 # their accumulator of 256 vregs read and written every width step).
 #
-# The A-build takes TWO width rows a step. CONTRACT: within one document
-# row the live term ids are DISTINCT (the ELL layout stores one posting
-# per distinct term; ingest rejects duplicate or unsorted ids) and pads
-# carry impact 0. So at most one compare of a (w, w+1) pair can select
-# a non-zero impact: the pair folds into ONE nested select chain and
-# ONE accumulate add, and because +0.0 is exact in f32 that is
-# bit-identical to adding the rows one by one. Cost per 2 entries:
-# 2 cmp + 2 sel + 1 add = 2.5 vreg-ops an entry. Term ids stay i32 even
-# where the vocabulary fits 15 bits: Mosaic for v5e refuses a dynamic
-# sublane load from an i16 tile ("cannot statically prove that index in
-# dimension 0 is a multiple of 8") and, with the rows unrolled, an i16
-# compare mask feeding an f32 select ("Invalid relayout").
+# The A-build is ONE select chain down the width: a sub-tile's
+# accumulator takes ``a = select(uniq == term_w, imp_w, a)`` an entry,
+# 1 cmp + 1 sel = 2 vreg-ops an entry and no add; the rows' sublane
+# broadcasts (8 ``vperm.slane`` a width row for the sub-tile's 16
+# vregs, in the same four vector slots a bundle) make it 2.5 slot-ops.
+# CONTRACT: within one document row the live term ids are
+# DISTINCT (the ELL layout stores one posting per distinct term; ingest
+# rejects duplicate or unsorted ids), pads carry impact 0 and TRAIL the
+# live entries. So for one (uniq lane, document) at most one live entry
+# matches, and selecting its impact is what adding it to 0.0 was, bit
+# for bit. THE ORDER IS PART OF IT: a pad is ``term 0, impact 0``
+# (``np.zeros`` in every builder) and term 0 is a real term, the most
+# frequent of a Zipf vocabulary, so a pad MATCHES the lane of term 0;
+# walked from the first row up, a trailing pad would overwrite a live
+# term-0 impact with its 0.0. The chain therefore walks the width from
+# its LAST row to its first, and a live entry is applied after every
+# pad of its row (``tests/test_kernel_parity.py test_pad_trap`` and
+# ``test_builders_trail_their_pads`` hold both halves). Term ids stay
+# i32 even where the vocabulary fits 15 bits: Mosaic for v5e refuses a
+# dynamic sublane load from an i16 tile ("cannot statically prove that
+# index in dimension 0 is a multiple of 8") and, with the rows unrolled,
+# an i16 compare mask feeding an f32 select ("Invalid relayout").
 #
 # The XLA reduce-fusion path (``_score_block``) stays untouched as the
 # oracle. ``tests/test_kernel_compile.py`` compiles every shape class
@@ -307,17 +317,22 @@ def _pallas_kernel(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
     """One (doc tile, uniq tile) grid step. Uniq SUB-TILES outer
     (``_PL_SU`` rows, trip count from ``n_uniq``), width inner: a
     sub-tile's accumulator ``[_PL_SU, td]`` stays in vector registers
-    across the whole width loop and is written ONCE to the VMEM scratch
-    ``a_ref [tu, td]``; then the MXU contracts the 128-row chunks of
-    ``a_ref`` that hold a live term. Sub-tiles and chunks past the live unique terms are never
-    built, compared or contracted.
+    across the whole width loop — ONE select chain from the last width
+    row to the first, a compare and a select an entry — and is written
+    ONCE to the VMEM scratch ``a_ref [tu, td]``; then the MXU contracts
+    the 128-row chunks of ``a_ref`` that hold a live term. Sub-tiles
+    and chunks past the live unique terms are never built, compared or
+    contracted.
 
-    CONTRACT (the pair fold's, see the notes above): within a document
-    row the live term ids are distinct and pads carry impact 0 — every
-    ELL builder in this tree lays out one entry per distinct term
+    CONTRACT (the select chain's, see the notes above): within a
+    document row the live term ids are distinct, pads carry impact 0
+    and TRAIL the live entries — every ELL builder in this tree lays
+    out one entry per distinct term from column 0 up over zeros
     (``build_ell_from_coo``; ``build_mesh_ell`` fills ``e.term_ids``,
-    and the terms-axis width shard is a contiguous column slice). A row
-    violating it would select once where the XLA path adds twice."""
+    and the terms-axis width shard is a contiguous column slice, whose
+    pads trail too). A row with a duplicate id would select once where
+    the XLA path adds twice; a row with a ``term 0`` pad BEFORE a live
+    term-0 entry would lose that entry."""
     d = pl.program_id(0)
     u = pl.program_id(1)
     su = _PL_SU
@@ -348,14 +363,13 @@ def _pallas_kernel(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
                                         (su, td), (0, 1))
 
             def rows(w0, n, a):
-                """Width rows ``w0 .. w0 + n`` (n static) onto ``a``, in
-                order: ONE load of the n rows of each array (a ref
-                access is the dearest thing here to trace and lower:
-                ~2 ms of a worker's warm-up each), every row then
-                spread over the sub-tile's sublanes. A pair of rows
-                folds into one select chain and one add (at most one
-                branch selects non-zero; a pad match selects its 0.0
-                impact), a last odd row goes alone."""
+                """Width rows ``w0 .. w0 + n`` (n static) onto ``a``, the
+                LAST row first: ONE load of the n rows of each array (a
+                ref access is the dearest thing here to trace and
+                lower: ~2 ms of a worker's warm-up each), every row
+                then spread over the sub-tile's sublanes and selected
+                into ``a`` where its term is the lane's: a compare and
+                a select an entry, no add."""
                 terms = term_ref[pl.ds(w0, n), :]    # [n, Td] i32
                 imps = imp_ref[pl.ds(w0, n), :]      # [n, Td] f32
 
@@ -363,27 +377,28 @@ def _pallas_kernel(lims_ref, uniq_ref, qc_ref, term_ref, imp_ref,
                     return lax.broadcast_in_dim(
                         lax.slice_in_dim(x, j, j + 1), (su, td), (0, 1))
 
-                for j in range(0, n, 2):
-                    x = zeros
-                    for i in reversed(range(j, min(j + 2, n))):
-                        x = lax.select(
-                            lax.eq(uniq, over_sublanes(terms, i)),
-                            over_sublanes(imps, i), x)
-                    a = a + x
+                for j in reversed(range(n)):
+                    a = lax.select(lax.eq(uniq, over_sublanes(terms, j)),
+                                   over_sublanes(imps, j), a)
                 return a
 
-            # _PL_ROWS width rows a loop iteration (Mosaic unrolls a
-            # loop wholly or not at all; wholly would grow with the
-            # width), the rest of the width as a static tail
+            # ONE select chain down the width, from its last row to its
+            # first (a pad must never be applied after a live entry of
+            # its row: the notes above): the static tail of the width
+            # first, then _PL_ROWS width rows a loop iteration from the
+            # top group down (Mosaic unrolls a loop wholly or not at
+            # all; wholly would grow with the width)
             a = zeros
-            if width >= _PL_ROWS:    # (a zero-trip loop is still traced)
-                a = lax.fori_loop(
-                    0, width // _PL_ROWS,
-                    lambda g, a: rows(
-                        pl.multiple_of(g * _PL_ROWS, _PL_ROWS), _PL_ROWS, a),
-                    a)
+            n_groups = width // _PL_ROWS
             if width % _PL_ROWS:
-                a = rows(width - width % _PL_ROWS, width % _PL_ROWS, a)
+                a = rows(n_groups * _PL_ROWS, width % _PL_ROWS, a)
+            if n_groups:             # (a zero-trip loop is still traced)
+                a = lax.fori_loop(
+                    0, n_groups,
+                    lambda g, a: rows(
+                        pl.multiple_of((n_groups - 1 - g) * _PL_ROWS,
+                                       _PL_ROWS), _PL_ROWS, a),
+                    a)
             a_ref[pl.ds(r0, su), :] = a
             return carry
 

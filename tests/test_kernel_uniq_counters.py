@@ -1,9 +1,10 @@
-"""``kernel_uniq_live`` / ``_built`` / ``_tiled``: per chunk dispatched
-to the fused kernel, the batch's distinct terms, the uniq lanes of A
-the kernel builds for them (sub-tiles of ``_PL_SU``) and what whole
-uniq tiles would hold — host arithmetic (``ops.ell.kernel_uniq_lanes``)
-on both searchers. ``live / built`` is what the benchmark's
-``uniq_fill.*`` read."""
+"""``kernel_uniq_live`` / ``_built``: per chunk dispatched to the fused
+kernel, the batch's distinct terms and the uniq lanes of A the kernel
+builds for them (sub-tiles of ``_PL_SU``) — host arithmetic
+(``ops.ell.kernel_uniq_lanes``) on both searchers. ``live / built`` is
+what the benchmark's ``uniq_fill.*`` read. (``_tiled``, what the whole
+uniq tiles of the kernel before PR 27 would have held, went in PR 35
+with its last reader.)"""
 
 import jax
 import pytest
@@ -14,8 +15,7 @@ from tfidf_tpu.parallel.mesh import make_mesh
 from tfidf_tpu.utils.config import Config
 from tfidf_tpu.utils.metrics import global_metrics
 
-KEYS = ("dispatch_chunks", "kernel_uniq_live", "kernel_uniq_built",
-        "kernel_uniq_tiled")
+KEYS = ("dispatch_chunks", "kernel_uniq_live", "kernel_uniq_built")
 
 
 def _counted(fn) -> list:
@@ -25,20 +25,19 @@ def _counted(fn) -> list:
     return [after.get(key, 0) - before.get(key, 0) for key in KEYS]
 
 
-# the benchmark cells' batches (PERF.md §4): bucket, distinct terms ->
-# lanes built, lanes the whole tiles hold
-@pytest.mark.parametrize("n_uniq, B, u_cap, built, tiled", [
-    (114, 64, 1024, 128, 512),      # msmarco2m.served-steady
-    (352, 256, 1024, 352, 512),     # the served-sat cells
-    (555, 512, 1024, 576, 1024),    # wiki1m.batch: a second tile begun
-    (512, 512, 1024, 512, 512),     # the control: nothing to save
-    (1024, 512, 1024, 1024, 1024),
-    (1, 32, 256, _PL_SU, 256),
-    (300, 2048, 1024, 320, 384),    # B > 1024: 128-lane uniq tiles
+# the benchmark cells' batches (PERF.md §4): distinct terms -> lanes built
+@pytest.mark.parametrize("n_uniq, built", [
+    (114, 128),      # msmarco2m.served-steady
+    (352, 352),      # the served-sat cells
+    (555, 576),      # wiki1m.batch
+    (512, 512),      # the control: nothing to save
+    (1024, 1024),
+    (1, _PL_SU),
+    (300, 320),
 ])
-def test_kernel_uniq_lanes(n_uniq, B, u_cap, built, tiled):
-    assert kernel_uniq_lanes(n_uniq, B, u_cap) == (built, tiled)
-    assert n_uniq <= built <= tiled
+def test_kernel_uniq_lanes(n_uniq, built):
+    assert kernel_uniq_lanes(n_uniq) == built
+    assert n_uniq <= built < n_uniq + _PL_SU
 
 
 def _ingest(e: Engine) -> None:
@@ -64,12 +63,12 @@ def test_kernel_uniq_counters_follow_the_batch(tmp_path, mode):
     _ingest(e)
     u_cap = e.searcher._u_floor
     # one dispatch of 3 queries in a bucket of 4: 6 distinct terms
-    want = (6,) + kernel_uniq_lanes(6, 4, u_cap)
-    assert want[1] == _PL_SU and want[2] == u_cap == 256
+    want = (6, kernel_uniq_lanes(6))
+    assert want[1] == _PL_SU and u_cap == 256
     assert _counted(lambda: e.search_batch(QUERIES)) == [1, *want]
     # three dispatches (4 + 4 + 1 queries): 6, 6 and 3 distinct terms
     nine = (QUERIES + QUERIES[:1]) * 2 + QUERIES[:1]
     assert [len(nine), nine[-1]] == [9, "w1 w2 w3"]
     got = _counted(lambda: e.search_batch(nine))
-    assert got == [3, 6 + 6 + 3, 3 * want[1], 3 * want[2]]
-    assert got[1] <= got[2] <= got[3]
+    assert got == [3, 6 + 6 + 3, 3 * want[1]]
+    assert got[1] <= got[2]

@@ -31,8 +31,8 @@ log = get_logger("cluster.batcher")
 
 
 class _Waiter:
-    __slots__ = ("query", "event", "result", "error", "t0", "t_set",
-                 "key", "lane", "span")
+    __slots__ = ("query", "event", "result", "error", "t0", "t_batch",
+                 "t_set", "key", "lane", "span")
 
     def __init__(self, query, lane: int = 0) -> None:
         self.query = query   # the submitted item (any shape)
@@ -40,6 +40,7 @@ class _Waiter:
         self.result = None
         self.error: BaseException | None = None
         self.t0 = 0.0   # submit time (queue-wait accounting)
+        self.t_batch = 0.0  # the dispatcher's stamp where the wait ended
         self.t_set = 0.0  # the dispatcher's stamp just before event.set()
         self.key = None  # group key, stamped at SUBMIT time
         self.lane = lane  # 0 = interactive, 1 = bulk (weighted dequeue)
@@ -57,6 +58,9 @@ class Coalescer:
     the dispatcher's ``event.set()`` to the submitter running again as
     ``{name}_wake``, so the serving-path breakdown can attribute
     queueing and wake-up delay separately from RPC time.
+    :meth:`pop_stamps` hands the item's four stamps (queued, batch
+    begun, set, running again) to a caller that times what lies before,
+    between and after them on the same clock.
 
     ``pipeline`` dispatcher threads let one batch's RPC round trip
     overlap the next batch's formation.
@@ -101,6 +105,7 @@ class Coalescer:
         self.group_key = group_key
         self.bulk_share = min(max(bulk_share, 0.0), 1.0)
         self._lock = threading.Lock()
+        self._tls = threading.local()   # pop_stamps()
         self._items: deque[_Waiter] = deque()   # lane 0: interactive
         self._bulk: deque[_Waiter] = deque()    # lane 1: bulk/batch
         self._wake = threading.Event()
@@ -158,10 +163,24 @@ class Coalescer:
                            else "dispatchers died"))
                 break
         if w.t_set:   # unset where stop() or a dying dispatcher woke us
-            trace_wait(f"{self.name}_wake", w.t_set)
+            self._tls.stamps = (w.t0, w.t_batch, w.t_set,
+                                trace_wait(f"{self.name}_wake", w.t_set))
         if w.error is not None:
             raise w.error
         return w.result
+
+    def pop_stamps(self) -> tuple[float, float, float, float] | None:
+        """The four stamps of the LAST ``submit()`` on THIS thread that
+        a dispatcher answered: the ``wait_stamp()`` it was queued at
+        (where ``{name}_queue_wait`` starts), the dispatcher's where
+        that wait ended and its batch began, the dispatcher's just
+        before this item's ``event.set()`` (where ``{name}_wake``
+        starts) and the one it ran again at (where it ends).
+        Thread-local and popped, so a caller that reached no coalescer
+        (a cache hit) reads None."""
+        stamps = getattr(self._tls, "stamps", None)
+        self._tls.stamps = None
+        return stamps
 
     def linger_bounds(self) -> tuple[float, float]:
         """Current adaptive-linger bounds ``(lo_s, hi_s)``."""
@@ -323,7 +342,7 @@ class Coalescer:
                         waited: float) -> None:
         t0 = time.perf_counter()
         for w in batch:   # queueing delay, attributed separately
-            trace_wait(f"{self.name}_queue_wait", w.t0, w.span)
+            w.t_batch = trace_wait(f"{self.name}_queue_wait", w.t0, w.span)
         # gauge the wait that actually happened: at saturation the
         # sleep is skipped, and reporting the computed linger there
         # would misattribute latency exactly where none was added

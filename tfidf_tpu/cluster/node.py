@@ -83,7 +83,9 @@ from tfidf_tpu.utils.config import Config
 from tfidf_tpu.utils.faults import global_injector
 from tfidf_tpu.utils.logging import get_logger
 from tfidf_tpu.utils.metrics import global_metrics
-from tfidf_tpu.utils.tracing import (global_tracer, propagation_headers,
+from tfidf_tpu.utils.tracing import (SERVER_TIMING_HEADER, epoch_now,
+                                     global_tracer, process_watch,
+                                     propagation_headers, server_timing,
                                      span_event, trace_phase)
 
 log = get_logger("cluster.node")
@@ -146,6 +148,15 @@ class _ScatterClient:
         # this node's endpoint identity for the nemesis shim (stamped
         # by SearchNode.start once the server port is known)
         self.origin = ""
+
+    def pop_server_timing(self) -> str | None:
+        """The ``Server-Timing`` header of the LAST 2xx reply on THIS
+        thread (None: the worker sent none), popped like
+        :meth:`pop_degraded`: ``tracing.trace_rpc_legs`` cuts the round
+        trip at the worker's two stamps it carries."""
+        v = getattr(self._tls, "server_timing", None)
+        self._tls.server_timing = None
+        return v
 
     def pop_degraded(self) -> bool:
         """Did the LAST 2xx reply on THIS thread carry
@@ -238,6 +249,7 @@ class _ScatterClient:
                 # degraded — the gatherer pops this per-thread flag
                 self._tls.degraded = (
                     r.getheader("X-Compute-Degraded") == "1")
+                self._tls.server_timing = r.getheader(SERVER_TIMING_HEADER)
                 return body
             except RuntimeError:
                 raise
@@ -329,6 +341,7 @@ class SearchNode(ScatterReadPlane):
         self.coord = coord
         self._coord_factory = coord_factory
         self._stopping = False
+        self._watching = False   # holds a share of process_watch
         self.engine = engine or Engine(self.config)
         self.registry = ServiceRegistry(
             coord, on_change=self._on_membership_change)
@@ -600,6 +613,10 @@ class SearchNode(ScatterReadPlane):
 
     def start(self, rebuild: bool = True,
               rebuild_newer_than: float | None = None) -> "SearchNode":
+        # what this process costs itself (CPU share, GIL wait, collector
+        # pauses) rides /api/metrics for as long as a node serves
+        process_watch.start()
+        self._watching = True
         self._server_thread.start()
         self._stamp_net_origin(self.coord)
         if rebuild:   # boot-time re-walk (Worker.java:77-88); after a
@@ -662,6 +679,9 @@ class SearchNode(ScatterReadPlane):
 
     def stop(self) -> None:
         self._stopping = True
+        if self._watching:   # give start()'s share of the watch back
+            self._watching = False
+            process_watch.stop()
         self._store_ledger.flush(fsync=False)   # best-effort final flush
         self.placement.stop()
         if self.placement_follower is not None:
@@ -2716,8 +2736,14 @@ class _NodeHandler(_HttpHandlerBase):
 
     def _serve_process_batch(self) -> None:
         """The ``/worker/process-batch`` branch, from the body read to
-        the reply written: what ``phase_handle_batch`` times."""
+        the reply written: what ``phase_handle_batch`` times. The 200
+        reply says so itself (``Server-Timing``: when this branch began
+        on the shared ``epoch_now`` clock, and how long it took to the
+        reply's first write), which is where the leader cuts its
+        ``scatter_rpc`` into the way out, the worker and the way
+        back."""
         node = self.node
+        recv_s = epoch_now()
         # batched scatter RPC (leader-internal; packed reply —
         # see cluster/wire.py). The per-query endpoint above
         # keeps the reference-compatible JSON shape. With
@@ -2830,7 +2856,8 @@ class _NodeHandler(_HttpHandlerBase):
             # end-to-end instead of silently presenting sick
             # hardware as healthy
             dh = ({"X-Compute-Degraded": "1"}
-                  if node.engine.pop_fallback_served() else None)
+                  if node.engine.pop_fallback_served() else {})
+            dh[SERVER_TIMING_HEADER] = server_timing(recv_s)
             self._send(200, body, "application/octet-stream",
                        headers=dh)
 

@@ -79,9 +79,11 @@ from tfidf_tpu.utils.config import Config
 from tfidf_tpu.utils.faults import global_injector
 from tfidf_tpu.utils.logging import get_logger
 from tfidf_tpu.utils.metrics import global_metrics
-from tfidf_tpu.utils.tracing import (SPAN_HEADER, TRACE_HEADER,
-                                     global_tracer, remote_context,
-                                     to_chrome_trace)
+from tfidf_tpu.utils.tracing import (SPAN_HEADER, TRACE_HEADER, epoch_now,
+                                     global_tracer, process_watch,
+                                     remote_context, to_chrome_trace,
+                                     trace_rpc_legs, trace_stages,
+                                     wait_stamp)
 
 log = get_logger("cluster.router")
 
@@ -375,14 +377,19 @@ class ScatterReadPlane:
                 # shipping a batch the worker will (rightly) refuse —
                 # and record nothing on the breaker (no RPC happened)
                 raise DeadlineExpired(addr + ": budget spent")
-            t0 = time.perf_counter()
+            t0, sent_s = time.perf_counter(), epoch_now()
             raw = self._scatter.post(
                 addr, "/worker/process-batch", body,
                 timeout=remaining, live=live,
                 headers={"X-Deadline-Ms": str(int(remaining * 1e3))})
-            global_metrics.observe("scatter_rpc",
-                                   time.perf_counter() - t0)
             t1 = time.perf_counter()
+            # scatter_rpc, and the same round trip cut at the worker's
+            # own two stamps: scatter_rpc_out + _handle + _back (a
+            # reply without the header, an older worker's, observes
+            # scatter_rpc alone)
+            trace_rpc_legs("scatter_rpc",
+                           self._scatter.pop_server_timing(),
+                           sent_s, t1 - t0)
             hit_lists = unpack_hit_lists(raw)
             global_metrics.observe("scatter_decode",
                                    time.perf_counter() - t1)
@@ -941,8 +948,32 @@ class _HttpHandlerBase(BaseHTTPRequestHandler):
     # on, write N+1 can stall behind the peer's delayed ACK of write N
     disable_nagle_algorithm = True
 
+    # what outlives a request (the handler instance lives as long as
+    # its keep-alive connection): when the last search's reply was
+    # written and the client's turnaround began, and the two timings
+    # that END after a reply is out, so the NEXT search on the
+    # connection observes them with its own stages, under the same
+    # acquisition of the metrics lock (a connection's last reply is
+    # not observed: 512 of ~33,000 in a window)
+    _t_replied = 0.0
+    _reply_write: float | None = None   # the last reply's writes
+    _client_gap: float | None = None    # the turnaround after them
+
     def log_message(self, fmt, *args):
         pass
+
+    def parse_request(self) -> bool:
+        """The request line is in: the client's turnaround ends
+        (``leader_client_gap``, from this connection's previous search
+        reply written; observed by ``_serve_search`` if this request
+        turns out to be a search; a connection's first request has
+        none). From here to ``leader_search``'s start, the headers'
+        parse and the routing, read 0.09 ms on the chip at saturation
+        (PR 35) and has no timer."""
+        self._client_gap = (wait_stamp() - self._t_replied
+                            if self._t_replied else None)
+        self._t_replied = 0.0
+        return super().parse_request()
 
     # ---- plumbing ----
 
@@ -1220,9 +1251,21 @@ class _HttpHandlerBase(BaseHTTPRequestHandler):
         request span minted at the admission point, the health-marker
         contract on the reply (degraded header + the (epoch,
         generation) route stamp), the live latency histogram, and the
-        slow-query log."""
+        slow-query log.
+
+        A reply, first byte to last, on this handler's thread:
+        ``leader_client_gap`` (``parse_request``) + ``leader_search`` +
+        ``leader_reply_write`` is one turn of a closed-loop client; and
+        ``leader_search`` is ``leader_pre_submit`` (admission, body
+        read and parse, quarantine, request log, cache probe) + the
+        coalescer's ``scatter_queue_wait`` + ``leader_in_batch`` (the
+        request's own share of ``scatter_batch_total``, between the
+        dispatcher's two stamps) + ``scatter_wake`` +
+        ``leader_post_wake`` (the cache insert, the reply's headers),
+        each chained on the stamp the one before ended at
+        (``Coalescer.pop_stamps``), so nothing lies between them."""
         node = self.node
-        t0 = time.perf_counter()
+        t0 = wait_stamp()
         with self._admitted("leader.search",
                             LANE_INTERACTIVE) as (sp, lane):
             if sp is None:
@@ -1277,6 +1320,10 @@ class _HttpHandlerBase(BaseHTTPRequestHandler):
                             or self.client_address[0])
             result, health = node.leader_search_with_health(
                 query, lane=lane, mode=mode, fusion=fusion)
+            # where this thread entered and left the coalescer (None:
+            # a cache hit, or a configuration without one)
+            stamps = (node.scatter_batcher.pop_stamps()
+                      if node.scatter_batcher is not None else None)
             # degraded marker: the body stays reference-compatible
             # (name -> score); the headers say whether every live
             # worker's shard is represented, which placement world
@@ -1323,10 +1370,23 @@ class _HttpHandlerBase(BaseHTTPRequestHandler):
                             **{k: health[k] for k in
                                ("attempted", "responded",
                                 "circuit_open")}))
-            dt = time.perf_counter() - t0
-            # live front-door latency histogram: the p50/p99
-            # operators read
-            global_metrics.observe("leader_search", dt)
+            t_end = wait_stamp()
+            dt = t_end - t0
+            # live front-door latency histogram (the p50/p99 operators
+            # read) and, under the same acquisition of the metrics
+            # lock, the stages only this thread can time
+            stages = [("leader_search", dt)]
+            if self._client_gap is not None:   # the reply before this
+                stages += (("leader_reply_write", self._reply_write),
+                           ("leader_client_gap", self._client_gap))
+            # (stamps older than t0 are a failed search's, left on
+            # this thread and popped by a cache hit: not this request's)
+            if stamps is not None and stamps[0] >= t0:
+                queued, begun, t_set, woke = stamps
+                stages += (("leader_pre_submit", queued - t0),
+                           ("leader_in_batch", t_set - begun),
+                           ("leader_post_wake", t_end - woke))
+            trace_stages(*stages)
             slow_ms = node.config.trace_slow_query_ms
             if slow_ms > 0 and dt * 1e3 >= slow_ms:
                 # trace-id-keyed slow-query log: the adapter
@@ -1338,6 +1398,12 @@ class _HttpHandlerBase(BaseHTTPRequestHandler):
                     query=query[:80],
                     degraded=health.get("degraded", 0))
             self._json(result, headers=hdrs)
+            # json.dumps, the status line, each header and the body,
+            # unbuffered; the next request line read on this connection
+            # ends the client's turnaround that starts here, and the
+            # next search observes both
+            self._t_replied = wait_stamp()
+            self._reply_write = self._t_replied - t_end
 
     def _serve_leader_download(self, u) -> None:
         """The ``/leader/download`` branch: admission (bulk lane — real
@@ -1637,6 +1703,7 @@ class QueryRouter(ScatterReadPlane):
         self._coord_factory = coord_factory
         coord.on_session_event(self._on_session_event)
         self._stopping = False
+        self._watching = False   # holds a share of process_watch
         # membership view ONLY: a router never registers itself as a
         # worker — it serves no shard. The watch keeps the scatter
         # target set fresh; the epoch keys coalesced batches.
@@ -1805,6 +1872,8 @@ class QueryRouter(ScatterReadPlane):
     # ---- lifecycle ----
 
     def start(self) -> "QueryRouter":
+        process_watch.start()
+        self._watching = True
         self._server_thread.start()
         self._scatter.origin = self.url
         if getattr(self.coord, "origin", None) == "":
@@ -1836,6 +1905,9 @@ class QueryRouter(ScatterReadPlane):
 
     def stop(self) -> None:
         self._stopping = True
+        if self._watching:   # give start()'s share of the watch back
+            self._watching = False
+            process_watch.stop()
         self.placement.stop()
         self.httpd.shutdown()
         self.httpd.server_close()

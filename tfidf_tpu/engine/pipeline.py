@@ -34,6 +34,13 @@ pure function of (snapshot, queries), so interleaving cannot change any
 caller's results — the parity gate in ``tests/test_pipeline.py`` holds
 bit-identical output against the unpipelined path.
 
+The dispatch thread's wait for a chunk, from finding its queue empty to
+holding one, is timed as ``phase_dispatch_idle`` (``trace_phase``: also
+the host span ``dispatch_idle`` of a profiler trace): its sum over the
+process's wall is the share of the time the device had nothing queued
+behind it. A starved stretch longer than ``idle_s`` ends at the
+thread's idle exit and the rest of it is not counted.
+
 Threads start lazily on first submit and exit after ``idle_s`` without
 work (tests build thousands of short-lived engines; parking two threads
 forever on each would pile up), reviving transparently on the next
@@ -50,7 +57,7 @@ from concurrent.futures import Future
 
 from tfidf_tpu.utils.metrics import global_metrics
 from tfidf_tpu.utils.tracing import (current_span, global_tracer,
-                                     trace_wait, wait_stamp)
+                                     trace_phase, trace_wait, wait_stamp)
 
 # Every live executor, stopped at interpreter exit: a daemon thread
 # reaped DURING finalization while inside XLA's C++ fetch path dies via
@@ -176,23 +183,39 @@ class PipelineExecutor:
                 name=f"{self.name}-fetch")
             self._fetch_thread.start()
 
+    def _await_job_locked(self) -> bool:
+        """Wait (lock held) for the dispatch queue to hold a chunk;
+        False where the thread should exit instead: ``stop()``, or
+        ``idle_s`` without work."""
+        while not self._dispatch_q and not self._stopping:
+            if not self._work.wait(timeout=self.idle_s):
+                if self._dispatch_q:
+                    continue   # work raced the timeout
+                # clear the slot UNDER THE LOCK before exiting:
+                # is_alive() stays True while this frame unwinds, and
+                # _ensure_threads_locked must not mistake a
+                # deciding-to-exit thread for a live one (a
+                # just-submitted job would strand)
+                if self._dispatch_thread is threading.current_thread():
+                    self._dispatch_thread = None
+                return False       # idle exit; submit() revives
+        return not self._stopping
+
     def _dispatch_loop(self) -> None:
         while True:
             with self._lock:
-                while not self._dispatch_q and not self._stopping:
-                    if not self._work.wait(timeout=self.idle_s):
-                        if self._dispatch_q:
-                            continue   # work raced the timeout
-                        # clear the slot UNDER THE LOCK before exiting:
-                        # is_alive() stays True while this frame
-                        # unwinds, and _ensure_threads_locked must not
-                        # mistake a deciding-to-exit thread for a live
-                        # one (a just-submitted job would strand)
-                        if self._dispatch_thread \
-                                is threading.current_thread():
-                            self._dispatch_thread = None
-                        return         # idle exit; submit() revives
-                if self._stopping:
+                if self._dispatch_q or self._stopping:
+                    ready = not self._stopping
+                else:
+                    # the dispatch thread STARVED: from finding the
+                    # queue empty to holding a chunk (or giving up: the
+                    # idle exit ends the span, and what a starved
+                    # stretch lasts past idle_s is not counted). In a
+                    # traced run this is the host span that names the
+                    # device's idle time "the worker had nothing to do"
+                    with trace_phase("dispatch_idle"):
+                        ready = self._await_job_locked()
+                if not ready:
                     return
                 job = self._dispatch_q.popleft()
             if not job.future.set_running_or_notify_cancel():

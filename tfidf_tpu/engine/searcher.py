@@ -141,20 +141,17 @@ class QueryVectorizerMixin:
     @staticmethod
     def _count_kernel_uniq(qb) -> None:
         """One chunk dispatched to the fused kernel: its distinct terms
-        (``kernel_uniq_live``), the uniq lanes of A the kernel builds
-        for them (``_built``) and what whole uniq tiles would hold
-        (``_tiled``). ``live / built`` is the share of the A-build's
-        compare/select work on lanes a query uses. And the 128-row
-        chunks of A it contracts (``kernel_contract_chunks``), with
+        (``kernel_uniq_live``) and the uniq lanes of A the kernel
+        builds for them (``_built``). ``live / built`` is the share of
+        the A-build's compare/select work on lanes a query uses. And the
+        128-row chunks of A it contracts (``kernel_contract_chunks``), with
         those that take three bf16 passes because the batch's weights
         are exact in bfloat16 (``_bf16x3``: all of a batch or none)."""
         n_uniq = int(qb.n_uniq)
-        built, tiled = kernel_uniq_lanes(
-            n_uniq, qb.slots.shape[0], qb.uniq.shape[0])
+        built = kernel_uniq_lanes(n_uniq)
         chunks, bf16x3 = kernel_contract_chunks(n_uniq, qb.weights)
         global_metrics.inc("kernel_uniq_live", n_uniq)
         global_metrics.inc("kernel_uniq_built", built)
-        global_metrics.inc("kernel_uniq_tiled", tiled)
         global_metrics.inc("kernel_contract_chunks", chunks)
         global_metrics.inc("kernel_contract_chunks_bf16x3", bf16x3)
 

@@ -523,15 +523,12 @@ def _pl_tiles(rows_cap: int, B: int, u_cap: int,
     return td, tu
 
 
-def kernel_uniq_lanes(n_uniq: int, B: int, u_cap: int) -> tuple[int, int]:
-    """``(built, tiled)``: the uniq lanes of A the kernel builds for a
-    batch of ``n_uniq`` distinct terms — sub-tiles of ``_PL_SU`` — and
-    what whole uniq tiles would hold (the kernel before PR 27 built
-    those). Host arithmetic for the ``kernel_uniq_*`` counters; the
-    same for every block of a dispatch (the uniq tile does not depend
-    on a block's rows)."""
-    _td, tu = _pl_tiles(_PL_TD, B, u_cap, 1)
-    return -(-n_uniq // _PL_SU) * _PL_SU, -(-n_uniq // tu) * tu
+def kernel_uniq_lanes(n_uniq: int) -> int:
+    """The uniq lanes of A the kernel builds for a batch of ``n_uniq``
+    distinct terms: whole sub-tiles of ``_PL_SU``. Host arithmetic for
+    the ``kernel_uniq_built`` counter; the same for every block of a
+    dispatch and every batch bucket."""
+    return -(-n_uniq // _PL_SU) * _PL_SU
 
 
 def ell_layout_gauges(shapes, live, res_doc: np.ndarray) -> dict[str, int]:

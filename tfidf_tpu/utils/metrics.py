@@ -123,6 +123,22 @@ class Metrics:
             self._hist[name][bisect.bisect_left(BUCKET_BOUNDS_S,
                                                 seconds)] += 1
 
+    def observe_many(self, items) -> None:
+        """``observe()`` for several ``(name, seconds)`` at once, under
+        ONE acquisition of the lock: the stages of one request land
+        together (a snapshot never splits them), and a hot path pays
+        for the lock once. (``observe`` keeps its own body: it is the
+        hottest call of the registry.)"""
+        with self._lock:
+            for name, seconds in items:
+                t = self._timings[name]
+                t[0] += 1
+                t[1] += seconds
+                t[2] = min(t[2], seconds)
+                t[3] = max(t[3], seconds)
+                self._hist[name][bisect.bisect_left(BUCKET_BOUNDS_S,
+                                                    seconds)] += 1
+
     def get(self, name: str, default: float = 0.0) -> float:
         """Read one counter/gauge (the namespaces are disjoint — see
         the emit-side guards) — the resilience paths and tests branch

@@ -40,20 +40,28 @@ operations.
 ``trace_phase`` is the ONE timer of a stage: wall time into the global
 metrics, a ``jax.profiler.TraceAnnotation`` so the stage shows up named
 in a profiler capture, and a ``phase.<name>`` event on the active span.
-``trace_wait`` is its companion for a wait that crosses threads.
+``trace_wait`` is its companion for a wait that crosses threads,
+``trace_rpc_legs`` for one that crosses PROCESSES (the worker's
+``Server-Timing`` reply header, on the :func:`epoch_now` clock), and
+:class:`ProcessWatch` says what the process itself costs: its CPU
+share, a runnable thread's wait for the interpreter, the collector's
+pauses.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import random
 import re
+import resource
+import threading
 import time
 from collections import deque
 from typing import Iterator, NamedTuple
 
-from tfidf_tpu.utils.metrics import global_metrics
+from tfidf_tpu.utils.metrics import Metrics, global_metrics
 
 try:  # jax is always present in this image, but keep host-only tools usable
     import jax.profiler as _jprof
@@ -506,7 +514,7 @@ def trace_phase(name: str) -> Iterator[None]:
 wait_stamp = time.perf_counter
 
 
-def trace_wait(key: str, since: float, span: Span | None = None) -> None:
+def trace_wait(key: str, since: float, span: Span | None = None) -> float:
     """``trace_phase``'s companion for a WAIT that starts on one thread
     and ends on another (a queue hand-off, an ``Event`` wake-up): call
     it where the wait ends with the ``wait_stamp()`` taken where it
@@ -514,10 +522,196 @@ def trace_wait(key: str, since: float, span: Span | None = None) -> None:
     so per-instance names stay ``<name>_queue_wait``) and an event
     named ``key`` with its first ``_`` as a ``.`` (``scatter.wake``,
     ``phase.fetch_wait``) on ``span``, or on the active span. No
-    profiler span: a ``TraceAnnotation`` cannot cross threads."""
-    dt = time.perf_counter() - since
+    profiler span: a ``TraceAnnotation`` cannot cross threads.
+
+    Returns the stamp the wait ENDED at, which is the start of whatever
+    comes next: stages chained through it share their clock reads, so
+    their sum is the whole with nothing between them."""
+    until = time.perf_counter()
+    dt = until - since
     global_metrics.observe(key, dt)
     if span is None:
         span = global_tracer.current()
     if span is not None:
         span.event(key.replace("_", ".", 1), ms=round(dt * 1e3, 3))
+    return until
+
+
+def trace_stages(*stages: tuple[str, float]) -> None:
+    """Record stages whose two stamps were taken elsewhere, ``(key,
+    seconds)`` each, under ONE acquisition of the metrics lock (a
+    request's stages land together, and a handler among hundreds pays
+    for the lock once). Timings only: NO span event. A saturated leader
+    is out of interpreter, and an event a stage a request is bytecode
+    under its GIL: with one the five front-door stages cost ~15 us a
+    request and `served_qps` read 9.5% under the parent's in four pairs
+    on the chip (PR 35); ``trace_wait`` keeps the events of the stages
+    that had them."""
+    global_metrics.observe_many(stages)
+
+
+# ---- an RPC's two legs, on one clock ----
+
+# the worker's reply to /worker/process-batch says when it took the
+# request and how long it held it, on the clock every span timestamp
+# already rides: _EPOCH0 + monotonic(). Between processes of one host
+# that clock is exact to the two anchors' read error (each process read
+# time.time() and time.monotonic() once, at import); across hosts it is
+# as good as their NTP
+SERVER_TIMING_HEADER = "Server-Timing"
+_SERVER_TIMING_RE = re.compile(
+    r"recv;t=(-?[0-9]+(?:\.[0-9]+)?),\s*handle;dur=([0-9]+(?:\.[0-9]+)?)")
+
+
+def server_timing(recv_s: float) -> str:
+    """The ``Server-Timing`` value of a reply about to be written:
+    ``recv;t=<epoch ms the request's body was read at>,handle;dur=<ms
+    from there to now>``. ``recv_s`` is the :func:`epoch_now` taken at
+    the body read; call this where the reply's first write starts."""
+    return (f"recv;t={recv_s * 1e3:.3f},"
+            f"handle;dur={(epoch_now() - recv_s) * 1e3:.3f}")
+
+
+def trace_rpc_legs(key: str, header: str | None, sent_s: float,
+                   rpc_s: float) -> None:
+    """Observe one RPC's round trip ``rpc_s`` as ``key`` and, cut at
+    the worker's ``Server-Timing`` header, as three more timings whose
+    sum is ``rpc_s`` by construction (all four under one acquisition of
+    the metrics lock, so no snapshot splits them):
+
+    * ``<key>_out``: the caller's send stamp ``sent_s`` (its
+      :func:`epoch_now` beside the start of ``rpc_s``) to the worker's
+      ``recv``: the caller's framing and send (its OWN interpreter,
+      which it may have to wait for between the stamp and the send),
+      the socket, the worker's accept, thread start and header parse
+      (the worker's interpreter);
+    * ``<key>_handle``: the worker's ``dur``;
+    * ``<key>_back``: the rest: the worker's reply written, the socket,
+      the caller's read of the body, i.e. the CALLER's interpreter.
+      Which side a slow ``out`` accuses is read off the two processes'
+      ``gil_wait``.
+
+    Across hosts ``out`` and ``back`` carry the two clocks' skew with
+    opposite signs and their sum does not; on one host a leg can read
+    negative by the two anchors' read error (well under a millisecond).
+    A reply without the header (an older worker) or with a malformed
+    one observes ``key`` alone, and nothing is raised."""
+    seen = [(key, rpc_s)]
+    m = _SERVER_TIMING_RE.search(header) if header else None
+    if m is not None:
+        out = float(m.group(1)) / 1e3 - sent_s
+        handle = float(m.group(2)) / 1e3
+        seen += [(f"{key}_out", out), (f"{key}_handle", handle),
+                 (f"{key}_back", rpc_s - out - handle)]
+    global_metrics.observe_many(seen)
+
+
+# ---- what the process costs itself ----
+
+class ProcessWatch:
+    """What this PROCESS costs itself, into the same ``/api/metrics``:
+
+    * timing ``gil_wait``: a daemon thread sleeps ``TICK_S`` (5 ms) and
+      observes how LATE each wake-up is: a runnable thread's wait for
+      the interpreter and for a core. ~0.1 ms in an idle process; the
+      switch interval times the queue of runnable threads in a
+      saturated one. A pause of the whole machine lands here too (the
+      benchmark's ``host_pause_ms.*`` tells them apart);
+    * counters ``process_cpu_ms`` (``getrusage``: every thread, user +
+      system), ``process_sys_ms`` (the system part of it: socket calls,
+      futexes, context switches) and ``process_wall_ms`` on the same
+      tick, so a ratio over a window is the cores the process used
+      (less the kernel's share: what ran as bytecode or under it), and
+      ANY ``<timing>_sum_ms`` over ``process_wall_ms`` is that stage's
+      share of the wall;
+    * timing ``gc_pause`` per collection (``gc.callbacks``, start to
+      stop) and counter ``gc_collections_gen2``.
+
+    Always on in a serving process (``SearchNode.start`` and
+    ``QueryRouter.start`` share the one :data:`process_watch`; the
+    library surface starts none): nothing is sampled and nothing is
+    switched. ``start``/``stop`` count their users, so the nodes of an
+    in-process cluster share one thread and the last ``stop`` removes
+    the thread and the collector hook.
+
+    The collector can run between any two bytecodes of a thread that
+    HOLDS the metrics lock, so the hook takes no lock: it appends to a
+    deque and the tick thread observes what it finds there."""
+
+    TICK_S = 0.005
+
+    def __init__(self, metrics: Metrics = global_metrics) -> None:
+        self._metrics = metrics   # a test's own registry, else the one
+        self._lock = threading.Lock()
+        self._users = 0
+        self._thread: threading.Thread | None = None
+        self._halt = threading.Event()
+        self._gc_t0 = 0.0
+        self._gc_seen: deque[tuple[float, int]] = deque()
+
+    def start(self) -> None:
+        with self._lock:
+            self._users += 1
+            if self._users > 1:
+                return
+            self._halt = threading.Event()
+            gc.callbacks.append(self._on_gc)
+            self._thread = threading.Thread(
+                target=self._run, args=(self._halt,), daemon=True,
+                name="process-watch")
+            self._thread.start()
+
+    def stop(self) -> None:
+        """Give one ``start()`` back; the last one joins the thread and
+        removes the collector hook (idempotent past zero)."""
+        with self._lock:
+            if self._users == 0:
+                return
+            self._users -= 1
+            if self._users:
+                return
+            thread, self._thread = self._thread, None
+            self._halt.set()
+            with contextlib.suppress(ValueError):
+                gc.callbacks.remove(self._on_gc)
+        if thread is not None:
+            thread.join(timeout=2.0)
+        self._drain_gc()
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # under the GIL and never nested: plain attributes suffice
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0:
+            self._gc_seen.append((time.perf_counter() - self._gc_t0,
+                                  info.get("generation", 0)))
+            self._gc_t0 = 0.0
+
+    def _drain_gc(self) -> None:
+        while self._gc_seen:
+            try:
+                dt, gen = self._gc_seen.popleft()
+            except IndexError:   # raced stop()'s own drain
+                return
+            self._metrics.observe("gc_pause", dt)
+            if gen == 2:
+                self._metrics.inc("gc_collections_gen2")
+
+    def _run(self, halt: threading.Event) -> None:
+        m, tick, me = self._metrics, self.TICK_S, resource.RUSAGE_SELF
+        wall, ru = time.monotonic(), resource.getrusage(me)
+        while not halt.is_set():
+            t = time.monotonic()
+            time.sleep(tick)
+            now, r = time.monotonic(), resource.getrusage(me)
+            m.observe("gil_wait", max(0.0, now - t - tick))
+            sys_ms = (r.ru_stime - ru.ru_stime) * 1e3
+            m.inc("process_cpu_ms", (r.ru_utime - ru.ru_utime) * 1e3 + sys_ms)
+            m.inc("process_sys_ms", sys_ms)
+            m.inc("process_wall_ms", (now - wall) * 1e3)
+            wall, ru = now, r
+            self._drain_gc()
+
+
+# the one watch of a serving process (see ProcessWatch)
+process_watch = ProcessWatch()

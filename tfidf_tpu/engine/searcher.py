@@ -16,6 +16,9 @@ from __future__ import annotations
 
 import threading
 from collections import deque
+from functools import partial
+from itertools import chain, compress
+from operator import attrgetter, itemgetter
 from typing import NamedTuple
 
 import jax
@@ -46,6 +49,13 @@ from tfidf_tpu.utils.tracing import trace_phase
 class SearchHit(NamedTuple):
     name: str
     score: float
+
+
+# SearchHit._make without its Python frame a hit: the same objects from
+# a (name, score) pair, built in C (assemble_hits makes 5,120 a call)
+_new_hit = partial(tuple.__new__, SearchHit)
+_hit_name = attrgetter("name")
+_pair_weight = itemgetter(1)
 
 
 class Stretch(NamedTuple):
@@ -90,18 +100,78 @@ def vectorize_queries(queries: list[str], analyzer: Analyzer,
     assert len(queries) <= batch_cap
     q_terms = np.zeros((batch_cap, max_terms), np.int32)
     q_weights = np.zeros((batch_cap, max_terms), np.float32)
-    widest = 1
-    for i, q in enumerate(queries):
-        counts = vocab.map_counts(analyzer.counts(q), add=False)
-        weights = model.query_weights(counts)
-        items = sorted(weights.items(), key=lambda kv: (-kv[1], kv[0]))
-        items = items[:max_terms]
-        widest = max(widest, len(items))
-        for j, (tid, w) in enumerate(items):
-            q_terms[i, j] = tid
-            q_weights[i, j] = w
-    return make_query_batch(q_terms, q_weights,
-                            min_slots=min_slots), widest
+    term_counts = analyzer.counts
+    map_counts = vocab.map_counts
+    query_weights = model.query_weights
+    # every query's (term id, weight) pairs end to end and how many each
+    # query gave: the matrices are filled ONCE after the loop, where a
+    # numpy scalar store a term was a seventh of this function
+    pairs: list[tuple[int, float]] = []
+    sizes: list[int] = []
+    for q in queries:
+        items = query_weights(
+            map_counts(term_counts(q), add=False)).items()
+        if len(items) > 1:
+            # heaviest first, ties by term id, i.e. key (-weight, id):
+            # by id, then a stable sort by weight. This is q_terms'
+            # column order, so it is kept to the letter and the compiled
+            # programs see the same arrays
+            items = sorted(items)
+            items.sort(key=_pair_weight, reverse=True)
+            del items[max_terms:]
+        sizes.append(len(items))
+        pairs.extend(items)
+    if pairs:
+        # row-major, the filled entries of the matrices are the pairs in
+        # order: query by query, column by column
+        filled = np.arange(max_terms) < np.array(sizes)[:, None]
+        # one pass from Python objects to numpy for ids and weights
+        # both: a term id is exact in a float64, and a float64 rounds
+        # to float32 as the scalar store did
+        flat = np.fromiter(chain.from_iterable(pairs), np.float64,
+                           2 * len(pairs)).reshape(-1, 2)
+        q_terms[:len(sizes)][filled] = flat[:, 0]
+        q_weights[:len(sizes)][filled] = flat[:, 1]
+    return (make_query_batch(q_terms, q_weights, min_slots=min_slots),
+            max([1, *sizes]))
+
+
+def assemble_hits(vals: np.ndarray, ids: np.ndarray, name_of,
+                  result_order: str) -> list[list[SearchHit]]:
+    """The hit lists of one fetched ``[n, kk]`` block of top-k values
+    and document ids, a list a query: the ONE place result arrays become
+    Python objects, for every searcher family. An entry is a hit where
+    its value is finite and > 0 (dead and pad entries are 0 or -inf);
+    ``name_of`` maps an id to its name, and an id it names ``None`` (a
+    mesh shard's pad row) is dropped. Every conversion between numpy and
+    Python happens once for the block, not once an entry: a float32
+    becomes the same ``float`` through ``tolist`` as through ``float()``.
+    """
+    live = np.isfinite(vals) & (vals > 0.0)
+    counts = np.count_nonzero(live, axis=1)
+    names = list(map(name_of, ids[live].tolist()))
+    scores = vals[live].tolist()
+    if None in names:
+        named = [name is not None for name in names]
+        counts = np.bincount(np.nonzero(live)[0][named],
+                             minlength=len(counts))
+        names = list(compress(names, named))
+        scores = list(compress(scores, named))
+    # ALL the hits in one C-level call, then cut: the interpreter runs
+    # the cyclic collector between bytecodes only, so these 5,120
+    # allocations cost one young collection, where building them a row
+    # (or an entry, as the old loop did) ran one every 700 and promoted
+    # enough survivors for eight times the full collections
+    hits = list(map(_new_hit, zip(names, scores)))
+    results = []
+    lo = 0
+    for count in counts.tolist():
+        results.append(hits[lo:lo + count])
+        lo += count
+    if result_order == "name":
+        for row in results:
+            row.sort(key=_hit_name)
+    return results
 
 
 class QueryVectorizerMixin:
@@ -772,14 +842,8 @@ class Searcher(QueryVectorizerMixin):
     def _assemble(self, snap: Snapshot, queries: list[str], vals, ids,
                   kk: int) -> list[list[SearchHit]]:
         self._poison_check(queries, vals)
-        segmented = isinstance(snap, SegmentedSnapshot)
-        names = snap.padded_names if segmented else snap.doc_names
-        results: list[list[SearchHit]] = []
-        for i in range(len(queries)):
-            hits = [SearchHit(names[int(d)], float(v))
-                    for v, d in zip(vals[i, :kk], ids[i, :kk])
-                    if np.isfinite(v) and v > 0.0]
-            if self.result_order == "name":
-                hits.sort(key=lambda h: h.name)
-            results.append(hits)
-        return results
+        names = (snap.padded_names if isinstance(snap, SegmentedSnapshot)
+                 else snap.doc_names)
+        n = len(queries)
+        return assemble_hits(vals[:n, :kk], ids[:n, :kk],
+                             names.__getitem__, self.result_order)

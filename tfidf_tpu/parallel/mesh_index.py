@@ -377,7 +377,8 @@ class MeshIndex:
         return arrays
 
 
-from tfidf_tpu.engine.searcher import QueryVectorizerMixin
+from tfidf_tpu.engine.searcher import (QueryVectorizerMixin,
+                                       assemble_hits)
 
 
 class MeshSearcher(QueryVectorizerMixin):
@@ -516,20 +517,9 @@ class MeshSearcher(QueryVectorizerMixin):
         return out
 
     def _assemble_hits(self, snap, chunk, vals, gids, kk):
-        from tfidf_tpu.engine.searcher import SearchHit
-        results = []
-        for i in range(len(chunk)):
-            hits = []
-            for v, g in zip(vals[i, :kk], gids[i, :kk]):
-                if not (np.isfinite(v) and v > 0.0):
-                    continue
-                name = snap.name_of(int(g))
-                if name is not None:
-                    hits.append(SearchHit(name, float(v)))
-            if self.result_order == "name":
-                hits.sort(key=lambda h: h.name)
-            results.append(hits)
-        return results
+        n = len(chunk)
+        return assemble_hits(vals[:n, :kk], gids[:n, :kk], snap.name_of,
+                             self.result_order)
 
     def _rank_all(self, snap: MeshSnapshot, qb):
         """Parity mode: full per-shard score matrices ranked on the host

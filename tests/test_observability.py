@@ -918,6 +918,57 @@ class TestStageTimer:
             assert evs.count(name) == 3, (name, evs)
         assert not [n for n in evs if n.startswith("pipeline.")]
 
+    @pytest.mark.parametrize("mesh_layout", [None, "ell"])
+    def test_hit_stages_nest_inside_assemble(self, tmp_path, mesh_layout):
+        """``hit_names`` and ``hit_objects`` (``assemble_hits``): one of
+        each a fetched chunk, as timings ``phase_hit_*`` and as events of
+        the active span, inside that chunk's ``assemble`` and summing to
+        it but for its two views and the poison check."""
+        e = _stage_engine(tmp_path, "inline", mesh_layout=mesh_layout)
+        keys = tuple(f"phase_{s}_{what}" for what in ("count", "sum_ms")
+                     for s in ("assemble", "hit_names", "hit_objects"))
+        before = _counts(*keys)
+        with global_tracer.span("req") as sp:
+            assert all(e.search_batch(self.QUERIES))
+        d = _grew(before, _counts(*keys))
+        assert [d[k] for k in keys[:3]] == [3, 3, 3]
+        stages = [(ev["name"], ev["attrs"]["ms"])
+                  for ev in sp.to_dict()["events"]
+                  if ev["name"] in ("phase.assemble", "phase.hit_names",
+                                    "phase.hit_objects")]
+        # a stage's event is written where it ends: the two inner ones,
+        # in order, then the chunk's assemble that held them
+        assert [n for n, _ms in stages] == [
+            "phase.hit_names", "phase.hit_objects", "phase.assemble"] * 3
+        for i in range(0, 9, 3):
+            names_ms, objects_ms, assemble_ms = (ms for _n, ms in
+                                                 stages[i:i + 3])
+            inner = names_ms + objects_ms
+            # events round to a microsecond; what assemble holds besides
+            # is tens of microseconds (2 ms: a loaded host's slack)
+            assert inner <= assemble_ms + 0.003, stages[i:i + 3]
+            assert assemble_ms - inner < 2.0, stages[i:i + 3]
+        assert d["phase_hit_names_sum_ms"] + d["phase_hit_objects_sum_ms"] \
+            <= d["phase_assemble_sum_ms"] + 0.01
+
+    def test_hit_counters_say_how_full_the_depth_is(self, tmp_path):
+        """``hit_slots``: queries x depth of every fetched chunk;
+        ``hits_built``: the hits that left ``assemble_hits``. A query of
+        a rare term fills one slot of its five."""
+        e = _stage_engine(tmp_path, "inline")
+        before = _counts("hit_slots", "hits_built")
+        # "common" is in all 12 documents, "word7" in one, "term1" in four
+        hits = e.search_batch(["common", "word7", "term1 word7",
+                               "nothing"], k=5)
+        assert [len(h) for h in hits] == [5, 1, 4, 0]
+        assert _grew(before, _counts("hit_slots", "hits_built")) == {
+            "hit_slots": 4 * 5, "hits_built": 10}
+        # the arrays path builds no hit
+        before = _counts("hit_slots", "hits_built")
+        e.search_batch_arrays(["common", "word7"], k=5)
+        assert _grew(before, _counts("hit_slots", "hits_built")) == {
+            "hit_slots": 0, "hits_built": 0}
+
     MESH = ("phase_vectorize_count", "phase_score_count") + FETCH \
         + CHUNKS + ("mesh_steps",)
 
@@ -1013,7 +1064,7 @@ class TestStageTimer:
         e = _stage_engine(tmp_path, "inline", mesh_layout="ell")
         snap = e.index.snapshot
         qb, _ = e.searcher._vectorize(self.QUERIES[:4], 4)
-        lowered = e.searcher._get_search_fn(3).lower(
+        lowered = e.searcher._get_search_fn(3, 3).lower(
             snap.base, snap.delta, snap.df_g, snap.n_docs, snap.avgdl, qb)
         assert "@jit_mesh_ell_search" in lowered.as_text()
         text = lowered.as_text(debug_info=True)

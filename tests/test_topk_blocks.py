@@ -301,5 +301,12 @@ def test_topk_chunk_counters_add_up_to_the_padded_space(tmp_path):
     assert topk_chunk_counts(
         (256, 524288, 1048576, 32768, 256),
         (201, 393204, 598231, 8311, 53), k=10) == (15, 4, 9)
-    # a caller's k in the thousands ranks every chunk straight
+    # a caller's k in the thousands ranks every chunk straight; so does
+    # msmarco2m-top1000's depth (1,024 groups a chunk are under 8 x
+    # 1,000): 19 straight chunks, and the two 256-column blocks narrower
+    # than the depth (the pad lanes of ``_block_topk``)
     assert topk_chunk_counts(caps, live, k=2000) == (20, 1, 0)
+    assert topk_chunk_counts(caps, live, k=1000) == (20, 1, 0)
+    assert not any(topk_grouped(c, min(c, 1 << 17), min(1000, c))
+                   for c in caps)
+    assert [c for c in caps if c < 1000] == [256, 256]

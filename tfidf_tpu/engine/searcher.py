@@ -146,31 +146,44 @@ def assemble_hits(vals: np.ndarray, ids: np.ndarray, name_of,
     mesh shard's pad row) is dropped. Every conversion between numpy and
     Python happens once for the block, not once an entry: a float32
     becomes the same ``float`` through ``tolist`` as through ``float()``.
+
+    Two stages of the one timer, inside the caller's ``assemble``:
+    ``hit_names`` (the mask, the two ``tolist``, the name of every live
+    entry) and ``hit_objects`` (the hits and their cut into rows), which
+    grow with the depth where the rest of ``assemble`` does not; and two
+    counters, ``hit_slots`` (the block's entries, queries x depth) and
+    ``hits_built`` (the hits that leave here): their ratio says how full
+    the depth is.
     """
-    live = np.isfinite(vals) & (vals > 0.0)
-    counts = np.count_nonzero(live, axis=1)
-    names = list(map(name_of, ids[live].tolist()))
-    scores = vals[live].tolist()
-    if None in names:
-        named = [name is not None for name in names]
-        counts = np.bincount(np.nonzero(live)[0][named],
-                             minlength=len(counts))
-        names = list(compress(names, named))
-        scores = list(compress(scores, named))
-    # ALL the hits in one C-level call, then cut: the interpreter runs
-    # the cyclic collector between bytecodes only, so these 5,120
-    # allocations cost one young collection, where building them a row
-    # (or an entry, as the old loop did) ran one every 700 and promoted
-    # enough survivors for eight times the full collections
-    hits = list(map(_new_hit, zip(names, scores)))
-    results = []
-    lo = 0
-    for count in counts.tolist():
-        results.append(hits[lo:lo + count])
-        lo += count
-    if result_order == "name":
-        for row in results:
-            row.sort(key=_hit_name)
+    with trace_phase("hit_names"):
+        live = np.isfinite(vals) & (vals > 0.0)
+        counts = np.count_nonzero(live, axis=1)
+        names = list(map(name_of, ids[live].tolist()))
+        scores = vals[live].tolist()
+        if None in names:
+            named = [name is not None for name in names]
+            counts = np.bincount(np.nonzero(live)[0][named],
+                                 minlength=len(counts))
+            names = list(compress(names, named))
+            scores = list(compress(scores, named))
+    with trace_phase("hit_objects"):
+        # ALL the hits in one C-level call, then cut: the interpreter
+        # runs the cyclic collector between bytecodes only, so these
+        # 5,120 allocations cost one young collection, where building
+        # them a row (or an entry, as the old loop did) ran one every
+        # 700 and promoted enough survivors for eight times the full
+        # collections
+        hits = list(map(_new_hit, zip(names, scores)))
+        results = []
+        lo = 0
+        for count in counts.tolist():
+            results.append(hits[lo:lo + count])
+            lo += count
+        if result_order == "name":
+            for row in results:
+                row.sort(key=_hit_name)
+    global_metrics.inc("hit_slots", vals.size)
+    global_metrics.inc("hits_built", len(hits))
     return results
 
 

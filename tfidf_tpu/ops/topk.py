@@ -43,22 +43,25 @@ def exact_topk(scores: jax.Array,     # f32 [B, doc_cap]
     return vals, idx.astype(jnp.int32)
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("k",))
 def merge_topk(vals: jax.Array,   # f32 [..., n_parts, B, k]
-               ids: jax.Array     # i32 [..., n_parts, B, k] (global doc ids)
-               ) -> tuple[jax.Array, jax.Array]:
+               ids: jax.Array,    # i32 [..., n_parts, B, k] (global doc ids)
+               *, k: int | None = None) -> tuple[jax.Array, jax.Array]:
     """Merge per-shard top-k lists into a global top-k (same k).
 
     Inputs are stacked along a parts axis (e.g. the result of an
     ``all_gather`` over the docs mesh axis). Associative and exact: the
     global top-k is always contained in the union of per-shard top-ks.
+    ``k`` (None: the parts' own) is the merged depth where it is not a
+    part's: a request deeper than one mesh shard is wide takes every
+    row of each shard and up to ``n_parts`` times that from here.
     """
-    n_parts, B, k = vals.shape[-3:]
+    n_parts, B, part_k = vals.shape[-3:]
     flat_vals = jnp.moveaxis(vals, -3, -2).reshape(*vals.shape[:-3], B,
-                                                   n_parts * k)
+                                                   n_parts * part_k)
     flat_ids = jnp.moveaxis(ids, -3, -2).reshape(*ids.shape[:-3], B,
-                                                 n_parts * k)
-    top_vals, pos = jax.lax.top_k(flat_vals, k)
+                                                 n_parts * part_k)
+    top_vals, pos = jax.lax.top_k(flat_vals, part_k if k is None else k)
     top_ids = jnp.take_along_axis(flat_ids, pos, axis=-1)
     return top_vals, top_ids
 

@@ -316,7 +316,8 @@ def make_mesh_ell_search(mesh: Mesh,
                          k1: float = 1.2,
                          b: float = 0.75,
                          use_pallas: bool = True,
-                         packed: bool = False):
+                         packed: bool = False,
+                         depth: int | None = None):
     """Distributed search over ELL base + COO delta.
 
     Returned callable:
@@ -326,7 +327,9 @@ def make_mesh_ell_search(mesh: Mesh,
     ``gids`` encode shard * (doc_cap_ell + doc_cap_delta) + local, where
     local < doc_cap_ell is an ELL row and local >= doc_cap_ell is a
     delta slot. Global stats arrive precomputed (the engine refreshes
-    them at commit), so the step needs no df psum.
+    them at commit), so the step needs no df psum. ``k`` is a SHARD's
+    depth; ``depth`` (None: ``k``) the merged reply's, for a request
+    deeper than one shard is wide.
 
     ``packed=True`` returns ONE i32 ``[B, 2k]`` array (values bitcast) so
     the caller fetches values and ids in a single device->host
@@ -410,7 +413,7 @@ def make_mesh_ell_search(mesh: Mesh,
                     + ids)
             all_vals = jax.lax.all_gather(vals, "docs")
             all_ids = jax.lax.all_gather(gids, "docs")
-            return merge_topk(all_vals, all_ids)
+            return merge_topk(all_vals, all_ids, k=depth)
 
     def in_specs(nb):
         return ((P(None), P(), P(),

@@ -502,14 +502,14 @@ class MeshEllSearcher(MeshSearcher):
     # opt in to a bigger parity replay.
     unbounded_parity_max_docs: int = 200_000
 
-    def _get_search_fn(self, k: int):
-        fn = self._search_fns.get(k)
+    def _get_search_fn(self, k: int, depth: int):
+        fn = self._search_fns.get((k, depth))
         if fn is None:
             fn = make_mesh_ell_search(
                 self.index.mesh, k=k,
                 model=self.model.score_kwargs()["model"],
-                packed=True, **self._model_kwargs())
-            self._search_fns[k] = fn
+                packed=True, depth=depth, **self._model_kwargs())
+            self._search_fns[k, depth] = fn
         return fn
 
     def _on_snapshot(self, snap) -> None:
@@ -532,11 +532,11 @@ class MeshEllSearcher(MeshSearcher):
                 for imp in snap.base.impact]
 
     def _dispatch_chunk(self, snap, qb, k: int):
-        kk = min(k, snap.stride)
+        kk, depth = self._depths(k, snap.stride)
         self._count_kernel_uniq(qb)
-        return self._get_search_fn(kk)(
+        return self._get_search_fn(kk, depth)(
             snap.base, snap.delta, snap.df_g, snap.n_docs,
-            snap.avgdl, qb), kk
+            snap.avgdl, qb), depth
 
     def _search_unbounded(self, snap, queries, k):
         # the ELL base cannot rank every matching document (its row

@@ -411,7 +411,7 @@ class MeshSearcher(QueryVectorizerMixin):
         # global_idf=False reproduces the reference's per-worker statistics
         # (each Lucene shard scores against local df/N, Worker.java:222-241)
         self.global_idf = global_idf
-        self._search_fns: dict[int, object] = {}
+        self._search_fns: dict[tuple[int, int], object] = {}
         self._scores_fn = None
 
     def _batch_cap(self, n: int) -> int:
@@ -422,15 +422,24 @@ class MeshSearcher(QueryVectorizerMixin):
         kw.pop("model", None)
         return kw
 
-    def _get_search_fn(self, k: int):
-        fn = self._search_fns.get(k)
+    def _depths(self, k: int, shard_cap: int) -> tuple[int, int]:
+        """``(a shard's depth, the merged reply's)`` for a request of
+        ``k``: a shard ranks at most its own ``shard_cap`` rows, and a
+        request deeper than that takes all of every shard, so the reply
+        holds up to that many a shard (``tests/test_deep_topk.py``: k
+        past one shard's rows and under the corpus)."""
+        kk = min(k, shard_cap)
+        return kk, min(k, kk * self.index.D)
+
+    def _get_search_fn(self, k: int, depth: int):
+        fn = self._search_fns.get((k, depth))
         if fn is None:
             fn = make_sharded_search(
                 self.index.mesh, k=k,
                 model=self.model.score_kwargs()["model"],
-                global_idf=self.global_idf, packed=True,
+                global_idf=self.global_idf, packed=True, depth=depth,
                 **self._model_kwargs())
-            self._search_fns[k] = fn
+            self._search_fns[k, depth] = fn
         return fn
 
     def _get_scores_fn(self):
@@ -492,8 +501,8 @@ class MeshSearcher(QueryVectorizerMixin):
 
     def _dispatch_chunk(self, snap, qb, k: int):
         """Layout hook: launch one chunk's packed top-k (not fetched)."""
-        kk = min(k, snap.arrays.doc_cap)
-        return self._get_search_fn(kk)(snap.arrays, qb), kk
+        kk, depth = self._depths(k, snap.arrays.doc_cap)
+        return self._get_search_fn(kk, depth)(snap.arrays, qb), depth
 
     def _finish_chunk(self, snap, chunk, packed, kk: int):
         # packed already crossed device->host in the fetch stage; this

@@ -222,7 +222,8 @@ def make_sharded_search(mesh: Mesh,
                         b: float = 0.75,
                         global_idf: bool = True,
                         chunk: int = 1 << 17,
-                        packed: bool = False):
+                        packed: bool = False,
+                        depth: int | None = None):
     """Build the jitted distributed search step for a fixed mesh/model.
 
     Returned callable:
@@ -230,7 +231,9 @@ def make_sharded_search(mesh: Mesh,
             -> (top_vals [B,k], top_global_ids [B,k])
 
     ``top_global_ids`` encode (docs_shard, local_id) as shard * doc_cap + id;
-    the engine maps them back to document names.
+    the engine maps them back to document names. ``k`` is a SHARD's
+    depth; ``depth`` (None: ``k``) the merged reply's, for a request
+    deeper than one shard is wide.
 
     ``global_idf=False`` reproduces the reference's per-worker statistics
     (each Lucene shard scores against local df/N — ``Worker.java:222-241``)
@@ -285,7 +288,7 @@ def make_sharded_search(mesh: Mesh,
 
         all_vals = jax.lax.all_gather(vals, "docs")    # [D, B, k]
         all_ids = jax.lax.all_gather(gids, "docs")
-        top_vals, top_ids = merge_topk(all_vals, all_ids)
+        top_vals, top_ids = merge_topk(all_vals, all_ids, k=depth)
         return top_vals, top_ids
 
     sharded = jax.shard_map(

@@ -11,7 +11,7 @@ interpret-mode test and were refused here).
 Run as a script (``tests/test_kernel_compile.py`` does, in a subprocess:
 the compile-only client is process-global state); prints one JSON line
 ``{"failures": [...], "compiled": N, "cells": M, "mesh_cells": [...],
-"cell_digests": {...}, "stretches": [...]}``.
+"cell_digests": {...}, "stretches": [...], "deep_topk": {...}}``.
 """
 
 from __future__ import annotations
@@ -297,6 +297,35 @@ def compile_full_stretches(dev, B: int = 512) -> list[dict]:
     return out
 
 
+def compile_deep_topk(dev, k: int = 1000, B: int = 512) -> dict:
+    """``msmarco2m-top1000``'s top-k program: ``packed_topk_chunked`` at
+    the run file's depth over ``msmarco2m``'s six blocks. What the v5e
+    compiler made of it: the width of every row it sorts, whether a
+    shape of the straight route's two sorts a chunk (``[65536, 1024]``
+    rows cut to 1,000, then ``[512, 128000]``: PERF.md section 3) or a
+    copy of the score space is in its text, and its temporaries."""
+    from tfidf_tpu.ops.topk import packed_topk_chunked
+    sh = SingleDeviceSharding(dev)
+    blocks, doc_cap, _batches = CELL_STEPS["msmarco2m"]
+    rows = [r for r, _w in blocks]
+    program = packed_topk_chunked.lower(
+        tuple(jax.ShapeDtypeStruct((B, r), jnp.float32, sharding=sh)
+              for r in rows),
+        jax.ShapeDtypeStruct((len(rows),), jnp.int32, sharding=sh),
+        k=k).compile()
+    text = program.as_text()
+    sorts = re.findall(r"^\s*(?:ROOT )?%\S+ = \(?[a-z]+\d+\[(\d+),(\d+)\]"
+                       r"[^=]* sort\(", text, flags=re.M)
+    back = [shape for shape in (
+        f"[{B},{128 * k}]", f"[{B * 128},1024]", f"[{B * 128},{k}]",
+        f"f32[{B},{sum(rows) + 1}]", f"f32[{B},{doc_cap}]")
+        if shape in text]
+    return {"k": k, "B": B, "sorts": len(sorts), "back": back,
+            "widest_sort": max(int(n) for _b, n in sorts),
+            "whiles": len(re.findall(r" while\(", text)),
+            "temp_bytes": program.memory_analysis().temp_size_in_bytes}
+
+
 def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -336,9 +365,16 @@ def main() -> int:
     except Exception as e:
         failures.append(f"cell msmarco-full stretches: "
                         f"{type(e).__name__}: {str(e)[:600]}")
+    deep: dict = {}
+    try:
+        deep = compile_deep_topk(topo.devices[0])
+    except Exception as e:
+        failures.append(f"cell msmarco2m-top1000 top-k: "
+                        f"{type(e).__name__}: {str(e)[:600]}")
     print(json.dumps({"failures": failures, "compiled": compiled,
                       "cells": cells, "mesh_cells": mesh_cells,
-                      "cell_digests": digests, "stretches": stretches}))
+                      "cell_digests": digests, "stretches": stretches,
+                      "deep_topk": deep}))
     return 1 if failures else 0
 
 

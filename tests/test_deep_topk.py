@@ -33,8 +33,9 @@ from tfidf_tpu.cluster.node import SearchNode
 from tfidf_tpu.cluster.wire import pack_hit_lists, unpack_hit_lists
 from tfidf_tpu.engine import searcher as searcher_mod
 from tfidf_tpu.engine.engine import Engine
-from tfidf_tpu.ops.topk import (TOPK_CHUNK, packed_topk_chunked,
-                                unpack_topk)
+from tfidf_tpu.ops.topk import (TOPK_CHUNK, TOPK_SUBGROUP,
+                                packed_topk_chunked, topk_chunk_counts,
+                                topk_widths, unpack_topk)
 from tfidf_tpu.parallel.mesh import make_mesh
 from tfidf_tpu.utils.config import Config
 
@@ -71,17 +72,17 @@ QUERIES = {
 NAMES = list(QUERIES)
 
 
-def _make_docs():
+def _make_docs(crowds=CROWDS, edges=EDGES):
     rng = np.random.default_rng(38)
     p = 1.0 / np.arange(1, VOCAB - 3) ** 0.9
     docs, lengths = [], []
-    for count, n_terms in CROWDS:
+    for count, n_terms in crowds:
         for _ in range(count):
             ids = rng.choice(VOCAB - 4, size=n_terms, replace=False,
                              p=p / p.sum())
             docs.append({int(t): 1.0 for t in ids})
             lengths.append(LENGTHS[int(rng.integers(len(LENGTHS)))])
-    for d in EDGES:
+    for d in edges:
         # one term swapped for the edge term: the document's distinct
         # count, and so its block, stays
         del docs[d][max(docs[d])]
@@ -338,3 +339,55 @@ def test_hit_counters_say_how_full_the_depth_is(deep):
     assert after["hit_slots"] - before.get("hit_slots", 0) \
         == len(NAMES) * DEPTH
     assert after["hits_built"] - before.get("hits_built", 0) == sum(want)
+
+
+# ---- a corpus WIDE enough for the selection by candidates (PR 39): the
+# blocks above are one small sort each at any depth; 40,000 documents of
+# five terms fill a 65,536-row block whose 8,192 sub-groups of 8 rows
+# outnumber the depth eight times, so its top-1,000 goes by sub-group
+# maxima. Same vocabulary, same three lengths: plateaus of thousands.
+
+WIDE_CROWDS = [(300, 14), (40000, 5)]
+WIDE_EDGES = [298, 299, 300, 301]      # the edge plateau, across the blocks
+
+
+class Wide:
+    def __init__(self, tmp) -> None:
+        self.docs, self.lengths = _make_docs(WIDE_CROWDS, WIDE_EDGES)
+        self.ref = Bm25Reference(self.docs, self.lengths, vocab=VOCAB,
+                                 k1=K1, b=B_)
+        self.local = Engine(_config(tmp / "wide"))
+        _load(self.local, self.docs, self.lengths)
+        snap = self.local.index.snapshot
+        self.place = _places(snap.doc_names[:snap.num_names])
+
+
+@pytest.fixture(scope="module")
+def wide(tmp_path_factory):
+    return Wide(tmp_path_factory.mktemp("wide"))
+
+
+@pytest.mark.parametrize("door", ["search_batch", "search_arrays", "wire"])
+def test_depth_1000_by_candidates_equals_the_reference(wide, door):
+    """The one-chip doors over a block that takes the deep route: the
+    same 1,000 documents in the same order as the reference, the cut
+    inside a plateau of thousands, ``topk_chunks_grouped`` counting the
+    window."""
+    from tfidf_tpu.utils.metrics import global_metrics
+    snap = wide.local.index.snapshot
+    caps = [imp.shape[0] for imp in snap.ell_impacts]
+    assert caps == [512, 65536]
+    assert topk_widths(65536, 65536, DEPTH) == (TOPK_SUBGROUP,)
+    assert topk_chunk_counts(caps, snap.ell_live_host,
+                             k=DEPTH) == (2, 0, 1)
+    before = global_metrics.snapshot().get("topk_chunks_grouped", 0)
+    got = DOORS[door](wide, list(QUERIES.values()), DEPTH)
+    assert global_metrics.snapshot()["topk_chunks_grouped"] - before == 1
+    for name, hits in zip(NAMES, got):
+        _same(hits, [(f"d{d}", s) for d, s in wide.ref.run(
+            QUERIES[name], DEPTH, wide.place)])
+    assert [len(h) for h in got] == [DEPTH] * 6 + [4, 0, 0]
+    # the cut falls inside a plateau: rank 1,001 scores as rank 1,000
+    for name in NAMES[:2]:
+        run = wide.ref.run(QUERIES[name], DEPTH + 1, wide.place)
+        assert run[-1][1] == run[-2][1]

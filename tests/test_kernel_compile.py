@@ -6,8 +6,9 @@ buckets on every step of the tile schedule x widths from the ELL ladder
 incl. 12, the rungs past 256 on every doc tile they take, a mesh-split
 width of 1 and an odd one, plus the (4, 1) ``make_mesh_ell_search`` program, the served
 device step at the benchmark cells' shapes (held to the programs of the
-commit before the stretched step, by digest) and the stretches of
-``msmarco-full``'s step. Interpret-mode
+commit before the stretched step, by digest), the stretches of
+``msmarco-full``'s step and the 1,000-deep top-k of
+``msmarco2m-top1000``. Interpret-mode
 parity (``tests/test_kernel_parity.py``) cannot see what this sees: a
 kernel the interpreter runs happily and Mosaic rejects.
 """
@@ -120,3 +121,23 @@ def test_full_collection_stretches_compile_for_v5e(report):
     assert max(held) <= stretches[0]["budget"]
     # the score space of the whole step would be 15.1 GB
     assert sum(s["score_output_bytes"] for s in stretches) > 15e9
+
+
+def test_deep_topk_compiles_for_v5e_and_sorts_no_wide_row(report):
+    """``packed_topk_chunked(k=1000)`` over ``msmarco2m``'s six blocks at
+    B = 512, compiled for the v5e: the selection goes by candidates, so
+    no row it sorts is wider than 16,384 (the straight route sorted
+    ``[65536, 1024]`` and ``[512, 128000]`` a chunk, nineteen times a
+    call), nothing in its text has those shapes or is a copy of the
+    score space, no operation became a loop, and its temporaries (568 MB
+    as compiled here; the straight route's 951 MB) leave the step's peak
+    where the score program set it."""
+    assert not _failures(report, of_cells=True)
+    deep = report["deep_topk"]
+    print(f"deep top-k: {deep}")
+    assert (deep["k"], deep["B"]) == (1000, 512)
+    assert deep["sorts"] >= 9      # three windows by candidates, at least
+    assert deep["widest_sort"] <= 16384
+    assert deep["back"] == []
+    assert deep["whiles"] == 0
+    assert deep["temp_bytes"] < 700e6

@@ -19,9 +19,11 @@ from tfidf_tpu.engine.engine import Engine
 from tfidf_tpu.ops.csr import build_coo
 from tfidf_tpu.ops.ell import ell_scores_to_real, score_ell_batch
 from tfidf_tpu.ops.scoring import make_query_batch
-from tfidf_tpu.ops.topk import (TOPK_GROUP, exact_topk, packed_topk,
+from tfidf_tpu.ops.topk import (TOPK_CHUNK, TOPK_GROUP, TOPK_SORT_WIDTH,
+                                TOPK_SUBGROUP,
+                                exact_topk, merge_packed, packed_topk,
                                 packed_topk_chunked, topk_chunk_counts,
-                                topk_grouped, unpack_topk)
+                                topk_grouped, topk_widths, unpack_topk)
 from tfidf_tpu.utils.config import Config
 from tfidf_tpu.utils.metrics import global_metrics
 
@@ -240,6 +242,169 @@ def test_grouped_topk_is_bit_equal_to_lax_topk(rng, name):
     assert n_grouped == (n_chunks - skipped if grouped else 0)
 
 
+# ---- the deep selection (PR 39): the same step twice. A depth past 128
+# takes the block for its window, ranks the k groups' 16 k sub-groups of
+# 8 contiguous columns, then the 8 k columns of the k chosen ones; a
+# window whose groups do not outnumber the depth enters at the second
+# level. Shapes, not a knob, make each route: (B, cap, live, k, the
+# window's width, ``topk_widths`` of it). The chunk is the production
+# one throughout.
+
+S = TOPK_SUBGROUP
+TWO = (G, S)
+DEEP_SHAPES = {
+    # one 2^20-column block at the run file's depth: msmarco2m's
+    "block_2e20_k_1000": (8, 1 << 20, (1 << 20) - 885, 1000, 1 << 20, TWO),
+    # the narrowest block whose groups outnumber 130: 1,040 of them
+    "k_130_two_levels": (4, 1040 * G, 1040 * G - 3, 130, 1040 * G, TWO),
+    "k_200_live_inside_a_subgroup": (2, 1 << 18, 901 * G + 3 * S + 5, 200,
+                                     1 << 18, TWO),
+    "k_500_live_on_a_group_edge": (2, 1 << 19, 4001 * G, 500, 1 << 19,
+                                   TWO),
+    # a depth of whole lane tiles: each level keeps exactly k
+    "k_256_whole_tiles": (2, 1 << 19, (1 << 19) - 300, 256, 1 << 19, TWO),
+    "two_levels_fewer_live_than_k": (2, 1040 * G, 101, 130, 1040 * G, TWO),
+    "two_levels_live_0": (2, 1040 * G, 0, 130, 1040 * G, TWO),
+    "two_levels_B_1": (1, 1040 * G, 1040 * G, 130, 1040 * G, TWO),
+    "two_levels_B_12": (12, 1040 * G, 1039 * G + 77, 130, 1040 * G, TWO),
+    # a block of 2.5 windows: the last one's start is clamped, the
+    # columns it shares with the one before are masked
+    "two_levels_clamped_last_window": (1, 5 << 19, (5 << 19) - 2 * G - 1,
+                                       130, 1 << 21, TWO),
+    # groups do not outnumber the depth, sub-groups do: the second
+    # level alone (msmarco2m's 131,072-column block at 1,000)
+    "chunk_2e17_k_1000": (8, 1 << 17, 78448, 1000, 1 << 17, (S,)),
+    "k_64_subgroups_alone": (4, 1 << 15, (1 << 15) - 9, 64, 1 << 15, (S,)),
+    "k_40_live_inside_a_subgroup": (2, 1 << 15, 100 * G + 2 * S + 3, 40,
+                                    1 << 15, (S,)),
+    "subgroups_fewer_live_than_k": (2, 1 << 15, 37, 64, 1 << 15, (S,)),
+    "subgroups_clamped_last_chunk": (2, 5 << 16, (5 << 16) - G - 5, 1000,
+                                     1 << 17, (S,)),
+    "k_2000_chunks_of_a_block": (2, 1 << 18, (1 << 18) - 70, 2000,
+                                 1 << 17, (S,)),
+    # no route by candidates: a sort of the window is small, or the
+    # depth too deep for either level
+    "window_of_one_sort": (2, 1 << 14, (1 << 14) - 3, 64, 1 << 14, ()),
+    "k_5000": (1, 1 << 18, (1 << 18) - 3, 5000, 1 << 17, ()),
+}
+DEEP_DATA = ("random", "plateaus", "zeros")
+
+
+def deep_scores(rng, data, B, cap, c, k):
+    if data == "random":
+        return rng.random((B, cap), dtype=np.float32)
+    if data == "zeros":     # one tie: the first k live columns win
+        return np.zeros((B, cap), np.float32)
+    # three levels over 97% zeros, and plateaus of ONE higher value that
+    # straddle a sub-group's, a group's and the window's edges, fewer
+    # than k columns in all: each is in the answer, in column order
+    x = tied_scores(rng, B, cap)
+    for edge in (5 * S, 3 * G, 77 * G, c, cap - c, cap // 2):
+        x[:, max(edge - 3, 0):edge + 4] = 7.0
+    return x
+
+
+@pytest.mark.parametrize("data", DEEP_DATA)
+@pytest.mark.parametrize("name", sorted(DEEP_SHAPES))
+def test_deep_topk_is_bit_equal_to_lax_topk(rng, name, data):
+    B, cap, live, k, c, widths = DEEP_SHAPES[name]
+    n_windows = -(-cap // c)
+    assert topk_widths(cap, c, min(k, c)) == widths
+    assert topk_chunk_counts([cap], [live], k=k)[0] == n_windows
+    x = deep_scores(rng, data, B, cap, c, k)
+    x[:, live:] = DEAD
+    want_v, want_i = lax_topk_of_masked(x, 0, live, k)
+    got_v, got_i = unpack_topk(np.asarray(packed_topk_chunked(
+        (jnp.asarray(x),), jnp.asarray([live], jnp.int32), k=k)))
+    assert np.array_equal(got_v, want_v)
+    assert np.array_equal(got_i, want_i)
+    if live < k:    # the -inf lanes too: the masked columns, in order
+        assert np.all(np.isneginf(got_v[:, live:]))
+    _n, skipped, grouped = topk_chunk_counts([cap], [live], k=k)
+    assert grouped == (n_windows - skipped if widths else 0)
+
+
+def test_deep_topk_between_blocks_and_stretches(rng):
+    """A two-level block, a sub-group one, a straight one and one
+    narrower than the depth, every score one of three values: the merged
+    top-300 is the 300 lowest real rows of the highest value, whichever
+    block holds them, plateaus running across every block's edge; and
+    the same blocks as two stretches with ``base`` joined by
+    ``merge_packed``."""
+    k = 300
+    caps, live = (1 << 15, 2400 * G, 4096, 256), (30000, 2400 * G - 77,
+                                                  4000, 200)
+    assert [topk_widths(c, c, min(k, c)) for c in caps] == [
+        (S,), TWO, (), ()]
+    assert topk_chunk_counts(caps, live, k=k) == (4, 0, 2)
+    blocks, lives = synthetic_blocks(rng, caps, live, B=3, levels=3)
+    total = sum(live)
+    real = np.concatenate([np.asarray(b)[:, :n]
+                           for b, n in zip(blocks, live)], axis=1)
+    want_v, want_i = lax_topk_of_masked(real, 0, total, k)
+    got = np.asarray(packed_topk_chunked(blocks, lives, k=k))
+    got_v, got_i = unpack_topk(got)
+    assert np.array_equal(got_v, want_v)
+    assert np.array_equal(got_i, want_i)
+    parts = (packed_topk_chunked(blocks[:1], lives[:1], jnp.int32(0), k=k),
+             packed_topk_chunked(blocks[1:], lives[1:],
+                                 jnp.int32(live[0]), k=k))
+    assert np.array_equal(np.asarray(merge_packed(parts)), got)
+
+
+# the msmarco2m cell's blocks (a commit of its corpus) and wiki1m's
+MSMARCO2M = ((4096, 1048576, 1048576, 131072, 256, 256),
+             (3310, 1047691, 870316, 78448, 233, 2))
+ONE, SUB = (G,), (S,)
+# k -> (chunks, skipped, grouped) of a dispatch, and the route of the
+# 4096-, the 2^20- and the 131072-column block's window (the two
+# 256-column ones are straight at every depth). A WINDOW is a chunk of
+# 131,072 columns up to a depth of 128 and past 2,048; between them a
+# 2^20-column block is ONE window (8,192 groups outnumber a depth up to
+# 1,024) or, past that, eight chunks ranked by sub-groups alone.
+ROUTES = {
+    10: ((20, 1, 16), (), ONE, ONE),
+    64: ((20, 1, 16), (), ONE, ONE),
+    100: ((20, 1, 16), (), ONE, ONE),
+    128: ((20, 1, 16), (), ONE, ONE),
+    129: ((6, 0, 3), (), TWO, SUB),
+    500: ((6, 0, 3), (), TWO, SUB),
+    1000: ((6, 0, 3), (), TWO, SUB),
+    1024: ((6, 0, 3), (), TWO, SUB),
+    1025: ((20, 1, 16), (), SUB, SUB),
+    2000: ((20, 1, 16), (), SUB, SUB),
+    2048: ((20, 1, 16), (), SUB, SUB),
+    2049: ((20, 1, 0), (), (), ()),
+    5000: ((20, 1, 0), (), (), ()),
+}
+
+
+@pytest.mark.parametrize("k", sorted(ROUTES))
+def test_route_of_every_depth_on_msmarco2m_blocks(k):
+    """Host integers only: the route a depth takes over the cell's six
+    blocks, so that no depth falls between two routes. Up to 128 it is
+    the parent's program (the digests of ``tests/test_kernel_compile.py``
+    hold k = 10)."""
+    caps, live = MSMARCO2M
+    counts, *routes = ROUTES[k]
+    assert topk_chunk_counts(caps, live, k=k) == counts
+    windows = [min(cap, TOPK_CHUNK) for cap in (4096, 1 << 20, 1 << 17)]
+    if TWO in routes:
+        windows[1] = 1 << 20
+    assert [topk_widths(cap, c, min(k, c)) for cap, c in zip(
+        (4096, 1 << 20, 1 << 17), windows)] == routes
+    assert [topk_grouped(cap, c, min(k, c)) for cap, c in zip(
+        (4096, 1 << 20, 1 << 17), windows)] == [bool(r) for r in routes]
+    # what the route promises: no row it sorts is wider than this (each
+    # level keeps k in whole lane tiles)
+    kept = -(-k // G) * G
+    for c, route in zip(windows, routes):
+        if route:
+            rows = [c // route[0]] + [
+                kept * w // nxt for w, nxt in zip(route, route[1:] + (1,))]
+            assert max(rows) <= max(TOPK_SORT_WIDTH, 16 * kept)
+
+
 def test_grouped_topk_between_blocks_breaks_ties_to_the_lower_row(rng):
     """Two grouped blocks and a narrow one, every score one of two
     values: the merged top-10 of each query is its ten lowest real rows
@@ -301,12 +466,22 @@ def test_topk_chunk_counters_add_up_to_the_padded_space(tmp_path):
     assert topk_chunk_counts(
         (256, 524288, 1048576, 32768, 256),
         (201, 393204, 598231, 8311, 53), k=10) == (15, 4, 9)
-    # a caller's k in the thousands ranks every chunk straight; so does
-    # msmarco2m-top1000's depth (1,024 groups a chunk are under 8 x
-    # 1,000): 19 straight chunks, and the two 256-column blocks narrower
-    # than the depth (the pad lanes of ``_block_topk``)
-    assert topk_chunk_counts(caps, live, k=2000) == (20, 1, 0)
-    assert topk_chunk_counts(caps, live, k=1000) == (20, 1, 0)
-    assert not any(topk_grouped(c, min(c, 1 << 17), min(1000, c))
+    # at msmarco2m-top1000's depth a WINDOW is no longer a chunk: each
+    # 2^20-column block is one (its 8,192 groups outnumber the depth
+    # where a chunk's 1,024 do not: ranked at two levels), the
+    # 131,072-column block is one, ranked by its sub-groups of 8; three
+    # grouped windows of six, none skipped (a window that starts at 0
+    # is skipped only where its block is empty); the 4096-column block
+    # is one small sort, the two 256-column ones are narrower than the
+    # depth (the pad lanes of ``_block_topk``): straight
+    assert topk_chunk_counts(caps, live, k=1000) == (6, 0, 3)
+    assert [topk_grouped(c, c, min(1000, c)) for c in caps] == [
+        False, True, True, True, False, False]
+    # at 2,000 a block's groups no longer outnumber the depth but a
+    # chunk's 16,384 sub-groups do: windows are chunks again, ranked by
+    # sub-groups alone; past 2,048 every chunk goes straight
+    assert topk_chunk_counts(caps, live, k=2000) == (20, 1, 16)
+    assert topk_chunk_counts(caps, live, k=5000) == (20, 1, 0)
+    assert not any(topk_grouped(c, min(c, 1 << 17), min(5000, c))
                    for c in caps)
     assert [c for c in caps if c < 1000] == [256, 256]

@@ -572,9 +572,9 @@ class Searcher(QueryVectorizerMixin):
         """Enqueue the top-k over a stretch's score blocks (of every
         layout: a non-ELL chunk is one block); the packed ``[B, 2kk]``
         winners, still on the device."""
-        # the top-k's chunks over this padded score space, those of
-        # them wholly in dead tails, which it skips, and those it ranks
-        # by group maxima
+        # the top-k's windows over this padded score space (chunks; at
+        # a depth past 128 whole blocks), those of them wholly in dead
+        # tails, which it skips, and those it ranks by group maxima
         chunks, skipped, grouped = topk_chunk_counts(
             [blk.shape[1] for blk in blocks], live_host, k=kk)
         global_metrics.inc("topk_chunks", chunks)

@@ -9,11 +9,14 @@ re-top-k (associative, so it composes under ``all_gather``), and a
 parity testing.
 
 The serving top-k (:func:`packed_topk_chunked`) reads the scorer's blocks
-where they lie, in chunks, and ranks a chunk in two stages: one reduce to
-the maxima of groups of 128 contiguous columns, then only the ``k`` groups
-that can hold a winner (:func:`_chunk_topk`, with the proof that it is
-``lax.top_k``'s own answer, ties included). :func:`exact_topk`, the
-unchunked form the mesh's shards use, is ``lax.top_k`` itself.
+where they lie, in windows, and ranks a window by its candidates: one
+reduce to the maxima of groups of 128 contiguous columns, then only the
+``k`` groups that can hold a winner, and at a depth past 128 the same step
+again inside them, on sub-groups of 8 (:func:`_chunk_topk`, with the proof
+that it is ``lax.top_k``'s own answer, ties included;
+:func:`topk_widths`, the route a shape and a depth take).
+:func:`exact_topk`, the unchunked form the mesh's shards use, is
+``lax.top_k`` itself.
 """
 
 from __future__ import annotations
@@ -128,19 +131,64 @@ def unpack_topk(packed) -> tuple:
 
 
 TOPK_GROUP = 128        # columns a group: one lane tile
+TOPK_SUBGROUP = 8       # columns a sub-group: the second level's width
+TOPK_SORT_WIDTH = 1 << 14   # the widest row a deep selection sorts
+
+
+def topk_widths(cap: int, c: int, k: int) -> tuple[int, ...]:
+    """The group widths, level by level, by which the top-``k`` of a
+    ``c``-column window of a ``cap``-column array is selected
+    (:func:`_chunk_topk`); ``()``: straight through ``lax.top_k``.
+
+    * ``(TOPK_GROUP,)`` where the window holds at least eight times
+      ``k`` groups, so that the ``k`` groups it ranks are well under it,
+      and groups are whole lane tiles of the array;
+    * ``(TOPK_GROUP, TOPK_SUBGROUP)`` where those ``k`` groups' columns
+      are more than ``TOPK_SORT_WIDTH`` (a ``k`` past 128): the same
+      step again inside them, sixteen sub-groups a group, of which
+      ``k`` (in whole lane tiles) are ranked;
+    * ``(TOPK_SUBGROUP,)`` where the window's groups do not outnumber
+      the depth but its sub-groups do, and the window is wider than
+      ``TOPK_SORT_WIDTH`` (a narrower one is one small sort: straight).
+
+    Every row a deep selection sorts is thereby ``TOPK_SORT_WIDTH`` wide
+    or less, or sixteen times ``k`` in whole lane tiles if that is more.
+    Static shapes and ``k`` alone: the device path and
+    :func:`topk_chunk_counts` share it, so the host's count is the
+    device's."""
+    g, w = TOPK_GROUP, TOPK_SUBGROUP
+    if cap % g or c % g:
+        return ()
+    if c // g >= 8 * k:
+        return (g, w) if k * g > TOPK_SORT_WIDTH else (g,)
+    if c > TOPK_SORT_WIDTH and c // w >= 8 * k:
+        return (w,)
+    return ()
 
 
 def topk_grouped(cap: int, c: int, k: int) -> bool:
     """Whether the top-``k`` of a ``c``-column window of a ``cap``-column
-    array goes by group maxima (:func:`_chunk_topk`): where the window
-    holds at least eight times ``k`` groups, so that the ``k`` groups it
-    ranks are well under it, and groups are whole lane tiles of the
-    array. A block narrower than that, or a caller's ``k`` in the
-    thousands, goes straight through ``lax.top_k``. Static shapes only:
-    the device path and :func:`topk_chunk_counts` share it, so the
-    host's count is the device's."""
-    g = TOPK_GROUP
-    return cap % g == 0 and c % g == 0 and c // g >= 8 * k
+    array goes by group maxima, at one level or two
+    (:func:`topk_widths`), and not straight through ``lax.top_k``."""
+    return bool(topk_widths(cap, c, k))
+
+
+def _top_by(vals: jax.Array, ids: jax.Array,
+            k: int) -> tuple[jax.Array, jax.Array]:
+    """The ``k`` largest of each row of ``vals`` with their ``ids``
+    (distinct within a row): by value descending, then by id ascending
+    -- ``lax.top_k``'s order wherever ids ascend with position, in
+    whatever order the operands lie. The ids ride the sort as its SECOND
+    KEY: a stable sort on the value alone carries the positions for a
+    third operand and takes twice the time (11.0 against 6.0 ms for
+    ``[512, 16000]`` on the v5e), and a gather by ``lax.top_k``'s
+    positions takes as long again as the sort (PERF.md section 6,
+    PR 39). Nor does the deep selection lean on ``lax.top_k`` for a tie:
+    where the v5e's compiler splits a wide row it returns equal values
+    in either order of their columns (same section)."""
+    nv, ni = jax.lax.sort((-vals, ids), dimension=1, num_keys=2,
+                          is_stable=False)
+    return -nv[:, :k], ni[:, :k]
 
 
 def _chunk_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
@@ -149,7 +197,7 @@ def _chunk_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
     """Exact top-``k`` of the ``c`` columns of ``x`` from ``off``, those at
     or past ``live`` masked to -inf: values and columns of ``x``, ties to
     the lower column, as ``lax.top_k`` of the masked chunk gives them.
-    Where :func:`topk_grouped` says so, in two stages:
+    Where :func:`topk_widths` says so, by group maxima:
 
     1. the maximum of each group of ``TOPK_GROUP`` CONTIGUOUS columns:
        the only pass over the chunk, a plain reduce with the mask fused
@@ -170,6 +218,22 @@ def _chunk_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
     groups would not carry this: a tie between two groups' maxima says
     nothing about the order of their columns. Scores that are exactly
     equal are the common case here: zeros, equal tf and length.)
+
+    A deep ``k`` takes the step TWICE (:func:`_subgroup_topk`): 128
+    columns a chosen group are 128,000 a query at ``k`` = 1,000, a sort
+    that costs what the chunk's own did. The candidates are cut into
+    sub-groups of ``TOPK_SUBGROUP`` contiguous columns, and the proof
+    above holds of them word for word with "the candidates" for "the
+    chunk": an element of a sub-group not chosen is beaten by one
+    element of each of ``k`` that were, on value or on the lower column.
+    What is ranked at the end is ``8 * k`` columns. A window whose
+    groups do not outnumber ``k`` but whose sub-groups do enters at the
+    second level, with every one of its groups for a candidate. At
+    depth the groups, the sub-groups and the columns are ranked by
+    :func:`_top_by`, value then NAME, so their order in memory is free
+    and no tie hangs on ``lax.top_k``; and each level keeps ``k``
+    rounded up to whole lane tiles (:func:`_whole_tiles`: keeping more
+    is as exact, the proof wants at least ``k``).
 
     The chunk is a ``dynamic_slice``, NOT a ``[B, n, c]``
     reshape+transpose: that would materialize a second copy of the
@@ -194,36 +258,98 @@ def _chunk_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
 
     masked = mask(jax.lax.dynamic_slice_in_dim(x, start, c, axis=1),
                   jnp.arange(c, dtype=jnp.int32)[None, :] + start)
-    if not topk_grouped(cap, c, k):
+    widths = topk_widths(cap, c, k)
+    if not widths:
         v, i = jax.lax.top_k(masked, k)
         return v, i.astype(jnp.int32) + start
     s = math.gcd(B, 8)      # rows a sublane tile
+
+    def group_columns(grp):
+        """The columns of groups ``grp`` ``[B, n]`` of ``x``, masked:
+        ``[B, n, g]``, one lane tile a group."""
+        tiles = x.reshape(B // s, s, cap // g, g).transpose(0, 2, 1, 3)
+        row = jax.lax.broadcasted_iota(jnp.int32, grp.shape, 0)
+        cand = jax.lax.gather(
+            tiles, jnp.stack([row // s, grp, row % s], axis=-1),
+            jax.lax.GatherDimensionNumbers(
+                offset_dims=(2,), collapsed_slice_dims=(0, 1, 2),
+                start_index_map=(0, 1, 2)),
+            (1, 1, 1, g), mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+        return mask(cand,
+                    grp[:, :, None] * g + jnp.arange(g, dtype=jnp.int32))
+
+    def every_group():      # the window's groups by name: [B, c / g]
+        return jnp.broadcast_to(
+            jnp.arange(c // g, dtype=jnp.int32) + start // g, (B, c // g))
+
+    if widths[0] != g:      # every group of the window is a candidate
+        return _subgroup_topk(masked.reshape(B, c // g, g), every_group(),
+                              group_columns, k)
     gmax = masked.reshape(B // s, s, c // g, g).max(-1).reshape(B, c // g)
+    if len(widths) > 1:
+        _, grp = _top_by(gmax, every_group(), _whole_tiles(k))
+        return _subgroup_topk(group_columns(grp), grp, group_columns, k)
     _, grp = jax.lax.top_k(gmax, k)
     grp = jnp.sort(grp.astype(jnp.int32), axis=-1) + start // g    # [B, k]
-    tiles = x.reshape(B // s, s, cap // g, g).transpose(0, 2, 1, 3)
-    row = jax.lax.broadcasted_iota(jnp.int32, (B, k), 0)
-    cand = jax.lax.gather(
-        tiles, jnp.stack([row // s, grp, row % s], axis=-1),
-        jax.lax.GatherDimensionNumbers(
-            offset_dims=(2,), collapsed_slice_dims=(0, 1, 2),
-            start_index_map=(0, 1, 2)),
-        (1, 1, 1, g), mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
-    cand = mask(cand, grp[:, :, None] * g + jnp.arange(g, dtype=jnp.int32))
-    v, p = jax.lax.top_k(cand.reshape(B, k * g), k)
+    v, p = jax.lax.top_k(group_columns(grp).reshape(B, k * g), k)
     # a winner's column, through its group: a [B, k, k] select, no gather
     slot = (p // g)[:, :, None] == jnp.arange(k, dtype=jnp.int32)
     return v, jnp.sum(jnp.where(slot, grp[:, None, :], 0), -1) * g + p % g
 
 
+def _whole_tiles(k: int) -> int:
+    """``k`` rounded up to whole lane tiles: how many groups, and how
+    many sub-groups, a deep selection keeps for a depth of ``k`` (more
+    than ``k`` is as exact). ``[B, n, 128]`` candidates reduce to their
+    ``[B, n, 16]`` sub-group maxima in one fusion behind one transposing
+    copy where ``n`` is whole tiles; at ``n`` = 1,000 the v5e's compiler
+    re-tiles them first, at four times the copy's cost (36.8 against
+    29.8 ms a 2^20-column block: PERF.md section 6, PR 39)."""
+    return -(-k // TOPK_GROUP) * TOPK_GROUP
+
+
+def _subgroup_topk(cand: jax.Array,    # f32 [B, n, g], masked
+                   grp: jax.Array,     # i32 [B, n], their groups of x
+                   group_columns, k: int) -> tuple[jax.Array, jax.Array]:
+    """The second level of :func:`_chunk_topk`: the top ``k`` of the
+    columns ``cand`` of groups ``grp``, with their columns in the block.
+    Maxima of the ``n * 16`` sub-groups, the ``k`` of them (in whole
+    tiles) that can hold a winner, their lane tiles fetched again
+    (``group_columns``) and all but the sub-group's eight lanes dropped
+    by a masked maximum over the tile's sixteen sub-groups; sub-groups
+    and columns ride the sorts by name (:func:`_top_by`)."""
+    B, n, g = cand.shape
+    w = TOPK_SUBGROUP
+    m = g // w
+    kk = _whole_tiles(k)
+    each = jnp.arange(m, dtype=jnp.int32)
+    _, sub = _top_by(cand.reshape(B, n, m, w).max(-1).reshape(B, n * m),
+                     (grp[:, :, None] * m + each).reshape(B, n * m), kk)
+    lane = jnp.arange(g, dtype=jnp.int32)       # sub: [B, kk] sub-groups of x
+    fine = jnp.where(lane // w == (sub % m)[:, :, None],
+                     group_columns(sub // m), -jnp.inf)
+    fine = fine.reshape(B, kk, m, w).max(2)     # [B, kk, w]
+    col = sub[:, :, None] * w + jnp.arange(w, dtype=jnp.int32)
+    return _top_by(fine.reshape(B, kk * w), col.reshape(B, kk * w), k)
+
+
 TOPK_CHUNK = 1 << 17    # doc columns one step of the scan sees
 
 
-def _chunk_starts(cap: int, chunk: int) -> tuple[int, list[int]]:
-    """``(width, nominal chunk starts)`` of a ``cap``-column block. The
-    device scan and :func:`topk_chunk_counts` share it, so the host's
-    count of skipped chunks is the device's."""
+def _chunk_starts(cap: int, chunk: int, k: int) -> tuple[int, list[int]]:
+    """``(width, nominal window starts)`` of a ``cap``-column block for
+    the top ``k``. A window is a chunk, except where a chunk's groups do
+    not outnumber the depth and a wider window's do: then it is the
+    block, up to ``TOPK_GROUP * TOPK_SORT_WIDTH`` columns (the chunks
+    bound ``lax.top_k``'s temporaries; a reduce to ``[B, c / 128]``
+    maxima needs no such bound). The device scan and
+    :func:`topk_chunk_counts` share it, so the host's count of skipped
+    windows is the device's."""
     c = min(chunk, cap)
+    wide = min(cap, TOPK_GROUP * TOPK_SORT_WIDTH)
+    if (wide > c and TOPK_GROUP not in topk_widths(cap, c, min(k, c))
+            and TOPK_GROUP in topk_widths(cap, wide, min(k, wide))):
+        c = wide
     return c, [j * c for j in range(-(-cap // c))]   # ceil: tail is clamped
 
 
@@ -232,13 +358,16 @@ def topk_chunk_counts(block_caps, block_live, chunk: int = TOPK_CHUNK,
     """``(chunks, skipped, grouped)`` of one :func:`packed_topk_chunked`
     call for the top ``k`` over blocks of ``block_caps`` columns holding
     ``block_live`` live ones — host integers only (what a commit already
-    knows), no device read. A chunk is skipped when it starts at or past
-    its block's live count; of the others, those wide enough for
-    :func:`topk_grouped` go by group maxima and the rest straight through
+    knows), no device read. What is counted is a WINDOW of
+    :func:`_chunk_starts`: a chunk of ``chunk`` columns, or at a depth
+    past 128 a whole block of up to 2,097,152 (one count, however many
+    chunks it spans). A window is skipped when it starts at or past its
+    block's live count; of the others, those :func:`topk_grouped` go by
+    group maxima, at one level or two, and the rest straight through
     ``lax.top_k``."""
     total = skipped = grouped = 0
     for cap, live in zip(block_caps, block_live):
-        c, starts = _chunk_starts(int(cap), chunk)
+        c, starts = _chunk_starts(int(cap), chunk, k)
         dead = sum(off >= int(live) for off in starts)
         total += len(starts)
         skipped += dead
@@ -250,10 +379,10 @@ def topk_chunk_counts(block_caps, block_live, chunk: int = TOPK_CHUNK,
 def _block_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
                 live: jax.Array,   # i32 scalar — its live columns (TRACED)
                 *, k: int, chunk: int) -> tuple[jax.Array, jax.Array]:
-    """Per-chunk winners of one block: ``[n, B, k]`` values and their
+    """Per-window winners of one block: ``[n, B, k]`` values and their
     columns IN THE BLOCK, columns at or past ``live`` masked to -inf."""
     B, cap = x.shape
-    c, starts = _chunk_starts(cap, chunk)
+    c, starts = _chunk_starts(cap, chunk, k)
     kc = min(k, c)
 
     def scan_chunk(off):
@@ -287,7 +416,7 @@ def packed_topk_chunked(scores, num_docs: jax.Array,
                         base: jax.Array | None = None,
                         *, k: int, chunk: int = TOPK_CHUNK) -> jax.Array:
     """:func:`packed_topk` over score BLOCKS, read where the scorer wrote
-    them, the doc axis scanned in chunks.
+    them, the doc axis scanned in windows.
 
     ``scores`` is a tuple of ``[B, cap_i]`` blocks and ``num_docs`` their
     ``[n_blocks]`` live counts (traced): block ``i`` holds real rows
@@ -300,22 +429,29 @@ def packed_topk_chunked(scores, num_docs: jax.Array,
     the first block's first column where ``scores`` is a STRETCH of a
     longer block list: the live rows of the blocks before it
     (:func:`merge_packed` joins the stretches). Block-then-column order
-    IS real-row order, a chunk's top-k (:func:`_chunk_topk`) breaks ties
+    IS real-row order, a window's top-k (:func:`_chunk_topk`) breaks ties
     toward the lower column and :func:`merge_topk` toward the earlier
-    chunk, so ties resolve to the lower document id whatever the
+    window, so ties resolve to the lower document id whatever the
     blocking.
 
-    A chunk wide enough is read ONCE, by a reduce to its group maxima,
-    and only the ``k`` groups that can hold a winner are ranked
-    (:func:`topk_grouped`); a narrow block goes straight through
-    ``lax.top_k``, whose k-deep selection over every column costs ten
-    times the read. Either way the chunks bound the temporaries at
-    O(B * chunk / 128) — ``lax.top_k`` over a whole [B, doc_cap] row
-    allocates value+index temporaries proportional to its input — and
-    per-chunk winners merge exactly (the global top-k is contained in
-    the union of chunk top-ks). A chunk that starts at or past its
-    block's live count is SKIPPED (the padded space is up to 1.5x the
-    live one; :func:`topk_chunk_counts`).
+    A window wide enough is read ONCE, by a reduce to its group maxima,
+    and only the ``k`` groups that can hold a winner are ranked, at a
+    depth past 128 by their sub-groups' maxima first
+    (:func:`topk_widths`): what is sorted follows the candidates, tens
+    of ``k`` a query, and not the columns. A narrow block goes straight
+    through ``lax.top_k``, as does a depth past 2,048, whose k-deep
+    selection over every column costs ten times the read at ``k`` = 10
+    and three hundred times at 1,000. A window is a chunk of ``chunk``
+    columns — the chunks bound ``lax.top_k``'s temporaries, which are
+    proportional to its input, and the group maxima's at O(B * chunk /
+    128) — or, where only a wider one's groups outnumber the depth, the
+    block (:func:`_chunk_starts`): the reduce's output is ``[B, cap /
+    128]``, and the candidates' ``[B, k, 128]`` follows ``k``, 262 MB at
+    B = 512 and ``k`` = 1,000, held twice. Per-window winners merge
+    exactly (the global top-k is contained in the union of the windows'
+    top-ks). A window that starts at or past its block's live count is
+    SKIPPED (the padded space is up to 1.5x the live one;
+    :func:`topk_chunk_counts`).
     """
     with jax.named_scope("topk_chunked"):
         blocks = scores if isinstance(scores, (tuple, list)) else (scores,)

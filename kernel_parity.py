@@ -15,7 +15,10 @@ the stretched step (``run_stretch_case``): three blocks scored and ranked
 a block at a time and merged, against the one program pair over all
 three, ties on the stretch edges included; and the matrix's last kernel
 case is the TRAP the A-build's order is held by (``run_trap_case``: a
-live term 0 before trailing ``term 0`` pads, bit-equal to the oracle).
+live term 0 before trailing ``term 0`` pads, bit-equal to the oracle);
+and one of the MESH step on whole documents (``run_mesh_case``: a 512-
+and a 384-wide bucket a shard inside ``shard_map``, the kernel against
+``_score_block`` in the same step, both weight kinds).
 ``python kernel_parity.py --against <checkout>`` also runs every kernel
 case on the kernel of ANOTHER checkout's ``tfidf_tpu/ops/ell.py`` (a
 parent commit unpacked under a directory ``.gitignore`` lists) and
@@ -352,6 +355,78 @@ def run_stretch_case(rng, *, rows_cap, width, B, n_blocks, last_live,
             "packed_equal": equal, "tie_straddles_edge": tied, "ok": ok}
 
 
+MESH_CASE = dict(docs=12_000, B=512, u_req=1024)
+# interpreted: the two wide buckets alone ride the kernel (the empty
+# narrow ones stay at 8 rows, on the XLA path: a twelfth of the compile)
+MESH_INTERPRET_CASE = dict(docs=2_400, B=16, u_req=256, min_rows=8)
+
+
+def run_mesh_case(rng, *, docs, B, u_req, vocab=500_000, devices=None,
+                  min_rows=256):
+    """The mesh step on whole documents: ``docs`` documents of 257 ...
+    512 distinct terms committed by ``MeshEllIndex`` over ``devices``
+    (None: every attached one) as a (D, 1) mesh (each shard a 512- and a
+    384-wide bucket,
+    the ten narrower ones empty at ``min_rows``, no residual), then
+    ``make_mesh_ell_search`` with the kernel against the same step with
+    ``_score_block`` in its place, once with fractional weights and once
+    with multiplicities: the merged top-10's scores within 1e-4 and its
+    documents identical."""
+    from tfidf_tpu.models.base import get_model
+    from tfidf_tpu.parallel.mesh import make_mesh
+    from tfidf_tpu.parallel.mesh_ell import make_mesh_ell_search
+    from tfidf_tpu.parallel.mesh_ell_index import MeshEllIndex
+
+    D = len(devices or jax.devices())
+    index = MeshEllIndex(get_model("bm25", k1=0.9, b=0.4),
+                         mesh=make_mesh((D, 1), devices=devices),
+                         min_doc_cap=min_rows)
+    rows = []
+    for i in range(docs):
+        ids = np.unique(rng.integers(0, vocab, 560))[
+            :rng.integers(257, 513)].astype(np.int32)
+        tfs = rng.integers(1, 6, ids.shape[0]).astype(np.float32)
+        index.add_document_arrays(f"d{i}", ids, tfs)
+        rows.append(ids)
+    snap = index.commit(vocab)
+    shapes = [tuple(a.shape) for a in snap.base.impact]
+    wide = [s[2] for s in shapes if s[2] > 256]
+    eligible = all(_pallas_eligible(s[1], B, u_req) for s in shapes[:2])
+    steps = [make_mesh_ell_search(index.mesh, k=TOP_K, model="bm25", k1=0.9,
+                                  b=0.4, use_pallas=use)
+             for use in (True, False)]
+    out = {"name": "mesh", "devices": D, "docs": docs, "B": B,
+           "buckets": [list(s[1:]) for s in shapes[:2]],
+           "residual_nnz": snap.res_nnz, "weights": {}}
+    ok = wide == [512, 384] and eligible and snap.res_nnz == 0
+    for kind in ("fractional", "multiplicity"):
+        q_terms = np.zeros((B, 8), np.int32)
+        q_weights = np.zeros((B, 8), np.float32)
+        for i in range(B):
+            k = rng.integers(1, 5)
+            q_terms[i, :k] = rng.choice(rows[rng.integers(0, docs)], k,
+                                        replace=False)
+            q_weights[i, :k] = (rng.integers(1, 4, size=k)
+                                if kind == "multiplicity"
+                                else 1.0 + rng.random(k, dtype=np.float32))
+        qb = make_query_batch(q_terms, q_weights, min_slots=u_req)
+        assert bool(bf16_exact(qb.weights)) == (kind == "multiplicity")
+        (vals, gids), (ref_vals, ref_gids) = (
+            tuple(np.asarray(x) for x in step(
+                snap.base, snap.delta, snap.df_g, snap.n_docs, snap.avgdl,
+                qb)) for step in steps)
+        delta = float(np.max(np.abs(vals - ref_vals)))
+        same = bool(np.array_equal(gids, ref_gids))
+        found = bool((vals[:, 0] > 0).all())
+        out["weights"][kind] = {"max_abs_delta": delta,
+                                "topk_identical": same}
+        ok = ok and delta < 1e-4 and same and found
+        log(f"[mesh {kind}] devices={D} buckets={out['buckets']} "
+            f"max|d|={delta:.2e} topk={same}")
+    out["ok"] = ok
+    return out
+
+
 # the hardware matrix: north-star-like shapes + every eligibility edge
 # (the tier-1 interpret run uses scaled-down shapes of the same edges)
 CASES = [
@@ -429,8 +504,9 @@ def run_matrix(seed: int = 7, against=None) -> dict:
     Mosaic program, ``INTERPRET_CASES`` where it is interpreted; each
     with fractional weights (``caseN``) and with multiplicities
     (``caseN-mult``), then the pad trap (``trap``), the last of
-    ``cases``; then the top-k's one case (``topk``) and the stretched
-    step's (``stretch``). ``against``: another checkout's kernel
+    ``cases``; then the top-k's one case (``topk``), the stretched
+    step's (``stretch``) and, on the chip, the mesh step's on whole
+    documents (``mesh``). ``against``: another checkout's kernel
     (:func:`kernel_of`), which every kernel case must then equal bit
     for bit."""
     rng = np.random.default_rng(seed)
@@ -445,6 +521,10 @@ def run_matrix(seed: int = 7, against=None) -> dict:
                                  else TOPK_CASE))
     stretch = run_stretch_case(rng, **(STRETCH_INTERPRET_CASE if interpret
                                        else STRETCH_CASE))
+    # on the chip only: interpreted, it is ``tests/test_kernel_parity.py``'s
+    # own case, and a rehearsal of ``chip_smoke.py`` need not pay for it
+    mesh = {"ok": True, "skipped": "interpreted"} if interpret \
+        else run_mesh_case(rng, **MESH_CASE)
     dev = jax.devices()[0]
     out = {
         "backend": jax.default_backend(),
@@ -452,10 +532,11 @@ def run_matrix(seed: int = 7, against=None) -> dict:
         "device_kind": dev.device_kind,
         "jax": jax.__version__,
         "all_ok": all(r["ok"] for r in results) and topk["ok"]
-        and stretch["ok"],
+        and stretch["ok"] and mesh["ok"],
         "cases": results,
         "topk": topk,
         "stretch": stretch,
+        "mesh": mesh,
     }
     if against is not None:
         out["bit_equal_against"] = sum(r["bit_equal_against"]

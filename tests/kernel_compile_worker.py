@@ -81,9 +81,11 @@ def compile_block(dev, rows: int, width: int, B: int,
 # The mesh cells: configuration -> the batch buckets its cell dispatches.
 # The committed snapshot's shapes per docs-shard of the (4, 1) mesh are
 # the configuration file's ``layout.shard_blocks`` (rows_cap of each
-# ELL_WIDTHS bucket, the same for every seed:
-# tests/test_mesh_block_capacities.py).
-MESH_CELL_STEPS = {"msmarco4m-mesh": (128, 256, 512)}
+# bucket of ``mesh_ell_widths``, the same for every seed:
+# tests/test_mesh_block_capacities.py). ``msmarco-doc-mesh``: whole
+# documents, so two buckets past 256 (512, 384) before the ten.
+MESH_CELL_STEPS = {"msmarco4m-mesh": (128, 256, 512),
+                   "msmarco-doc-mesh": (512,)}
 
 
 def compile_mesh_step(devices) -> list[dict]:
@@ -92,13 +94,14 @@ def compile_mesh_step(devices) -> list[dict]:
     virtual CPU devices, then every array is replaced by its shape on
     the same mesh of v5e devices — and then by the shapes of the mesh
     cell's snapshot (``MESH_CELL_STEPS``), whose per-device
-    ``memory_analysis()`` is returned, a dict a batch bucket."""
+    ``memory_analysis()`` and :func:`program_digest` are returned, a
+    dict a batch bucket."""
     import dataclasses
 
     from tfidf_tpu.engine import Engine
     from tfidf_tpu.ops.scoring import QueryBatch
-    from tfidf_tpu.parallel.mesh_ell import (ELL_WIDTHS,
-                                             make_mesh_ell_search)
+    from tfidf_tpu.parallel.mesh_ell import (make_mesh_ell_search,
+                                             mesh_ell_widths)
     from tfidf_tpu.utils.config import Config
 
     engine = Engine(Config(engine_mode="mesh", query_batch=32,
@@ -126,8 +129,8 @@ def compile_mesh_step(devices) -> list[dict]:
     make_mesh_ell_search(tpu_mesh, k=10,
                          packed=True).lower(*args).compile()
 
-    def each(arrays, shapes):
-        return tuple(abstract(a, shape) for a, shape in zip(arrays, shapes))
+    def each(like, shapes):
+        return tuple(abstract(like, shape) for shape in shapes)
 
     out = []
     for name, batches in MESH_CELL_STEPS.items():
@@ -135,16 +138,19 @@ def compile_mesh_step(devices) -> list[dict]:
                 os.path.abspath(__file__))), "benchmarks", "configs",
                 name + ".json")) as f:
             shard = json.load(f)["layout"]["shard_blocks"]
-        assert tuple(shard["widths"]) == ELL_WIDTHS
+        widths = tuple(shard["widths"])
+        assert widths == mesh_ell_widths(widths[0])
         rows, doc_cap = shard["rows"], shard["doc_cap"]
         vocab_cap = shard["vocab_cap"]
-        blocks = tuple([4, r, w] for r, w in zip(rows, ELL_WIDTHS))
+        blocks = tuple([4, r, w] for r, w in zip(rows, widths))
         by_rows = tuple([4, r] for r in rows)
         base = dataclasses.replace(
             jax.tree.map(abstract, snap.base),
-            tf=each(snap.base.tf, blocks), term=each(snap.base.term, blocks),
-            impact=each(snap.base.impact, blocks),
-            dl=each(snap.base.dl, by_rows),
+            tf=each(snap.base.tf[0], blocks),
+            term=each(snap.base.term[0], blocks),
+            impact=each(snap.base.impact[0], blocks),
+            dl=each(snap.base.dl[0], by_rows),
+            block_live=abstract(snap.base.block_live, [4, len(widths)]),
             live=abstract(snap.base.live, [4, doc_cap]),
             res_dl=abstract(snap.base.res_dl, [4, doc_cap]),
             doc_cap=doc_cap)
@@ -166,6 +172,9 @@ def compile_mesh_step(devices) -> list[dict]:
                 and "all-gather" in text, "kernels or gather missing"
             m = step.memory_analysis()
             out.append({"cell": name, "B": B,
+                        "digest": program_digest(step),
+                        "kernels": sorted(set(re.findall(
+                            r"ell_score_v4_w(\d+)", text)), key=int),
                         "temp_bytes": m.temp_size_in_bytes,
                         "argument_bytes": m.argument_size_in_bytes,
                         "output_bytes": m.output_size_in_bytes})

@@ -76,13 +76,16 @@ def test_cell_and_configuration_entries(bench):
     cell, = [w for w in bench["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) \
         == ("msmarco2m-top1000", "batch", 1)
-    # new entries go at the end of their lists
-    assert bench["configs"][-1] is cfg and bench["workloads"][-1] is cell
+    # new entries went at the end of their lists: right behind the six
+    # configurations and seven cells that PR 38 found (later PRs append)
+    assert bench["configs"].index(cfg) == 5
+    assert bench["workloads"].index(cell) == 7
 
 
 def test_every_batch_metric_of_the_control_lists_the_cell(bench):
     e2e = {m["name"]: m for m in bench["end_to_end"]}
-    assert e2e["batch_qps"]["workloads"][-1] == CELL
+    assert e2e["batch_qps"]["workloads"].index(CELL) \
+        == e2e["batch_qps"]["workloads"].index(CONTROL) + 1
     assert "workloads" not in e2e["setup_s"]        # every cell's
     listed = {m["name"] for m in bench["per_layer"]
               if CONTROL in m.get("workloads", ())}

@@ -4,7 +4,9 @@ Runs ``tests/kernel_compile_worker.py`` (compile-only Mosaic through
 libtpu's topology client — no chip, seconds) in a subprocess: batch
 buckets on every step of the tile schedule x widths from the ELL ladder
 incl. 12, the rungs past 256 on every doc tile they take, a mesh-split
-width of 1 and an odd one, plus the (4, 1) ``make_mesh_ell_search`` program, the served
+width of 1 and an odd one, plus the (4, 1) ``make_mesh_ell_search``
+program at the two mesh cells' shapes (``msmarco4m-mesh``'s held to the
+parent's by digest), the served
 device step at the benchmark cells' shapes (held to the programs of the
 commit before the stretched step, by digest), the stretches of
 ``msmarco-full``'s step and the 1,000-deep top-k of
@@ -71,6 +73,42 @@ def test_mesh_cell_step_compiles_for_v5e(report, B):
     assert len(mine) == 1, report["mesh_cells"]
     print(f"mesh step memory_analysis, B={B}: {mine[0]}")
     assert mine[0]["temp_bytes"] + mine[0]["argument_bytes"] < 8e9
+    # no row past 256 distinct terms: the ten buckets, and the program
+    # of the commit before the mesh had wider ones (a193a7b, PR 39's),
+    # instruction for instruction (``program_digest``)
+    assert mine[0]["kernels"] == ["8", "16", "24", "32", "48", "64", "96",
+                                  "128", "192", "256"]
+    assert mine[0]["digest"] == PARENT_MESH_STEP_DIGESTS[B]
+
+
+# ``program_digest`` of ``msmarco4m-mesh``'s step, compiled for v5e:2x2
+# from commit a193a7b (PR 39's, the parent of the PR that took the
+# mesh's buckets from the one ladder): passages fill no bucket past
+# 256, so that cell goes on running exactly these. A PR that MEANS to
+# change the mesh step reads the new digests off
+# ``python tests/kernel_compile_worker.py`` (``mesh_cells``).
+PARENT_MESH_STEP_DIGESTS = {128: "ac4051463eac3b17",
+                            256: "3a83d306e0c95e20",
+                            512: "168da395b36d85c8"}
+
+
+def test_doc_mesh_cell_step_compiles_for_v5e(report):
+    """``msmarco-doc-mesh``'s step: the (4, 1) program at the twelve
+    per-shard buckets of its ``layout.shard_blocks`` and the one batch
+    bucket its cell dispatches. The v5e compiler accepts the kernel at
+    384 and 512 wide inside ``shard_map`` (VMEM by ``_pl_tiles``), and a
+    chip's share of the step (temporaries 3.9 GB, among them the two
+    wide blocks' transposes, + 1.9 GB of impacts and terms as compiled
+    here; the commit keeps 0.94 GB of ``tf`` beside them) fits half the
+    chip's 16 GB."""
+    assert not _failures(report, of_cells=False)
+    mine = [m for m in report["mesh_cells"]
+            if m["cell"] == "msmarco-doc-mesh"]
+    assert [m["B"] for m in mine] == [512], report["mesh_cells"]
+    print(f"doc mesh step memory_analysis: {mine[0]}")
+    assert mine[0]["kernels"][-2:] == ["384", "512"] \
+        and len(mine[0]["kernels"]) == 12
+    assert 3e9 < mine[0]["temp_bytes"] + mine[0]["argument_bytes"] < 8e9
 
 
 # ``program_digest`` of the two programs of every accepted one-chip

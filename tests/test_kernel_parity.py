@@ -22,9 +22,11 @@ if _root not in sys.path:
     sys.path.insert(0, _root)
 
 from kernel_parity import INTERPRET_CASES as T1_CASES  # noqa: E402
-from kernel_parity import (STRETCH_INTERPRET_CASE, TOP_K,  # noqa: E402
+from kernel_parity import (MESH_INTERPRET_CASE,  # noqa: E402
+                           STRETCH_INTERPRET_CASE, TOP_K,
                            TOPK_INTERPRET_CASE, make_case, run_case,
-                           run_stretch_case, run_topk_case, run_trap_case)
+                           run_mesh_case, run_stretch_case, run_topk_case,
+                           run_trap_case)
 from tfidf_tpu.ops import ell  # noqa: E402
 from tfidf_tpu.ops.csr import build_coo  # noqa: E402
 from tfidf_tpu.ops.ell import (_pallas_eligible, _pl_tiles,  # noqa: E402
@@ -70,6 +72,20 @@ def test_stretch_case_of_the_matrix():
                          **STRETCH_INTERPRET_CASE)
     assert r["ok"], r
     assert r["stretches"] == 3 and r["lives"] == [512, 512, 300]
+
+
+def test_mesh_case_of_the_matrix():
+    """The mesh step's case ``kernel_parity.py`` runs on the chip, at a
+    CPU's scale: whole documents on a (4, 1) mesh of the virtual
+    devices, every shard a 512- and a 384-wide bucket on the
+    (interpreted) kernel and no residual, against the same step on
+    ``_score_block``; both weight kinds."""
+    r = run_mesh_case(np.random.default_rng(34), devices=jax.devices()[:4],
+                      **MESH_INTERPRET_CASE)
+    assert r["ok"], r
+    assert r["devices"] == 4 and r["residual_nnz"] == 0
+    assert r["buckets"] == [[512, 512], [512, 384]]
+    assert sorted(r["weights"]) == ["fractional", "multiplicity"]
 
 
 # one sub-tile's build at each of its shapes: no ``_PL_ROWS``-row loop

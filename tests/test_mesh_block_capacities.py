@@ -13,7 +13,9 @@ change of ``docs`` cannot walk back into the trap unnoticed.
 
 ``msmarco-doc`` (one chip, whole documents) is held the same way at the
 end of the file: its two blocks, at the 384 and the 512 rung of
-``ops/ell.py``'s ladder, as its configuration states them. And
+``ops/ell.py``'s ladder, as its configuration states them; a docs-shard
+of ``msmarco-doc-mesh`` (the same law, 400,000 documents a shard of a
+(4, 1) mesh) by that draw's shares. And
 ``msmarco-full`` (one chip, the passage collection in one index): its
 eleven blocks, the rungs over ``ELL_BLOCK_ROWS_MAX`` rows cut into full blocks
 and a last one, that last one clear of its power of two.
@@ -30,7 +32,7 @@ import pytest
 
 from tfidf_tpu.ops.csr import next_capacity
 from tfidf_tpu.ops.ell import ELL_BLOCK_ROWS_MAX, ELL_WIDTH_LADDER
-from tfidf_tpu.parallel.mesh_ell import ELL_WIDTHS
+from tfidf_tpu.parallel.mesh_ell import mesh_ell_widths
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -51,6 +53,7 @@ data = bench_lib("data")
 
 SEEDS = [2147483659 + 7919 * i for i in range(32)]
 MIN_ROWS = 256          # build_mesh_ell's floor of a block's rows
+ELL_WIDTHS = mesh_ell_widths()   # passages: no row past 256, ten buckets
 CLEAR = 0.02            # of its capacity, every block's fullest shard
 
 
@@ -87,8 +90,8 @@ def _in_seed_order(per_chunk: list[np.ndarray], seed: int) -> np.ndarray:
 
 def _fullest_shard(distinct: np.ndarray, shards: int) -> np.ndarray:
     """Documents of the fullest docs-shard in every ``ELL_WIDTHS`` bucket
-    (``_bucket_of``: the narrowest bucket that holds the document; wider
-    than the widest rides the widest), dealt ``i % shards``."""
+    (``build_mesh_ell``: the narrowest bucket that holds the document;
+    wider than the widest rides the widest), dealt ``i % shards``."""
     asc = np.asarray(sorted(ELL_WIDTHS))
     bucket = np.minimum(np.searchsorted(asc, distinct, side="left"),
                         len(asc) - 1)
@@ -122,7 +125,8 @@ def test_every_seed_compiles_the_same_ten_blocks(fullest):
     """... those the configuration states, which the compile-only test
     (``tests/kernel_compile_worker.py``) builds its shapes from."""
     blocks = _spec()["layout"]["shard_blocks"]
-    assert tuple(blocks["widths"]) == ELL_WIDTHS
+    assert tuple(blocks["widths"]) == ELL_WIDTHS \
+        == (256, 192, 128, 96, 64, 48, 32, 24, 16, 8)
     for per_seed in fullest:
         assert [next_capacity(int(n) or 1, MIN_ROWS)
                 for n in per_seed] == blocks["rows"]
@@ -170,6 +174,42 @@ def test_doc_cell_blocks_are_the_configurations(doc_rungs):
         assert live[w] <= rows * (1 - CLEAR), (w, live[w], rows)
     assert next_capacity(sum(live.values()), MIN_ROWS) \
         == blocks["doc_cap"]
+
+
+def test_doc_mesh_shard_stays_clear_of_its_buckets(doc_rungs):
+    """``msmarco-doc-mesh`` deals 1,600,000 documents of ``msmarco-doc``'s
+    law round-robin over four shards, and a bucket's row capacity is the
+    power of two above its FULLEST shard. The 400,000 documents drawn
+    above are a sample of that law: a shard's count in a bucket is
+    binomial in the bucket's share of them, and six standard deviations
+    over its mean still clear the capacity the configuration states by
+    2% (the 512 bucket: 57,590 + 6 x 222 against 65,536), so every seed
+    commits the same twelve buckets a shard: two past 256, the ladder's
+    rungs to the one that holds the widest row, and the ten every mesh
+    index has, empty at their floor. (The 1.6M documents themselves,
+    drawn once by the builder of PR 40: fullest shard of 32 seeds
+    342,923 and 57,856, no document past 455 distinct terms.)"""
+    spec = _spec("msmarco-doc-mesh")
+    doc = _spec("msmarco-doc")
+    for key in ("vocab", "zipf_a", "doc_len_mean", "doc_len_min",
+                "corpus_seed", "query_terms", "scoring",
+                "unique_term_capacity"):
+        assert spec[key] == doc[key], key
+    assert spec["engine_config"]["mesh_shape"] == [4, 1]
+    assert spec["engine_config"]["ell_width_cap"] is None
+    assert list(spec["reduced"]) == ["docs"] and spec["docs"] % 4 == 0
+    shard = spec["docs"] // 4
+    blocks = spec["layout"]["shard_blocks"]
+    share = {w: n / doc_rungs[0].sum()
+             for w, n in zip(ELL_WIDTH_LADDER, doc_rungs[0]) if n}
+    assert tuple(blocks["widths"]) == mesh_ell_widths(max(share))
+    assert blocks["widths"][:2] == sorted(share, reverse=True)
+    for w, rows in zip(blocks["widths"], blocks["rows"]):
+        p = share.get(w, 0.0)
+        worst = shard * p + 6 * (shard * p * (1 - p)) ** 0.5
+        assert next_capacity(int(shard * p) or 1, MIN_ROWS) == rows
+        assert worst <= rows * (1 - CLEAR), (w, worst, rows)
+    assert next_capacity(shard, MIN_ROWS) == blocks["doc_cap"]
 
 
 # ---- msmarco-full: one chip, rungs of several blocks --------------------

@@ -6,12 +6,25 @@ without an O(corpus) rebuild; stats are live-corpus (so deletes tighten
 IDF immediately, matching the local rebuild engine).
 """
 
+import dataclasses
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.bm25_reference import Bm25Reference
+from tests.test_wide_rows import SIZES as WIDE_SIZES
+from tests.test_wide_rows import TOP
+from tests.test_wide_rows import VOCAB as WIDE_VOCAB
 from tfidf_tpu.engine.engine import Engine
+from tfidf_tpu.engine.index import DocEntry
+from tfidf_tpu.ops.ell import ELL_WIDTH_LADDER
+from tfidf_tpu.parallel.mesh import make_mesh
+from tfidf_tpu.parallel.mesh_ell import build_mesh_ell, mesh_ell_widths
 from tfidf_tpu.parallel.mesh_ell_index import MeshEllIndex
 from tfidf_tpu.utils.config import Config
+from tfidf_tpu.utils.metrics import global_metrics
 
 TEXTS = {
     "a.txt": "the quick brown fox jumps over the lazy dog",
@@ -270,3 +283,219 @@ class TestIncrementalStats:
             local.ingest_text(n, t)
         local.commit()
         assert results(e) == results(local)
+
+
+# ---- whole documents: rows past 256 ride the mesh's buckets -----------
+
+# the documents of tests/test_wide_rows.py: two in every rung from 256
+# up (one at the rung exactly), two past the top, a crowd of short ones
+PARENT_WIDTHS = (256, 192, 128, 96, 64, 48, 32, 24, 16, 8)
+
+
+def _wide_corpus():
+    rng = np.random.default_rng(40)
+    p = 1.0 / np.arange(1, WIDE_VOCAB + 1) ** 0.6
+    docs, lengths = [], []
+    for n in WIDE_SIZES:
+        ids = np.sort(rng.choice(WIDE_VOCAB, size=n, replace=False,
+                                 p=p / p.sum())).astype(np.int32)
+        tfs = rng.integers(1, 6, size=n).astype(np.float32)
+        docs.append(dict(zip(ids.tolist(), tfs.tolist())))
+        lengths.append(float(tfs.sum()))
+    return docs, lengths
+
+
+def _wide_engine(tmp_path, shape, docs, lengths):
+    cfg = Config(documents_path=str(tmp_path / "wide"), engine_mode="mesh",
+                 mesh_layout="ell", min_doc_capacity=256,
+                 min_nnz_capacity=1 << 16, min_vocab_capacity=1 << 14,
+                 query_batch=32, embedding_enabled=False)
+    assert cfg.ell_width_cap is None
+    engine = Engine(cfg, mesh=make_mesh(
+        shape, devices=jax.devices()[:shape[0] * shape[1]]))
+    for t in range(WIDE_VOCAB):
+        engine.vocab.add(f"t{t}")
+    for i, d in enumerate(docs):
+        engine.index.add_document_arrays(
+            f"d{i}", np.asarray(list(d), np.int32),
+            np.asarray(list(d.values()), np.float32), lengths[i])
+    engine.commit()
+    return engine
+
+
+def _wide_queries(docs):
+    """Per document of 200 terms or more, a query of three of its terms
+    with one repeated (a multiplicity of 2): two of them from past the
+    row's 256th entry where it has one (a row's terms ascend, so those
+    are its largest ids), for the two documents past the top rung from
+    the residual."""
+    rng = np.random.default_rng(41)
+    out = []
+    for d in docs:
+        if len(d) < 200:
+            continue
+        ids = sorted(d)
+        tail = ids[TOP:] or ids[256:] or ids
+        picks = [int(rng.choice(ids)), int(rng.choice(tail)),
+                 int(rng.choice(tail))]
+        out.append(" ".join(f"t{t}" for t in picks + picks[:1]))
+    return out
+
+
+def _assert_equals_float64_bm25(engine, docs, lengths):
+    """``search_batch`` against the plain float64 BM25 over ONE
+    unsharded index (global df, N, avgdl): the same hits in the same
+    order, each score within rtol 1e-4, the float32 limit
+    ``tests/test_wide_rows.py`` uses (float32 impacts and sums differ
+    from float64 by ~1e-6 relative a term; a dropped residual or a
+    truncated row misses by a whole term's impact)."""
+    ref = Bm25Reference(docs, lengths, vocab=WIDE_VOCAB,
+                        k1=engine.config.bm25_k1, b=engine.config.bm25_b)
+    queries = _wide_queries(docs)
+    got = engine.search_batch(queries, k=10)
+    for q, hits in zip(queries, got):
+        want = ref.run(q, 10)
+        assert [h.name for h in hits] == [f"d{d}" for d, _s in want], q
+        np.testing.assert_allclose([h.score for h in hits],
+                                   [s for _d, s in want], rtol=1e-4)
+    return len(queries)
+
+
+@pytest.fixture(scope="module")
+def wide_corpus():
+    return _wide_corpus()
+
+
+@pytest.fixture(scope="module")
+def wide_engines(wide_corpus, tmp_path_factory):
+    """``shape -> (engine, the gauges its commit set)``, each built
+    once (the metrics are reset after every test)."""
+    built = {}
+
+    def get(shape):
+        if shape not in built:
+            engine = _wide_engine(tmp_path_factory.mktemp("wide"), shape,
+                                  *wide_corpus)
+            built[shape] = engine, global_metrics.snapshot()
+        return built[shape]
+
+    return get
+
+
+class TestWideRows:
+    @pytest.mark.parametrize("shape", [(4, 1), (2, 2)])
+    def test_every_rung_and_a_live_residual(self, wide_corpus,
+                                            wide_engines, shape):
+        """One mesh engine whose documents hold 200 ... 4,996 distinct
+        terms: a bucket at 256 and at every rung past it, the same on
+        every shard, and a live residual for the two documents past the
+        top rung: exact against the unsharded float64 reference, the
+        gauges and the counter saying so."""
+        docs, lengths = wide_corpus
+        D, T = shape
+        engine, g = wide_engines(shape)
+        snap = engine.index.snapshot
+        widths = mesh_ell_widths(max(WIDE_SIZES))
+        assert widths[0] == TOP and widths[-len(PARENT_WIDTHS):] \
+            == PARENT_WIDTHS
+        assert [tuple(a.shape) for a in snap.base.impact] \
+            == [(D, 256, w) for w in widths]
+        stats = engine.compute_stats()
+        assert stats["kernel_blocks"] == stats["posting_blocks"] \
+            == len(widths)
+        spilled = sum(n - TOP for n in WIDE_SIZES if n > TOP)
+        assert snap.res_nnz == spilled == 1100
+        assert g["ell_blocks"] == D * len(widths)
+        assert g["ell_width_max"] == TOP
+        assert g["ell_rows_padded"] == D * 256 * len(widths)
+        assert g["ell_entries_padded"] == D * 256 * sum(widths)
+        assert g["ell_residual_nnz"] == 1100
+        assert g["ell_residual_docs"] == 2
+        assert g["mesh_docs_shards"] == D and g["mesh_terms_shards"] == T
+        before = global_metrics.get("residual_entries_scored")
+        n = _assert_equals_float64_bm25(engine, docs, lengths)
+        # every dispatched step scored the residual's 1,100 live entries
+        assert global_metrics.get("residual_entries_scored") - before \
+            == 1100 * -(-n // engine.config.query_batch)
+
+    @pytest.mark.parametrize("fault", ["residual_dropped",
+                                       "tail_truncated_at_256"])
+    def test_comparison_sees_a_lost_posting(self, wide_corpus, wide_engines,
+                                            monkeypatch, fault):
+        """The comparison above fails on a snapshot without its
+        residual, and on one whose buckets are cut at a row's 256th
+        entry (what a mesh whose buckets stop at 256 would score
+        without its residual)."""
+        engine, _g = wide_engines((4, 1))
+        snap = engine.index.snapshot
+        base = snap.base
+        if fault == "residual_dropped":
+            broken = dataclasses.replace(base,
+                                         res_tf=jnp.zeros_like(base.res_tf))
+        else:
+            broken = dataclasses.replace(base, impact=tuple(
+                imp.at[:, :, 256:].set(0.0) for imp in base.impact))
+        monkeypatch.setattr(snap, "base", broken)
+        with pytest.raises(AssertionError):
+            _assert_equals_float64_bm25(engine, *wide_corpus)
+
+
+class TestOneLadder:
+    @pytest.mark.parametrize("widest, cap, want", [
+        (0, None, PARENT_WIDTHS), (33, None, PARENT_WIDTHS),
+        (256, None, PARENT_WIDTHS), (256, 256, PARENT_WIDTHS),
+        (257, None, (384,) + PARENT_WIDTHS),
+        (455, None, (512, 384) + PARENT_WIDTHS),
+        (455, 256, PARENT_WIDTHS), (455, 400, (384,) + PARENT_WIDTHS),
+        (100, 16, (16, 8)), (TOP + 1, None, None), (10 ** 6, None, None)])
+    def test_bucket_widths(self, widest, cap, want):
+        """The ladder's rungs that 8 divides: always the ten to 256,
+        above them up to the rung that holds the widest row, under the
+        cap; past the top, the whole ladder."""
+        if want is None:
+            want = tuple(w for w in reversed(ELL_WIDTH_LADDER) if w % 8 == 0)
+        assert mesh_ell_widths(widest, cap) == want
+        assert all(w in ELL_WIDTH_LADDER for w in want)
+
+    @pytest.mark.parametrize("shape", [(4, 1), (2, 2)])
+    def test_no_row_over_256_builds_the_parents_buckets(self, shape):
+        """A corpus of passages commits exactly the ten buckets, with
+        the capacities, that the mesh had before it had wide ones (so
+        ``msmarco4m-mesh``'s compiled step does not move): rows, dl and
+        live counts laid out as a plain per-document loop lays them."""
+        rng = np.random.default_rng(7)
+        mesh = make_mesh(shape, devices=jax.devices()[:4])
+        D = shape[0]
+        entries = []
+        for i in range(700):
+            k = int(rng.integers(0, 257))
+            entries.append(DocEntry(
+                name=f"d{i}", tfs=rng.integers(1, 5, k).astype(np.float32),
+                term_ids=np.sort(rng.choice(5000, k, replace=False))
+                .astype(np.int32), length=float(k + i % 3)))
+        per_shard = [entries[s::D] for s in range(D)]
+        host, perms = build_mesh_ell(per_shard, mesh, lambda x: x * 0.5,
+                                     width_cap=None, min_rows=8)
+        assert tuple(a.shape[2] for a in host.tf) == PARENT_WIDTHS
+        assert host.res_nnz == 0 and not host.res_tf.any()
+        for s, mine in enumerate(per_shard):
+            order = sorted(range(len(mine)),
+                           key=lambda i: -mine[i].term_ids.shape[0])
+            assert perms[s].tolist() == order
+            cursor = [0] * len(PARENT_WIDTHS)
+            for i in order:
+                e = mine[i]
+                k = e.term_ids.shape[0]
+                b = max(j for j, w in enumerate(PARENT_WIDTHS) if k <= w)
+                r = cursor[b]
+                cursor[b] += 1
+                assert (host.term[b][s, r, :k] == e.term_ids).all()
+                assert (host.tf[b][s, r, :k] == e.tfs).all()
+                assert not host.tf[b][s, r, k:].any()
+                assert host.dl[b][s, r] == np.float32(e.length * 0.5)
+            assert host.block_live[s].tolist() == cursor
+            for b, n in enumerate(cursor):
+                assert not host.tf[b][s, n:].any()
+        for b, a in enumerate(host.tf):
+            fullest = int(host.block_live[:, b].max())
+            assert a.shape[1] == max(8, 1 << max(fullest - 1, 0).bit_length())

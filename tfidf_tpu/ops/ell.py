@@ -21,8 +21,8 @@ yields a block only where documents need it: passages fill rungs up to
 padded entries stay well within 2x of nnz regardless of skew. A residual
 exists only where a document holds more distinct terms than the top rung
 (4,096: a text of some tens of thousands of tokens) or than a lower
-``width_cap``, and on the mesh, whose buckets stop at 256
-(``parallel/mesh_ell.py``): those entries spill into a COO *residual*
+``width_cap`` (the mesh takes its buckets from the same ladder:
+``parallel/mesh_ell.py``): those entries spill into a COO *residual*
 scored by the chunked scatter path, and the partial score tensors add.
 
 Row counts are power-of-two bucketed and widths come from the fixed

@@ -6,10 +6,15 @@ single-device blocked-ELL path at equal scale. This module gives the mesh
 the same layout the single-device engine uses (``ops/ell.py``), organized
 for SPMD:
 
-* per docs-shard, live documents are laid out as blocked ELL with a
-  FIXED set of width buckets (8..width_cap) whose row capacities are
-  padded to the max across shards — every device slice has identical
-  static shapes, as ``shard_map`` requires;
+* per docs-shard, live documents are laid out as blocked ELL in width
+  buckets taken from the ONE ladder, ``ops.ell.ELL_WIDTH_LADDER``
+  (:func:`mesh_ell_widths`): always the ten rungs to 256, above them
+  every rung up to the one that holds the widest row of ANY shard
+  (whole documents fill 384 and 512), under ``width_cap``. Every shard
+  has the same buckets, their row capacities padded to the max across
+  shards — every device slice has identical static shapes, as
+  ``shard_map`` requires — and only a row past the ladder's top or the
+  cap spills into the COO residual;
 * the ``terms`` axis shards each block's WIDTH columns: one document row
   keeps its entries split across terms-devices, partial scores
   ``psum``-reduce exactly like the COO path (entries are disjoint across
@@ -41,19 +46,34 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tfidf_tpu.ops.csr import next_capacity
-from tfidf_tpu.ops.ell import (_pallas_eligible, _score_block,
-                               score_block_pallas, _rearrange_to_real)
+from tfidf_tpu.ops.ell import (ELL_WIDTH_LADDER, _pallas_eligible,
+                               _rearrange_to_real, _score_block,
+                               ell_layout_gauges, score_block_pallas)
 from tfidf_tpu.ops.scoring import (QueryBatch, _compile_queries,
                                    bm25_weights, score_coo_compiled,
                                    tfidf_weights)
 from tfidf_tpu.ops.topk import exact_topk, merge_topk, pack_topk
 from tfidf_tpu.utils.metrics import global_metrics
 
-# fixed width buckets so every shard shares one block structure; every
-# width is a multiple of 8 so the terms axis (up to 8-way) can shard the
-# width columns evenly. The 1.5x intermediate steps cut pad entries
-# ~13% vs pure powers of two (see ops/ell.py ELL_WIDTH_LADDER).
-ELL_WIDTHS = (256, 192, 128, 96, 64, 48, 32, 24, 16, 8)
+# the rungs EVERY mesh index has, whatever its documents: a corpus of
+# passages commits the same ten buckets (and so runs the same compiled
+# step) whether or not one of them reaches the 192 or the 256 rung
+MESH_ELL_BASE_WIDTH = 256
+
+
+def mesh_ell_widths(widest_row: int = 0,
+                    width_cap: int | None = None) -> tuple[int, ...]:
+    """The mesh's bucket widths, widest first, for a corpus whose
+    longest row holds ``widest_row`` distinct terms: the rungs of
+    ``ops.ell.ELL_WIDTH_LADDER`` that are multiples of 8 (so the terms
+    axis, up to 8-way, shards the width columns evenly) — all of them
+    to ``MESH_ELL_BASE_WIDTH``, and above it up to the rung that holds
+    ``widest_row`` — under ``width_cap`` (None: the ladder's top)."""
+    rungs = [w for w in ELL_WIDTH_LADDER
+             if w % 8 == 0 and (width_cap is None or w <= width_cap)]
+    top = next((w for w in rungs if w >= widest_row), rungs[-1])
+    return tuple(w for w in reversed(rungs)
+                 if w <= max(top, MESH_ELL_BASE_WIDTH))
 
 
 @dataclass
@@ -107,13 +127,14 @@ class MeshEllHost(NamedTuple):
     res_doc: np.ndarray
     res_dl: np.ndarray
     doc_cap: int
+    res_nnz: int         # live residual entries, all shards
 
 
 def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
                    mesh: Mesh,
                    transform_len,                   # model.transform_doc_len
                    *,
-                   width_cap: int | None = 256,
+                   width_cap: int | None = None,
                    min_rows: int = 256,
                    min_res_cap: int = 1 << 10
                    ) -> tuple[MeshEllHost, list[np.ndarray]]:
@@ -127,85 +148,99 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
     """
     D = mesh.shape["docs"]
     T = mesh.shape["terms"]
-    widths = [w for w in ELL_WIDTHS
-              if width_cap is None or w <= width_cap]
+    # one structure for every shard: the widest row of ANY of them
+    widths = mesh_ell_widths(
+        max((e.term_ids.shape[0] for entries in entries_per_shard
+             for e in entries), default=0), width_cap)
     assert T <= min(widths), "terms axis cannot exceed the narrowest bucket"
 
-    # per shard: sort rows by distinct-count desc, assign to buckets
+    # per shard: rows sorted by distinct-term count, descending, so a
+    # bucket is one run of them; only the widest bucket can spill
+    asc = np.asarray(widths[::-1], np.int64)
+    nb = len(widths)
     per_shard = []
-    doc_caps = []
-    rows_need = np.zeros((D, len(widths)), np.int64)
+    rows_need = np.zeros((D, nb), np.int64)
     res_need = np.zeros(D, np.int64)
-    for s in range(D):
-        entries = entries_per_shard[s]
-        order = np.argsort([-e.term_ids.shape[0] for e in entries],
-                           kind="stable")
-        entries = [entries[i] for i in order]
-        per_shard.append((entries, order))
-        doc_caps.append(len(entries))
-        for e in entries:
-            k = e.term_ids.shape[0]
-            b = _bucket_of(k, widths)
-            rows_need[s, b] += 1
-            if k > widths[b]:
-                # spill size must use the BUCKET width (the widest rung
-                # <= width_cap), not width_cap itself — for non-rung
-                # caps the estimate would undercount the residual
-                res_need[s] += k - widths[b]
-    doc_cap = next_capacity(max(max(doc_caps, default=1), 1), min_rows)
+    for s, entries in enumerate(entries_per_shard):
+        sizes = np.fromiter((e.term_ids.shape[0] for e in entries),
+                            np.int64, len(entries))
+        order = np.argsort(-sizes, kind="stable")
+        sizes = sizes[order]
+        bucket = nb - 1 - np.minimum(np.searchsorted(asc, sizes), nb - 1)
+        per_shard.append((order, sizes))
+        rows_need[s] = np.bincount(bucket, minlength=nb)
+        res_need[s] = np.maximum(sizes - widths[0], 0).sum()
+    doc_cap = next_capacity(
+        max(max((len(e) for e in entries_per_shard), default=1), 1),
+        min_rows)
     rows_cap = [next_capacity(int(rows_need[:, b].max()) or 1, min_rows)
-                for b in range(len(widths))]
+                for b in range(nb)]
     res_cap = next_capacity(int(res_need.max()) or 1, min_res_cap)
     res_chunk = -(-res_cap // T)
 
     g_tf = [np.zeros((D, rows_cap[b], widths[b]), np.float32)
-            for b in range(len(widths))]
+            for b in range(nb)]
     g_term = [np.zeros((D, rows_cap[b], widths[b]), np.int32)
-              for b in range(len(widths))]
-    g_dl = [np.zeros((D, rows_cap[b]), np.float32)
-            for b in range(len(widths))]
-    g_bl = np.zeros((D, len(widths)), np.int32)
+              for b in range(nb)]
+    g_dl = [np.zeros((D, rows_cap[b]), np.float32) for b in range(nb)]
+    g_bl = rows_need.astype(np.int32)
     g_live = np.zeros((D, doc_cap), np.float32)
     g_res_tf = np.zeros((D, T, res_chunk), np.float32)
     g_res_term = np.zeros((D, T, res_chunk), np.int32)
     g_res_doc = np.full((D, T, res_chunk), doc_cap - 1, np.int32)
     g_res_dl = np.zeros((D, doc_cap), np.float32)
-    perms = []
-    for s in range(D):
-        entries, order = per_shard[s]
-        perms.append(order.astype(np.int64))
-        cursors = np.zeros(len(widths), np.int64)
-        res_rows, res_terms, res_tfs = [], [], []
-        ell_row = 0
-        raw = np.asarray([e.length for e in entries], np.float32)
-        kdl = transform_len(raw).astype(np.float32) if len(entries) \
-            else raw
-        for i, e in enumerate(entries):
-            k = e.term_ids.shape[0]
-            b = _bucket_of(k, widths)
-            r = int(cursors[b])
-            cursors[b] += 1
-            take = min(k, widths[b])
-            g_tf[b][s, r, :take] = e.tfs[:take]
-            g_term[b][s, r, :take] = e.term_ids[:take]
-            g_dl[b][s, r] = kdl[i]
-            if k > widths[b]:     # only the widest bucket can spill
-                res_rows.extend([ell_row] * (k - take))
-                res_terms.extend(e.term_ids[take:].tolist())
-                res_tfs.extend(e.tfs[take:].tolist())
-            g_live[s, ell_row] = 1.0
-            g_res_dl[s, ell_row] = kdl[i]
-            ell_row += 1
-        g_bl[s] = cursors
-        n_res = len(res_rows)
-        step = -(-n_res // T) if n_res else 0
+    perms = [order for order, _sizes in per_shard]
+    res_rows = []        # the residual's rows, shard after shard
+    for s, entries in enumerate(entries_per_shard):
+        order, sizes = per_shard[s]
+        n = len(entries)
+        if not n:
+            continue
+        raw = np.fromiter((e.length for e in entries), np.float32, n)
+        kdl = transform_len(raw[order]).astype(np.float32)
+        g_live[s, :n] = 1.0
+        g_res_dl[s, :n] = kdl
+        # the shard's postings in ELL row order: a row's entries are
+        # the first of its block row, so a block's live cells, taken
+        # row by row, are its run of postings in their own order
+        tfs = np.concatenate([entries[i].tfs for i in order])
+        terms = np.concatenate([entries[i].term_ids for i in order])
+        bounds = np.concatenate([[0], np.cumsum(sizes)])
+        row0 = np.cumsum(rows_need[s]) - rows_need[s]
+        res = (tfs[:0], terms[:0], np.zeros(0, np.int32))
+        for b in range(nb):
+            lo, hi = int(row0[b]), int(row0[b] + rows_need[s, b])
+            if hi == lo:
+                continue
+            run = slice(int(bounds[lo]), int(bounds[hi]))
+            size = sizes[lo:hi]
+            cells = np.arange(widths[b]) < size[:, None]
+            run_tfs, run_terms = tfs[run], terms[run]
+            if size[0] > widths[b]:
+                # only the widest bucket can spill: what lies past its
+                # width is the residual, in row order
+                first = np.repeat(bounds[lo:hi] - bounds[lo], size)
+                keep = np.arange(first.shape[0]) - first < widths[b]
+                rows = np.repeat(np.arange(lo, hi, dtype=np.int32), size)
+                res = (run_tfs[~keep], run_terms[~keep], rows[~keep])
+                run_tfs, run_terms = run_tfs[keep], run_terms[keep]
+            g_tf[b][s, :hi - lo][cells] = run_tfs
+            g_term[b][s, :hi - lo][cells] = run_terms
+            g_dl[b][s, :hi - lo] = kdl[lo:hi]
+        # the residual cut in T even runs
+        step = -(-res[2].shape[0] // T)
         for t in range(T):
-            lo, hi = min(t * step, n_res), min((t + 1) * step, n_res)
-            n = hi - lo
-            if n:
-                g_res_tf[s, t, :n] = res_tfs[lo:hi]
-                g_res_term[s, t, :n] = res_terms[lo:hi]
-                g_res_doc[s, t, :n] = res_rows[lo:hi]
+            for g, a in zip((g_res_tf, g_res_term, g_res_doc), res):
+                part = a[t * step:(t + 1) * step]
+                g[s, t, :part.shape[0]] = part
+        res_rows.append(res[2].astype(np.int64) + s * doc_cap)
+    # the ``ell_*`` gauges of the local commit, summed over the shards
+    # (``ell_width_max``: the widest bucket)
+    gauges = ell_layout_gauges(
+        list(zip(rows_cap, widths)) * D, rows_need.ravel(),
+        np.concatenate(res_rows) if res_rows else np.zeros(0, np.int64))
+    for name, value in gauges.items():
+        global_metrics.set_gauge(name, value)
 
     # device-residency accounting (ISSUE 18): the mesh base is always
     # fully resident (no cold tier on the mesh path), so publish its
@@ -223,7 +258,8 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
     return MeshEllHost(tf=g_tf, term=g_term, dl=g_dl, block_live=g_bl,
                        live=g_live, res_tf=g_res_tf, res_term=g_res_term,
                        res_doc=g_res_doc, res_dl=g_res_dl,
-                       doc_cap=doc_cap), perms
+                       doc_cap=doc_cap,
+                       res_nnz=gauges["ell_residual_nnz"]), perms
 
 
 def place_mesh_ell(host: MeshEllHost, mesh: Mesh) -> MeshEllArrays:
@@ -250,15 +286,6 @@ def place_mesh_ell(host: MeshEllHost, mesh: Mesh) -> MeshEllArrays:
         res_dl=put(host.res_dl, P("docs", None)),
         doc_cap=host.doc_cap,
     )
-
-
-def _bucket_of(k: int, widths: list[int]) -> int:
-    """Smallest bucket with width >= k; over-wide rows use bucket 0 and
-    spill the excess into the residual."""
-    for b in range(len(widths) - 1, -1, -1):
-        if k <= widths[b]:
-            return b
-    return 0
 
 
 def make_impact_refresh(mesh: Mesh, *, model: str = "bm25",

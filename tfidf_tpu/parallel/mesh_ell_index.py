@@ -48,9 +48,10 @@ class MeshEllSnapshot:
 
     def __init__(self, *, base: MeshEllArrays, delta: ShardedArrays,
                  perms, base_counts, shard_docs, df_g, n_docs, avgdl,
-                 version, nnz, total_live) -> None:
+                 version, nnz, total_live, res_nnz=0) -> None:
         self.base = base
         self.delta = delta
+        self.res_nnz = res_nnz             # live residual entries
         self.perms = perms                 # per shard: ell_row -> ins id
         self.base_counts = base_counts     # docs in base per shard
         self.shard_docs = shard_docs
@@ -85,7 +86,7 @@ class MeshEllIndex(MeshIndex):
 
     def __init__(self, model, mesh=None, min_doc_cap: int = 1024,
                  min_chunk_cap: int = 1 << 14,
-                 ell_width_cap: int | None = 256,
+                 ell_width_cap: int | None = None,
                  delta_rebuild_frac: float = 0.5) -> None:
         super().__init__(model, mesh=mesh, min_doc_cap=min_doc_cap,
                          min_chunk_cap=min_chunk_cap)
@@ -94,6 +95,7 @@ class MeshEllIndex(MeshIndex):
         # the corpus (the merge policy)
         self.delta_rebuild_frac = delta_rebuild_frac
         self._base: MeshEllArrays | None = None
+        self._res_nnz = 0
         self._perms: list[np.ndarray] = []
         self._base_counts: list[int] = []
         self._refresh_fn = None
@@ -271,7 +273,7 @@ class MeshEllIndex(MeshIndex):
                 shard_docs=self._shard_docs,
                 df_g=df_g, n_docs=n_docs, avgdl=avgdl,
                 version=self._version, nnz=self.nnz_live,
-                total_live=len(self._placed))
+                total_live=len(self._placed), res_nnz=self._res_nnz)
             self.snapshot = snap
             self._committed_gen = gen0
         global_metrics.set_gauge("index_docs", snap.total_live)
@@ -354,6 +356,7 @@ class MeshEllIndex(MeshIndex):
         # pointing into arrays that were never installed (ADVICE r2)
         with trace_phase("mesh_build_upload"):
             base = jax.block_until_ready(place_mesh_ell(host, self.mesh))
+        self._res_nnz = host.res_nnz
         del host
         self._shard_docs = shard_docs
         self._placed = placed
@@ -534,6 +537,8 @@ class MeshEllSearcher(MeshSearcher):
     def _dispatch_chunk(self, snap, qb, k: int):
         kk, depth = self._depths(k, snap.stride)
         self._count_kernel_uniq(qb)
+        # what the step scores by the scatter path, on every shard
+        global_metrics.inc("residual_entries_scored", snap.res_nnz)
         return self._get_search_fn(kk, depth)(
             snap.base, snap.delta, snap.df_g, snap.n_docs,
             snap.avgdl, qb), depth

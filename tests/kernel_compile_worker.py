@@ -171,7 +171,14 @@ def compile_mesh_step(devices) -> list[dict]:
             assert text.count("tpu_custom_call") >= 4 \
                 and "all-gather" in text, "kernels or gather missing"
             m = step.memory_analysis()
+            # a shard's whole score space as ONE f32 array: the padded
+            # concatenation, or (where no bucket is that wide) the
+            # row-order matrix and its transpose
+            whole = [f"f32[{B},{sum(rows) + 1}]"] + (
+                [] if doc_cap in rows
+                else [f"f32[{B},{doc_cap}]", f"f32[{doc_cap},{B}]"])
             out.append({"cell": name, "B": B,
+                        "row_order_shapes": [x for x in whole if x in text],
                         "digest": program_digest(step),
                         "kernels": sorted(set(re.findall(
                             r"ell_score_v4_w(\d+)", text)), key=int),

@@ -5,8 +5,9 @@ libtpu's topology client — no chip, seconds) in a subprocess: batch
 buckets on every step of the tile schedule x widths from the ELL ladder
 incl. 12, the rungs past 256 on every doc tile they take, a mesh-split
 width of 1 and an odd one, plus the (4, 1) ``make_mesh_ell_search``
-program at the two mesh cells' shapes (``msmarco4m-mesh``'s held to the
-parent's by digest), the served
+program at the two mesh cells' shapes (``msmarco4m-mesh``'s held to its
+accepted programs by digest, both cells' temporaries under those of the
+step that rearranged the scores), the served
 device step at the benchmark cells' shapes (held to the programs of the
 commit before the stretched step, by digest), the stretches of
 ``msmarco-full``'s step and the 1,000-deep top-k of
@@ -74,22 +75,41 @@ def test_mesh_cell_step_compiles_for_v5e(report, B):
     print(f"mesh step memory_analysis, B={B}: {mine[0]}")
     assert mine[0]["temp_bytes"] + mine[0]["argument_bytes"] < 8e9
     # no row past 256 distinct terms: the ten buckets, and the program
-    # of the commit before the mesh had wider ones (a193a7b, PR 39's),
-    # instruction for instruction (``program_digest``)
+    # PR 41 left (``program_digest``: instruction for instruction)
     assert mine[0]["kernels"] == ["8", "16", "24", "32", "48", "64", "96",
                                   "128", "192", "256"]
     assert mine[0]["digest"] == PARENT_MESH_STEP_DIGESTS[B]
+    # a shard's top-k reads the score blocks in place: the step's text
+    # holds no array of the whole score space (``row_order_shapes``:
+    # the padded concatenation, ``[B, doc_cap]`` or its transpose) and
+    # its temporaries stay UNDER those of the step that gathered one
+    assert mine[0]["temp_bytes"] < REARRANGED_MESH_STEP_TEMP_BYTES[
+        "msmarco4m-mesh", B]
+    assert mine[0]["row_order_shapes"] == [], mine[0]
 
 
 # ``program_digest`` of ``msmarco4m-mesh``'s step, compiled for v5e:2x2
-# from commit a193a7b (PR 39's, the parent of the PR that took the
-# mesh's buckets from the one ladder): passages fill no bucket past
-# 256, so that cell goes on running exactly these. A PR that MEANS to
-# change the mesh step reads the new digests off
-# ``python tests/kernel_compile_worker.py`` (``mesh_cells``).
-PARENT_MESH_STEP_DIGESTS = {128: "ac4051463eac3b17",
-                            256: "3a83d306e0c95e20",
-                            512: "168da395b36d85c8"}
+# from PR 41's tree, the PR that MEANT to change it: a shard's top-k
+# over its score blocks in place (``ops.topk.blocks_topk``) where
+# commit 64c8812 (PR 40's; its digests ac4051463eac3b17,
+# 3a83d306e0c95e20, 168da395b36d85c8 were a193a7b's, PR 39's) gathered
+# the blocks into ELL-row order and ran one ``lax.top_k`` over the row.
+# The next PR that means to change the mesh step reads the new digests
+# off ``python tests/kernel_compile_worker.py`` (``mesh_cells``).
+PARENT_MESH_STEP_DIGESTS = {128: "82937132470bc899",
+                            256: "d637b8be94d6690c",
+                            512: "c7557907eaac58ad"}
+
+# ``memory_analysis().temp_size_in_bytes`` a device of the step of
+# commit 64c8812 (PR 41's parent: padded concatenation, its transposed
+# copy, the gathered ``[B, doc_cap]``, the unchunked ``lax.top_k``),
+# compiled here for v5e:2x2. PR 41's step reads 284,236,288 /
+# 554,291,200 / 1,093,693,440 and 2,699,314,688.
+REARRANGED_MESH_STEP_TEMP_BYTES = {
+    ("msmarco4m-mesh", 128): 1_181_567_488,
+    ("msmarco4m-mesh", 256): 2_362_511_872,
+    ("msmarco4m-mesh", 512): 4_723_658_752,
+    ("msmarco-doc-mesh", 512): 3_906_532_352}
 
 
 def test_doc_mesh_cell_step_compiles_for_v5e(report):
@@ -97,10 +117,12 @@ def test_doc_mesh_cell_step_compiles_for_v5e(report):
     per-shard buckets of its ``layout.shard_blocks`` and the one batch
     bucket its cell dispatches. The v5e compiler accepts the kernel at
     384 and 512 wide inside ``shard_map`` (VMEM by ``_pl_tiles``), and a
-    chip's share of the step (temporaries 3.9 GB, among them the two
-    wide blocks' transposes, + 1.9 GB of impacts and terms as compiled
-    here; the commit keeps 0.94 GB of ``tf`` beside them) fits half the
-    chip's 16 GB."""
+    chip's share of the step (temporaries 2.7 GB since PR 41 — the
+    score blocks, one masked copy of them, the two wide blocks'
+    transposes — where the step that gathered the scores into row
+    order took 3.9, + 1.9 GB of impacts and terms as compiled here; the
+    commit keeps 0.94 GB of ``tf`` beside them) fits half the chip's
+    16 GB."""
     assert not _failures(report, of_cells=False)
     mine = [m for m in report["mesh_cells"]
             if m["cell"] == "msmarco-doc-mesh"]
@@ -109,6 +131,9 @@ def test_doc_mesh_cell_step_compiles_for_v5e(report):
     assert mine[0]["kernels"][-2:] == ["384", "512"] \
         and len(mine[0]["kernels"]) == 12
     assert 3e9 < mine[0]["temp_bytes"] + mine[0]["argument_bytes"] < 8e9
+    assert mine[0]["temp_bytes"] < REARRANGED_MESH_STEP_TEMP_BYTES[
+        "msmarco-doc-mesh", 512]
+    assert mine[0]["row_order_shapes"] == [], mine[0]
 
 
 # ``program_digest`` of the two programs of every accepted one-chip

@@ -19,9 +19,19 @@ from tests.test_wide_rows import TOP
 from tests.test_wide_rows import VOCAB as WIDE_VOCAB
 from tfidf_tpu.engine.engine import Engine
 from tfidf_tpu.engine.index import DocEntry
-from tfidf_tpu.ops.ell import ELL_WIDTH_LADDER
+from jax.sharding import PartitionSpec as P
+
+from tfidf_tpu.ops.ell import (ELL_WIDTH_LADDER, _pallas_eligible,
+                               _score_block, ell_scores_to_real,
+                               score_block_pallas)
+from tfidf_tpu.ops.scoring import (QueryBatch, _compile_queries,
+                                   score_coo_compiled)
+from tfidf_tpu.ops.topk import (exact_topk, merge_topk, topk_chunk_counts,
+                                topk_widths)
 from tfidf_tpu.parallel.mesh import make_mesh
-from tfidf_tpu.parallel.mesh_ell import build_mesh_ell, mesh_ell_widths
+from tfidf_tpu.parallel.mesh_ell import (build_mesh_ell,
+                                         make_mesh_ell_search,
+                                         mesh_ell_widths)
 from tfidf_tpu.parallel.mesh_ell_index import MeshEllIndex
 from tfidf_tpu.utils.config import Config
 from tfidf_tpu.utils.metrics import global_metrics
@@ -499,3 +509,258 @@ class TestOneLadder:
         for b, a in enumerate(host.tf):
             fullest = int(host.block_live[:, b].max())
             assert a.shape[1] == max(8, 1 << max(fullest - 1, 0).bit_length())
+
+
+# ---- a shard's top-k reads its score blocks in place -------------------
+
+# ``make_mesh_ell_search``'s step as it was before it ranked the blocks
+# where the scorers wrote them, kept here as the reference: the blocks
+# gathered into ELL-row order (``ell_scores_to_real``), residual, psum
+# and tombstone mask applied there, the delta concatenated behind, and
+# ``exact_topk`` over the whole row.
+def _rearranged_mesh_search(mesh, *, k, depth, model="bm25", k1=1.2,
+                            b=0.75):
+    def step(df_g, n_docs, avgdl, base_live, block_live,
+             res_tf, res_term, res_doc, res_dl,
+             d_tf, d_term, d_doc, d_len, d_n, d_live,
+             q_uniq, q_n_uniq, q_slots, q_weights, *blocks):
+        q = QueryBatch(q_uniq, q_n_uniq, q_slots, q_weights)
+        nb = len(blocks) // 2
+        impacts = [x.reshape(x.shape[1:]) for x in blocks[:nb]]
+        terms = [x.reshape(x.shape[1:]) for x in blocks[nb:]]
+        (base_live, block_live, res_tf, res_term, res_doc, res_dl, d_tf,
+         d_term, d_doc, d_len, d_live) = (
+            x.reshape(x.shape[-1]) for x in (
+                base_live, block_live, res_tf, res_term, res_doc, res_dl,
+                d_tf, d_term, d_doc, d_len, d_live))
+        B = q.slots.shape[0]
+        doc_cap_ell, doc_cap_delta = base_live.shape[0], d_live.shape[0]
+        slot_of, qc_ext = _compile_queries(q, df_g.shape[0])
+        parts = [
+            score_block_pallas(imp, term, q.uniq, q.n_uniq, qc_ext,
+                               block_live[i])
+            if _pallas_eligible(imp.shape[0], B, q.uniq.shape[0])
+            else _score_block(imp, term, slot_of, qc_ext.T, 2048)
+            for i, (imp, term) in enumerate(zip(impacts, terms))]
+        kw = dict(model=model, k1=k1, b=b)
+        ell_scores = ell_scores_to_real(parts, block_live, doc_cap_ell)
+        ell_scores = ell_scores + score_coo_compiled(
+            res_tf, res_term, res_doc, res_dl, df_g, slot_of, qc_ext,
+            n_docs, avgdl, None, chunk=min(1 << 10, res_tf.shape[0]), **kw)
+        ell_scores = jax.lax.psum(ell_scores, "terms") * base_live[None, :]
+        delta_scores = score_coo_compiled(
+            d_tf, d_term, d_doc, d_len, df_g, slot_of, qc_ext, n_docs,
+            avgdl, None, chunk=min(1 << 17, d_tf.shape[0]), **kw)
+        delta_scores = jax.lax.psum(delta_scores, "terms") * d_live[None, :]
+        vals, ids = exact_topk(
+            jnp.concatenate([ell_scores, delta_scores], axis=1),
+            jnp.int32(doc_cap_ell) + d_n.reshape(()), k=k)
+        gids = (jax.lax.axis_index("docs").astype(jnp.int32)
+                * jnp.int32(doc_cap_ell + doc_cap_delta) + ids)
+        return merge_topk(jax.lax.all_gather(vals, "docs"),
+                          jax.lax.all_gather(gids, "docs"), k=depth)
+
+    def search(base, delta, df_g, n_docs, avgdl, q):
+        docs, split = P("docs", None), P("docs", "terms", None)
+        in_specs = ((P(None), P(), P(), docs, docs, split, split, split,
+                     docs, split, split, split, docs, P("docs"), docs,
+                     P(None), P(), P(None, None), P(None, None))
+                    + (P("docs", None, "terms"),) * base.n_buckets * 2)
+        return jax.shard_map(
+            step, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()),
+            check_vma=False)(
+            df_g, n_docs, avgdl, base.live, base.block_live,
+            base.res_tf, base.res_term, base.res_doc, base.res_dl,
+            delta.tf, delta.term, delta.doc, delta.doc_len, delta.n_live,
+            delta.live, jnp.asarray(q.uniq), jnp.asarray(q.n_uniq),
+            jnp.asarray(q.slots), jnp.asarray(q.weights),
+            *base.impact, *base.term)
+
+    return jax.jit(search)
+
+
+# The corpus of the in-place top-k's cases, term ids under IN_PLACE_VOCAB:
+# widths capped at 32, so a shard's buckets are (32, 24, 16, 8).
+IN_PLACE_VOCAB = 400
+(T_TIE, T_DEAD, T_RARE, T_WIDE, T_FRESH, T_COMMON) = range(300, 306)
+
+
+def _in_place_engine(tmp_path, shape):
+    """A mesh engine that holds every case at once: a crowd of narrow
+    documents (a bucket of 1,024 or 2,048 rows a shard), some in every
+    wider bucket, three past the widest (a live residual), twelve that
+    score the SAME for ``T_TIE`` from two buckets and every shard, base
+    documents deleted after the commit, and a delta of twelve with one
+    slot deleted."""
+    cfg = Config(documents_path=str(tmp_path / "inplace"),
+                 engine_mode="mesh", mesh_layout="ell", ell_width_cap=32,
+                 min_doc_capacity=16, min_nnz_capacity=256,
+                 min_vocab_capacity=512, query_batch=8,
+                 max_query_terms=8, embedding_enabled=False)
+    engine = Engine(cfg, mesh=make_mesh(
+        shape, devices=jax.devices()[:shape[0] * shape[1]]))
+    for t in range(IN_PLACE_VOCAB):
+        engine.vocab.add(f"t{t}")
+    rng = np.random.default_rng(41)
+
+    def add(name, counts):
+        ids = np.asarray(sorted(counts), np.int32)
+        tfs = np.asarray([counts[int(t)] for t in ids], np.float32)
+        engine.index.add_document_arrays(name, ids, tfs, float(tfs.sum()))
+
+    def filler(n):
+        return {int(t): int(rng.integers(1, 4))
+                for t in rng.choice(300, size=n, replace=False)}
+
+    for i in range(2400):
+        add(f"n{i}", filler(int(rng.integers(2, 8)))
+            | ({T_COMMON: 1 + i % 3} if i % 5 == 0 else {})
+            | ({T_DEAD: 2} if i % 400 == 7 else {})
+            | ({T_RARE: 1} if i in (100, 1201, 2302) else {}))
+    for i in range(120):
+        add(f"m{i}", filler(int(rng.integers(9, 31)))
+            | ({T_COMMON: 2} if i % 2 else {})
+            | ({T_DEAD: 1} if i % 40 == 3 else {}))
+    for i in range(3):      # 52 distinct terms: 20 spill past the cap
+        add(f"w{i}", {t: 1 + (t + i) % 2 for t in range(i, 250, 5)}
+            | {T_WIDE: 3, T_COMMON: 1})
+    for i in range(6):
+        # the same tf of T_TIE and the same length, 2 and 11 distinct
+        # terms: one score, from the 8 bucket and from the 16 bucket
+        add(f"tie8_{i}", {T_TIE: 2, 10 + i: 18})
+        add(f"tie16_{i}", {T_TIE: 2} | {20 + 10 * i + j: 1 + (j < 8)
+                                        for j in range(10)})
+    engine.commit()
+    for i in range(12):     # f5 ties with them from its shard's delta
+        add(f"f{i}", {T_TIE: 2, T_FRESH: 1, T_COMMON: 1, 299: 16} if i == 5
+            else filler(4) | {T_FRESH: 1 + i % 2, T_COMMON: 1})
+    engine.commit()
+    for name in ("n7", "n407", "m3", "tie16_1", "n1201", "f4"):
+        assert engine.delete(name)
+    engine.commit()
+    return engine
+
+
+@pytest.fixture(scope="module")
+def in_place_engines(tmp_path_factory):
+    built = {}
+
+    def get(shape):
+        if shape not in built:
+            built[shape] = _in_place_engine(
+                tmp_path_factory.mktemp("inplace"), shape)
+        return built[shape]
+
+    return get
+
+
+# case -> (query terms, the request's depth)
+IN_PLACE_CASES = {
+    "tombstones_in_the_base": ((T_DEAD,), 10),
+    "a_residual": ((T_WIDE,), 10),
+    "a_delta_with_a_deleted_slot": ((T_FRESH,), 16),
+    "fewer_than_k_matches": ((T_RARE,), 10),
+    "ties_across_blocks_and_shards": ((T_TIE,), 10),
+    "k_10": ((T_COMMON, 17), 10),
+    "k_deeper_than_a_blocks_live_rows": ((T_COMMON, 17), 64),
+    "group_maxima": ((T_COMMON, 17), 1),
+}
+
+
+class TestTopkInPlace:
+    @pytest.mark.parametrize("shape", [(4, 1), (2, 2)])
+    @pytest.mark.parametrize("case", sorted(IN_PLACE_CASES))
+    def test_step_equals_the_rearranged_formulation(self, in_place_engines,
+                                                    shape, case):
+        """The step's (values, ids) against the formulation it replaced
+        on the same snapshot: values equal to the bit and ids equal,
+        zeros and ties included, on a docs-sharded mesh and on one whose
+        ``"terms"`` axis splits the widths (the psum over the parts)."""
+        engine = in_place_engines(shape)
+        snap = engine.index.snapshot
+        terms, k = IN_PLACE_CASES[case]
+        D = shape[0]
+        lives = np.asarray(snap.shard_live)
+        assert snap.res_nnz == 3 * 20 and lives[:, -1].sum() == 12
+        assert (lives.sum(1) > 64).all()    # no depth here passes a shard
+        qb, _ = engine.searcher._vectorize(
+            [" ".join(f"t{t}" for t in terms), "t1 t2", ""], 8)
+        args = (snap.base, snap.delta, snap.df_g, snap.n_docs, snap.avgdl,
+                qb)
+        kw = dict(engine.searcher._model_kwargs(), k=k, depth=k)
+        vals, gids = make_mesh_ell_search(engine.index.mesh, **kw)(*args)
+        want_vals, want_gids = _rearranged_mesh_search(
+            engine.index.mesh, **kw)(*args)
+        vals, gids = np.asarray(vals), np.asarray(gids)
+        np.testing.assert_array_equal(vals.view(np.int32),
+                                      np.asarray(want_vals).view(np.int32))
+        np.testing.assert_array_equal(gids, np.asarray(want_gids))
+
+        # and the case is in the snapshot it ran on
+        hits = [(snap.name_of(int(g)), float(v))
+                for g, v in zip(gids[0], vals[0]) if v > 0]
+        names = [n for n, _v in hits]
+        shard, row = np.divmod(gids[0], snap.stride)
+        if case == "tombstones_in_the_base":
+            assert sorted(names) == ["m43", "m83", "n1207", "n1607",
+                                     "n2007", "n807"]    # less n7 n407 m3
+        elif case == "a_residual":
+            # T_WIDE is among a wide row's last 20 ids: past the cap
+            assert sorted(names) == ["w0", "w1", "w2"]
+        elif case == "a_delta_with_a_deleted_slot":
+            assert sorted(names) == sorted(
+                f"f{i}" for i in range(12) if i != 4)
+            assert (row[:11] >= snap.base.doc_cap).all()
+        elif case == "fewer_than_k_matches":
+            assert sorted(names) == ["n100", "n2302"] and (vals[0, 2:] == 0
+                                                           ).all()
+        elif case == "ties_across_blocks_and_shards":
+            # thirteen equal scores (twelve in the base, one in a
+            # delta) less the deleted one: the ten lowest (shard, row)
+            # of them, a shard's from its 16 bucket before its 8 bucket
+            assert len(hits) == 10 and len({v for _n, v in hits}) == 1
+            assert (np.diff(shard * snap.stride + row) > 0).all()
+            assert len(set(shard.tolist())) == D
+            widths = [n.split("_")[0] for n in names]
+            assert "tie8" in widths and "tie16" in widths \
+                and "tie16_1" not in names
+        elif case == "k_deeper_than_a_blocks_live_rows":
+            assert len(hits) == 64 and (lives[:, :3] < 64).all()
+        elif case == "group_maxima":
+            caps = snap.topk_block_caps
+            assert topk_widths(caps[3], caps[3], 1) == (128,)
+        else:
+            assert len(hits) == 10
+
+    @pytest.mark.parametrize("shape", [(4, 1), (2, 2)])
+    def test_topk_counters_follow_the_shards_blocks(self, in_place_engines,
+                                                    shape):
+        """A dispatched mesh step raises ``topk_chunks`` / ``_skipped`` /
+        ``_grouped`` by the windows of every docs-shard's top-k over its
+        buckets and its delta (``topk_chunk_counts`` on the snapshot's
+        host integers), as the one-chip step's ``Searcher._rank`` does
+        (``tests/test_topk_blocks.py``)."""
+        engine = in_place_engines(shape)
+        snap = engine.index.snapshot
+        caps = snap.topk_block_caps
+        assert caps[:-1] == tuple(a.shape[1] for a in snap.base.impact)
+        assert np.array_equal(np.asarray(snap.shard_live)[:, :-1],
+                              np.asarray(snap.base.block_live))
+        assert np.array_equal(np.asarray(snap.shard_live)[:, -1],
+                              np.asarray(snap.delta.n_live))
+        for k, grouped in ((1, shape[0]), (10, 0)):
+            want = np.sum([topk_chunk_counts(caps, live, k=k)
+                           for live in snap.shard_live], axis=0)
+            # five blocks a shard, one window each, skipped where the
+            # shard has no row that wide; at k = 1 the crowd's bucket
+            # has eight groups or more and goes by their maxima
+            assert want.tolist() == [
+                5 * shape[0], np.count_nonzero(
+                    np.asarray(snap.shard_live) == 0), grouped]
+            before = global_metrics.snapshot()
+            engine.search_batch([f"t{T_COMMON}"] * 9, k=k)   # two chunks
+            after = global_metrics.snapshot()
+            got = [after.get(key, 0) - before.get(key, 0) for key in
+                   ("mesh_steps", "topk_chunks", "topk_chunks_skipped",
+                    "topk_chunks_grouped")]
+            assert got == [2, *(2 * want).tolist()]

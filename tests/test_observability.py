@@ -1068,9 +1068,11 @@ class TestStageTimer:
             snap.base, snap.delta, snap.df_g, snap.n_docs, snap.avgdl, qb)
         assert "@jit_mesh_ell_search" in lowered.as_text()
         text = lowered.as_text(debug_info=True)
-        for scope in ("ell_blocks", "rearrange_to_real", "coo_residual",
-                      "delta", "shard_topk", "gather_merge"):
+        for scope in ("ell_blocks", "coo_residual", "live_mask", "delta",
+                      "shard_topk", "gather_merge"):
             assert f'loc("{scope}/' in text, scope
+        # the blocks are ranked where they lie: nothing gathers them
+        assert 'loc("rearrange_to_real/' not in text
 
     def test_coalescer_queue_wait_and_wake(self):
         """Two one-item batches through ONE dispatcher whose batch_fn

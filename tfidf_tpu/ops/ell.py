@@ -872,8 +872,9 @@ def score_ell_with_residual(impacts, terms, block_live,
 
 def ell_scores_to_real(parts, block_live, doc_cap: int) -> jax.Array:
     """The ``[B, doc_cap]`` matrix in real doc-id order from per-block
-    scores — for parity mode, probes and tests; the serving path never
-    builds it."""
+    scores — for parity mode, probes and tests; no serving path builds
+    it, on one chip or on the mesh (both rank the blocks in place:
+    ``ops.topk.blocks_topk``)."""
     return _rearrange_to_real(list(parts), [p.shape[1] for p in parts],
                               block_live, doc_cap, parts[0].shape[0])
 

@@ -15,8 +15,10 @@ reduce to the maxima of groups of 128 contiguous columns, then only the
 again inside them, on sub-groups of 8 (:func:`_chunk_topk`, with the proof
 that it is ``lax.top_k``'s own answer, ties included;
 :func:`topk_widths`, the route a shape and a depth take).
-:func:`exact_topk`, the unchunked form the mesh's shards use, is
-``lax.top_k`` itself.
+A mesh shard ranks its buckets and its delta the same way
+(:func:`blocks_topk`, the part of it that both share).
+:func:`exact_topk`, the unchunked form (the COO mesh's shards, parity
+tests), is ``lax.top_k`` itself.
 """
 
 from __future__ import annotations
@@ -411,6 +413,29 @@ def _block_topk(x: jax.Array,      # f32 [B, cap] — one block's scores
     return vals, ids
 
 
+def blocks_topk(blocks, lives: jax.Array, row0s: jax.Array,
+                *, k: int, chunk: int = TOPK_CHUNK
+                ) -> tuple[jax.Array, jax.Array]:
+    """The exact top ``k`` ``[B, k]`` (values, ids) over score blocks
+    read where they lie: block ``i`` ``[B, cap_i]`` holds ids ``row0s[i]
+    .. row0s[i] + lives[i]`` in its first ``lives[i]`` columns (both i32
+    ``[n_blocks]``, TRACED), the rest of it dead. Blocks in ascending id
+    order: a window breaks ties toward its lower column and
+    :func:`merge_topk` toward the earlier window, so a tie goes to the
+    lower id. What :func:`packed_topk_chunked` packs, and what a mesh
+    shard ranks its buckets and its delta by
+    (``parallel.mesh_ell.make_mesh_ell_search``)."""
+    vals, ids = [], []
+    for i, x in enumerate(blocks):
+        v, local = _block_topk(x, lives[i], k=k, chunk=chunk)
+        vals.append(v)
+        ids.append(local + row0s[i])
+    vals, ids = jnp.concatenate(vals), jnp.concatenate(ids)
+    if vals.shape[0] > 1:       # [n_chunks, B, k], ascending id order
+        return merge_topk(vals, ids)
+    return vals[0], ids[0]
+
+
 @functools.partial(jax.jit, static_argnames=("k", "chunk"))
 def packed_topk_chunked(scores, num_docs: jax.Array,
                         base: jax.Array | None = None,
@@ -459,15 +484,8 @@ def packed_topk_chunked(scores, num_docs: jax.Array,
         row0s = jnp.cumsum(lives) - lives
         if base is not None:
             row0s = row0s + base
-        vals, ids = [], []
-        for i, x in enumerate(blocks):
-            v, local = _block_topk(x, lives[i], k=k, chunk=chunk)
-            vals.append(v)
-            ids.append(local + row0s[i])
-        vals, ids = jnp.concatenate(vals), jnp.concatenate(ids)
-        if vals.shape[0] > 1:       # [n_chunks, B, k], real-row order
-            return pack_topk(*merge_topk(vals, ids))
-        return pack_topk(vals[0], ids[0])
+        return pack_topk(*blocks_topk(blocks, lives, row0s, k=k,
+                                      chunk=chunk))
 
 
 @jax.jit

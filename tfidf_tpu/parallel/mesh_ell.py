@@ -47,12 +47,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tfidf_tpu.ops.csr import next_capacity
 from tfidf_tpu.ops.ell import (ELL_WIDTH_LADDER, _pallas_eligible,
-                               _rearrange_to_real, _score_block,
-                               ell_layout_gauges, score_block_pallas)
+                               _score_block, ell_layout_gauges,
+                               score_block_pallas)
 from tfidf_tpu.ops.scoring import (QueryBatch, _compile_queries,
                                    bm25_weights, score_coo_compiled,
                                    tfidf_weights)
-from tfidf_tpu.ops.topk import exact_topk, merge_topk, pack_topk
+from tfidf_tpu.ops.topk import blocks_topk, merge_topk, pack_topk
 from tfidf_tpu.utils.metrics import global_metrics
 
 # the rungs EVERY mesh index has, whatever its documents: a corpus of
@@ -345,7 +345,19 @@ def make_mesh_ell_search(mesh: Mesh,
                          use_pallas: bool = True,
                          packed: bool = False,
                          depth: int | None = None):
-    """Distributed search over ELL base + COO delta.
+    """Distributed search over ELL base + COO delta: ONE ``shard_map``
+    program a batch. A docs-shard scores each width bucket into its own
+    ``[B, rows_cap]`` block (the kernel, as on one chip) and its top-k
+    READS THE BLOCKS WHERE THEY LIE (``ops.topk.blocks_topk``, what the
+    one-chip step's ``packed_topk_chunked`` runs): a shard's rows are
+    sorted by width, so block-then-column order is ELL-row order; the
+    residual (rows of the widest bucket only) adds to block 0, the
+    ``"terms"`` psum runs over the blocks, the tombstone mask reaches a
+    block by a slice at its first row, and the delta is one more block
+    behind them. No ``[B, doc_cap]`` matrix in row order is built and
+    nothing is gathered. Then ``all_gather`` + ``merge_topk`` over the
+    docs axis: ties go to the lower ELL row within a shard and to the
+    lower shard across them.
 
     Returned callable:
         search(base: MeshEllArrays, delta: ShardedArrays, df_g, n, avgdl,
@@ -406,17 +418,31 @@ def make_mesh_ell_search(mesh: Mesh,
                 else:
                     parts.append(_score_block(imp, term, slot_of, qc_t,
                                               2048))
-        with jax.named_scope("rearrange_to_real"):
-            ell_scores = _rearrange_to_real(
-                parts, [imp.shape[0] for imp in impacts], block_live,
-                doc_cap_ell, B)
+        # The scores STAY where the scorers wrote them, a block a
+        # bucket. Rows are sorted by width, descending, so block i's
+        # first block_live[i] columns are the ELL rows from row0s[i]
+        # on: what lives in row space reaches a block by a slice there,
+        # and no [B, doc_cap] matrix in row order is built.
+        caps = [p.shape[1] for p in parts]
+        row0s = jnp.cumsum(block_live) - block_live
         with jax.named_scope("coo_residual"):
-            ell_scores = ell_scores + score_coo_compiled(
-                res_tf, res_term, res_doc, res_dl, df_g, slot_of, qc_ext,
-                n_docs, avgdl, None, model=model, k1=k1, b=b,
-                chunk=min(1 << 10, res_tf.shape[0]))
-        ell_scores = jax.lax.psum(ell_scores, "terms")
-        ell_scores = ell_scores * base_live[None, :]
+            # only the widest bucket spills (build_mesh_ell), and its
+            # columns ARE ELL rows 0 ..: the residual is scored in that
+            # block's row space (a pad entry's row, doc_cap - 1, falls
+            # outside it and is dropped by the segment sum)
+            assert caps[0] <= doc_cap_ell, (caps[0], doc_cap_ell)
+            parts[0] = parts[0] + score_coo_compiled(
+                res_tf, res_term, res_doc, res_dl[:caps[0]], df_g,
+                slot_of, qc_ext, n_docs, avgdl, None, model=model, k1=k1,
+                b=b, chunk=min(1 << 10, res_tf.shape[0]))
+        parts = jax.lax.psum(tuple(parts), "terms")
+        with jax.named_scope("live_mask"):
+            # tombstones score 0: the mask padded by the widest
+            # capacity, so that no block's slice is clamped
+            live_rows = jnp.pad(base_live, (0, max(caps)))
+            parts = [p * jax.lax.dynamic_slice_in_dim(
+                live_rows, row0s[i], cap)[None, :]
+                for i, (p, cap) in enumerate(zip(parts, caps))]
 
         # --- COO delta (appends since the last re-shard) ---
         with jax.named_scope("delta"):
@@ -428,12 +454,17 @@ def make_mesh_ell_search(mesh: Mesh,
             delta_scores = delta_scores * d_live[None, :]
 
         with jax.named_scope("shard_topk"):
-            scores = jnp.concatenate([ell_scores, delta_scores], axis=1)
-            n_local = jnp.int32(doc_cap_ell) + d_n
-            # mask via per-position liveness, not a row-count prefix: the
-            # ELL space is permuted, so exact_topk's prefix mask is wrong
-            # — dead positions already score 0 and top_k handles the rest
-            vals, ids = exact_topk(scores, n_local, k=k)
+            # every bucket and, behind them, the delta as one more
+            # block (its slots' ids from doc_cap_ell on), each ranked
+            # in place as the one-chip step ranks its blocks: a block's
+            # dead tail (past block_live[i]; past d_n in the delta) is
+            # masked to -inf there, and a tombstone inside it scores 0
+            vals, ids = blocks_topk(
+                (*parts, delta_scores),
+                jnp.concatenate([block_live, d_n[None]]),
+                jnp.concatenate([row0s,
+                                 jnp.full((1,), doc_cap_ell, jnp.int32)]),
+                k=k)
         with jax.named_scope("gather_merge"):
             shard_idx = jax.lax.axis_index("docs").astype(jnp.int32)
             gids = (shard_idx * jnp.int32(doc_cap_ell + doc_cap_delta)

@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from tfidf_tpu.ops.csr import CooShard, next_capacity
 from tfidf_tpu.ops.dfdelta import DfDeltaApplier
 from tfidf_tpu.ops.ell import _pallas_eligible
+from tfidf_tpu.ops.topk import topk_chunk_counts
 from tfidf_tpu.parallel.mesh_ell import (MeshEllArrays, build_mesh_ell,
                                          make_impact_refresh,
                                          make_mesh_ell_search,
@@ -48,10 +49,15 @@ class MeshEllSnapshot:
 
     def __init__(self, *, base: MeshEllArrays, delta: ShardedArrays,
                  perms, base_counts, shard_docs, df_g, n_docs, avgdl,
-                 version, nnz, total_live, res_nnz=0) -> None:
+                 version, nnz, total_live, shard_live,
+                 res_nnz=0) -> None:
         self.base = base
         self.delta = delta
         self.res_nnz = res_nnz             # live residual entries
+        # per docs-shard, host integers: the live rows of each bucket,
+        # then the delta's occupied slots (the blocks a shard's top-k
+        # reads, in their order: ``topk_block_caps``)
+        self.shard_live = shard_live
         self.perms = perms                 # per shard: ell_row -> ins id
         self.base_counts = base_counts     # docs in base per shard
         self.shard_docs = shard_docs
@@ -65,6 +71,13 @@ class MeshEllSnapshot:
     @property
     def stride(self) -> int:
         return self.base.doc_cap + self.delta.doc_cap
+
+    @property
+    def topk_block_caps(self) -> tuple[int, ...]:
+        """The columns of each score block a shard's top-k reads: its
+        buckets' row capacities, then the delta's slots."""
+        return (*(imp.shape[1] for imp in self.base.impact),
+                self.delta.doc_cap)
 
     def name_of(self, gid: int) -> str | None:
         s, local = divmod(gid, self.stride)
@@ -96,6 +109,7 @@ class MeshEllIndex(MeshIndex):
         self.delta_rebuild_frac = delta_rebuild_frac
         self._base: MeshEllArrays | None = None
         self._res_nnz = 0
+        self._block_live = np.zeros((self.D, 0), np.int32)
         self._perms: list[np.ndarray] = []
         self._base_counts: list[int] = []
         self._refresh_fn = None
@@ -273,7 +287,11 @@ class MeshEllIndex(MeshIndex):
                 shard_docs=self._shard_docs,
                 df_g=df_g, n_docs=n_docs, avgdl=avgdl,
                 version=self._version, nnz=self.nnz_live,
-                total_live=len(self._placed), res_nnz=self._res_nnz)
+                total_live=len(self._placed), res_nnz=self._res_nnz,
+                shard_live=tuple(
+                    (*self._block_live[s].tolist(), len(sd) - bc)
+                    for s, (sd, bc) in enumerate(zip(
+                        self._shard_docs, self._base_counts))))
             self.snapshot = snap
             self._committed_gen = gen0
         global_metrics.set_gauge("index_docs", snap.total_live)
@@ -357,6 +375,7 @@ class MeshEllIndex(MeshIndex):
         with trace_phase("mesh_build_upload"):
             base = jax.block_until_ready(place_mesh_ell(host, self.mesh))
         self._res_nnz = host.res_nnz
+        self._block_live = host.block_live
         del host
         self._shard_docs = shard_docs
         self._placed = placed
@@ -539,6 +558,16 @@ class MeshEllSearcher(MeshSearcher):
         self._count_kernel_uniq(qb)
         # what the step scores by the scatter path, on every shard
         global_metrics.inc("residual_entries_scored", snap.res_nnz)
+        # the windows of every shard's top-k over its score blocks,
+        # those wholly in dead tails and those ranked by group maxima
+        # (as ``Searcher._rank`` counts the one-chip step's)
+        caps = snap.topk_block_caps
+        chunks, skipped, grouped = np.sum(
+            [topk_chunk_counts(caps, live, k=kk)
+             for live in snap.shard_live], axis=0).tolist()
+        global_metrics.inc("topk_chunks", chunks)
+        global_metrics.inc("topk_chunks_skipped", skipped)
+        global_metrics.inc("topk_chunks_grouped", grouped)
         return self._get_search_fn(kk, depth)(
             snap.base, snap.delta, snap.df_g, snap.n_docs,
             snap.avgdl, qb), depth

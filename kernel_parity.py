@@ -39,6 +39,7 @@ callers share ``run_case``:
 from __future__ import annotations
 
 import importlib.util
+import inspect
 import json
 import os
 import sys
@@ -65,7 +66,9 @@ def log(msg):
 
 def make_case(rng, *, rows_cap, width, n_rows, B, n_terms, u_req,
               vocab=500_000, ragged=False, multiplicity=False):
-    """Random ELL block + query batch. Term ids are DISTINCT within
+    """Random ELL block + query batch, the block as a case is written
+    and read: a document a line, ``[rows_cap, width]`` (the device takes
+    it through :func:`width_major`). Term ids are DISTINCT within
     each row (the layout contract every ELL builder guarantees and the
     kernel's select chain relies on: stride-offset construction — position
     w draws from the congruence class w mod width). Pad rows
@@ -103,17 +106,29 @@ def make_case(rng, *, rows_cap, width, n_rows, B, n_terms, u_req,
     return imp, term, qb
 
 
+def width_major(block: np.ndarray) -> jax.Array:
+    """A case's ``[rows_cap, width]`` block on the device as the index
+    holds it and the kernel reads it: ``[width, rows_cap]``."""
+    return jnp.asarray(np.ascontiguousarray(block.T))
+
+
 def kernel_of(tree: str):
     """``score_block_pallas`` of the checkout at ``tree`` (a parent
     commit unpacked beside this one), its ``ops/ell.py`` loaded under a
-    module name of its own: everything it imports is this tree's."""
+    module name of its own: everything it imports is this tree's. It
+    takes this tree's width-major blocks: a checkout from before PR 43,
+    whose wrapper takes ``[rows_cap, width]`` and turns it itself, is
+    handed them turned back."""
     spec = importlib.util.spec_from_file_location(
         "tfidf_tpu.ops._ell_against",
         os.path.join(tree, "tfidf_tpu", "ops", "ell.py"))
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
-    return mod.score_block_pallas
+    kernel = mod.score_block_pallas
+    if "impact.T" not in inspect.getsource(kernel):
+        return kernel
+    return lambda imp, term, *rest: kernel(imp.T, term.T, *rest)
 
 
 def _kernel_and_oracle(imp, term, qb, vocab, n_rows, against=None):
@@ -121,7 +136,7 @@ def _kernel_and_oracle(imp, term, qb, vocab, n_rows, against=None):
     scores of one block under one batch, from ONE jitted program, and
     whether ``against``, another checkout's ``score_block_pallas``
     (:func:`kernel_of`), gave the kernel's bits (None without one)."""
-    imp_d, term_d = jnp.asarray(imp), jnp.asarray(term)
+    imp_d, term_d = width_major(imp), width_major(term)
     n_rows = jnp.int32(n_rows)
 
     @jax.jit
@@ -219,11 +234,17 @@ def run_trap_case(rng, against=None, *, vocab=500_000, **kw):
     equal = bool(np.array_equal(out, ref))
     term0_live = bool(np.array_equal(out[0, rows], imp[rows, 0])
                       and out[0, rows].all())
-    ok = equal and term0_live and same is not False
+    # the block as the kernel was handed it: down the width axis of
+    # ``[width, rows_cap]`` no live entry stands behind a pad
+    filled = imp.T != 0
+    trail = bool(filled.shape == (kw["width"], kw["rows_cap"])
+                 and (filled[1:] <= filled[:-1]).all())
+    ok = equal and term0_live and trail and same is not False
     log(f"[trap] width={kw['width']} oracle_bit_equal={equal} "
         f"term0_live={term0_live} bit_equal_against={same} ok={ok}")
     return {"name": "trap", "oracle_bit_equal": equal,
             "term0_live": term0_live, "term0_rows": int(rows.size),
+            "pads_trail_the_width": trail,
             "bit_equal_against": same, "ok": ok, **kw}
 
 
@@ -287,7 +308,8 @@ TIE_IMPACT = 1000.0     # over any sum of four impacts under 1 times 3
 def run_stretch_case(rng, *, rows_cap, width, B, n_blocks, last_live,
                      vocab=500_000):
     """The stretched step against the whole one: ``n_blocks`` blocks
-    ``[rows_cap, width]`` (the last with ``last_live`` live rows), scored
+    of ``rows_cap`` rows, ``width`` wide (the last with ``last_live``
+    live rows), scored
     and ranked ONE BLOCK A STRETCH — ``score_ell_batch`` on the block,
     ``packed_topk_chunked`` with the stretch's base row, ``merge_packed``
     — and once by the one program pair over every block. The packed
@@ -315,8 +337,8 @@ def run_stretch_case(rng, *, rows_cap, width, B, n_blocks, last_live,
         edge = np.r_[0:5 if i else 0, live - 5:live]
         term[edge, 0] = tie_term
         imp[edge, 0] = TIE_IMPACT
-        imps.append(jnp.asarray(imp))
-        terms.append(jnp.asarray(term))
+        imps.append(width_major(imp))
+        terms.append(width_major(term))
     q_terms[:3, 0], q_weights[:3, 0] = tie_term, 1.0
     q_terms[1:3, 1:3], q_weights[1:3, 1:3] = q_terms[3:5, :2], 1.0
     qb = make_query_batch(q_terms, q_weights, min_slots=1024)
@@ -390,8 +412,8 @@ def run_mesh_case(rng, *, docs, B, u_req, vocab=500_000, devices=None,
         rows.append(ids)
     snap = index.commit(vocab)
     shapes = [tuple(a.shape) for a in snap.base.impact]
-    wide = [s[2] for s in shapes if s[2] > 256]
-    eligible = all(_pallas_eligible(s[1], B, u_req) for s in shapes[:2])
+    wide = [s[1] for s in shapes if s[1] > 256]     # [D, width, rows]
+    eligible = all(_pallas_eligible(s[2], B, u_req) for s in shapes[:2])
     steps = [make_mesh_ell_search(index.mesh, k=TOP_K, model="bm25", k1=0.9,
                                   b=0.4, use_pallas=use)
              for use in (True, False)]

@@ -73,7 +73,7 @@ def compile_block(dev, rows: int, width: int, B: int,
 
     assert ell._pallas_eligible(rows, B, u_cap)
     fn = jax.jit(ell.score_block_pallas)
-    fn.lower(s((rows, width), jnp.float32), s((rows, width), jnp.int32),
+    fn.lower(s((width, rows), jnp.float32), s((width, rows), jnp.int32),
              s((u_cap,), jnp.int32), s((), jnp.int32),
              s((B, u_cap + 1), jnp.float32), s((), jnp.int32)).compile()
 
@@ -142,7 +142,8 @@ def compile_mesh_step(devices) -> list[dict]:
         assert widths == mesh_ell_widths(widths[0])
         rows, doc_cap = shard["rows"], shard["doc_cap"]
         vocab_cap = shard["vocab_cap"]
-        blocks = tuple([4, r, w] for r, w in zip(rows, widths))
+        # a bucket is held width-major: [D, width, rows_cap]
+        blocks = tuple([4, w, r] for r, w in zip(rows, widths))
         by_rows = tuple([4, r] for r in rows)
         base = dataclasses.replace(
             jax.tree.map(abstract, snap.base),
@@ -179,6 +180,8 @@ def compile_mesh_step(devices) -> list[dict]:
                 else [f"f32[{B},{doc_cap}]", f"f32[{doc_cap},{B}]"])
             out.append({"cell": name, "B": B,
                         "row_order_shapes": [x for x in whole if x in text],
+                        "block_copies": block_copies(
+                            text, list(zip(rows, widths)), B),
                         "digest": program_digest(step),
                         "kernels": sorted(set(re.findall(
                             r"ell_score_v4_w(\d+)", text)), key=int),
@@ -205,6 +208,30 @@ CELL_STEPS = {
     # tests/test_mesh_block_capacities.py holds to the generator)
     "msmarco-doc": (((65536, 512), (524288, 384)), 1 << 19, (512,)),
 }
+
+
+def block_copies(text: str, blocks, B: int) -> list[str]:
+    """The ``copy`` / ``transpose`` instructions of a compiled program
+    (fused ones included) whose result is as large as one of
+    ``blocks``, ``(rows_cap, width)`` pairs, whichever way it lies and
+    whatever axes of 1 lead it: ``s32[524288,384]``, ``f32[1,512,65536]``.
+    A step that held a block ``[rows, width]`` turned it for the kernel
+    in such copies from 128 wide on, every posting of the block a call
+    (PR 43). An f32 ``[B, rows_cap]`` is left out: that is a block of
+    SCORES (at B = 512 as large as the 512-wide block of impacts),
+    which ``row_order_shapes`` and the temporaries hold; a turned block
+    is found by its terms, which are turned with it. Returned as the
+    text has them, dtype and shape."""
+    sizes = {tuple(sorted(b)) for b in blocks}
+    scores = {(B, rows) for rows, _w in blocks}
+    found = set()
+    for dtype, dims in re.findall(
+            r"= ([fs]32)\[([\d,]+)\]\S* (?:copy|transpose)\(", text):
+        shape = tuple(int(d) for d in dims.split(",") if d != "1")
+        if tuple(sorted(shape)) in sizes \
+                and not (dtype == "f32" and shape in scores):
+            found.add(f"{dtype}[{dims}]")
+    return sorted(found)
 
 
 def program_digest(program) -> str:
@@ -241,8 +268,11 @@ def compile_step_pair(dev, blocks, doc_cap: int, B: int,
     q = QueryBatch(uniq=s((1024,), i32), n_uniq=s((), i32),
                    slots=s((B, 32), i32), weights=s((B, 32), f32))
     live = s((len(blocks),), i32)
+    # ``blocks`` are (rows_cap, width); the index holds a block
+    # width-major, [width, rows_cap]
+    held = [(w, r) for r, w in blocks]
     score = ell._score_ell_batch_jit.lower(
-        tuple(s(b, f32) for b in blocks), tuple(s(b, i32) for b in blocks),
+        tuple(s(b, f32) for b in held), tuple(s(b, i32) for b in held),
         live, None, None, None, s((doc_cap,), f32), s((1 << 19,), f32), q,
         s((), f32), s((), f32), s((doc_cap,), f32),
         model="bm25", use_pallas=True).compile()
@@ -257,7 +287,9 @@ def compile_cell_step(dev, blocks, doc_cap: int, B: int) -> dict:
     program, then the top-k over the blocks it returns. Neither may
     hold a ``[B, padded rows + 1]`` or ``[B, doc_cap]`` f32 array — the
     score space exists once, where the kernel wrote it. Returns the two
-    programs' digests (:func:`program_digest`)."""
+    programs' digests (:func:`program_digest`), the score program's
+    temporaries and the block-sized copies in it
+    (:func:`block_copies`)."""
     score, topk = compile_step_pair(dev, blocks, doc_cap, B)
     assert score.as_text().count("tpu_custom_call") >= min(len(blocks), 4)
     rows = [r for r, _ in blocks]
@@ -268,7 +300,9 @@ def compile_cell_step(dev, blocks, doc_cap: int, B: int) -> dict:
         text = program.as_text()
         for shape in gone:
             assert shape not in text, f"{shape} is back in the step"
-    return {"score": program_digest(score), "topk": program_digest(topk)}
+    return {"score": program_digest(score), "topk": program_digest(topk),
+            "temp_bytes": score.memory_analysis().temp_size_in_bytes,
+            "block_copies": block_copies(score.as_text(), blocks, B)}
 
 
 # what a v5e chip reports as memory_stats()["bytes_limit"] (my chip run,

@@ -546,7 +546,7 @@ class TestClusterEndToEnd:
             snap = w.engine.index.snapshot
             assert len(snap.ell_impacts) >= 2
             counts = topk_chunk_counts(
-                [imp.shape[0] for imp in snap.ell_impacts],
+                [imp.shape[1] for imp in snap.ell_impacts],
                 snap.ell_live_host, k=10)
             want = [a + b for a, b in zip(want, counts)]
         assert after["dispatch_chunks"] - before["dispatch_chunks"] == 2

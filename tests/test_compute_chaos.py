@@ -440,6 +440,38 @@ class TestFallbackParity:
         assert np.asarray(dev[0]).tobytes() == host[0].tobytes()
         assert np.array_equal(np.asarray(dev[1]), host[1])
 
+    @pytest.mark.parametrize("width_cap", [None, 24],
+                             ids=["no_residual", "residual"])
+    def test_bit_parity_over_fetched_width_major_blocks(self, tmp_path,
+                                                        width_cap):
+        """The mirror scores from the blocks AS FETCHED, ``[width,
+        rows_cap]`` (PR 43; no host copy turns them): several blocks
+        whose width is not their row capacity, with and without a
+        residual, the device's hits bit for bit."""
+        e = make_engine(tmp_path, scoring_layout="ell",
+                        ell_width_cap=width_cap)
+        rng = np.random.default_rng(11)
+        for i, n in enumerate([40] * 3 + [18] * 10 + [5] * 20):
+            words = rng.choice(60, size=n, replace=False)
+            e.ingest_text(f"long{i}.txt", " ".join(f"w{w}" for w in words)
+                          + " fast cat")
+        e.commit()
+        snap = e.index.snapshot
+        shapes = [tuple(a.shape) for a in snap.ell_impacts]
+        assert shapes == ([(48, 8)] if width_cap is None else []) \
+            + [(24, 16), (8, 32)]
+        assert (snap.res_tf is not None) == (width_cap is not None)
+        fb = HostFallbackScorer(e.searcher)
+        queries = QUERIES + ["w1 w2 fast", "w7", "w30 w31 cat night"]
+        host = fb.search_arrays(queries, k=5)
+        mirror = fb._mirror_for(snap)
+        assert [a.shape for a in mirror.imps] == shapes \
+            == [a.shape for a in mirror.terms]
+        dev = e.searcher.search_arrays(queries, k=5)
+        assert np.asarray(dev[0]).tobytes() == host[0].tobytes()
+        assert np.array_equal(np.asarray(dev[1]), host[1])
+        assert np.asarray(dev[0])[-1].max() > 0
+
     def test_bit_parity_assembled_hits_and_unbounded(self, tmp_path):
         e = make_engine(tmp_path)
         fb = HostFallbackScorer(e.searcher)

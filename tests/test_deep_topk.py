@@ -222,7 +222,7 @@ def _same(got, want):
 
 def test_corpus_has_the_shape_the_cases_need(deep):
     snap = deep.local.index.snapshot
-    assert [imp.shape[0] for imp in snap.ell_impacts] == BLOCK_ROWS
+    assert [imp.shape[1] for imp in snap.ell_impacts] == BLOCK_ROWS
     assert snap.ell_live_host == tuple(c for c, _n in CROWDS)
     assert deep.local.compute_stats()["kernel_blocks"] == 4
     # rows lie in the order the documents went in, so the edge
@@ -375,7 +375,7 @@ def test_depth_1000_by_candidates_equals_the_reference(wide, door):
     window."""
     from tfidf_tpu.utils.metrics import global_metrics
     snap = wide.local.index.snapshot
-    caps = [imp.shape[0] for imp in snap.ell_impacts]
+    caps = [imp.shape[1] for imp in snap.ell_impacts]
     assert caps == [512, 65536]
     assert topk_widths(65536, 65536, DEPTH) == (TOPK_SUBGROUP,)
     assert topk_chunk_counts(caps, snap.ell_live_host,

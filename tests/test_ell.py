@@ -41,7 +41,7 @@ def build_ell_arrays(coo, model, n_docs, avgdl, *, width_cap,
     ell = build_ell_from_coo(coo, width_cap=width_cap, min_rows=min_rows)
     impacts, terms, live = [], [], []
     for blk in ell.blocks:
-        rows_cap = blk.tf.shape[0]
+        rows_cap = blk.tf.shape[1]
         dl = np.zeros(rows_cap, np.float32)
         dl[:blk.n_rows] = coo.doc_len[blk.row0:blk.row0 + blk.n_rows]
         nrm = np.zeros(rows_cap, np.float32)
@@ -70,7 +70,7 @@ class TestBuild:
                        if b.row0 <= d < b.row0 + b.n_rows)
             r = d - blk.row0
             row = {int(t): float(f)
-                   for t, f in zip(blk.term[r], blk.tf[r]) if f > 0}
+                   for t, f in zip(blk.term[:, r], blk.tf[:, r]) if f > 0}
             assert row == {t: float(f) for t, f in counts.items()}
 
     def test_blocks_bucketed_by_width(self, rng):
@@ -88,7 +88,8 @@ class TestBuild:
             covered += b.n_rows
         assert covered == len(docs)
         # padding stays bounded: blocked entries < 2x the true nnz + bucket
-        padded = sum(b.tf.shape[0] * b.width for b in ell.blocks)
+        padded = sum(b.tf.shape[1] * b.width for b in ell.blocks)
+        assert all(b.tf.shape[0] == b.width for b in ell.blocks)
         assert padded < 2 * coo.nnz + 8 * 256
 
     def test_spill_to_residual(self, rng):
@@ -275,6 +276,8 @@ class TestPallasKernel:
     the same kernels run compiled on TPU)."""
 
     def _block(self, rng, rows_cap, width, vocab):
+        """A random block, width-major ``[width, rows_cap]`` as the
+        index holds it (drawn a document a line, then turned)."""
         imp = rng.random((rows_cap, width), dtype=np.float32)
         # distinct term ids within each row — the layout contract every
         # ELL builder guarantees (one posting per distinct term) and
@@ -288,7 +291,7 @@ class TestPallasKernel:
         # pad tail rows like a real block
         imp[-rows_cap // 4:] = 0.0
         term[-rows_cap // 4:] = 0
-        return jnp.asarray(imp), jnp.asarray(term)
+        return jnp.asarray(imp.T), jnp.asarray(term.T)
 
     @pytest.mark.parametrize("vocab", [1 << 12, 1 << 17])
     def test_matches_xla_block_path(self, rng, vocab):
@@ -345,7 +348,7 @@ class TestPallasKernel:
         imp, term = self._block(rng, rows_cap, width, vocab)
         T = len(weights)
         # terms the block holds, so every query hits
-        ids = rng.choice(np.unique(np.asarray(term)[:rows_cap // 2]),
+        ids = rng.choice(np.unique(np.asarray(term)[:, :rows_cap // 2]),
                          size=n_uniq, replace=False)
         # every id is used: walk the ids T at a time, wrapping
         assert T < n_uniq <= B * T
@@ -444,8 +447,8 @@ class TestPallasKernel:
                                            make_query_batch)
         vocab = 64
         rows_cap, width, B = 512, 8, 8
-        imp = np.abs(rng.random((rows_cap, width), dtype=np.float32))
-        term = np.zeros((rows_cap, width), np.int32)   # ALL term 0
+        imp = np.abs(rng.random((width, rows_cap), dtype=np.float32))
+        term = np.zeros((width, rows_cap), np.int32)   # ALL term 0
         q_terms = np.full((B, 2), 5, np.int32)         # term 0 not queried
         q_weights = np.ones((B, 2), np.float32)
         qb = make_query_batch(q_terms, q_weights, min_slots=16)

@@ -26,7 +26,7 @@ from kernel_parity import (MESH_INTERPRET_CASE,  # noqa: E402
                            STRETCH_INTERPRET_CASE, TOP_K,
                            TOPK_INTERPRET_CASE, make_case, run_case,
                            run_mesh_case, run_stretch_case, run_topk_case,
-                           run_trap_case)
+                           run_trap_case, width_major)
 from tfidf_tpu.ops import ell  # noqa: E402
 from tfidf_tpu.ops.csr import build_coo  # noqa: E402
 from tfidf_tpu.ops.ell import (_pallas_eligible, _pl_tiles,  # noqa: E402
@@ -84,7 +84,7 @@ def test_mesh_case_of_the_matrix():
                       **MESH_INTERPRET_CASE)
     assert r["ok"], r
     assert r["devices"] == 4 and r["residual_nnz"] == 0
-    assert r["buckets"] == [[512, 512], [512, 384]]
+    assert r["buckets"] == [[512, 512], [384, 512]]    # [width, rows]
     assert sorted(r["weights"]) == ["fractional", "multiplicity"]
 
 
@@ -107,6 +107,9 @@ def test_pad_trap(width):
     assert r["ok"], r
     assert r["oracle_bit_equal"] and r["term0_live"]
     assert r["term0_rows"] >= 128
+    # the block the kernel was handed is width-major and its pads
+    # trail down the WIDTH axis, rows of every length from 1 up
+    assert r["pads_trail_the_width"]
 
 
 def _all_eqns(jaxpr, out=None):
@@ -134,7 +137,7 @@ def test_a_build_is_one_select_chain():
     width, rows_cap, td = 38, 512, 512
     f32, i32 = jnp.float32, jnp.int32
     jaxpr = jax.make_jaxpr(score_block_pallas)(
-        jnp.zeros((rows_cap, width), f32), jnp.zeros((rows_cap, width), i32),
+        jnp.zeros((width, rows_cap), f32), jnp.zeros((width, rows_cap), i32),
         jnp.zeros((_U_CAP,), i32), jnp.int32(5),
         jnp.zeros((_B, _U_CAP + 1), f32), jnp.int32(rows_cap))
     eqns = _all_eqns(jaxpr.jaxpr)
@@ -156,8 +159,9 @@ def test_a_build_is_one_select_chain():
 
 def _mesh_shards(docs):
     """``build_mesh_ell`` over a (2, 2) mesh of the CPU's devices, as
-    each device holds it: the ``tf`` of every bucket's every shard, the
-    terms axis's contiguous width slice included."""
+    each device holds it: the ``tf`` ``[1, width / 2, rows_cap]`` of
+    every bucket's every shard, the terms axis's contiguous slice of
+    the width axis included."""
     from tfidf_tpu.engine.index import DocEntry
     from tfidf_tpu.parallel.mesh import make_mesh
     from tfidf_tpu.parallel.mesh_ell import build_mesh_ell, place_mesh_ell
@@ -170,30 +174,36 @@ def _mesh_shards(docs):
     host, _perm = build_mesh_ell([entries[0::2], entries[1::2]], mesh,
                                  lambda x: x, min_rows=8)
     arrays = place_mesh_ell(host, mesh)
-    assert any(sh.data.shape[-1] < a.shape[-1]
+    assert all(a.shape[1] == w for a, w in zip(
+        arrays.tf, (256, 192, 128, 96, 64, 48, 32, 24, 16, 8)))
+    assert all(sh.data.shape[-2] * 2 == a.shape[-2]
                for a in arrays.tf for sh in a.addressable_shards)
     return [np.asarray(sh.data) for a in arrays.tf
             for sh in a.addressable_shards]
 
 
 def _coo_blocks(docs):
-    """``build_ell_from_coo``'s blocks over the same documents, longest
-    first as ``ShardIndex.to_coo`` hands them over."""
+    """``build_ell_from_coo``'s blocks ``[width, rows_cap]`` over the
+    same documents, longest first as ``ShardIndex.to_coo`` hands them
+    over."""
     docs = sorted(docs, key=len, reverse=True)
     coo = build_coo(docs, vocab_cap=512, min_nnz_cap=1 << 10,
                     min_doc_cap=64)
-    return [b.tf for b in build_ell_from_coo(coo, width_cap=64,
-                                             min_rows=8).blocks]
+    blocks = build_ell_from_coo(coo, width_cap=64, min_rows=8).blocks
+    assert all(b.tf.shape[0] == b.width for b in blocks)
+    return [b.tf for b in blocks]
 
 
 @pytest.mark.parametrize("blocks_of", [_coo_blocks, _mesh_shards],
                          ids=["build_ell_from_coo", "build_mesh_ell"])
 def test_builders_trail_their_pads(blocks_of):
     """The other half of the select chain's contract, at the two
-    builders that feed the kernel: in every row of every block (on the
-    mesh: of every device's slice of the width) the non-zero entries
-    all precede the first pad, so the chain, walking the width from its
-    last row down, applies a live entry after every pad of its row."""
+    builders that feed the kernel: in every document of every block
+    (on the mesh: of every device's slice of the width) the non-zero
+    entries all precede the first pad DOWN THE WIDTH AXIS (the last but
+    one: a block is ``[width, rows_cap]``), so the chain, walking the
+    width from its last row down, applies a live entry after every pad
+    of its document."""
     rng = np.random.default_rng(33)
     docs = []
     for _ in range(120):
@@ -206,7 +216,7 @@ def test_builders_trail_their_pads(blocks_of):
     live = 0
     for tf in blocks:
         filled = tf != 0
-        assert (filled[..., 1:] <= filled[..., :-1]).all()
+        assert (filled[..., 1:, :] <= filled[..., :-1, :]).all()
         live += int(filled.sum())
     assert live == sum(len(d) for d in docs)
 
@@ -317,7 +327,7 @@ def _kernel_scores(imp, term, q, *, poison=False):
         uniq[n:] = np.resize(term[:_LIVE_ROWS, :4].ravel(), _U_CAP - n)
         qc_ext = qc_ext.at[:, n:_U_CAP].set(7.0)
     return np.asarray(_kernel(
-        jnp.asarray(imp), jnp.asarray(term), jnp.asarray(uniq),
+        width_major(imp), width_major(term), jnp.asarray(uniq),
         jnp.int32(n), qc_ext, jnp.int32(_LIVE_ROWS)))
 
 
@@ -330,7 +340,7 @@ def test_subtile_nest_matches_xla(n_uniq, width):
     imp, term, q = _subtile_case(n_uniq, width)
     got = _kernel_scores(imp, term, q)
     slot_of, qc_ext = _compiled(q)
-    want = np.asarray(_xla(jnp.asarray(imp), jnp.asarray(term),
+    want = np.asarray(_xla(width_major(imp), width_major(term),
                            slot_of, qc_ext.T, 2048))
     assert np.abs(want).max() > 0
     assert np.abs(got - want)[:, :_LIVE_ROWS].max() < 1e-4

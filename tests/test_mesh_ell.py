@@ -409,7 +409,7 @@ class TestWideRows:
         assert widths[0] == TOP and widths[-len(PARENT_WIDTHS):] \
             == PARENT_WIDTHS
         assert [tuple(a.shape) for a in snap.base.impact] \
-            == [(D, 256, w) for w in widths]
+            == [(D, w, 256) for w in widths]      # [D, width, rows]
         stats = engine.compute_stats()
         assert stats["kernel_blocks"] == stats["posting_blocks"] \
             == len(widths)
@@ -444,7 +444,7 @@ class TestWideRows:
                                          res_tf=jnp.zeros_like(base.res_tf))
         else:
             broken = dataclasses.replace(base, impact=tuple(
-                imp.at[:, :, 256:].set(0.0) for imp in base.impact))
+                imp.at[:, 256:, :].set(0.0) for imp in base.impact))
         monkeypatch.setattr(snap, "base", broken)
         with pytest.raises(AssertionError):
             _assert_equals_float64_bm25(engine, *wide_corpus)
@@ -486,7 +486,7 @@ class TestOneLadder:
         per_shard = [entries[s::D] for s in range(D)]
         host, perms = build_mesh_ell(per_shard, mesh, lambda x: x * 0.5,
                                      width_cap=None, min_rows=8)
-        assert tuple(a.shape[2] for a in host.tf) == PARENT_WIDTHS
+        assert tuple(a.shape[1] for a in host.tf) == PARENT_WIDTHS
         assert host.res_nnz == 0 and not host.res_tf.any()
         for s, mine in enumerate(per_shard):
             order = sorted(range(len(mine)),
@@ -499,16 +499,19 @@ class TestOneLadder:
                 b = max(j for j, w in enumerate(PARENT_WIDTHS) if k <= w)
                 r = cursor[b]
                 cursor[b] += 1
-                assert (host.term[b][s, r, :k] == e.term_ids).all()
-                assert (host.tf[b][s, r, :k] == e.tfs).all()
-                assert not host.tf[b][s, r, k:].any()
+                # a bucket is [D, width, rows]: document r is a column,
+                # its entries lead down the width and its pads trail
+                assert (host.term[b][s, :k, r] == e.term_ids).all()
+                assert (host.tf[b][s, :k, r] == e.tfs).all()
+                assert not host.tf[b][s, k:, r].any()
+                assert not host.term[b][s, k:, r].any()
                 assert host.dl[b][s, r] == np.float32(e.length * 0.5)
             assert host.block_live[s].tolist() == cursor
             for b, n in enumerate(cursor):
-                assert not host.tf[b][s, n:].any()
+                assert not host.tf[b][s, :, n:].any()
         for b, a in enumerate(host.tf):
             fullest = int(host.block_live[:, b].max())
-            assert a.shape[1] == max(8, 1 << max(fullest - 1, 0).bit_length())
+            assert a.shape[2] == max(8, 1 << max(fullest - 1, 0).bit_length())
 
 
 # ---- a shard's top-k reads its score blocks in place -------------------
@@ -539,7 +542,7 @@ def _rearranged_mesh_search(mesh, *, k, depth, model="bm25", k1=1.2,
         parts = [
             score_block_pallas(imp, term, q.uniq, q.n_uniq, qc_ext,
                                block_live[i])
-            if _pallas_eligible(imp.shape[0], B, q.uniq.shape[0])
+            if _pallas_eligible(imp.shape[1], B, q.uniq.shape[0])
             else _score_block(imp, term, slot_of, qc_ext.T, 2048)
             for i, (imp, term) in enumerate(zip(impacts, terms))]
         kw = dict(model=model, k1=k1, b=b)
@@ -565,7 +568,7 @@ def _rearranged_mesh_search(mesh, *, k, depth, model="bm25", k1=1.2,
         in_specs = ((P(None), P(), P(), docs, docs, split, split, split,
                      docs, split, split, split, docs, P("docs"), docs,
                      P(None), P(), P(None, None), P(None, None))
-                    + (P("docs", None, "terms"),) * base.n_buckets * 2)
+                    + (split,) * base.n_buckets * 2)
         return jax.shard_map(
             step, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()),
             check_vma=False)(
@@ -743,7 +746,7 @@ class TestTopkInPlace:
         engine = in_place_engines(shape)
         snap = engine.index.snapshot
         caps = snap.topk_block_caps
-        assert caps[:-1] == tuple(a.shape[1] for a in snap.base.impact)
+        assert caps[:-1] == tuple(a.shape[2] for a in snap.base.impact)
         assert np.array_equal(np.asarray(snap.shard_live)[:, :-1],
                               np.asarray(snap.base.block_live))
         assert np.array_equal(np.asarray(snap.shard_live)[:, -1],

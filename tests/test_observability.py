@@ -1007,7 +1007,7 @@ class TestStageTimer:
             "mesh_docs_shards", "mesh_terms_shards", "mesh_shard_docs_min",
             "mesh_shard_docs_max")] == [4, 1, 3, 3]
         index = e.index.snapshot
-        rows = sum(imp.shape[1] for imp in index.base.impact) \
+        rows = sum(imp.shape[2] for imp in index.base.impact) \
             if layout == "ell" else index.arrays.doc_cap
         assert snap["mesh_shard_rows_padded"] == rows >= 3
         if layout == "ell":     # the rebuild's parts, timed
@@ -1147,8 +1147,8 @@ class TestStageTimer:
         jaxpr = jax.make_jaxpr(
             lambda imp, term, uniq, qc: score_block_pallas(
                 imp, term, uniq, jnp.int32(3), qc))(
-            jnp.zeros((rows, width), jnp.float32),
-            jnp.zeros((rows, width), jnp.int32),
+            jnp.zeros((width, rows), jnp.float32),
+            jnp.zeros((width, rows), jnp.int32),
             jnp.zeros((u_cap,), jnp.int32),
             jnp.zeros((B, u_cap + 1), jnp.float32))
         assert "ell_score_v4" in str(jaxpr)

@@ -127,8 +127,9 @@ def test_layout_is_split_at_the_ceiling(stretched):
     engine, docs, _lengths = stretched
     snap = engine.index.snapshot
     shapes = [imp.shape for imp in snap.ell_impacts]
-    assert [w for _r, w in shapes] == [32] * 2 + [24] * 3 + [8] * 2
-    assert all(rows == CEILING for rows, _w in shapes)
+    # a block is held width-major: [width, rows_cap]
+    assert [w for w, _r in shapes] == [32] * 2 + [24] * 3 + [8] * 2
+    assert all(rows == CEILING for _w, rows in shapes)
     # the last block of every rung has a dead tail; 302 = the two
     # spilling rows and the 300 of their rung
     assert snap.ell_live_host == (256, 302 - 256, 256, 256, 88, 256, 144)
@@ -245,11 +246,13 @@ def test_every_posting_in_one_block_or_the_residual(stretched, ceiling):
     got = []
     row0 = 0
     for blk in built.blocks:
-        assert blk.tf.shape[0] <= ceiling and blk.row0 == row0
-        assert not blk.tf[blk.n_rows:].any()
-        r, c = np.nonzero(blk.tf)
-        got += zip((r + row0).tolist(), blk.term[r, c].tolist(),
-                   blk.tf[r, c].tolist())
+        assert blk.tf.shape == blk.term.shape == (
+            blk.width, blk.tf.shape[1])
+        assert blk.tf.shape[1] <= ceiling and blk.row0 == row0
+        assert not blk.tf[:, blk.n_rows:].any()
+        c, r = np.nonzero(blk.tf)           # [width, rows_cap]
+        got += zip((r + row0).tolist(), blk.term[c, r].tolist(),
+                   blk.tf[c, r].tolist())
         row0 += blk.n_rows
     assert row0 == coo.num_docs
     n = built.res_nnz
@@ -265,8 +268,8 @@ def test_every_posting_in_one_block_or_the_residual(stretched, ceiling):
     g = ell_layout_gauges(shapes, [b.n_rows for b in built.blocks],
                           built.res_doc[:n])
     assert g["ell_blocks"] == len(shapes)
-    assert g["ell_rows_padded"] == sum(r for r, _w in shapes)
-    assert g["ell_entries_padded"] == sum(r * w for r, w in shapes)
+    assert g["ell_rows_padded"] == sum(r for _w, r in shapes)
+    assert g["ell_entries_padded"] == sum(w * r for w, r in shapes)
     assert g["ell_residual_nnz"] == n and g["ell_residual_docs"] == 2
 
 
@@ -296,7 +299,7 @@ def test_checkpoint_round_trip_keeps_the_split_blocks(stretched, tmp_path):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ell, "ELL_BLOCK_ROWS_MAX", 512)
         doubled = load_checkpoint(str(tmp_path / "ck"), engine.config)
-    rows = [imp.shape[0] for imp in doubled.index.snapshot.ell_impacts]
+    rows = [imp.shape[1] for imp in doubled.index.snapshot.ell_impacts]
     assert rows == [512, 512, 256, 512]     # 302, 512 + 88, 400 rows
     got = doubled.searcher.search_arrays(queries)[:2]
     assert all(np.array_equal(a, b) for a, b in zip(got, want))

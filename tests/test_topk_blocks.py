@@ -446,7 +446,7 @@ def test_topk_chunk_counters_add_up_to_the_padded_space(tmp_path):
             f"w{i}x{j}" for j in range((3, 11, 20)[i % 3])) + " shared")
     e.commit()
     snap = e.index.snapshot
-    caps = [imp.shape[0] for imp in snap.ell_impacts]
+    caps = [imp.shape[1] for imp in snap.ell_impacts]
     assert len(caps) == 3
     assert snap.ell_live_host == tuple(np.asarray(snap.ell_live))
     # 3 dispatches of <= 4 queries; every block here is one live chunk

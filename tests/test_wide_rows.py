@@ -58,9 +58,9 @@ def wide(tmp_path_factory):
 def test_layout_has_every_wide_rung_and_a_residual(wide):
     engine, docs, _lengths = wide
     snap = engine.index.snapshot
-    widths = [imp.shape[1] for imp in snap.ell_impacts]
+    widths = [imp.shape[0] for imp in snap.ell_impacts]    # [width, rows]
     assert widths[:len(WIDE_RUNGS)] == WIDE_RUNGS[::-1]
-    assert all(imp.shape[0] == 256 for imp in snap.ell_impacts)
+    assert all(imp.shape[1] == 256 for imp in snap.ell_impacts)
     stats = engine.compute_stats()
     assert stats["kernel_blocks"] == stats["posting_blocks"] == len(widths)
     spilled = [len(d) - TOP for d in docs if len(d) > TOP]

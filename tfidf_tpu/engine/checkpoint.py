@@ -50,7 +50,13 @@ from tfidf_tpu.utils.tracing import span_event
 
 log = get_logger("engine.checkpoint")
 
-FORMAT_VERSION = 1
+# 2 (PR 43): ``snapshot.npz`` holds the ELL blocks width-major,
+# ``ell_imp_i`` / ``ell_term_i`` ``[width, rows_cap]``, as the index
+# holds them. A version 1 directory (``[rows_cap, width]``) still
+# loads: its blocks are turned on the way in (``_snapshot_arrays``), so
+# a restored index never serves a transposed block. Nothing else moved.
+FORMAT_VERSION = 2
+_ROW_MAJOR_VERSION = 1
 
 
 def _score_signature(engine: Engine) -> list:
@@ -242,6 +248,20 @@ def _restore_dense(engine: Engine, directory: str, meta: dict,
     engine.dense.commit()
 
 
+def _snapshot_arrays(path: str, format_version: int):
+    """``snapshot.npz`` as ``install_snapshot_arrays`` takes it: the
+    file itself, or for a version 1 checkpoint its arrays with every
+    ELL block turned from ``[rows_cap, width]`` to width-major."""
+    data = np.load(path)
+    if format_version != _ROW_MAJOR_VERSION or "n_blocks" not in data:
+        return data
+    arrays = {name: data[name] for name in data.files}
+    for i in range(int(arrays["n_blocks"])):
+        for name in (f"ell_imp_{i}", f"ell_term_{i}"):
+            arrays[name] = np.ascontiguousarray(arrays[name].T)
+    return arrays
+
+
 def load_checkpoint(directory: str, config: Config | None = None,
                     verify: bool = True) -> Engine:
     """Load one checkpoint version (``directory`` may be the published
@@ -257,7 +277,7 @@ def load_checkpoint(directory: str, config: Config | None = None,
                 + "; ".join(problems))
     with open(os.path.join(directory, "meta.json"), encoding="utf-8") as f:
         meta = json.load(f)
-    if meta["format_version"] != FORMAT_VERSION:
+    if meta["format_version"] not in (_ROW_MAJOR_VERSION, FORMAT_VERSION):
         raise ValueError(f"unknown checkpoint format {meta['format_version']}")
     config = config or Config()
     if meta["model"] != config.model:
@@ -322,7 +342,7 @@ def load_checkpoint(directory: str, config: Config | None = None,
             and hasattr(engine.index, "install_snapshot_arrays")
             and snap_meta.get("score_signature")
             == _score_signature(engine)):
-        data = np.load(snap_path)
+        data = _snapshot_arrays(snap_path, meta["format_version"])
         if int(data["df"].shape[0]) == engine.vocab.capacity():
             snap_names = [names[i] for i in data["name_order"]]
             engine.index.install_snapshot_arrays(data, snap_names)

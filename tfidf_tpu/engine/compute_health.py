@@ -257,14 +257,16 @@ _ROW_CHUNK = 4096   # bounds the [rows, W, B] temporary, like doc_chunk
 def _score_block_host(imp: np.ndarray, term: np.ndarray,
                       slot_of: np.ndarray,
                       qc_ext: np.ndarray) -> np.ndarray:
-    """One ELL block: gather + lane-reduced contraction, ``[B, rows]``."""
+    """One ELL block as fetched, width-major ``[W, rows]``: gather +
+    lane-reduced contraction over its ``[r, W]`` row chunks (the device
+    oracle's order, ``ops.ell._score_block``), ``[B, rows]``."""
     B = qc_ext.shape[0]
-    rows_cap, w = imp.shape
+    w, rows_cap = imp.shape
     qc_t = np.ascontiguousarray(qc_ext.T)               # [U+1, B]
     out = np.empty((B, rows_cap), np.float32)
     for lo in range(0, rows_cap, _ROW_CHUNK):
-        imp_c = imp[lo:lo + _ROW_CHUNK]
-        term_c = term[lo:lo + _ROW_CHUNK]
+        imp_c = imp[:, lo:lo + _ROW_CHUNK].T            # [r, W]
+        term_c = term[:, lo:lo + _ROW_CHUNK].T
         qg = qc_t[slot_of[term_c]]                      # [r, W, B]
         x = qg * imp_c[:, :, None]
         r = x.shape[0]
@@ -373,7 +375,7 @@ class _SnapshotMirror:
         real doc id -> its row in the padded block concat (the trailing
         zero column for rows past the live count)."""
         row0 = np.concatenate([[0], np.cumsum(block_live)])
-        total_pad = int(sum(i.shape[0] for i in self.imps))
+        total_pad = int(sum(i.shape[1] for i in self.imps))
         real = np.arange(self.doc_cap)
         padded_of_real = np.full(self.doc_cap, total_pad, np.int32)
         pad0 = 0
@@ -381,7 +383,7 @@ class _SnapshotMirror:
             in_b = (real >= row0[i]) & (real < row0[i + 1])
             padded_of_real = np.where(
                 in_b, pad0 + real - row0[i], padded_of_real)
-            pad0 += imp.shape[0]
+            pad0 += imp.shape[1]
         return padded_of_real.astype(np.int32)
 
     def scores(self, qb) -> np.ndarray:

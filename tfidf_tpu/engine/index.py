@@ -114,8 +114,9 @@ class Snapshot:
     host_coo: CooShard | None = None   # host copy for mesh re-sharding
     # Blocked-ELL fast path (tfidf_tpu.ops.ell): per-commit precomputed
     # impact blocks + term rows, plus a COO residual for overlong docs.
-    ell_impacts: tuple = ()       # tuple of f32 [rows_cap_i, width_i]
-    ell_terms: tuple = ()         # tuple of i32 [rows_cap_i, width_i]
+    # width-major, as the kernel reads a block (``ops/ell.py EllBlock``)
+    ell_impacts: tuple = ()       # tuple of f32 [width_i, rows_cap_i]
+    ell_terms: tuple = ()         # tuple of i32 [width_i, rows_cap_i]
     # live rows per block — TRACED so commits within the same capacity
     # buckets never retrace the query path
     ell_live: jax.Array | None = None     # i32 [n_blocks]
@@ -413,7 +414,7 @@ class ShardIndex:
                     coo, width_cap=self.ell_width_cap,
                     min_rows=min(256, self.min_doc_cap))
                 for blk in ell.blocks:
-                    rows_cap = blk.tf.shape[0]
+                    rows_cap = blk.tf.shape[1]
                     dl_blk = np.zeros(rows_cap, np.float32)
                     dl_blk[:blk.n_rows] = doc_len_host[
                         blk.row0:blk.row0 + blk.n_rows]

@@ -454,7 +454,7 @@ class Searcher(QueryVectorizerMixin):
         if not snap.is_ell:
             return [(snap.tf, False)]
         return [(imp, self.use_pallas and _pallas_eligible(
-                    imp.shape[0], self.query_batch, self._u_floor))
+                    imp.shape[1], self.query_batch, self._u_floor))
                 for imp in snap.ell_impacts]
 
     def _score_chunk(self, snap: Snapshot, queries: list[str]):
@@ -519,7 +519,7 @@ class Searcher(QueryVectorizerMixin):
         (``ops.ell.plan_stretches``). One stretch over every block
         passes the snapshot's own live counts and no base: the program
         pair of a corpus that fits is the unstretched one."""
-        rows = [imp.shape[0] for imp in snap.ell_impacts]
+        rows = [imp.shape[1] for imp in snap.ell_impacts]
         live = snap.ell_live_host
         out = []
         for first, stop in plan_stretches(rows, cap, budget):

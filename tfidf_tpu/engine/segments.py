@@ -714,7 +714,11 @@ class SegmentedIndex:
         """Host-side ELL layout of ``entries`` IN ORDER (no sorting —
         callers sort; checkpoint export relies on order preservation so
         a re-layout of ``host_docs`` reproduces the stored name order).
-        Returns ``(ell, df, raw_len, doc_len, doc_cap, nnz)``."""
+        Returns ``(ell, df, raw_len, doc_len, doc_cap, nnz)``, the
+        blocks of ``ell`` turned ONCE, here, to the ``[rows, width]`` a
+        segment, its checkpoint and its cold file hold (no kernel reads
+        a segment; ``build_ell_from_coo`` writes the kernel's
+        width-major blocks)."""
         n = len(entries)
         sizes = np.fromiter((d.term_ids.shape[0] for d in entries),
                             np.int64, n)
@@ -738,6 +742,9 @@ class SegmentedIndex:
                        nnz=nnz, num_docs=n)
         ell = build_ell_from_coo(coo, width_cap=self.ell_width_cap,
                                  min_rows=min(256, self.min_doc_cap))
+        for blk in ell.blocks:
+            blk.tf = np.ascontiguousarray(blk.tf.T)
+            blk.term = np.ascontiguousarray(blk.term.T)
         return ell, df, raw_len, doc_len, doc_cap, nnz
 
     def _build_segment(self, entries: list[DocEntry],

@@ -4,11 +4,17 @@ The COO path (:mod:`tfidf_tpu.ops.scoring`) scores with per-chunk
 ``segment_sum`` — a *scatter*, the weakest memory op on TPU. This module is
 the TPU-first alternative (SURVEY.md §7 "hard parts": padded ELL blocks,
 bucketing by row length): postings are laid out as dense
-``[rows, width]`` blocks — one padded row of (term id, impact) pairs per
-document — so scoring becomes *gathers* + a contraction the compiler fuses
-for the VPU/MXU, with the output indexed directly by document row:
+blocks — one padded row of (term id, impact) pairs per document — so
+scoring becomes *gathers* + a contraction the compiler fuses for the
+VPU/MXU, with the output indexed directly by document row:
 
-    scores[b, d] = sum_w  qc[b, slot_of[term[d, w]]] * impact[d, w]
+    scores[b, d] = sum_w  qc[b, slot_of[term[w, d]]] * impact[w, d]
+
+A block is HELD width-major, ``[width, rows_cap]``: a document is a
+COLUMN, its entries run down the width axis. That is how the fused
+kernel reads it (``BlockSpec((width, td))``), so the commit writes it so
+and nothing between the commit and the kernel turns it (from 128 wide a
+``[rows, width]`` array is a physical copy away from it on a TPU: PR 43).
 
 A single width would waste heavily on skewed corpora (a few long documents
 force every row to their width), so documents are **sorted by distinct-term
@@ -59,8 +65,9 @@ from tfidf_tpu.ops.scoring import (QueryBatch, _compile_queries,
 
 @dataclass
 class EllBlock:
-    tf: np.ndarray     # f32 [rows_cap, width]
-    term: np.ndarray   # i32 [rows_cap, width] (pad id 0, pad tf 0)
+    tf: np.ndarray     # f32 [width, rows_cap]: a document is a column
+    term: np.ndarray   # i32 [width, rows_cap] (pad id 0, pad tf 0; a
+    #                    column's pads TRAIL its live entries)
     row0: int          # first shard doc row this block covers
     n_rows: int        # live rows (rows_cap - n_rows are padding)
     width: int
@@ -95,6 +102,31 @@ ELL_WIDTH_LADDER = (8, 12, 16, 24, 32, 48, 64, 96, 128, 192, 256, 384, 512,
 ELL_BLOCK_ROWS_MAX = 1 << 20
 
 
+_FILL_ROWS = 512    # rows a line buffer of ``fill_width_major`` holds
+
+
+def fill_width_major(dst: np.ndarray, values: np.ndarray,
+                     sizes: np.ndarray) -> None:
+    """Write the postings of consecutive rows into the zeroed block
+    ``dst [width, rows_cap]``, row ``r`` as COLUMN ``r``: ``values`` are
+    the rows' entries in row order, ``sizes[r] <= width`` of them row
+    ``r``'s, which lead its column; its pads (``dst``'s zeros) trail
+    them down the width, the select chain's order contract
+    (``_pallas_kernel``). ``_FILL_ROWS`` rows at a time through a
+    ``[rows, width]`` line buffer that stays in cache and is turned
+    into place: a scatter straight into the width-major block misses
+    the cache on every entry (3.5 s against 1.1 s for 38M entries in
+    blocks of 512 and 384, on the sandbox's host: PR 43)."""
+    width = dst.shape[0]
+    bounds = np.concatenate([[0], np.cumsum(sizes)])
+    cols = np.arange(width)
+    for lo in range(0, sizes.shape[0], _FILL_ROWS):
+        top = min(lo + _FILL_ROWS, sizes.shape[0])
+        lines = np.zeros((top - lo, width), dst.dtype)
+        lines[cols < sizes[lo:top, None]] = values[bounds[lo]:bounds[top]]
+        dst[:, lo:top] = lines.T
+
+
 def build_ell_from_coo(coo: CooShard,
                        *,
                        width_cap: int | None = None,
@@ -112,6 +144,8 @@ def build_ell_from_coo(coo: CooShard,
     ``ELL_BLOCK_ROWS_MAX``) yields full blocks of ``max_rows`` and a
     last one of the rest, in row order: a real row is still the running
     sum of the live counts before its block plus its column.
+    A block is written width-major, ``[width, rows_cap]``: entry ``pos``
+    of the block's row ``r`` is ``[pos, r]`` (:func:`fill_width_major`).
     """
     if width_cap is None:
         width_cap = ELL_WIDTH_LADDER[-1]
@@ -151,15 +185,15 @@ def build_ell_from_coo(coo: CooShard,
                  row0 + max_rows)
         n_rows = hi - row0
         rows_cap = next_capacity(n_rows, min_rows)
-        tf = np.zeros((rows_cap, w), np.float32)
-        term = np.zeros((rows_cap, w), np.int32)
+        tf = np.zeros((w, rows_cap), np.float32)
+        term = np.zeros((w, rows_cap), np.int32)
         # the block's rows are one run of the COO (entries lie in row
         # order), so only that run is read, not the whole shard a block
         run = slice(int(bounds[row0]), int(bounds[hi]))
         sel = pos[run] < w
-        at = (doc_ids[run][sel] - row0, pos[run][sel])
-        tf[at] = coo.tf[run][sel]
-        term[at] = coo.term[run][sel]
+        sizes = np.minimum(row_len[row0:hi], w)
+        fill_width_major(tf, coo.tf[run][sel], sizes)
+        fill_width_major(term, coo.term[run][sel], sizes)
         blocks.append(EllBlock(tf=tf, term=term, row0=row0,
                                n_rows=n_rows, width=w))
         row0 = hi
@@ -188,9 +222,10 @@ def build_ell_from_coo(coo: CooShard,
 
 def _entry_weights(model: str, tf, df_t, dl_col, n_docs, avgdl,
                    norms_col, k1: float, b: float):
-    """Per-entry model weights for a [rows, width] block (dl_col/norms_col
-    broadcast as [rows, 1]) — the single dispatch shared by the
-    precomputed-impact and query-time paths."""
+    """Per-entry model weights for a block (dl_col/norms_col broadcast
+    along its width axis: ``[1, rows]`` beside a width-major block,
+    ``[rows, 1]`` beside a segment's ``[rows, width]``) — the single
+    dispatch shared by the precomputed-impact and query-time paths."""
     if model == "bm25":
         return bm25_weights(tf, df_t, dl_col, n_docs, avgdl, k1=k1, b=b)
     if model == "tfidf":
@@ -201,19 +236,19 @@ def _entry_weights(model: str, tf, df_t, dl_col, n_docs, avgdl,
     raise ValueError(f"unknown model {model!r}")
 
 
-def ell_impacts(tf: jax.Array,        # f32 [rows, width]
-                term: jax.Array,      # i32 [rows, width]
+def ell_impacts(tf: jax.Array,        # f32 [width, rows]
+                term: jax.Array,      # i32 [width, rows]
                 doc_len: jax.Array,   # f32 [rows] (this block's rows)
                 df: jax.Array,        # f32 [vocab_cap]
                 n_docs: jax.Array, avgdl: jax.Array,
                 doc_norms: jax.Array | None = None,
                 *, model: str = "bm25", k1: float = 1.2,
                 b: float = 0.75) -> jax.Array:
-    """Per-entry impact weights [rows, width] — everything about the score
+    """Per-entry impact weights [width, rows] — everything about the score
     that does not depend on the query, precomputed once per commit
     (Lucene's "impacts" idea). The query path is then pure gather+contract."""
-    norms_col = None if doc_norms is None else doc_norms[:, None]
-    return _entry_weights(model, tf, df[term], doc_len[:, None],
+    norms_col = None if doc_norms is None else doc_norms[None, :]
+    return _entry_weights(model, tf, df[term], doc_len[None, :],
                           n_docs, avgdl, norms_col, k1, b)
 
 
@@ -231,7 +266,7 @@ ell_impacts = jax.jit(ell_impacts, static_argnames=("model", "k1", "b"))
 # factoring the score through the batch's compact term-slot space:
 #
 #     scores[b, d] = sum_u qc[b, u] * A[u, d]
-#     A[u, d]      = sum_w imp[d, w] * (term[d, w] == uniq[u])
+#     A[u, d]      = sum_w imp[w, d] * (term[w, d] == uniq[u])
 #
 # A (the slot-impact matrix for a doc tile) is built with dense VPU
 # compare+select against the batch's unique term ids — full-width vector
@@ -533,7 +568,7 @@ def kernel_uniq_lanes(n_uniq: int) -> int:
 
 def ell_layout_gauges(shapes, live, res_doc: np.ndarray) -> dict[str, int]:
     """A committed layout as the ``ell_*`` gauges: ``shapes`` the
-    blocks' ``(rows_cap, width)``, ``live`` their live rows, ``res_doc``
+    blocks' own, ``(width, rows_cap)``, ``live`` their live rows, ``res_doc``
     the document row of every live entry of the COO residual, in its
     non-decreasing order.
     ``ell_rows_padded`` is the row axis of a step's score space (sum of
@@ -543,13 +578,13 @@ def ell_layout_gauges(shapes, live, res_doc: np.ndarray) -> dict[str, int]:
     skipped), at the doc tile of a batch of up to 512 queries. Host
     arithmetic on the commit's own counts."""
     streamed = 0
-    for (rows_cap, width), n_rows in zip(shapes, live):
+    for (width, rows_cap), n_rows in zip(shapes, live):
         td, _tu = _pl_tiles(rows_cap, 1, _PL_TK, width)
         streamed += min(rows_cap, -(-int(n_rows) // td) * td) * width
     return {"ell_blocks": len(shapes),
-            "ell_width_max": max((w for _r, w in shapes), default=0),
-            "ell_rows_padded": sum(r for r, _w in shapes),
-            "ell_entries_padded": sum(r * w for r, w in shapes),
+            "ell_width_max": max((w for w, _r in shapes), default=0),
+            "ell_rows_padded": sum(r for _w, r in shapes),
+            "ell_entries_padded": sum(w * r for w, r in shapes),
             "ell_entries_live_tiles": streamed,
             "ell_residual_nnz": int(res_doc.shape[0]),
             "ell_residual_docs":
@@ -609,8 +644,8 @@ def kernel_contract_chunks(n_uniq: int,
     return chunks, chunks if bf16_exact(weights) else 0
 
 
-def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
-                       term: jax.Array,      # i32 [rows_cap, width]
+def score_block_pallas(impact: jax.Array,    # f32 [width, rows_cap]
+                       term: jax.Array,      # i32 [width, rows_cap]
                        uniq: jax.Array,      # i32 [U_cap] batch term ids
                        n_uniq: jax.Array,    # i32 scalar (traced)
                        qc_ext: jax.Array,    # f32 [B, U_cap+1]
@@ -622,8 +657,10 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     past it skip the A-build and contraction (their scores are zeroed by
     the unconditional init, exactly what all-pad rows would score).
     The XLA reduce-fusion path is the oracle (``kernel_parity.py``).
+    The block arrives as the index holds it and as the kernel's
+    ``BlockSpec((width, td))`` reads it: nothing here turns it.
     """
-    rows_cap, width = impact.shape
+    width, rows_cap = impact.shape
     B, _ = qc_ext.shape
     # the kernel contracts whole 128-row chunks: a capacity that is not
     # a multiple (no eligible shape; small direct callers) is padded
@@ -645,8 +682,6 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
     # takes a contraction chunk by its leading index
     qc = jnp.pad(qc_ext[:, :uniq.shape[0]], ((0, 0), (0, grow))).reshape(
         B, u_cap // _PL_TK, _PL_TK).swapaxes(0, 1)
-    imp_t = impact.T                                 # [W, rows] width-major
-    term_t = term.T
     if n_rows is None:
         n_rows = jnp.int32(rows_cap)
     # the scalars a grid step reads: live unique terms, live rows, and
@@ -682,7 +717,7 @@ def score_block_pallas(impact: jax.Array,    # f32 [rows_cap, width]
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=pallas_interpret(),
-    )(lims, uniq_col, qc, term_t, imp_t)
+    )(lims, uniq_col, qc, term, impact)
 
 
 def _pallas_eligible(rows_cap: int, B: int, u_cap: int) -> bool:
@@ -738,12 +773,17 @@ def _lane_sum_w(x: jax.Array) -> jax.Array:
 def _score_block(impact: jax.Array, term: jax.Array,
                  slot_of: jax.Array, qc_t: jax.Array,
                  doc_chunk: int) -> jax.Array:
-    """One ELL block: gathers + contraction, chunked over rows.
+    """One ELL block ``[width, rows_cap]``: gathers + contraction,
+    chunked over rows.
 
     Returns ``[B, rows_cap]``. The [Dc, W, B] gathered intermediate is
-    bounded by the chunk size regardless of block size.
+    bounded by the chunk size regardless of block size. The arithmetic
+    and its pinned reduction order are written over ``[rows, width]``
+    chunks (what the host mirror reproduces bit for bit), so this path
+    turns its operands itself: it scores the blocks the kernel does not
+    take (a few rows) and every block of a CPU test.
     """
-    rows_cap, width = impact.shape
+    width, rows_cap = impact.shape
     B = qc_t.shape[1]
     chunk = _pick_chunk(rows_cap, width, B, doc_chunk)
     n_chunks = rows_cap // chunk
@@ -771,8 +811,8 @@ def _score_block(impact: jax.Array, term: jax.Array,
         scores_c = _lane_sum_w(prod).T                # [B, Dc]
         return None, scores_c
 
-    xs = (impact.reshape(n_chunks, chunk, width),
-          term.reshape(n_chunks, chunk, width))
+    xs = (impact.T.reshape(n_chunks, chunk, width),
+          term.T.reshape(n_chunks, chunk, width))
     _, chunks = jax.lax.scan(body, None, xs)          # [n, B, Dc]
     return jnp.moveaxis(chunks, 0, 1).reshape(B, rows_cap)
 
@@ -805,8 +845,8 @@ def _rearrange_to_real(parts, block_caps, block_live, doc_cap: int,
     return padded[:, padded_of_real]                  # [B, doc_cap]
 
 
-def score_ell_impl(impacts,            # tuple of f32 [rows_cap_i, width_i]
-                   terms,              # tuple of i32 [rows_cap_i, width_i]
+def score_ell_impl(impacts,            # tuple of f32 [width_i, rows_cap_i]
+                   terms,              # tuple of i32 [width_i, rows_cap_i]
                    block_live,         # i32 [n_blocks] — live rows (TRACED)
                    q: QueryBatch,
                    vocab_cap: int,
@@ -833,7 +873,7 @@ def score_ell_impl(impacts,            # tuple of f32 [rows_cap_i, width_i]
         return tuple(
             score_block_pallas(imp, term, q.uniq, q.n_uniq, qc_ext,
                                block_live[i])
-            if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap)
+            if use_pallas and _pallas_eligible(imp.shape[1], B, u_cap)
             else _score_block(imp, term, slot_of, qc_t, doc_chunk)
             for i, (imp, term) in enumerate(zip(impacts, terms)))
 
@@ -943,6 +983,8 @@ class SegmentView(NamedTuple):
     never observes later deletes or df drift (snapshot isolation, the
     "fresh DirectoryReader" guarantee of ``Worker.java:223``).
     """
+    # a segment's blocks stay [rows, width] (no kernel reads them):
+    # the segmented index turns ``build_ell_from_coo``'s at ITS commit
     tfs: tuple            # f32 [rows_cap_i, width_i] blocks
     terms: tuple          # i32 [rows_cap_i, width_i]
     dls: tuple            # f32 [rows_cap_i] (model-transformed lengths)

@@ -15,7 +15,11 @@ for SPMD:
   shards — every device slice has identical static shapes, as
   ``shard_map`` requires — and only a row past the ladder's top or the
   cap spills into the COO residual;
-* the ``terms`` axis shards each block's WIDTH columns: one document row
+* a bucket is held width-major, ``[D, width, rows_cap]`` under
+  ``P("docs", "terms", None)``: a shard's slice is the ``[width,
+  rows_cap]`` block the kernel reads (``ops/ell.py EllBlock``), and
+  nothing in the step turns it;
+* the ``terms`` axis shards each block's WIDTH axis: one document row
   keeps its entries split across terms-devices, partial scores
   ``psum``-reduce exactly like the COO path (entries are disjoint across
   slices; scores and df are additive);
@@ -48,7 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tfidf_tpu.ops.csr import next_capacity
 from tfidf_tpu.ops.ell import (ELL_WIDTH_LADDER, _pallas_eligible,
                                _score_block, ell_layout_gauges,
-                               score_block_pallas)
+                               fill_width_major, score_block_pallas)
 from tfidf_tpu.ops.scoring import (QueryBatch, _compile_queries,
                                    bm25_weights, score_coo_compiled,
                                    tfidf_weights)
@@ -80,15 +84,15 @@ def mesh_ell_widths(widest_row: int = 0,
 class MeshEllArrays:
     """Device-resident ELL base for the whole mesh.
 
-    Per width bucket b: ``tf[b] [D, rows_cap_b, W_b]`` etc., sharded
-    ``P("docs", None, "terms")``. ``doc_cap`` is the per-shard ELL doc
+    Per width bucket b: ``tf[b] [D, W_b, rows_cap_b]`` etc., sharded
+    ``P("docs", "terms", None)``. ``doc_cap`` is the per-shard ELL doc
     space (block rows concatenated); ``live`` masks tombstones in that
     space.
     """
 
-    tf: tuple            # per bucket f32 [D, rows_cap_b, W_b]
-    term: tuple          # per bucket i32 [D, rows_cap_b, W_b]
-    impact: tuple        # per bucket f32 [D, rows_cap_b, W_b]
+    tf: tuple            # per bucket f32 [D, W_b, rows_cap_b]
+    term: tuple          # per bucket i32 [D, W_b, rows_cap_b]
+    impact: tuple        # per bucket f32 [D, W_b, rows_cap_b]
     dl: tuple            # per bucket f32 [D, rows_cap_b]
     block_live: jax.Array  # i32 [D, n_buckets] live rows per block
     live: jax.Array      # f32 [D, doc_cap] in ELL row space
@@ -178,9 +182,9 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
     res_cap = next_capacity(int(res_need.max()) or 1, min_res_cap)
     res_chunk = -(-res_cap // T)
 
-    g_tf = [np.zeros((D, rows_cap[b], widths[b]), np.float32)
+    g_tf = [np.zeros((D, widths[b], rows_cap[b]), np.float32)
             for b in range(nb)]
-    g_term = [np.zeros((D, rows_cap[b], widths[b]), np.int32)
+    g_term = [np.zeros((D, widths[b], rows_cap[b]), np.int32)
               for b in range(nb)]
     g_dl = [np.zeros((D, rows_cap[b]), np.float32) for b in range(nb)]
     g_bl = rows_need.astype(np.int32)
@@ -201,8 +205,9 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
         g_live[s, :n] = 1.0
         g_res_dl[s, :n] = kdl
         # the shard's postings in ELL row order: a row's entries are
-        # the first of its block row, so a block's live cells, taken
-        # row by row, are its run of postings in their own order
+        # the first of its block column (its pads trail them down the
+        # width), so a block's live cells, taken row by row, are its
+        # run of postings in their own order
         tfs = np.concatenate([entries[i].tfs for i in order])
         terms = np.concatenate([entries[i].term_ids for i in order])
         bounds = np.concatenate([[0], np.cumsum(sizes)])
@@ -214,7 +219,6 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
                 continue
             run = slice(int(bounds[lo]), int(bounds[hi]))
             size = sizes[lo:hi]
-            cells = np.arange(widths[b]) < size[:, None]
             run_tfs, run_terms = tfs[run], terms[run]
             if size[0] > widths[b]:
                 # only the widest bucket can spill: what lies past its
@@ -224,8 +228,9 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
                 rows = np.repeat(np.arange(lo, hi, dtype=np.int32), size)
                 res = (run_tfs[~keep], run_terms[~keep], rows[~keep])
                 run_tfs, run_terms = run_tfs[keep], run_terms[keep]
-            g_tf[b][s, :hi - lo][cells] = run_tfs
-            g_term[b][s, :hi - lo][cells] = run_terms
+            kept = np.minimum(size, widths[b])
+            fill_width_major(g_tf[b][s], run_tfs, kept)
+            fill_width_major(g_term[b][s], run_terms, kept)
             g_dl[b][s, :hi - lo] = kdl[lo:hi]
         # the residual cut in T even runs
         step = -(-res[2].shape[0] // T)
@@ -237,7 +242,7 @@ def build_mesh_ell(entries_per_shard: list[list],   # list[DocEntry]/shard
     # the ``ell_*`` gauges of the local commit, summed over the shards
     # (``ell_width_max``: the widest bucket)
     gauges = ell_layout_gauges(
-        list(zip(rows_cap, widths)) * D, rows_need.ravel(),
+        list(zip(widths, rows_cap)) * D, rows_need.ravel(),
         np.concatenate(res_rows) if res_rows else np.zeros(0, np.int64))
     for name, value in gauges.items():
         global_metrics.set_gauge(name, value)
@@ -270,12 +275,12 @@ def place_mesh_ell(host: MeshEllHost, mesh: Mesh) -> MeshEllArrays:
     def put(x, spec):
         return jax.device_put(x, NamedSharding(mesh, spec))
 
-    # width columns shard over "terms": entries of one row split across
+    # the width axis shards over "terms": entries of one row split across
     # terms-devices; contributions are additive, like the COO split
     return MeshEllArrays(
-        tf=tuple(put(a, P("docs", None, "terms")) for a in host.tf),
-        term=tuple(put(a, P("docs", None, "terms")) for a in host.term),
-        impact=tuple(put(np.zeros_like(a), P("docs", None, "terms"))
+        tf=tuple(put(a, P("docs", "terms", None)) for a in host.tf),
+        term=tuple(put(a, P("docs", "terms", None)) for a in host.term),
+        impact=tuple(put(np.zeros_like(a), P("docs", "terms", None))
                      for a in host.tf),
         dl=tuple(put(a, P("docs", None)) for a in host.dl),
         block_live=put(host.block_live, P("docs", None)),
@@ -302,12 +307,12 @@ def make_impact_refresh(mesh: Mesh, *, model: str = "bm25",
         tfs, terms, dls = flat[:k], flat[k:2 * k], flat[2 * k:]
         out = []
         for tf, term, dl in zip(tfs, terms, dls):
-            tf = tf.reshape(tf.shape[1:])            # [rows, Wt]
+            tf = tf.reshape(tf.shape[1:])            # [Wt, rows]
             term = term.reshape(term.shape[1:])
             dl = dl.reshape(dl.shape[-1])            # [rows]
             df_t = df_g[term]
             if model == "bm25":
-                imp = bm25_weights(tf, df_t, dl[:, None], n_docs, avgdl,
+                imp = bm25_weights(tf, df_t, dl[None, :], n_docs, avgdl,
                                    k1=k1, b=b)
             elif model == "tfidf":
                 imp = tfidf_weights(tf, df_t, n_docs)
@@ -318,7 +323,7 @@ def make_impact_refresh(mesh: Mesh, *, model: str = "bm25",
 
     def n_in(k):
         return ((P(None),) + (P(),) * 2
-                + (P("docs", None, "terms"),) * k * 2
+                + (P("docs", "terms", None),) * k * 2
                 + (P("docs", None),) * k)
 
     def refresh(arrays: MeshEllArrays, df_g, n_docs, avgdl):
@@ -326,7 +331,7 @@ def make_impact_refresh(mesh: Mesh, *, model: str = "bm25",
         k = arrays.n_buckets
         sharded = jax.shard_map(
             step, mesh=mesh, in_specs=n_in(k),
-            out_specs=(P("docs", None, "terms"),) * k,
+            out_specs=(P("docs", "terms", None),) * k,
             check_vma=False)
         impacts = sharded(df_g, n_docs, avgdl,
                           *arrays.tf, *arrays.term, *arrays.dl)
@@ -411,7 +416,7 @@ def make_mesh_ell_search(mesh: Mesh,
         parts = []
         with jax.named_scope("ell_blocks"):
             for i, (imp, term) in enumerate(zip(impacts, terms)):
-                if use_pallas and _pallas_eligible(imp.shape[0], B, u_cap):
+                if use_pallas and _pallas_eligible(imp.shape[1], B, u_cap):
                     parts.append(score_block_pallas(
                         imp, term, q.uniq, q.n_uniq, qc_ext,
                         block_live[i]))
@@ -482,7 +487,7 @@ def make_mesh_ell_search(mesh: Mesh,
                  P("docs", "terms", None), P("docs", None), P("docs"),
                  P("docs", None),
                  P(None), P(), P(None, None), P(None, None))
-                + (P("docs", None, "terms"),) * nb * 2)
+                + (P("docs", "terms", None),) * nb * 2)
 
     # the function's name is the program's in a device trace
     # (``jit_mesh_ell_search(<fingerprint>)`` on the ``XLA Modules`` line)

@@ -76,7 +76,7 @@ class MeshEllSnapshot:
     def topk_block_caps(self) -> tuple[int, ...]:
         """The columns of each score block a shard's top-k reads: its
         buckets' row capacities, then the delta's slots."""
-        return (*(imp.shape[1] for imp in self.base.impact),
+        return (*(imp.shape[2] for imp in self.base.impact),
                 self.delta.doc_cap)
 
     def name_of(self, gid: int) -> str | None:
@@ -297,7 +297,7 @@ class MeshEllIndex(MeshIndex):
         global_metrics.set_gauge("index_docs", snap.total_live)
         global_metrics.set_gauge("index_nnz", snap.nnz)
         self._publish_shard_gauges(
-            snap.shard_docs, sum(imp.shape[1] for imp in base.impact))
+            snap.shard_docs, sum(imp.shape[2] for imp in base.impact))
         log.info("committed mesh-ell snapshot", version=snap.version,
                  docs=snap.total_live, nnz=snap.nnz,
                  mesh=dict(self.mesh.shape))
@@ -544,12 +544,12 @@ class MeshEllSearcher(MeshSearcher):
             self._unbounded_cache = None
 
     def posting_blocks(self) -> list[tuple]:
-        # per-device block rows are dim 1 of the [D, rows_cap, W] base
+        # per-device block rows are dim 2 of the [D, W, rows_cap] base
         # arrays; make_mesh_ell_search dispatches on the same predicate
         snap = self.index.snapshot
         if snap is None:
             return []
-        return [(imp, _pallas_eligible(imp.shape[1], self.query_batch,
+        return [(imp, _pallas_eligible(imp.shape[2], self.query_batch,
                                        self._u_floor))
                 for imp in snap.base.impact]
 

@@ -683,7 +683,8 @@ def served_stage(sz: dict, *, rehearse: bool, chips: int, seed: int,
                                for f1 in front1)
         if scatter_failures:
             failures.append(f"served: scatter_failures={scatter_failures}")
-        served = count(m1, "queries_served") - count(m0, "queries_served")
+        served = count(m1, "dispatch_queries") - count(m0,
+                                                       "dispatch_queries")
         if served < n_q:
             failures.append(f"served: the worker scored {served} of "
                             f"{n_q} window queries")

@@ -3,7 +3,8 @@ top-k block built in bulk, against the per-element loop it replaced.
 
 The loop is kept HERE as the plain reference (it was
 ``Searcher._assemble`` and, a second time, ``MeshSearcher._assemble_hits``
-until PR 36): one numpy scalar drawn, tested and converted an entry.
+until PR 36; since PR 45 every family's is ``SearchLoop._assemble``):
+one numpy scalar drawn, tested and converted an entry.
 The arithmetic is unchanged (no sum, no reorder), so equality is ``==``
 on the lists, and the objects are the same types a caller saw before.
 """
@@ -81,7 +82,7 @@ def segmented_snap():
 
 def test_all_live():
     vals, ids = block(np.random.default_rng(1), 7, 10)
-    got = assemble_hits(vals, ids, NAMES.__getitem__, "score")
+    got = assemble_hits(vals, ids, NAMES, "score")
     same(got, loop_assemble(7, vals, ids, 10, NAMES.__getitem__, "score"))
     assert all(len(r) == 10 for r in got)
 
@@ -94,7 +95,7 @@ def test_dead_tails():
     vals[1, 3:] = 0.0
     vals[2, 8:] = -1.5
     vals[3, 4] = 0.0           # a hole, not a tail: the rest stay
-    got = assemble_hits(vals, ids, NAMES.__getitem__, "score")
+    got = assemble_hits(vals, ids, NAMES, "score")
     same(got, loop_assemble(4, vals, ids, 10, NAMES.__getitem__, "score"))
     assert [len(r) for r in got] == [6, 3, 8, 9]
 
@@ -102,7 +103,7 @@ def test_dead_tails():
 def test_fully_dead_row():
     vals, ids = block(np.random.default_rng(3), 3, 10)
     vals[1, :] = -np.inf
-    got = assemble_hits(vals, ids, NAMES.__getitem__, "score")
+    got = assemble_hits(vals, ids, NAMES, "score")
     same(got, loop_assemble(3, vals, ids, 10, NAMES.__getitem__, "score"))
     assert got[1] == [] and len(got[0]) == len(got[2]) == 10
 
@@ -110,8 +111,7 @@ def test_fully_dead_row():
 def test_every_row_dead():
     vals = np.zeros((3, 10), np.float32)
     ids = np.zeros((3, 10), np.int32)
-    assert assemble_hits(vals, ids, NAMES.__getitem__,
-                         "score") == [[], [], []]
+    assert assemble_hits(vals, ids, NAMES, "score") == [[], [], []]
 
 
 def test_kk_smaller_than_width():
@@ -151,7 +151,7 @@ def test_result_order_name_is_stable():
     names = ["b", "a", "a", "c"]
     vals = np.array([[4.0, 3.0, 2.0, 1.0]], np.float32)
     ids = np.array([[0, 1, 2, 3]], np.int32)
-    got = assemble_hits(vals, ids, names.__getitem__, "name")
+    got = assemble_hits(vals, ids, names, "name")
     same(got, loop_assemble(1, vals, ids, 4, names.__getitem__, "name"))
     assert got == [[("a", 3.0), ("a", 2.0), ("b", 4.0), ("c", 1.0)]]
 
@@ -167,8 +167,8 @@ def test_segmented_padded_names():
     ids[3, 4:] = [5, 12]
     got = searcher()._assemble(snap, ["q"] * 4, vals, ids, 6)
     same(got, loop_assemble(4, vals, ids, 6,
-                            snap.padded_names.__getitem__, "score"))
-    assert got[0][0].name == snap.name_of(int(ids[0, 0]))
+                            snap.doc_names.__getitem__, "score"))
+    assert got[0][0].name == snap.doc_names[int(ids[0, 0])]
     assert len(got[3]) == 4
 
 
@@ -177,9 +177,8 @@ def test_mesh_name_of_none(result_order):
     """A mesh shard's pad row has no name: that hit is dropped, the
     row's others stay and the next row starts where it should."""
     gone = {3, 17, 40}
-
-    def name_of(gid):
-        return None if gid in gone else NAMES[gid]
+    names = [None if gid in gone else name
+             for gid, name in enumerate(NAMES)]
 
     vals, gids = block(np.random.default_rng(9), 6, 10,
                        id_dtype=np.int64)
@@ -188,9 +187,9 @@ def test_mesh_name_of_none(result_order):
     vals[5, 7:] = -np.inf
     mesh = MeshSearcher.__new__(MeshSearcher)
     mesh.result_order = result_order
-    snap = SimpleNamespace(name_of=name_of)
-    got = mesh._assemble_hits(snap, ["q"] * 6, vals, gids, 10)
-    same(got, loop_assemble(6, vals, gids, 10, name_of, result_order))
+    got = mesh._assemble(plain_snap(names), ["q"] * 6, vals, gids, 10)
+    same(got, loop_assemble(6, vals, gids, 10, names.__getitem__,
+                            result_order))
     assert got[2] == []
     assert all(h.name is not None for row in got for h in row)
 
@@ -211,7 +210,7 @@ def test_one_query_block():
     """The ``/worker/process`` shape, 1 x 10: one path for every size."""
     vals, ids = block(np.random.default_rng(11), 1, 10)
     vals[0, 7:] = -np.inf
-    got = assemble_hits(vals, ids, NAMES.__getitem__, "score")
+    got = assemble_hits(vals, ids, NAMES, "score")
     same(got, loop_assemble(1, vals, ids, 10, NAMES.__getitem__, "score"))
 
 
@@ -224,8 +223,7 @@ def test_rank_all_block():
                     rng.random((5, 4096)), 0.0).astype(np.float32)
     order = np.argsort(-vals, axis=1, kind="stable")
     vals = np.take_along_axis(vals, order, axis=1)
-    got = assemble_hits(vals, order.astype(np.int64), names.__getitem__,
-                        "score")
+    got = assemble_hits(vals, order.astype(np.int64), names, "score")
     same(got, loop_assemble(5, vals, order, 4096, names.__getitem__,
                             "score"))
     assert 0 < len(got[0]) < 4096
@@ -239,5 +237,5 @@ def test_views_of_a_packed_buffer():
     vals[1, 4:] = -np.inf
     packed = np.concatenate([vals.view(np.int32), ids], axis=1)
     pv, pi = unpack_topk(packed)
-    got = assemble_hits(pv, pi, NAMES.__getitem__, "score")
+    got = assemble_hits(pv, pi, NAMES, "score")
     same(got, loop_assemble(6, vals, ids, 10, NAMES.__getitem__, "score"))

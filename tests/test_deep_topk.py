@@ -137,8 +137,7 @@ class Deep:
         msnap = self.mesh.index.snapshot
         self.place = {
             "local": _places(snap.doc_names[:snap.num_names]),
-            "mesh": _places([msnap.name_of(g)
-                             for g in range(4 * msnap.stride)])}
+            "mesh": _places(msnap.doc_names)}
         self._runs: dict = {}
 
     def want(self, name: str, k: int, order: str = "local"):
@@ -307,7 +306,8 @@ def test_chunked_topk_pads_and_merges_at_depth(deep, chunk):
     s = deep.local.searcher
     snap = deep.local.index.snapshot
     queries = list(QUERIES.values())
-    blocks, live, _host = s._score_chunk(snap, queries)
+    qb, _widest = s._vectorize(queries, s._batch_cap(len(queries)))
+    blocks, live, _host = s._score_chunk(snap, qb)
     vals, ids = unpack_topk(np.asarray(packed_topk_chunked(
         blocks, live, k=DEPTH, chunk=chunk)))
     assert vals.shape == ids.shape == (16, DEPTH)

@@ -9,8 +9,8 @@ in id order, ``bulk_load_packed``, ``commit``) from
 answers go through ``benchmarks/lib/oracle.compare`` — the float64 BM25
 over ONE unsharded index that decides ``correct`` on the chip — by both
 doors a query has: ``Engine.search_batch`` and a ``SearchNode``'s
-``/worker/process-batch`` (on the mesh: hit objects, then
-``pack_hit_lists``).
+``/worker/process-batch`` (on the mesh as on one chip: the searcher's
+arrays, then ``pack_topk_arrays``).
 """
 
 import json
@@ -22,10 +22,11 @@ import pytest
 
 from tfidf_tpu.cluster.coordination import CoordinationCore, LocalCoordination
 from tfidf_tpu.cluster.node import SearchNode, http_post
-from tfidf_tpu.cluster.wire import unpack_hit_lists
+from tfidf_tpu.cluster.wire import pack_hit_lists, unpack_hit_lists
 from tfidf_tpu.engine import Engine
 from tfidf_tpu.parallel.mesh import make_mesh
 from tfidf_tpu.utils.config import Config
+from tfidf_tpu.utils.metrics import global_metrics
 
 from tests.test_mesh_block_capacities import ROOT, bench_lib, data
 
@@ -130,16 +131,22 @@ def _held_to_reference(dep, door, queries) -> dict[int, list]:
     return got
 
 
-def test_the_engine_is_the_configurations(dep, spec):
+def test_the_engine_is_the_configurations(dep, spec, queries):
     stats = dep.engine.compute_stats()
     assert dict(dep.engine.index.mesh.shape) == {"docs": 4, "terms": 1}
     assert type(dep.engine.searcher).__name__ == "MeshEllSearcher"
     assert dep.engine.searcher.query_batch == 512
     assert stats["kernel_blocks"] >= 1
     assert dep.engine.index.snapshot.total_live == spec["docs"]
-    assert getattr(dep.engine.searcher, "search_arrays", None) is None, \
-        "the mesh worker now packs arrays: worker_pack_ms.mesh reads " \
-        "another path than its file says"
+    # the mesh worker's wire reply comes from the searcher's arrays (no
+    # hit object is built on the way) and is the hit lists' to the byte
+    k = spec["scoring"]["top_k"]
+    built = global_metrics.snapshot().get("hits_built", 0)
+    reply = dep.node.worker_search_batch_wire(queries, k=k)
+    assert global_metrics.snapshot().get("hits_built", 0) == built
+    hits = dep.engine.search_batch(queries, k=k)
+    assert global_metrics.snapshot()["hits_built"] > built
+    assert reply == pack_hit_lists(hits)
 
 
 @pytest.mark.parametrize("door", DOORS)

@@ -236,6 +236,139 @@ class TestLifecycle:
             assert [h.name for h in e.search(f"mark{i:02d}")] == \
                 [f"p{i:02d}.txt"], i
 
+# ---------------------------------------------------------------------------
+# The names by id (PR 45): a mesh snapshot's ``doc_names`` against the
+# ``name_of`` methods it replaced, kept HERE to the letter as the plain
+# reference (a divmod, a permutation lookup and a numpy scalar a hit)
+# ---------------------------------------------------------------------------
+
+def _name_of_ell(snap, gid):
+    s, local = divmod(gid, snap.stride)
+    if s >= len(snap.shard_docs):
+        return None
+    sd = snap.shard_docs[s]
+    if local < snap.base.doc_cap:      # ELL row -> permuted ins id
+        perm = snap.perms[s]
+        if local >= perm.shape[0]:
+            return None
+        return sd[int(perm[local])].name
+    delta_local = local - snap.base.doc_cap
+    ins = snap.base_counts[s] + delta_local
+    return sd[ins].name if ins < len(sd) else None
+
+
+def _name_of_coo(snap, gid):
+    doc_cap = snap.arrays.doc_cap
+    sd = snap.shard_docs[gid // doc_cap]
+    local = gid % doc_cap
+    return sd[local].name if local < len(sd) else None
+
+
+def _named(snap, name_of):
+    """The snapshot's names by id, held to ``name_of`` on EVERY id of
+    its id space."""
+    names = snap.doc_names
+    assert type(names) is list          # a C-level index a hit
+    want = [name_of(snap, g) for g in range(len(names))]
+    assert names == want
+    with pytest.raises(IndexError):
+        names[len(names)]
+    return want
+
+
+class TestNamesById:
+    @pytest.mark.parametrize("layout", ["ell", "coo"])
+    def test_every_id_class_through_append_and_reshard(self, tmp_path,
+                                                       layout):
+        name_of = {"ell": _name_of_ell, "coo": _name_of_coo}[layout]
+        e = make_engine(tmp_path, layout, "mesh", mesh_layout=layout)
+        D = e.index.D
+        for i in range(24):
+            e.ingest_text(f"p{i:02d}.txt", " ".join(
+                f"u{i:02d}x{j}" for j in range(1 + 5 * i % 7)) + " common")
+        e.commit()
+        snap1 = e.index.snapshot
+        names1 = _named(snap1, name_of)
+        per_shard = len(names1) // D
+        assert len(names1) == D * per_shard
+        # a row of every shard is named, and every shard has pad rows
+        for s in range(D):
+            of_shard = names1[s * per_shard:(s + 1) * per_shard]
+            assert sorted(n for n in of_shard if n is not None) == \
+                sorted(f"p{i:02d}.txt" for i in range(s, 24, D))
+            assert None in of_shard
+        if layout == "ell":     # width-sorted: not the insertion order
+            assert [n for n in names1 if n is not None] != \
+                [d.name for sd in snap1.shard_docs for d in sd]
+
+        # an append commit fills the SAME sequence, in place: the new
+        # documents' slots (on the ELL mesh: delta slots, past the base)
+        for i in range(3):
+            e.ingest_text(f"new{i}.txt", f"fresh{i} common")
+        assert e.delete("p05.txt")          # a tombstone keeps its name
+        e.commit()
+        snap2 = e.index.snapshot
+        assert (e.index.rebuilds, e.index.appends) == (1, 1)
+        assert snap2.doc_names is snap1.doc_names
+        names2 = _named(snap2, name_of)
+        added = {g: n for g, n in enumerate(names2) if names1[g] != n}
+        assert sorted(added.values()) == [f"new{i}.txt" for i in range(3)]
+        assert "p05.txt" in names2
+        if layout == "ell":
+            assert all(g % snap2.stride >= snap2.base.doc_cap
+                       for g in added)
+            # the slot past a shard's occupied delta slots
+            g = max(added)
+            assert names2[g + 1] is None
+        # the OLD snapshot still resolves every id it could return
+        assert all(snap1.doc_names[g] == n
+                   for g, n in enumerate(names1) if n is not None)
+        assert [h.name for h in e.search("fresh1")] == ["new1.txt"]
+
+        # a re-shard lays a fresh sequence out; snapshots from before it
+        # keep theirs
+        for i in range(80):
+            e.ingest_text(f"r{i:02d}.txt", f"more{i % 5} common")
+        e.commit()
+        snap3 = e.index.snapshot
+        assert e.index.rebuilds == 2
+        assert snap3.doc_names is not snap2.doc_names
+        names3 = _named(snap3, name_of)
+        assert "p05.txt" not in names3      # the re-shard dropped it
+        assert sorted(n for n in names3 if n is not None) == sorted(
+            d.name for d in e.index.live_entries())
+        assert snap2.doc_names == names2
+        assert [h.name for h in e.search("u07x0")] == ["p07.txt"]
+
+    @pytest.mark.parametrize("layout", ["ell", "coo"])
+    def test_nan_row_of_a_mesh_step_raises_naming_the_query(
+            self, tmp_path, layout):
+        """The poison check is the loop's, so every family's: a NaN a
+        mesh step hands back is ``DevicePoisonedOutput`` with the
+        offending query, from ``search`` and from ``search_arrays``
+        (until PR 45 the mesh loop handed it to the caller)."""
+        from tfidf_tpu.utils.device_nemesis import DevicePoisonedOutput
+        e = make_engine(tmp_path, layout, "mesh", mesh_layout=layout)
+        for name, text in TEXTS.items():
+            e.ingest_text(name, text)
+        e.commit()
+        queries = ["fox", "brown dog", "meadow"]
+        assert all(e.searcher.search(queries, k=3))
+        step_of = e.searcher._get_search_fn
+
+        def poisoned(kk, depth):
+            def step(*args):
+                packed = np.array(step_of(kk, depth)(*args))
+                packed[1, 0] = np.float32(np.nan).view(np.int32)
+                return packed
+            return step
+
+        e.searcher._get_search_fn = poisoned
+        for search in (e.searcher.search, e.searcher.search_arrays):
+            with pytest.raises(DevicePoisonedOutput) as ei:
+                search(queries, k=3)
+            assert ei.value.queries == ("brown dog",)
+
 
 class TestIncrementalStats:
     """Incremental df/N/avgdl must equal a from-scratch recompute after
@@ -700,7 +833,7 @@ class TestTopkInPlace:
         np.testing.assert_array_equal(gids, np.asarray(want_gids))
 
         # and the case is in the snapshot it ran on
-        hits = [(snap.name_of(int(g)), float(v))
+        hits = [(snap.doc_names[g], float(v))
                 for g, v in zip(gids[0], vals[0]) if v > 0]
         names = [n for n, _v in hits]
         shard, row = np.divmod(gids[0], snap.stride)

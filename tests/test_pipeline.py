@@ -278,30 +278,168 @@ def test_concurrent_search_calls_parity(tmp_path):
         assert got == want
 
 
-def test_search_arrays_packs_identical_wire_bytes(tmp_path):
+# Every searcher family through the one loop (engine/searcher.py
+# SearchLoop): the corpus of a family is TEXTS, then MORE committed on
+# top (on the ELL mesh: a live delta), then one document deleted (a
+# tombstone). PARENT_ANSWERS are what the tree before PR 45 (7b10ebf)
+# returned, family by family, for FAMILY_QUERIES at depth 4 (names and order
+# exact; a score to a float32's rounding, since another CPU may sum in
+# another order).
+MORE = {
+    "f.txt": "the fox and the dog are searching for the den",
+    "g.txt": "a lazy brown document",
+    "h.txt": "quick quick quick",
+}
+BASE = {**TEXTS, **{f"base{i}.txt": f"filler{i} word{i % 3} fox"
+                    for i in range(8)}}
+FAMILY_QUERIES = ["fox", "lazy dog", "quick brown", "searching den",
+                  "nothing matches this zzz", "the"]
+FAMILIES = {
+    "local-ell": {},
+    "local-coo-cosine": {"model": "tfidf_cosine", "scoring_layout": "coo"},
+    "segments": {"index_mode": "segments"},
+    "mesh-coo-cosine": {"engine_mode": "mesh", "model": "tfidf_cosine"},
+    "mesh-ell": {"engine_mode": "mesh"},
+}
+PARENT_ANSWERS = {
+    "local-coo-cosine": [
+        [("e.txt", 0.8222360610961914), ("base0.txt", 0.3138309717178345),
+        ("base1.txt", 0.3138309717178345), ("base3.txt", 0.3138309717178345)],
+        [("b.txt", 0.5942659974098206), ("a.txt", 0.5741746425628662),
+        ("g.txt", 0.4580156207084656), ("f.txt", 0.23703327775001526)],
+        [("h.txt", 1.0), ("a.txt", 0.6433948278427124), ("g.txt",
+        0.5132321119308472)],
+        [("e.txt", 0.5691466927528381), ("f.txt", 0.5312181115150452),
+        ("d.txt", 0.3785386383533478)],
+        [],
+        [("f.txt", 0.7110998630523682), ("a.txt", 0.5741746425628662),
+        ("b.txt", 0.2971329987049103)],
+    ],
+    "local-ell": [
+        [("e.txt", 0.2419874370098114), ("base0.txt", 0.17421592772006989),
+        ("base1.txt", 0.17421592772006989), ("base2.txt",
+        0.17421592772006989)],
+        [("b.txt", 1.0524251461029053), ("a.txt", 0.984736979007721),
+        ("g.txt", 0.7257594466209412), ("f.txt", 0.4626147747039795)],
+        [("h.txt", 1.4295387268066406), ("a.txt", 1.2027466297149658),
+        ("g.txt", 0.8864344358444214)],
+        [("f.txt", 1.1300649642944336), ("e.txt", 0.8864344358444214),
+        ("d.txt", 0.7451490759849548)],
+        [],
+        [("f.txt", 0.8626722097396851), ("a.txt", 0.7437793612480164),
+        ("b.txt", 0.5262125730514526)],
+    ],
+    "mesh-coo-cosine": [
+        [("e.txt", 0.8120247721672058), ("base3.txt", 0.30355560779571533),
+        ("base7.txt", 0.30355560779571533), ("base0.txt",
+        0.30355560779571533)],
+        [("a.txt", 0.5837608575820923), ("b.txt", 0.5785374641418457),
+        ("g.txt", 0.47151345014572144), ("f.txt", 0.22744174301624298)],
+        [("h.txt", 1.0), ("a.txt", 0.6116501688957214), ("g.txt",
+        0.47151345014572144)],
+        [("e.txt", 0.5836228728294373), ("f.txt", 0.5593752264976501),
+        ("d.txt", 0.3791692554950714)],
+        [],
+        [("f.txt", 0.6823251843452454), ("a.txt", 0.5558715462684631),
+        ("b.txt", 0.27544882893562317)],
+    ],
+    "mesh-ell": [
+        [("e.txt", 0.2419874370098114), ("base3.txt", 0.17421592772006989),
+        ("base7.txt", 0.17421592772006989), ("base0.txt",
+        0.17421592772006989)],
+        [("b.txt", 1.0524251461029053), ("a.txt", 0.984736979007721),
+        ("g.txt", 0.7257594466209412), ("f.txt", 0.4626147747039795)],
+        [("h.txt", 1.4295387268066406), ("a.txt", 1.2027466297149658),
+        ("g.txt", 0.8864344358444214)],
+        [("f.txt", 1.1300649642944336), ("e.txt", 0.8864344358444214),
+        ("d.txt", 0.7451490759849548)],
+        [],
+        [("f.txt", 0.8626723289489746), ("a.txt", 0.7437793612480164),
+        ("b.txt", 0.5262125730514526)],
+    ],
+    "segments": [
+        [("e.txt", 0.22675864398479462), ("base0.txt", 0.16390442848205566),
+        ("base1.txt", 0.16390442848205566), ("base2.txt",
+        0.16390442848205566)],
+        [("b.txt", 1.0259472131729126), ("a.txt", 0.9608937501907349),
+        ("g.txt", 0.7642409801483154), ("f.txt", 0.4127751290798187)],
+        [("h.txt", 1.2232588529586792), ("a.txt", 1.0438905954360962),
+        ("g.txt", 0.7642409801483154)],
+        [("f.txt", 1.1906352043151855), ("e.txt", 0.926945149898529),
+        ("d.txt", 0.7817791700363159)],
+        [],
+        [("f.txt", 0.7638711929321289), ("a.txt", 0.6599483489990234),
+        ("b.txt", 0.4686656892299652)],
+    ],
+}
+
+
+def family_engine(tmp_path, family: str):
+    import jax
+
+    from tfidf_tpu.parallel.mesh import make_mesh
+
+    cfg = Config(documents_path=str(tmp_path / "docs"),
+                 min_doc_capacity=8, min_nnz_capacity=256,
+                 min_vocab_capacity=64, query_batch=4, max_query_terms=8,
+                 search_pipeline_mode="executor", **FAMILIES[family])
+    mesh = (make_mesh((4, 1), devices=jax.devices()[:4])
+            if family.startswith("mesh") else None)
+    e = Engine(cfg, mesh=mesh)
+    for docs in (BASE, MORE):
+        for name, text in docs.items():
+            e.ingest_text(name, text)
+        e.commit()
+    e.delete("c.txt")
+    e.commit()
+    return e
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_search_arrays_packs_identical_wire_bytes(tmp_path, family):
     """The serving fast path (search_arrays -> pack_topk_arrays) must
     produce byte-identical wire replies to the hit-list path
-    (pack_hit_lists over assembled SearchHits)."""
+    (pack_hit_lists over assembled SearchHits), for every searcher
+    family, at a shallow depth and at one deeper than a mesh shard's
+    rows; and the hits are the parent's."""
     from tfidf_tpu.cluster.wire import (pack_hit_lists, pack_topk_arrays,
                                         unpack_hit_lists)
 
-    engine = make_engine(tmp_path)
-    hits = engine.search_batch(QUERIES, k=5)
-    vals, ids, kk, names = engine.searcher.search_arrays(QUERIES, k=5)
-    assert vals.shape == (len(QUERIES), kk)
-    fast = pack_topk_arrays(vals, ids, names)
-    slow = pack_hit_lists(hits)
-    assert fast == slow
-    # and the decoded lists agree with the SearchHit view
-    decoded = unpack_hit_lists(fast)
-    assert decoded == [[(h.name, float(np.float32(h.score)))
-                        for h in hl] for hl in hits]
+    engine = family_engine(tmp_path, family)
+    snap = engine.index.snapshot
+    if family == "mesh-ell":     # the case is in the snapshot
+        assert engine.index.rebuilds == 1 and engine.index.appends == 1
+        assert sum(live[-1] for live in snap.shard_live) == len(MORE)
+        assert None in snap.doc_names
+    deep = 4 * len(snap.doc_names)
+    for k in (4, deep):
+        hits = engine.search_batch(FAMILY_QUERIES, k=k)
+        vals, ids, kk, names = engine.search_batch_arrays(
+            FAMILY_QUERIES, k=k)
+        assert vals.shape == ids.shape == (len(FAMILY_QUERIES), kk)
+        assert names is snap.doc_names
+        fast = pack_topk_arrays(vals, ids, names)
+        assert fast == pack_hit_lists(hits)
+        # and the decoded lists agree with the SearchHit view
+        assert unpack_hit_lists(fast) == [
+            [(h.name, float(np.float32(h.score))) for h in hl]
+            for hl in hits]
+    assert all("c.txt" not in [h.name for h in hl] for hl in hits)
+    assert len(hits[0]) > 4                  # the deep request is deep
+    got = engine.search_batch(FAMILY_QUERIES, k=4)
+    want = PARENT_ANSWERS[family]
+    assert [[h.name for h in hl] for hl in got] == \
+        [[name for name, _s in hl] for hl in want]
+    for hl, wl in zip(got, want):
+        assert [h.score for h in hl] == pytest.approx(
+            [s for _n, s in wl], rel=1e-6)
 
 
-def test_search_arrays_empty_cases(tmp_path):
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_search_arrays_empty_cases(tmp_path, family):
     from tfidf_tpu.cluster.wire import pack_topk_arrays, unpack_hit_lists
 
-    engine = make_engine(tmp_path)
+    engine = family_engine(tmp_path, family)
     vals, ids, kk, names = engine.searcher.search_arrays([], k=5)
     assert vals.shape == (0, 0) and kk == 0
     assert unpack_hit_lists(pack_topk_arrays(vals, ids, names)) == []

@@ -115,7 +115,8 @@ def _whole_space_topk(engine, queries, k=10):
     block scored by ONE program and gathered into document order."""
     s = engine.searcher
     snap = engine.index.snapshot
-    blocks, live, _ = s._score_chunk(snap, queries)
+    qb, _widest = s._vectorize(queries, s._batch_cap(len(queries)))
+    blocks, live, _ = s._score_chunk(snap, qb)
     real = ell_scores_to_real(blocks, live, snap.doc_len.shape[0])
     col = jnp.arange(real.shape[1])[None, :]
     vals, ids = jax.lax.top_k(

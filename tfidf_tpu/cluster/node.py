@@ -816,31 +816,24 @@ class SearchNode(ScatterReadPlane):
     def worker_search_batch_wire(self, queries: list[str],
                                  k: int | None = None,
                                  deadline: float | None = None) -> bytes:
-        """Batched scatter RPC -> packed wire reply bytes. Fast path:
-        the local searcher's raw top-k arrays packed vectorized
-        (``search_arrays`` + ``pack_topk_arrays`` — no per-hit
-        SearchHit churn on the serving path). Falls back to the
-        hit-list path when the engine's searcher lacks the arrays
-        entrypoint (mesh layouts) or name-ordered parity results are
-        configured; both produce byte-identical wire replies for
-        score-ordered results (tests/test_pipeline.py)."""
-        got = None
-        if (self.config.result_order == "score"
-                and getattr(self.engine.searcher, "search_arrays",
-                            None) is not None):
-            got = self._search_batch_guarded(
+        """Batched scatter RPC -> packed wire reply bytes: the
+        searcher's raw top-k arrays packed vectorized (``search_arrays``
+        + ``pack_topk_arrays`` — no per-hit SearchHit churn on the
+        serving path). Name-ordered parity results go through hit lists,
+        which alone carry that order; for score-ordered results both
+        produce byte-identical wire replies (tests/test_pipeline.py)."""
+        if self.config.result_order == "score":
+            vals, ids, _kk, names = self._search_batch_guarded(
                 len(queries),
                 lambda: self.engine.search_batch_arrays(queries, k=k),
                 deadline=deadline)
-        if got is None:   # mesh layouts / name-ordered parity configs
+            t0 = time.perf_counter()
+            body = pack_topk_arrays(vals, ids, names)
+        else:
             results = self.worker_search_batch(queries, k=k,
                                                deadline=deadline)
             t0 = time.perf_counter()
             body = pack_hit_lists(results)
-        else:
-            vals, ids, _kk, names = got
-            t0 = time.perf_counter()
-            body = pack_topk_arrays(vals, ids, names)
         global_metrics.observe("worker_batch_pack",
                                time.perf_counter() - t0)
         return body
@@ -2891,7 +2884,6 @@ class _NodeHandler(_HttpHandlerBase):
                         # (Worker.java:183)
                         log.warning("search failed", err=repr(e))
                         hits = []
-                    # queries_served is counted once, by Searcher.search
                     # (the degraded flag is popped even on this parity
                     # endpoint: a stale thread-local would mis-stamp
                     # the NEXT batch this handler thread serves)

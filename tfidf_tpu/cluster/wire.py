@@ -56,14 +56,15 @@ def pack_hit_lists(results) -> bytes:
 
 def pack_topk_arrays(vals, ids, names) -> bytes:
     """Serialize raw top-k result arrays straight into the wire layout —
-    the serving fast path (``Searcher.search_arrays`` ->
+    the serving fast path (``SearchLoop.search_arrays`` ->
     ``/worker/process-batch`` reply) that skips building per-hit
     ``SearchHit`` objects entirely.
 
     ``vals [N, k] f32`` / ``ids [N, k] i32`` are one exact top-k per
     query in score-descending column order; ``ids`` index ``names``.
     Entries with a non-finite or <= 0 value are dead (padding / no
-    match) and are dropped, exactly as the hit-assembly path drops
+    match) and are dropped, and so is an entry whose name is ``None``
+    (a mesh shard's pad row), exactly as the hit-assembly path drops
     them, so the produced bytes are identical to
     ``pack_hit_lists(assembled_hits)`` for score-ordered results (the
     parity gate in ``tests/test_pipeline.py`` holds this).
@@ -71,11 +72,15 @@ def pack_topk_arrays(vals, ids, names) -> bytes:
     vals = np.asarray(vals, np.float32)
     ids = np.asarray(ids)
     live = np.isfinite(vals) & (vals > 0.0)
-    counts = live.sum(axis=1, dtype=np.uint32)
     # boolean-mask flattening is row-major: query order preserved,
     # within-query order stays score-descending (the top-k column order)
+    found = [names[d] for d in ids[live].tolist()]
+    if None in found:
+        live[live] = [name is not None for name in found]
+        found = [name for name in found if name is not None]
+    counts = live.sum(axis=1, dtype=np.uint32)
     scores = np.ascontiguousarray(vals[live])
-    name_blobs = [names[d].encode("utf-8") for d in ids[live].tolist()]
+    name_blobs = [name.encode("utf-8") for name in found]
     total = len(name_blobs)
     lens = np.fromiter(map(len, name_blobs), np.uint32, count=total)
     return b"".join((_HEADER.pack(MAGIC, vals.shape[0]),

@@ -438,51 +438,42 @@ class HostFallbackScorer:
                 global_metrics.inc("compute_fallback_mirror_builds")
             return m
 
-    def search(self, queries: list[str], k: int | None = None,
-               *, unbounded: bool = False) -> list[list]:
+    def _search_chunks(self, queries: list[str], k: int | None,
+                       unbounded: bool, finish) -> tuple:
+        """``(names, results)``: the searcher's chunks scored on the
+        mirror and ranked on the host, each handed to
+        ``finish(snap, queries, vals, ids, kk)``, and the snapshot's
+        names by id; ``(None, [])`` where there is nothing to search."""
         s = self.searcher
         snap = s.index.snapshot
-        if snap is None or not getattr(snap, "num_names", 0) \
-                or not queries:
-            return [[] for _ in queries]
+        if snap is None or not snap.num_names or not queries:
+            return None, []
         m = self._mirror_for(snap)
         k = s.top_k if k is None else k
         cap = s._batch_cap(len(queries))
-        out: list[list] = []
+        out: list = []
         for lo in range(0, len(queries), cap):
             chunk = queries[lo:lo + cap]
             qb, _w = s._vectorize(chunk, cap)
             scores = m.scores(qb)
             if unbounded:
-                rank_n = snap.num_names
-                vals, ids = _host_full_ranking(scores, rank_n)
-                out.extend(s._assemble(snap, chunk, vals, ids, rank_n))
+                kk = snap.num_names
+                vals, ids = _host_full_ranking(scores, kk)
             else:
                 kk = min(k, snap.num_names)
                 vals, ids = _host_topk(scores, m.num_docs, kk)
-                out.extend(s._assemble(snap, chunk, vals, ids, kk))
-        global_metrics.inc("queries_served", len(queries))
-        return out
+            out.extend(finish(snap, chunk, vals, ids, kk))
+        return snap.doc_names, out
+
+    def search(self, queries: list[str], k: int | None = None,
+               *, unbounded: bool = False) -> list[list]:
+        names, out = self._search_chunks(queries, k, unbounded,
+                                         self.searcher._assemble)
+        return [[] for _ in queries] if names is None else out
 
     def search_arrays(self, queries: list[str], k: int | None = None):
         s = self.searcher
-        snap = s.index.snapshot
-        k = s.top_k if k is None else k
-        if snap is None or not getattr(snap, "num_names", 0) \
-                or not queries:
-            n = len(queries)
-            return (np.zeros((n, 0), np.float32),
-                    np.zeros((n, 0), np.int32), 0, [])
-        m = self._mirror_for(snap)
-        kk = min(k, snap.num_names)
-        cap = s._batch_cap(len(queries))
-        all_vals, all_ids = [], []
-        for lo in range(0, len(queries), cap):
-            chunk = queries[lo:lo + cap]
-            qb, _w = s._vectorize(chunk, cap)
-            vals, ids = _host_topk(m.scores(qb), m.num_docs, kk)
-            all_vals.append(vals[:len(chunk)])
-            all_ids.append(ids[:len(chunk)])
-        global_metrics.inc("queries_served", len(queries))
-        return (np.concatenate(all_vals, axis=0),
-                np.concatenate(all_ids, axis=0), kk, snap.doc_names)
+        names, parts = self._search_chunks(
+            queries, k, False,
+            lambda snap, chunk, *block: [s._checked(chunk, *block)])
+        return s._arrays_reply(parts, len(queries), names)

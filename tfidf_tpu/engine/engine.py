@@ -553,16 +553,12 @@ class Engine:
                             k: int | None = None):
         """Exact top-k as raw result arrays ``(vals, ids, kk, names)``
         for wire packing (the batched-scatter serving fast path — see
-        ``Searcher.search_arrays``), or ``None`` when the active
-        searcher has no arrays path (mesh layouts) and the caller must
-        assemble hits via :meth:`search_batch`. Engine failures surface
-        exactly as they do from ``search_batch``."""
-        arrays = getattr(self.searcher, "search_arrays", None)
-        if arrays is None:
-            return None
+        ``SearchLoop.search_arrays``), whatever the searcher family.
+        Engine failures surface exactly as they do from
+        ``search_batch``."""
         return self._run_compute(
             queries,
-            lambda qs: arrays(qs, k=k),
+            lambda qs: self.searcher.search_arrays(qs, k=k),
             lambda qs: self._fallback.search_arrays(qs, k=k),
             merge=self._merge_arrays)
 

@@ -76,8 +76,7 @@ def device_bytes_limit(array) -> int | None:
     return stats.get("bytes_limit") if stats else None
 
 
-# guards lazy per-searcher PipelineExecutor construction (the mixin has
-# no __init__ of its own to hang a per-instance lock on)
+# guards lazy per-searcher PipelineExecutor construction
 _pipe_init_lock = threading.Lock()
 
 
@@ -136,16 +135,17 @@ def vectorize_queries(queries: list[str], analyzer: Analyzer,
             max([1, *sizes]))
 
 
-def assemble_hits(vals: np.ndarray, ids: np.ndarray, name_of,
+def assemble_hits(vals: np.ndarray, ids: np.ndarray, doc_names,
                   result_order: str) -> list[list[SearchHit]]:
     """The hit lists of one fetched ``[n, kk]`` block of top-k values
     and document ids, a list a query: the ONE place result arrays become
     Python objects, for every searcher family. An entry is a hit where
     its value is finite and > 0 (dead and pad entries are 0 or -inf);
-    ``name_of`` maps an id to its name, and an id it names ``None`` (a
-    mesh shard's pad row) is dropped. Every conversion between numpy and
-    Python happens once for the block, not once an entry: a float32
-    becomes the same ``float`` through ``tolist`` as through ``float()``.
+    ``doc_names`` is the snapshot's, the names by id, and an id it
+    names ``None`` (a mesh shard's pad row) is dropped. Every conversion
+    between numpy and Python happens once for the block, not once an
+    entry: a float32 becomes the same ``float`` through ``tolist`` as
+    through ``float()``.
 
     Two stages of the one timer, inside the caller's ``assemble``:
     ``hit_names`` (the mask, the two ``tolist``, the name of every live
@@ -158,7 +158,7 @@ def assemble_hits(vals: np.ndarray, ids: np.ndarray, name_of,
     with trace_phase("hit_names"):
         live = np.isfinite(vals) & (vals > 0.0)
         counts = np.count_nonzero(live, axis=1)
-        names = list(map(name_of, ids[live].tolist()))
+        names = list(map(doc_names.__getitem__, ids[live].tolist()))
         scores = vals[live].tolist()
         if None in names:
             named = [name is not None for name in names]
@@ -187,21 +187,193 @@ def assemble_hits(vals: np.ndarray, ids: np.ndarray, name_of,
     return results
 
 
-class QueryVectorizerMixin:
-    """The unique-term capacity high-water policy, shared by every
-    searcher family (local, COO mesh, ELL mesh): batches are vectorized
-    with ``min_slots`` floored at the largest u_cap seen so far, so the
-    compiled scoring program stays stable across query batches instead
-    of recompiling whenever the unique count crosses a power-of-two
-    bucket. Hosts must provide analyzer/vocab/model/max_query_terms.
+class SearchLoop:
+    """The ONE search loop of every searcher family (local, COO mesh,
+    ELL mesh): :meth:`search` and :meth:`search_arrays` cut the queries
+    into chunks, vectorize, hand each chunk to the family's dispatch
+    hook, fetch its packed top-k and finish it on the caller's thread,
+    ``pipeline_depth`` chunks deep (:meth:`_run_pipelined`). A family
+    provides ``index``, analyzer / vocab / model, ``query_batch``,
+    ``max_query_terms``, ``top_k``, ``result_order`` and the hooks
+    :meth:`_dispatch_chunk`, :meth:`_search_unbounded` and, where it
+    caches by snapshot, :meth:`_on_snapshot`; its snapshots name their
+    documents by id (``doc_names``) and count them (``num_names``).
 
-    Also hosts the ONE implementation of depth-N chunk pipelining
-    (``_run_pipelined``) so the engine and mesh search loops cannot
-    drift."""
+    Batches are vectorized with ``min_slots`` floored at the largest
+    u_cap seen so far, so the compiled scoring program stays stable
+    across query batches instead of recompiling whenever the unique
+    count crosses a power-of-two bucket."""
 
     _u_floor = 256
     _pipe: PipelineExecutor | None = None
     pipeline_mode = "auto"
+
+    def __init__(self, index, analyzer: Analyzer, vocab: Vocabulary,
+                 model: ScoringModel,
+                 *, query_batch: int = 32, max_query_terms: int = 32,
+                 top_k: int = 10, result_order: str = "score",
+                 pipeline_depth: int = 2,
+                 pipeline_mode: str = "auto") -> None:
+        self.index = index
+        self.analyzer = analyzer
+        self.vocab = vocab
+        self.model = model
+        self.query_batch = query_batch
+        self.max_query_terms = max_query_terms
+        self.top_k = top_k
+        # "name" reproduces the reference's alphabetical result ordering
+        # (Leader.java:80-91 sorts the merged map by document name)
+        self.result_order = result_order
+        # in-flight chunks: on small corpora the device step is far
+        # shorter than the device->host fetch RTT, so serial execution
+        # caps throughput at ~1 chunk per RTT; depth D keeps D fetches
+        # overlapped (D+1 chunks in flight including the one just
+        # dispatched — see _run_pipelined's in-flight accounting; each
+        # pending chunk holds only a packed [B, 2k] top-k buffer)
+        self.pipeline_depth = max(1, pipeline_depth)
+        # "auto" | "executor" | "inline" — see _use_executor
+        self.pipeline_mode = pipeline_mode
+
+    def _batch_cap(self, n: int) -> int:
+        return min(self.query_batch, next_capacity(max(n, 1), 1))
+
+    def search(self, queries: list[str], k: int | None = None,
+               *, unbounded: bool = False) -> list[list[SearchHit]]:
+        """Score queries against the current snapshot.
+
+        ``unbounded=True`` returns every matching document (the reference's
+        ``Integer.MAX_VALUE`` behavior, ``Worker.java:230``) via a host-side
+        full ranking — parity mode only; exact top-k is the fast path.
+
+        Chunks are PIPELINED ``pipeline_depth`` deep (default 2): later
+        chunks' device programs are dispatched before earlier chunks'
+        packed top-k buffers are fetched, so the device->host round trip
+        and host-side hit assembly hide under device time. Fetches
+        serialize on one stream, so depth beyond 2 buys nothing; what
+        depth a locally attached chip needs is not measured (ROADMAP
+        D3).
+        """
+        snap = self._snapshot(queries)
+        if snap is None:
+            return [[] for _ in queries]
+        if unbounded:
+            cap = self._batch_cap(len(queries))
+            return [hits for lo in range(0, len(queries), cap)
+                    for hits in self._search_unbounded(
+                        snap, queries[lo:lo + cap])]
+        return self._search_chunks(
+            snap, queries, k,
+            lambda vals, ids: assemble_hits(vals, ids, snap.doc_names,
+                                            self.result_order))
+
+    def search_arrays(self, queries: list[str], k: int | None = None):
+        """Pipelined exact top-k returning the RAW result arrays —
+        ``(vals [N, kk] f32, ids [N, kk] i32, kk, names)`` — instead of
+        assembled :class:`SearchHit` lists. ``ids`` index ``names``;
+        entries whose value is non-finite or <= 0 are dead (padding /
+        no match), exactly the entries :func:`assemble_hits` drops. The
+        worker serving path packs these straight into the scatter wire
+        reply (:func:`tfidf_tpu.cluster.wire.pack_topk_arrays`) without
+        building per-hit Python objects, keeping the post-fetch host
+        cost off the serving critical path."""
+        snap = self._snapshot(queries)
+        if snap is None:
+            return self._arrays_reply([], len(queries), [])
+        return self._arrays_reply(
+            self._search_chunks(snap, queries, k,
+                                lambda vals, ids: [(vals, ids)]),
+            len(queries), snap.doc_names)
+
+    @staticmethod
+    def _arrays_reply(parts: list, n_queries: int, names):
+        """``search_arrays``' reply of a search's chunks, each its
+        checked ``(vals, ids)``; none: nothing was there to search."""
+        if not parts:
+            return (np.zeros((n_queries, 0), np.float32),
+                    np.zeros((n_queries, 0), np.int32), 0, [])
+        vals = np.concatenate([p[0] for p in parts], axis=0)
+        ids = np.concatenate([p[1] for p in parts], axis=0)
+        return vals, ids, vals.shape[1], names
+
+    def _snapshot(self, queries: list[str]):
+        """The snapshot a search runs on, or None where there is
+        nothing to search."""
+        snap = self.index.snapshot
+        self._on_snapshot(snap)
+        if snap is None or not snap.num_names or not queries:
+            return None
+        return snap
+
+    def _search_chunks(self, snap, queries: list[str], k: int | None,
+                       finish) -> list:
+        """Every chunk of ``queries`` through the pipeline: counted and
+        vectorized, its device work launched by the family's
+        :meth:`_dispatch_chunk`, its packed top-k fetched in ONE
+        device->host transfer (high-latency links make per-fetch cost
+        dominate) and, on the caller's thread, split into two views,
+        checked for poison and handed to ``finish(vals, ids)``: the
+        hits of ``search`` or the arrays of ``search_arrays``. A chunk
+        is finished while later ones are in flight."""
+        k = self.top_k if k is None else k
+        cap = self._batch_cap(len(queries))
+
+        def dispatch(chunk):
+            chunk_cap = self._batch_cap(len(chunk))
+            self._count_chunk(len(chunk), chunk_cap)
+            with trace_phase("vectorize"):
+                qb, _widest = self._vectorize(chunk, chunk_cap)
+            return (chunk, *self._dispatch_chunk(snap, qb, len(chunk), k))
+
+        def assemble(chunk, arr, kk):
+            with trace_phase("assemble"):
+                return finish(*self._checked(chunk, *unpack_topk(arr), kk))
+
+        return self._run_pipelined(
+            (queries[lo:lo + cap] for lo in range(0, len(queries), cap)),
+            dispatch,
+            lambda chunk, packed, kk: (chunk, fetch_packed(packed), kk),
+            assemble)
+
+    def _on_snapshot(self, snap) -> None:
+        """Family hook: called with the snapshot each search (lets a
+        family drop per-snapshot caches when the version moves)."""
+
+    def _dispatch_chunk(self, snap, qb, n_queries: int, k: int):
+        """Family hook: launch one vectorized chunk's device work;
+        returns ``(packed, kk)``, the packed top-k still ON DEVICE (not
+        fetched) and the depth of the reply it holds."""
+        raise NotImplementedError
+
+    def _search_unbounded(self, snap,
+                          queries: list[str]) -> list[list[SearchHit]]:
+        """Family hook: the reference's unbounded (parity) results of
+        one chunk."""
+        raise NotImplementedError
+
+    def _checked(self, queries: list[str], vals, ids, kk: int):
+        """A fetched block cut to its real queries and its depth, and
+        held to the poison-detection seam: a NaN in a fetched result
+        row is never legitimate (scores are finite by construction;
+        dead/pad entries are 0 or -inf), so it means the device produced
+        garbage for that query — a miscompiled kernel, corrupted HBM, or
+        the nemesis' injected poison. Raises with the OFFENDING query
+        strings only, so the worker can report per-query blame and the
+        leader's quarantine never punishes innocent batch cohorts."""
+        n = len(queries)
+        rows = np.isnan(vals[:n]).any(axis=1)
+        if rows.any():
+            from tfidf_tpu.utils.device_nemesis import \
+                DevicePoisonedOutput
+            raise DevicePoisonedOutput(tuple(
+                q for q, bad in zip(queries, rows) if bad))
+        return vals[:n, :kk], ids[:n, :kk]
+
+    def _assemble(self, snap, queries: list[str], vals, ids,
+                  kk: int) -> list[list[SearchHit]]:
+        """The checked hit lists of a block ranked on the host (the
+        unbounded results, ``compute_health``'s fallback)."""
+        return assemble_hits(*self._checked(queries, vals, ids, kk),
+                             snap.doc_names, self.result_order)
 
     def _vectorize(self, queries, cap):
         qb, widest = vectorize_queries(
@@ -254,9 +426,7 @@ class QueryVectorizerMixin:
                     # transiently double the depth+1 HBM budget and
                     # leak a thread pair until idle exit)
                     pipe = self._pipe = PipelineExecutor(
-                        depth=max(1, getattr(self, "pipeline_depth",
-                                             1)),
-                        name="search")
+                        depth=self.pipeline_depth, name="search")
         return pipe
 
     def _use_executor(self) -> bool:
@@ -266,12 +436,10 @@ class QueryVectorizerMixin:
         three thread hand-offs per chunk cost more than they hide —
         measured ~27% concurrent-caller throughput loss — so "auto"
         keeps CPU inline and turns the executor on for accelerators."""
-        mode = getattr(self, "pipeline_mode", "auto")
-        if mode == "executor":
+        if self.pipeline_mode == "executor":
             return True
-        if mode == "inline":
+        if self.pipeline_mode == "inline":
             return False
-        import jax
         return jax.default_backend() != "cpu"
 
     def _run_pipelined(self, chunks, dispatch, fetch, assemble) -> list:
@@ -312,132 +480,29 @@ class QueryVectorizerMixin:
         """Single-thread dispatch-then-drain over the SAME three stages
         (the pre-executor loop): overlaps one call's chunks via async
         dispatch, but not chunks across concurrent calls."""
-        from collections import deque
-
-        depth = max(1, getattr(self, "pipeline_depth", 1))
         pending: deque = deque()
         out: list = []
         for chunk in chunks:
             pending.append(dispatch(chunk))
-            if len(pending) > depth:
+            if len(pending) > self.pipeline_depth:
                 out.extend(assemble(*fetch(*pending.popleft())))
         while pending:
             out.extend(assemble(*fetch(*pending.popleft())))
         return out
 
 
-class Searcher(QueryVectorizerMixin):
+class Searcher(SearchLoop):
     def __init__(self, index: ShardIndex, analyzer: Analyzer,
                  vocab: Vocabulary, model: ScoringModel,
-                 *, query_batch: int = 32, max_query_terms: int = 32,
-                 top_k: int = 10, result_order: str = "score",
-                 use_pallas: bool = False,
-                 pipeline_depth: int = 2,
-                 pipeline_mode: str = "auto") -> None:
-        self.index = index
-        self.analyzer = analyzer
-        self.vocab = vocab
-        self.model = model
-        self.query_batch = query_batch
-        self.max_query_terms = max_query_terms
-        self.top_k = top_k
-        # "name" reproduces the reference's alphabetical result ordering
-        # (Leader.java:80-91 sorts the merged map by document name)
-        self.result_order = result_order
+                 *, use_pallas: bool = False, **loop) -> None:
+        super().__init__(index, analyzer, vocab, model, **loop)
         self.use_pallas = use_pallas
-        # in-flight chunks: on small corpora the device step is far
-        # shorter than the device->host fetch RTT, so serial execution
-        # caps throughput at ~1 chunk per RTT; depth D keeps D fetches
-        # overlapped (D+1 chunks in flight including the one just
-        # dispatched — see _run_pipelined's in-flight accounting; each
-        # pending chunk holds only a packed [B, 2k] top-k buffer)
-        self.pipeline_depth = max(1, pipeline_depth)
-        # "auto" | "executor" | "inline" — see _use_executor
-        self.pipeline_mode = pipeline_mode
         # the packed top-k of the stretches whose scores may still be
         # allocated, oldest first (see _hold_back), and the stretch
         # plans of the current snapshot by batch bucket
         self._in_flight: deque = deque()
         self._flight_lock = threading.Lock()
         self._plans: dict[tuple[int, int], list[Stretch]] = {}
-
-    def _batch_cap(self, n: int) -> int:
-        return min(self.query_batch, next_capacity(max(n, 1), 1))
-
-    def search(self, queries: list[str], k: int | None = None,
-               *, unbounded: bool = False) -> list[list[SearchHit]]:
-        """Score queries against the current snapshot.
-
-        ``unbounded=True`` returns every matching document (the reference's
-        ``Integer.MAX_VALUE`` behavior, ``Worker.java:230``) via a host-side
-        full ranking — parity mode only; exact top-k is the fast path.
-
-        Chunks are PIPELINED ``pipeline_depth`` deep (default 2): later
-        chunks' device programs are dispatched before earlier chunks'
-        packed top-k buffers are fetched, so the device->host round trip
-        and host-side hit assembly hide under device time. Fetches
-        serialize on one stream, so depth beyond 2 buys nothing; what
-        depth a locally attached chip needs is not measured (ROADMAP
-        D3).
-        """
-        snap = self.index.snapshot
-        if snap is None or not snap.num_names or not queries:
-            return [[] for _ in queries]
-        k = self.top_k if k is None else k
-        out: list[list[SearchHit]] = []
-        cap = self._batch_cap(len(queries))
-        if unbounded:
-            for lo in range(0, len(queries), cap):
-                chunk = queries[lo:lo + cap]
-                out.extend(self._search_unbounded(snap, chunk))
-            global_metrics.inc("queries_served", len(queries))
-            return out
-        out.extend(self._run_pipelined(
-            (queries[lo:lo + cap]
-             for lo in range(0, len(queries), cap)),
-            lambda chunk: (chunk,) + self._dispatch_chunk(snap, chunk,
-                                                          k),
-            lambda chunk, packed, kk: (chunk, fetch_packed(packed), kk),
-            lambda chunk, arr, kk: self._finish_chunk(snap, chunk, arr,
-                                                      kk)))
-        global_metrics.inc("queries_served", len(queries))
-        return out
-
-    def search_arrays(self, queries: list[str], k: int | None = None):
-        """Pipelined exact top-k returning the RAW result arrays —
-        ``(vals [N, kk] f32, ids [N, kk] i32, kk, names)`` — instead of
-        assembled :class:`SearchHit` lists. ``ids`` index ``names``;
-        entries whose value is non-finite or <= 0 are dead (padding /
-        no match), exactly the rows :meth:`_assemble` would drop. The
-        worker serving path packs these straight into the scatter wire
-        reply (:func:`tfidf_tpu.cluster.wire.pack_topk_arrays`) without
-        building per-hit Python objects, keeping the post-fetch host
-        cost off the serving critical path."""
-        snap = self.index.snapshot
-        k = self.top_k if k is None else k
-        if snap is None or not snap.num_names or not queries:
-            n = len(queries)
-            return (np.zeros((n, 0), np.float32),
-                    np.zeros((n, 0), np.int32), 0, [])
-        kk = min(k, snap.num_names)
-        cap = self._batch_cap(len(queries))
-        parts = self._run_pipelined(
-            (queries[lo:lo + cap]
-             for lo in range(0, len(queries), cap)),
-            lambda chunk: (chunk,) + self._dispatch_chunk(snap, chunk,
-                                                          k),
-            lambda chunk, packed, kk_: (chunk, fetch_packed(packed),
-                                        kk_),
-            # assemble: two views of the fetched buffer, pad rows cut
-            # (the poison check runs on the fetched values exactly like
-            # the hit-assembly path's _assemble)
-            lambda chunk, arr, kk_: [self._checked_unpack(chunk, arr)])
-        vals = np.concatenate([p[0] for p in parts], axis=0)
-        ids = np.concatenate([p[1] for p in parts], axis=0)
-        names = (snap.padded_names if isinstance(snap, SegmentedSnapshot)
-                 else snap.doc_names)
-        global_metrics.inc("queries_served", len(queries))
-        return vals, ids, kk, names
 
     def posting_blocks(self) -> list[tuple]:
         """``(array, rides_kernel)`` for every posting block of the
@@ -457,7 +522,7 @@ class Searcher(QueryVectorizerMixin):
                     imp.shape[1], self.query_batch, self._u_floor))
                 for imp in snap.ell_impacts]
 
-    def _score_chunk(self, snap: Snapshot, queries: list[str]):
+    def _score_chunk(self, snap: Snapshot, qb):
         """``(blocks, live, live_host)``: the chunk's WHOLE score space
         as the tuple of ``[B, cap_i]`` blocks ``packed_topk_chunked``
         takes (the ELL layout's own; one block for the others), the
@@ -465,9 +530,6 @@ class Searcher(QueryVectorizerMixin):
         the host integers the commit had. The serving path of the ELL
         layout takes that space a stretch at a time instead
         (:meth:`_dispatch_ell`)."""
-        cap = self._batch_cap(len(queries))
-        with trace_phase("vectorize"):
-            qb, _widest = self._vectorize(queries, cap)
         if (self.use_pallas and not isinstance(snap, SegmentedSnapshot)
                 and snap.is_ell):
             self._count_kernel_uniq(qb)
@@ -485,7 +547,7 @@ class Searcher(QueryVectorizerMixin):
                 # the whole padded space is live: pads score 0
                 return (scores,), snap.num_docs, (scores.shape[1],)
             if snap.is_ell:
-                whole, = self._stretches(snap, cap, None)
+                whole, = self._stretches(snap, qb.slots.shape[0], None)
                 return (self._score_ell(snap, qb, whole), whole.live,
                         whole.live_host)
             scores = score_coo_batch(
@@ -587,22 +649,19 @@ class Searcher(QueryVectorizerMixin):
     # baseline tests and chaos runs compare the skipping path against
     tier_bypass = False
 
-    def _dispatch_chunk(self, snap: Snapshot, queries: list[str],
+    def _dispatch_chunk(self, snap: Snapshot, qb, n_queries: int,
                         k: int):
-        """Launch one chunk's device work; returns (packed, kk) with the
-        packed top-k still ON DEVICE (not fetched)."""
-        self._count_chunk(len(queries), self._batch_cap(len(queries)))
         kk = min(k, snap.num_names)
         if isinstance(snap, SegmentedSnapshot):
             if snap.tier is not None and not self.tier_bypass:
-                return self._dispatch_tiered(snap, queries, k)
+                return self._dispatch_tiered(snap, qb, n_queries, kk), kk
         elif snap.is_ell:
-            return self._dispatch_ell(snap, queries, kk), kk
-        blocks, live, live_host = self._score_chunk(snap, queries)
+            return self._dispatch_ell(snap, qb, kk), kk
+        blocks, live, live_host = self._score_chunk(snap, qb)
         with trace_phase("topk"):
             return self._rank(blocks, live, live_host, None, kk), kk
 
-    def _dispatch_ell(self, snap: Snapshot, queries: list[str], kk: int):
+    def _dispatch_ell(self, snap: Snapshot, qb, kk: int):
         """One chunk over the ELL blocks, a STRETCH at a time: score
         program on the stretch, top-k program on its ``[B, cap_i]``
         outputs with the stretch's base row, the scores dropped (their
@@ -615,9 +674,7 @@ class Searcher(QueryVectorizerMixin):
         holds every stretch but the last one's top-k (and the waits of
         :meth:`_hold_back`), ``topk`` that and the merge — for a
         corpus of one stretch, exactly the two enqueues."""
-        cap = self._batch_cap(len(queries))
-        with trace_phase("vectorize"):
-            qb, _widest = self._vectorize(queries, cap)
+        cap = qb.slots.shape[0]
         if self.use_pallas:
             self._count_kernel_uniq(qb)
         plan = self._stretch_plan(snap, cap)
@@ -643,8 +700,8 @@ class Searcher(QueryVectorizerMixin):
             packed = rank(plan[-1], blocks)
             return merge_packed((*done, packed)) if done else packed
 
-    def _dispatch_tiered(self, snap: SegmentedSnapshot,
-                         queries: list[str], k: int):
+    def _dispatch_tiered(self, snap: SegmentedSnapshot, qb, B: int,
+                         kk: int):
         """Tiered top-k: score the HOT segments in one device program,
         then walk the COLD segments in descending bound order, skipping
         every segment whose block-max upper bound proves it cannot beat
@@ -666,12 +723,8 @@ class Searcher(QueryVectorizerMixin):
         import jax.numpy as jnp
 
         tier = snap.tier
-        B = len(queries)
-        cap = self._batch_cap(B)
-        kk = min(k, snap.num_names)
+        cap = qb.slots.shape[0]
         skw = self.model.score_kwargs()
-        with trace_phase("vectorize"):
-            qb, _widest = self._vectorize(queries, cap)
 
         # ---- hot pass: one device program over the resident set ----
         cand_vals = np.zeros((B, 0), np.float64)
@@ -798,21 +851,14 @@ class Searcher(QueryVectorizerMixin):
             arr = np.zeros((B, 2 * kk), np.int32)
             arr[:, :kk] = top_v.view(np.int32)
             arr[:, kk:] = top_g
-        return arr, kk
-
-    def _finish_chunk(self, snap: Snapshot, queries: list[str],
-                      packed, kk: int) -> list[list[SearchHit]]:
-        # ``packed`` already crossed device->host in the fetch stage
-        # (fetch_packed: ONE transfer for values+ids — high-latency
-        # host<->device links make per-fetch cost dominate); this runs
-        # on the caller's thread and only splits views + builds hits
-        with trace_phase("assemble"):
-            vals, ids = unpack_topk(packed)
-            return self._assemble(snap, queries, vals, ids, kk)
+        return arr
 
     def _search_unbounded(self, snap: Snapshot,
                           queries: list[str]) -> list[list[SearchHit]]:
-        blocks, live, _ = self._score_chunk(snap, queries)
+        with trace_phase("vectorize"):
+            qb, _widest = self._vectorize(queries,
+                                          self._batch_cap(len(queries)))
+        blocks, live, _ = self._score_chunk(snap, qb)
         segmented = isinstance(snap, SegmentedSnapshot)
         with trace_phase("rank_all"):
             # ELL blocks -> document order; the other layouts' one
@@ -828,35 +874,3 @@ class Searcher(QueryVectorizerMixin):
             vals = np.asarray(vals)
             ids = np.asarray(ids)
         return self._assemble(snap, queries, vals, ids, rank_n)
-
-    def _checked_unpack(self, chunk: list[str], arr):
-        with trace_phase("assemble"):
-            vals, ids = unpack_topk(arr[:len(chunk)])
-            self._poison_check(chunk, vals)
-            return vals, ids
-
-    @staticmethod
-    def _poison_check(queries: list[str], vals) -> None:
-        """The poison-detection seam: a NaN in a fetched result row is
-        never legitimate (scores are finite by construction; dead/pad
-        entries are 0 or -inf), so it means the device produced garbage
-        for that query — a miscompiled kernel, corrupted HBM, or the
-        nemesis' injected poison. Raises with the OFFENDING query
-        strings only, so the worker can report per-query blame and the
-        leader's quarantine never punishes innocent batch cohorts."""
-        rows = np.isnan(vals[:len(queries)]).any(axis=tuple(
-            range(1, vals.ndim)))
-        if rows.any():
-            from tfidf_tpu.utils.device_nemesis import \
-                DevicePoisonedOutput
-            raise DevicePoisonedOutput(tuple(
-                q for q, bad in zip(queries, rows) if bad))
-
-    def _assemble(self, snap: Snapshot, queries: list[str], vals, ids,
-                  kk: int) -> list[list[SearchHit]]:
-        self._poison_check(queries, vals)
-        names = (snap.padded_names if isinstance(snap, SegmentedSnapshot)
-                 else snap.doc_names)
-        n = len(queries)
-        return assemble_hits(vals[:n, :kk], ids[:n, :kk],
-                             names.__getitem__, self.result_order)

@@ -121,8 +121,8 @@ class Segment:
 
 class _PaddedNameResolver:
     """gid -> name over the concatenated padded segment spaces — the
-    ONE implementation of padded-id resolution (``name_of`` delegates
-    here too, so search-hit assembly cannot drift from it)."""
+    ONE implementation of padded-id resolution (a segmented snapshot's
+    ``doc_names``)."""
 
     __slots__ = ("_segments", "_bases")
 
@@ -201,25 +201,16 @@ class SegmentedSnapshot:
         return sum(seg.n_docs for seg in self.segments)
 
     @property
-    def doc_names(self) -> list[str]:
+    def doc_names(self):
+        """The names by id: lookup in the concatenated padded doc-id
+        space (None at pad slots). A lazy bisecting RESOLVER, not a
+        materialized list: top-k assembly touches a handful of ids per
+        query, so building the O(corpus) padded list per snapshot was
+        pure waste."""
         cached = getattr(self, "_doc_names", None)
         if cached is None:
-            cached = []
-            for seg in self.segments:
-                cached.extend(seg.names)
-            object.__setattr__(self, "_doc_names", cached)
-        return cached
-
-    @property
-    def padded_names(self):
-        """Name lookup in the concatenated padded doc-id space (None at
-        pad slots). A lazy bisecting RESOLVER, not a materialized list:
-        top-k assembly touches a handful of ids per query, so building
-        the O(corpus) padded list per snapshot was pure waste."""
-        cached = getattr(self, "_padded_names", None)
-        if cached is None:
             cached = _PaddedNameResolver(self.segments)
-            object.__setattr__(self, "_padded_names", cached)
+            object.__setattr__(self, "_doc_names", cached)
         return cached
 
     @property
@@ -229,12 +220,6 @@ class SegmentedSnapshot:
             bases.append(acc)
             acc += seg.doc_cap
         return bases
-
-    def name_of(self, gid: int) -> str | None:
-        try:
-            return self.padded_names[gid]
-        except IndexError:
-            return None
 
     @property
     def df_host(self) -> np.ndarray:

@@ -48,8 +48,8 @@ class MeshEllSnapshot:
     """Published state: ELL base + COO delta + current global stats."""
 
     def __init__(self, *, base: MeshEllArrays, delta: ShardedArrays,
-                 perms, base_counts, shard_docs, df_g, n_docs, avgdl,
-                 version, nnz, total_live, shard_live,
+                 perms, base_counts, shard_docs, doc_names, df_g, n_docs,
+                 avgdl, version, nnz, total_live, shard_live,
                  res_nnz=0) -> None:
         self.base = base
         self.delta = delta
@@ -61,6 +61,11 @@ class MeshEllSnapshot:
         self.perms = perms                 # per shard: ell_row -> ins id
         self.base_counts = base_counts     # docs in base per shard
         self.shard_docs = shard_docs
+        # the names by global id (shard * stride + local: an ELL row of
+        # the base, through ``perms``, below ``base.doc_cap``, a delta
+        # slot from there on), None at a slot no document holds; shared
+        # with the index and append-only like ``shard_docs``
+        self.doc_names = doc_names
         self.df_g = df_g                   # f32 [vocab_cap] replicated
         self.n_docs = n_docs               # f32 scalar (LIVE count)
         self.avgdl = avgdl
@@ -79,19 +84,9 @@ class MeshEllSnapshot:
         return (*(imp.shape[2] for imp in self.base.impact),
                 self.delta.doc_cap)
 
-    def name_of(self, gid: int) -> str | None:
-        s, local = divmod(gid, self.stride)
-        if s >= len(self.shard_docs):
-            return None
-        sd = self.shard_docs[s]
-        if local < self.base.doc_cap:      # ELL row -> permuted ins id
-            perm = self.perms[s]
-            if local >= perm.shape[0]:
-                return None
-            return sd[int(perm[local])].name
-        delta_local = local - self.base.doc_cap
-        ins = self.base_counts[s] + delta_local
-        return sd[ins].name if ins < len(sd) else None
+    @property
+    def num_names(self) -> int:
+        return self.total_live
 
 
 class MeshEllIndex(MeshIndex):
@@ -232,16 +227,14 @@ class MeshEllIndex(MeshIndex):
                 or vocab_cap > self.snapshot.df_g.shape[0]
                 or self._delta_too_big(pending))
             if need_rebuild:
-                self._rebuild_ell_locked(pending, vocab_cap)
-                delta = self._empty_delta(vocab_cap)
+                delta = self._rebuild_ell_locked(pending, vocab_cap)
             elif pending:
                 try:
                     delta = self._append_locked(delta, pending)
                 except ValueError as e:
                     log.info("delta overflow; folding into ELL base",
                              reason=str(e).split(";")[0])
-                    self._rebuild_ell_locked(pending, vocab_cap)
-                    delta = self._empty_delta(vocab_cap)
+                    delta = self._rebuild_ell_locked(pending, vocab_cap)
             self._pending = {}
 
             # live-corpus global stats (appends and deletes both move
@@ -284,7 +277,7 @@ class MeshEllIndex(MeshIndex):
             snap = MeshEllSnapshot(
                 base=base, delta=delta, perms=self._perms,
                 base_counts=list(self._base_counts),
-                shard_docs=self._shard_docs,
+                shard_docs=self._shard_docs, doc_names=self._doc_names,
                 df_g=df_g, n_docs=n_docs, avgdl=avgdl,
                 version=self._version, nnz=self.nnz_live,
                 total_live=len(self._placed), res_nnz=self._res_nnz,
@@ -345,9 +338,11 @@ class MeshEllIndex(MeshIndex):
             df = np.zeros(vocab_cap, np.float32)
         return df, n, len_sum
 
-    def _rebuild_ell_locked(self, pending, vocab_cap: int) -> None:
+    def _rebuild_ell_locked(self, pending,
+                            vocab_cap: int) -> ShardedArrays:
         """Fold everything (base + delta + pending) into a fresh ELL
-        base with round-robin placement; drops tombstones."""
+        base with round-robin placement; drops tombstones. Returns the
+        fresh, empty delta that goes with it."""
         # the rebuild's parts as stages of the one timer: the host's
         # loops over every document (``mesh_build_host``, here and at
         # the stats resync below) and the copy onto the devices
@@ -387,15 +382,19 @@ class MeshEllIndex(MeshIndex):
         # (pending was just merged into the shard lists above) — the
         # one O(corpus nnz) pass steady commits never take (witness)
         self.df_full_recomputes += 1
+        delta = self._empty_delta(vocab_cap)
         with trace_phase("mesh_build_host"):
             df, n, len_sum = self._live_stats_scratch(
                 max(vocab_cap, self._df_live.shape[0], 1),
                 include_pending=False)
+            self._doc_names = self._name_table(
+                entries, base.doc_cap + delta.doc_cap, perms)
         self._df_live = df.astype(np.float64)
         self._n_live_stat = n
         self._len_sum_stat = len_sum
         self.rebuilds += 1
         global_metrics.inc("mesh_reshards")
+        return delta
 
     def _empty_delta(self, vocab_cap: int) -> ShardedArrays:
         """Fresh COO delta. For an index that has OBSERVED appends, it
@@ -478,10 +477,14 @@ class MeshEllIndex(MeshIndex):
             make = make_sharded_ingest
             self._ingest_fn = make(self.mesh)
         delta = self._ingest_fn(delta, *batch)
-        for s, es in enumerate(per_entries):
+        base_cap = self._base.doc_cap
+        stride = base_cap + delta.doc_cap
+        for s, (es, bc) in enumerate(zip(per_entries, self._base_counts)):
             for e in es:
-                self._placed[e.name] = (s, len(self._shard_docs[s]))
+                ins = len(self._shard_docs[s])
+                self._placed[e.name] = (s, ins)
                 self._shard_docs[s].append(e)
+                self._doc_names[s * stride + base_cap + ins - bc] = e.name
         self.appends += 1
         global_metrics.inc("mesh_appends")
         return delta
@@ -553,7 +556,7 @@ class MeshEllSearcher(MeshSearcher):
                                        self._u_floor))
                 for imp in snap.base.impact]
 
-    def _dispatch_chunk(self, snap, qb, k: int):
+    def _step(self, snap, qb, k: int):
         kk, depth = self._depths(k, snap.stride)
         self._count_kernel_uniq(qb)
         # what the step scores by the scatter path, on every shard
@@ -572,15 +575,11 @@ class MeshEllSearcher(MeshSearcher):
             snap.base, snap.delta, snap.df_g, snap.n_docs,
             snap.avgdl, qb), depth
 
-    def _search_unbounded(self, snap, queries, k):
-        # the ELL base cannot rank every matching document (its row
-        # space is permuted and lives behind top-k); serve parity
-        # requests by scoring the same live postings through a COO mesh
-        # engine instead of erroring (VERDICT r2 weak #8)
-        return self._search_unbounded_coo(snap, queries, k)
-
-    def _search_unbounded_coo(self, snap, queries, k):
-        """Per-call parity fallback (VERDICT r2 weak #8): replay the
+    def _search_unbounded(self, snap, queries):
+        """The ELL base cannot rank every matching document (its row
+        space is permuted and lives behind top-k), so serve parity
+        requests by scoring the same live postings through a COO mesh
+        engine instead of erroring (VERDICT r2 weak #8): replay the
         COMMITTED snapshot's postings into a COO mesh index and rank
         every match there. Slow by design — parity mode is a correctness
         tool, not the serving path — but a per-request ``unbounded=True``
@@ -590,11 +589,9 @@ class MeshEllSearcher(MeshSearcher):
         uncommitted writes in flight. The throwaway searcher is cached
         by snapshot version — parity harnesses issuing many unbounded
         calls against one snapshot pay the O(corpus) replay once."""
-        from tfidf_tpu.parallel.mesh_index import MeshIndex, MeshSearcher
-
         cached = getattr(self, "_unbounded_cache", None)
         if cached is not None and cached[0] == snap.version:
-            return cached[1].search(queries, k=k, unbounded=True)
+            return cached[1].search(queries, unbounded=True)
         total_live = int(np.sum(np.asarray(snap.n_docs)))
         if total_live > self.unbounded_parity_max_docs:
             raise ValueError(
@@ -628,4 +625,4 @@ class MeshEllSearcher(MeshSearcher):
             max_query_terms=self.max_query_terms,
             top_k=self.top_k, result_order=self.result_order)
         self._unbounded_cache = (snap.version, searcher)
-        return searcher.search(queries, k=k, unbounded=True)
+        return searcher.search(queries, unbounded=True)

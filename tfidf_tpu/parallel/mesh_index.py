@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass
+from operator import attrgetter
 
 import numpy as np
 
@@ -59,22 +60,25 @@ from tfidf_tpu.utils.tracing import trace_phase
 
 log = get_logger("parallel.mesh_index")
 
+_entry_name = attrgetter("name")
+
 
 @dataclass
 class MeshSnapshot:
     """Immutable published state: device arrays + the name mapping."""
     arrays: ShardedArrays
     shard_docs: list      # list[list[DocEntry]] — append-only per shard
+    # the names by global id (docs_shard * doc_cap + local), None at a
+    # slot no document holds; shared with the index and append-only
+    # like ``shard_docs`` (see ``MeshIndex._doc_names``)
+    doc_names: list
     version: int
     nnz: int
     total_live: int
 
-    def name_of(self, gid: int) -> str | None:
-        """Global id (docs_shard * doc_cap + local) -> document name."""
-        doc_cap = self.arrays.doc_cap
-        sd = self.shard_docs[gid // doc_cap]
-        local = gid % doc_cap
-        return sd[local].name if local < len(sd) else None
+    @property
+    def num_names(self) -> int:
+        return self.total_live
 
 
 class MeshIndex:
@@ -94,6 +98,12 @@ class MeshIndex:
         # committed docs per shard in local-id order (tombstones included —
         # a slot is never reused until a re-shard)
         self._shard_docs: list[list[DocEntry]] = [[] for _ in range(self.D)]
+        # the names by global id, what a snapshot's ``doc_names`` is:
+        # laid out whole at a re-shard (a fresh list: snapshots from
+        # before it keep theirs), filled in place by an append commit —
+        # a slot is never reused until a re-shard, and an older
+        # snapshot's ids never reach a newer slot
+        self._doc_names: list = []
         self._placed: dict[str, tuple[int, int]] = {}
         self._pending: dict[str, DocEntry] = {}   # upsert: latest wins
         self._mask_dirty = False
@@ -247,6 +257,7 @@ class MeshIndex:
             self._version += 1
             snap = MeshSnapshot(
                 arrays=arrays, shard_docs=self._shard_docs,
+                doc_names=self._doc_names,
                 version=self._version, nnz=self.nnz_live,
                 total_live=len(self._placed))
             self.snapshot = snap
@@ -327,10 +338,28 @@ class MeshIndex:
             s = i % self.D
             self._placed[e.name] = (s, len(self._shard_docs[s]))
             self._shard_docs[s].append(e)
+        self._doc_names = self._name_table(entries, arrays.doc_cap)
         self._mask_dirty = False
         self.rebuilds += 1
         global_metrics.inc("mesh_reshards")
         return arrays
+
+    def _name_table(self, entries: list[DocEntry], stride: int,
+                    perms=None) -> list:
+        """The names by global id after a re-shard dealt ``entries``
+        round-robin: shard ``s`` holds ``entries[s::D]`` from
+        ``s * stride`` on, in insertion order or, with ``perms``, where
+        its ELL permutation put them (``perms[s][row]`` = insertion id
+        of the document in ELL row ``row``)."""
+        names = np.fromiter(map(_entry_name, entries), object,
+                            len(entries))
+        table = np.full(self.D * stride, None, object)
+        for s in range(self.D):
+            of_shard = names[s::self.D]
+            if perms is not None:
+                of_shard = of_shard[perms[s]]
+            table[s * stride:s * stride + len(of_shard)] = of_shard
+        return table.tolist()
 
     def _append_locked(self, arrays: ShardedArrays,
                        pending: list[DocEntry]) -> ShardedArrays:
@@ -370,52 +399,33 @@ class MeshIndex:
         arrays = self._ingest_fn(arrays, *batch)
         for s, es in enumerate(per_entries):
             for e in es:
-                self._placed[e.name] = (s, len(self._shard_docs[s]))
+                local = len(self._shard_docs[s])
+                self._placed[e.name] = (s, local)
                 self._shard_docs[s].append(e)
+                self._doc_names[s * arrays.doc_cap + local] = e.name
         self.appends += 1
         global_metrics.inc("mesh_appends")
         return arrays
 
 
-from tfidf_tpu.engine.searcher import (QueryVectorizerMixin,
-                                       assemble_hits)
+from tfidf_tpu.engine.searcher import SearchLoop
 
 
-class MeshSearcher(QueryVectorizerMixin):
+class MeshSearcher(SearchLoop):
     """Query execution against MeshSnapshots — the distributed forward
-    pass. Mirrors :class:`~tfidf_tpu.engine.searcher.Searcher`'s interface
-    so Engine/cluster code is layout-agnostic. Subclasses (the ELL mesh
-    layout) override only the hooks — :meth:`_dispatch_chunk`,
-    :meth:`_finish_chunk`, :meth:`_search_unbounded`,
-    :meth:`_on_snapshot` — the chunking and hit-assembly loop lives in
-    one place."""
+    pass: :class:`~tfidf_tpu.engine.searcher.SearchLoop`'s hooks over
+    the sharded step. The ELL mesh layout overrides :meth:`_step`,
+    :meth:`_search_unbounded` and :meth:`_on_snapshot`."""
 
     def __init__(self, index: MeshIndex, analyzer, vocab,
                  model: ScoringModel,
-                 *, query_batch: int = 32, max_query_terms: int = 32,
-                 top_k: int = 10, result_order: str = "score",
-                 global_idf: bool = True,
-                 pipeline_depth: int = 2,
-                 pipeline_mode: str = "auto") -> None:
-        self.index = index
-        self.analyzer = analyzer
-        self.vocab = vocab
-        self.model = model
-        self.query_batch = query_batch
-        self.max_query_terms = max_query_terms
-        self.top_k = top_k
-        self.result_order = result_order
-        self.pipeline_depth = max(1, pipeline_depth)
-        # "auto" | "executor" | "inline" — see QueryVectorizerMixin
-        self.pipeline_mode = pipeline_mode
+                 *, global_idf: bool = True, **loop) -> None:
+        super().__init__(index, analyzer, vocab, model, **loop)
         # global_idf=False reproduces the reference's per-worker statistics
         # (each Lucene shard scores against local df/N, Worker.java:222-241)
         self.global_idf = global_idf
         self._search_fns: dict[tuple[int, int], object] = {}
         self._scores_fn = None
-
-    def _batch_cap(self, n: int) -> int:
-        return min(self.query_batch, next_capacity(max(n, 1), 1))
 
     def _model_kwargs(self) -> dict:
         kw = dict(self.model.score_kwargs())
@@ -450,85 +460,28 @@ class MeshSearcher(QueryVectorizerMixin):
                 global_idf=self.global_idf, **self._model_kwargs())
         return self._scores_fn
 
-    def search(self, queries: list[str], k: int | None = None,
-               *, unbounded: bool = False):
-        """Chunks are pipelined ``pipeline_depth`` deep, as in
-        :meth:`tfidf_tpu.engine.searcher.Searcher.search`: later chunks'
-        shard_map programs are dispatched before earlier chunks' packed
-        top-k buffers are fetched, hiding the device->host RTT (which
-        dominates device compute on small corpora)."""
-        snap = self.index.snapshot
-        self._on_snapshot(snap)
-        if snap is None or snap.total_live == 0 or not queries:
-            return [[] for _ in queries]
-        if unbounded:
-            return self._search_unbounded(snap, queries, k)
-        k = self.top_k if k is None else k
-        cap = self._batch_cap(len(queries))
-
-        def dispatch(chunk):
-            chunk_cap = self._batch_cap(len(chunk))
-            self._count_chunk(len(chunk), chunk_cap)
-            global_metrics.inc("mesh_steps")
-            with trace_phase("vectorize"):
-                qb, _widest = self._vectorize(chunk, chunk_cap)
-            # jax's ENQUEUE of the shard_map program, not the devices'
-            # work (that is ``device_wait``, in the fetch stage)
-            with trace_phase("score"):
-                return (chunk,) + self._dispatch_chunk(snap, qb, k)
-
-        from tfidf_tpu.ops.topk import fetch_packed
-
-        out = self._run_pipelined(
-            (queries[lo:lo + cap]
-             for lo in range(0, len(queries), cap)),
-            dispatch,
-            lambda chunk, packed, kk: (chunk, fetch_packed(packed), kk),
-            lambda chunk, arr, kk: self._finish_chunk(snap, chunk, arr,
-                                                      kk))
-        global_metrics.inc("queries_served", len(queries))
-        return out
-
-    def _on_snapshot(self, snap) -> None:
-        """Layout hook: called with the snapshot each search (lets
-        subclasses drop per-snapshot caches when the version moves)."""
-
     def posting_blocks(self) -> list[tuple]:
         """Layout hook, as :meth:`Searcher.posting_blocks`: the COO
         scatter step never rides the Pallas kernel."""
         snap = self.index.snapshot
         return [] if snap is None else [(snap.arrays.tf, False)]
 
-    def _dispatch_chunk(self, snap, qb, k: int):
+    def _dispatch_chunk(self, snap, qb, n_queries: int, k: int):
+        global_metrics.inc("mesh_steps")
+        # jax's ENQUEUE of the shard_map program, not the devices'
+        # work (that is ``device_wait``, in the fetch stage)
+        with trace_phase("score"):
+            return self._step(snap, qb, k)
+
+    def _step(self, snap, qb, k: int):
         """Layout hook: launch one chunk's packed top-k (not fetched)."""
         kk, depth = self._depths(k, snap.arrays.doc_cap)
         return self._get_search_fn(kk, depth)(snap.arrays, qb), depth
 
-    def _finish_chunk(self, snap, chunk, packed, kk: int):
-        # packed already crossed device->host in the fetch stage; this
-        # runs on the caller's thread (views + hit assembly only)
-        from tfidf_tpu.ops.topk import unpack_topk
-        with trace_phase("assemble"):
-            vals, gids = unpack_topk(packed)
-            return self._assemble_hits(snap, chunk, vals, gids, kk)
-
-    def _search_unbounded(self, snap, queries, k):
-        """Layout hook: the reference's unbounded (parity) results."""
-        out = []
-        cap = self._batch_cap(len(queries))
-        for lo in range(0, len(queries), cap):
-            chunk = queries[lo:lo + cap]
-            qb, _widest = self._vectorize(chunk,
-                                          self._batch_cap(len(chunk)))
-            vals, gids, kk = self._rank_all(snap, qb)
-            out.extend(self._assemble_hits(snap, chunk, vals, gids, kk))
-        global_metrics.inc("queries_served", len(queries))
-        return out
-
-    def _assemble_hits(self, snap, chunk, vals, gids, kk):
-        n = len(chunk)
-        return assemble_hits(vals[:n, :kk], gids[:n, :kk], snap.name_of,
-                             self.result_order)
+    def _search_unbounded(self, snap, queries):
+        qb, _widest = self._vectorize(queries,
+                                      self._batch_cap(len(queries)))
+        return self._assemble(snap, queries, *self._rank_all(snap, qb))
 
     def _rank_all(self, snap: MeshSnapshot, qb):
         """Parity mode: full per-shard score matrices ranked on the host

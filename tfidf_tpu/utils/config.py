@@ -142,7 +142,6 @@ class Config:
     # support).
     mesh_layout: str = "ell"           # "ell" | "coo"
     mesh_shape: tuple[int, ...] = ()   # () = all local devices on one "docs" axis
-    mesh_axes: tuple[str, ...] = ("docs", "terms")
     # Multi-host bootstrap (jax.distributed over DCN). On TPU pods the
     # coordinator/process values are auto-detected; leave the defaults.
     # Elsewhere set them (or the standard JAX_COORDINATOR_ADDRESS /
@@ -589,7 +588,6 @@ class Config:
     oom_backoff_min_batch: int = 8
 
     # --- misc ---
-    log_level: str = "INFO"
     seed: int = 0
 
     def replace(self, **kw: Any) -> "Config":

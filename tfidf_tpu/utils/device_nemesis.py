@@ -23,7 +23,7 @@ accelerator produces:
                   breaks the kernel, the case the leader's poison
                   quarantine exists for. No exception is raised at the
                   dispatch site; detection happens at the fetch seam
-                  (``Searcher._assemble``), exactly where a real
+                  (``SearchLoop._checked``), exactly where a real
                   miscompiled kernel's garbage would first be seen.
 - ``delay``     — dispatch latency (sleeps ``delay_s``): the wedged /
                   slow device.

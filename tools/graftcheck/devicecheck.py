@@ -93,11 +93,11 @@ SANITIZERS = {"next_capacity"}
 CONE_ROOTS = (
     "engine.searcher.Searcher._dispatch_chunk",
     "engine.searcher.Searcher._dispatch_tiered",
-    "engine.searcher.Searcher._finish_chunk",
+    "engine.searcher.SearchLoop._search_chunks",
     "engine.searcher.Searcher._search_unbounded",
     "engine.segments.SegmentedSnapshot.df_host",
-    "engine.searcher.QueryVectorizerMixin._run_pipelined",
-    "engine.searcher.QueryVectorizerMixin._run_inline",
+    "engine.searcher.SearchLoop._run_pipelined",
+    "engine.searcher.SearchLoop._run_inline",
     "engine.pipeline.PipelineExecutor._dispatch_loop",
     "engine.pipeline.PipelineExecutor._fetch_loop",
     "engine.tiering.TierManager.prefetch",
@@ -106,7 +106,7 @@ CONE_ROOTS = (
     "engine.tiering.TierManager._build_device",
     "engine.dense.EmbeddingColumn.search_batch",
     "parallel.mesh_index.MeshSearcher._dispatch_chunk",
-    "parallel.mesh_index.MeshSearcher._finish_chunk",
+    "parallel.mesh_index.MeshSearcher._step",
     "parallel.mesh_index.MeshSearcher._rank_all",
 )
 

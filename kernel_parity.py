@@ -14,9 +14,11 @@ kernel's matrix runs one case of the top-k that reads its blocks
 the stretched step (``run_stretch_case``): three blocks scored and ranked
 a block at a time and merged, against the one program pair over all
 three, ties on the stretch edges included; and the matrix's last kernel
-case is the TRAP the A-build's order is held by (``run_trap_case``: a
-live term 0 before trailing ``term 0`` pads, bit-equal to the oracle);
-and one of the MESH step on whole documents (``run_mesh_case``: a 512-
+cases are the TRAP the A-build's order is held by (``run_trap_case``: a
+live term 0 before trailing ``term 0`` pads, bit-equal to the oracle)
+and the LONG queries of ``msmarco2m-q2d`` (``run_long_query_case``:
+~10,000 distinct terms a batch under a capacity of 16,384, ``T`` 128,
+a multiplicity up to 53); and one of the MESH step on whole documents (``run_mesh_case``: a 512-
 and a 384-wide bucket a shard inside ``shard_map``, the kernel against
 ``_score_block`` in the same step, both weight kinds).
 ``python kernel_parity.py --against <checkout>`` also runs every kernel
@@ -377,6 +379,81 @@ def run_stretch_case(rng, *, rows_cap, width, B, n_blocks, last_live,
             "packed_equal": equal, "tie_straddles_edge": tied, "ok": ok}
 
 
+# the expanded queries of ``msmarco2m-q2d`` (PR 46): a batch of 512
+# queries of ~66 distinct terms out of ~10,000, ``T`` 128, capacity
+# 16,384 (twenty live uniq tiles of 32, the last partly live), one
+# term a query repeated up to 53 times
+LONG_CASE = dict(rows_cap=8192, width=48, n_rows=8000, B=512, T=128,
+                 n_pool=10_000, u_req=16384, max_mult=53)
+LONG_INTERPRET_CASE = dict(rows_cap=512, width=24, n_rows=400, B=64,
+                           T=128, n_pool=1_500, u_req=2048, max_mult=53)
+
+
+def make_long_case(rng, *, rows_cap, width, n_rows, B, T, n_pool, u_req,
+                   max_mult, vocab=500_000):
+    """A block as :func:`make_case` draws it under a batch of LONG
+    queries: every query holds 40 .. 97 distinct terms (``msmarco2m-
+    q2d``'s range, 66 on average) of a pool of ``n_pool`` terms, half of
+    them terms of the block's live rows so that most documents score;
+    multiplicities are 1 for most terms, 2 .. 5 for a tenth, and one
+    term a query repeats up to ``max_mult`` times (exact in bfloat16:
+    the three-pass contraction)."""
+    imp, term, _qb = make_case(rng, rows_cap=rows_cap, width=width,
+                               n_rows=n_rows, B=1, n_terms=1, u_req=256,
+                               vocab=vocab)
+    in_block = np.unique(term[:n_rows])
+    pool = np.unique(np.r_[
+        rng.choice(in_block, min(n_pool // 2, in_block.size), replace=False),
+        rng.integers(0, vocab, n_pool // 2)])
+    q_terms = np.zeros((B, T), np.int32)
+    q_weights = np.zeros((B, T), np.float32)
+    for i in range(B):
+        n = int(np.clip(40 + rng.poisson(26), 40, min(97, T)))
+        q_terms[i, :n] = rng.choice(pool, n, replace=False)
+        mult = np.ones(n, np.float32)
+        some = rng.random(n) < 0.1
+        mult[some] = rng.integers(2, 6, int(some.sum()))
+        mult[0] = rng.integers(max_mult // 2, max_mult + 1)
+        q_weights[i, :n] = mult
+    return imp, term, make_query_batch(q_terms, q_weights, min_slots=u_req)
+
+
+def run_long_query_case(rng, against=None, *, vocab=500_000, **kw):
+    """:func:`make_long_case` through the kernel and the XLA oracle. A
+    score is a sum of ~66 products of up to 53 x an impact, so it is
+    held relative to the block's largest score, 1e-5, and the ranking
+    free of tie order: the oracle's scores of the kernel's ten best
+    documents are the oracle's ten best scores, to 1e-5 (a near-tie the
+    two summation orders split is no fault of either)."""
+    imp, term, qb = make_long_case(rng, vocab=vocab, **kw)
+    n_uniq, u_cap = int(qb.n_uniq), qb.uniq.shape[0]
+    assert bool(bf16_exact(qb.weights)) and u_cap == kw["u_req"] \
+        and u_cap // 2 < n_uniq and qb.weights.max() > kw["max_mult"] // 2
+    assert _pallas_eligible(kw["rows_cap"], kw["B"], u_cap)
+    out, ref, same = _kernel_and_oracle(imp, term, qb, vocab,
+                                        kw["n_rows"], against)
+    a, b = out[:, :kw["n_rows"]], ref[:, :kw["n_rows"]]
+    top = float(b.max())
+    max_rel = float(np.max(np.abs(a - b))) / top
+    k = min(TOP_K, kw["n_rows"])
+    mine = np.argsort(-a, axis=1, kind="stable")[:, :k]
+    best = -np.sort(-b, axis=1)[:, :k]
+    rank_rel = float(np.max(np.abs(
+        np.take_along_axis(b, mine, axis=1) - best) / top))
+    dead_zero = bool(not out[:, kw["n_rows"]:].any())
+    ok = max_rel < 1e-5 and rank_rel < 1e-5 and dead_zero \
+        and same is not False and top > kw["max_mult"] // 2
+    log(f"[long] n_uniq={n_uniq} u_cap={u_cap} T={kw['T']} "
+        f"max_weight={float(qb.weights.max()):.0f} top_score={top:.2f} "
+        f"max_rel={max_rel:.2e} rank_rel={rank_rel:.2e} "
+        f"bit_equal_against={same} ok={ok}")
+    return {"name": "long", "n_uniq": n_uniq, "u_cap": u_cap,
+            "max_weight": float(qb.weights.max()), "top_score": top,
+            "max_rel_delta": max_rel, "rank_rel_delta": rank_rel,
+            "dead_rows_zero": dead_zero, "bit_equal_against": same,
+            "ok": ok, **kw}
+
+
 MESH_CASE = dict(docs=12_000, B=512, u_req=1024)
 # interpreted: the two wide buckets alone ride the kernel (the empty
 # narrow ones stay at 8 rows, on the XLA path: a twelfth of the compile)
@@ -525,8 +602,9 @@ def run_matrix(seed: int = 7, against=None) -> dict:
     ``KERNEL_PARITY.json`` holds: ``CASES`` where the kernel is a
     Mosaic program, ``INTERPRET_CASES`` where it is interpreted; each
     with fractional weights (``caseN``) and with multiplicities
-    (``caseN-mult``), then the pad trap (``trap``), the last of
-    ``cases``; then the top-k's one case (``topk``), the stretched
+    (``caseN-mult``), then the pad trap (``trap``) and the long
+    queries (``long``), the last two of ``cases``; then the top-k's one
+    case (``topk``), the stretched
     step's (``stretch``) and, on the chip, the mesh step's on whole
     documents (``mesh``). ``against``: another checkout's kernel
     (:func:`kernel_of`), which every kernel case must then equal bit
@@ -539,6 +617,8 @@ def run_matrix(seed: int = 7, against=None) -> dict:
                for i, kw in enumerate(cases) for mult in (False, True)]
     results.append(run_trap_case(rng, against, **(
         TRAP_INTERPRET_CASE if interpret else TRAP_CASE)))
+    results.append(run_long_query_case(rng, against, **(
+        LONG_INTERPRET_CASE if interpret else LONG_CASE)))
     topk = run_topk_case(rng, **(TOPK_INTERPRET_CASE if interpret
                                  else TOPK_CASE))
     stretch = run_stretch_case(rng, **(STRETCH_INTERPRET_CASE if interpret

@@ -11,7 +11,8 @@ interpret-mode test and were refused here).
 Run as a script (``tests/test_kernel_compile.py`` does, in a subprocess:
 the compile-only client is process-global state); prints one JSON line
 ``{"failures": [...], "compiled": N, "cells": M, "mesh_cells": [...],
-"cell_digests": {...}, "stretches": [...], "deep_topk": {...}}``.
+"cell_digests": {...}, "stretches": [...], "deep_topk": {...},
+"long_query": {...}}``.
 """
 
 from __future__ import annotations
@@ -252,11 +253,14 @@ def program_digest(program) -> str:
 
 
 def compile_step_pair(dev, blocks, doc_cap: int, B: int,
-                      stretched: bool = False):
+                      stretched: bool = False, *, u_cap: int = 1024,
+                      T: int = 32):
     """``(score, topk)``: the two programs of a step over ``blocks``
     compiled for ``dev`` — the whole block list of a snapshot, or with
     ``stretched`` one stretch of it (the top-k then takes its base
-    row)."""
+    row). ``u_cap`` and ``T`` are the query batch's: the unique-term
+    capacity the warm-up pins and ``max_query_terms``, 1,024 and 32 in
+    every cell but ``msmarco2m-q2d``."""
     from tfidf_tpu.ops.scoring import QueryBatch
     from tfidf_tpu.ops.topk import packed_topk_chunked
     sh = SingleDeviceSharding(dev)
@@ -265,8 +269,8 @@ def compile_step_pair(dev, blocks, doc_cap: int, B: int,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
     f32, i32 = jnp.float32, jnp.int32
-    q = QueryBatch(uniq=s((1024,), i32), n_uniq=s((), i32),
-                   slots=s((B, 32), i32), weights=s((B, 32), f32))
+    q = QueryBatch(uniq=s((u_cap,), i32), n_uniq=s((), i32),
+                   slots=s((B, T), i32), weights=s((B, T), f32))
     live = s((len(blocks),), i32)
     # ``blocks`` are (rows_cap, width); the index holds a block
     # width-major, [width, rows_cap]
@@ -376,6 +380,32 @@ def compile_deep_topk(dev, k: int = 1000, B: int = 512) -> dict:
             "temp_bytes": program.memory_analysis().temp_size_in_bytes}
 
 
+def compile_long_query_step(dev, B: int = 512, u_cap: int = 16384,
+                            T: int = 128) -> dict:
+    """``msmarco2m-q2d``'s step: ``msmarco2m``'s six blocks at B = 512
+    under a query batch of ``u_cap`` 16,384 and ``T`` 128 (expanded
+    queries: ~9,750 distinct terms a call). The v5e compiler has to
+    accept the kernel on a grid of 32 uniq tiles a doc tile, and the
+    ``[B, u_cap + 1]`` query matrix and its chunk-major copy have to
+    fit beside the 4.57 GB score space. Returns the score program's
+    ``memory_analysis()``, its kernels and the shapes the query matrix
+    takes in its text."""
+    blocks, doc_cap, _batches = CELL_STEPS["msmarco2m"]
+    score, _topk = compile_step_pair(dev, blocks, doc_cap, B,
+                                     u_cap=u_cap, T=T)
+    text = score.as_text()
+    m = score.memory_analysis()
+    return {"B": B, "u_cap": u_cap, "T": T,
+            "kernels": text.count("tpu_custom_call"),
+            "query_matrix_shapes": [
+                shape for shape in (f"f32[{B},{u_cap + 1}]",
+                                    f"f32[{u_cap // 128},{B},128]")
+                if shape in text],
+            "temp_bytes": m.temp_size_in_bytes,
+            "output_bytes": m.output_size_in_bytes,
+            "argument_bytes": m.argument_size_in_bytes}
+
+
 def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -421,10 +451,16 @@ def main() -> int:
     except Exception as e:
         failures.append(f"cell msmarco2m-top1000 top-k: "
                         f"{type(e).__name__}: {str(e)[:600]}")
+    long_query: dict = {}
+    try:
+        long_query = compile_long_query_step(topo.devices[0])
+    except Exception as e:
+        failures.append(f"cell msmarco2m-q2d step: "
+                        f"{type(e).__name__}: {str(e)[:600]}")
     print(json.dumps({"failures": failures, "compiled": compiled,
                       "cells": cells, "mesh_cells": mesh_cells,
                       "cell_digests": digests, "stretches": stretches,
-                      "deep_topk": deep}))
+                      "deep_topk": deep, "long_query": long_query}))
     return 1 if failures else 0
 
 

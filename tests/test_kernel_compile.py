@@ -10,8 +10,9 @@ accepted programs by digest, both cells' temporaries under those of the
 step that rearranged the scores), the served
 device step at the benchmark cells' shapes (held to the programs of PR
 43's tree, which turned the blocks width-major, by digest), the
-stretches of ``msmarco-full``'s step and the 1,000-deep top-k of
-``msmarco2m-top1000``; and that the two whole-document cells' steps
+stretches of ``msmarco-full``'s step, the 1,000-deep top-k of
+``msmarco2m-top1000`` and the step of ``msmarco2m-q2d``'s expanded
+queries (a unique-term capacity of 16,384, ``T`` 128); and that the two whole-document cells' steps
 turn no block (no block-sized ``copy``, 1.6 GB fewer temporaries a
 device than the steps that held ``[rows, width]``) while the narrow
 cells' temporaries stand where they stood. Interpret-mode
@@ -289,3 +290,24 @@ def test_deep_topk_compiles_for_v5e_and_sorts_no_wide_row(report):
     assert deep["back"] == []
     assert deep["whiles"] == 0
     assert deep["temp_bytes"] < 700e6
+
+
+def test_long_query_step_compiles_for_v5e(report):
+    """``msmarco2m-q2d``'s score program: ``msmarco2m``'s six blocks at
+    B = 512 under a query batch of ``u_cap`` 16,384 and ``T`` 128 (no
+    shape class past 1,024 / 32 was compiled for the v5e before PR 46).
+    The v5e compiler accepts the kernel on every block (the uniq tile
+    stays 512: a grid of 32 uniq steps a doc tile, the same VMEM as at
+    1,024), the query matrix is in the program in both its shapes, and
+    what the program holds beside its 4.57 GB of scores is the matrix
+    and its chunk-major copy (33.5 MB each) at most, not a second score
+    space."""
+    assert not _failures(report, of_cells=True)
+    step = report["long_query"]
+    print(f"long-query step: {step}")
+    assert (step["B"], step["u_cap"], step["T"]) == (512, 16384, 128)
+    assert step["kernels"] == 6
+    assert step["query_matrix_shapes"] == ["f32[512,16385]",
+                                           "f32[128,512,128]"]
+    assert 0 <= step["output_bytes"] - 4 * 512 * 2_232_832 < 4096
+    assert step["temp_bytes"] < 2 * 4 * 512 * 16385 + 2_000_000

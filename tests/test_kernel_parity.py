@@ -22,10 +22,12 @@ if _root not in sys.path:
     sys.path.insert(0, _root)
 
 from kernel_parity import INTERPRET_CASES as T1_CASES  # noqa: E402
-from kernel_parity import (MESH_INTERPRET_CASE,  # noqa: E402
+from kernel_parity import (LONG_INTERPRET_CASE,  # noqa: E402
+                           MESH_INTERPRET_CASE,
                            STRETCH_INTERPRET_CASE, TOP_K,
                            TOPK_INTERPRET_CASE, make_case, run_case,
-                           run_mesh_case, run_stretch_case, run_topk_case,
+                           run_long_query_case, run_mesh_case,
+                           run_stretch_case, run_topk_case,
                            run_trap_case, width_major)
 from tfidf_tpu.ops import ell  # noqa: E402
 from tfidf_tpu.ops.csr import build_coo  # noqa: E402
@@ -72,6 +74,21 @@ def test_stretch_case_of_the_matrix():
                          **STRETCH_INTERPRET_CASE)
     assert r["ok"], r
     assert r["stretches"] == 3 and r["lives"] == [512, 512, 300]
+
+
+def test_long_query_case_of_the_matrix():
+    """The long-query case ``kernel_parity.py`` runs on the chip (a
+    batch of ``msmarco2m-q2d``'s expanded queries: ~10,000 distinct
+    terms under a capacity of 16,384, ``T`` 128, a multiplicity up to
+    53), at a CPU's scale: a capacity of 2,048, so four uniq tiles a doc
+    tile and the last of them partly live, on the three-pass
+    contraction."""
+    r = run_long_query_case(np.random.default_rng(46),
+                            **LONG_INTERPRET_CASE)
+    assert r["ok"], r
+    assert 1024 < r["n_uniq"] < r["u_cap"] == 2048
+    assert r["n_uniq"] % 512             # the last uniq tile partly live
+    assert 26 <= r["max_weight"] <= 53 and r["dead_rows_zero"]
 
 
 def test_mesh_case_of_the_matrix():

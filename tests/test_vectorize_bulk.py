@@ -100,9 +100,10 @@ CASES = {
                         "omega omega", "pi"],
     "unknown_term": ["alpha nosuchterm beta", "nosuchterm", "zeta"],
     "empty_query": ["", "alpha", "   ", "?!", "beta gamma"],
-    "over_max_terms": [" ".join(WORDS[:12]) + " alpha alpha beta",
-                       " ".join(f"t{i}" for i in range(30)),
-                       "kappa"],
+    # as wide as the matrices, the widest a query may be
+    "at_max_terms": [" ".join(WORDS[:8]) + " alpha alpha beta",
+                     " ".join(f"t{i}" for i in range(8)),
+                     "kappa"],
     "fewer_than_batch_cap": ["alpha beta", "gamma"],
     "weights_tie": ["beta beta alpha alpha gamma delta delta",
                     "tau sigma rho", "mu nu nu mu xi xi"],
@@ -121,20 +122,44 @@ def test_bit_equal_to_the_loop(monkeypatch, vocab, case, model_name):
     bit_equal(got, want)
 
 
-def test_over_max_terms_keeps_the_heaviest_ties_by_id(monkeypatch, vocab):
-    query = "alpha " * 3 + "beta " * 3 + " ".join(WORDS[2:14])
+def test_full_width_orders_heaviest_first_ties_by_id(monkeypatch, vocab):
+    query = "alpha " * 3 + "beta " * 3 + " ".join(WORDS[2:4])
     (q_terms, q_weights, qb, widest), want = both(
         monkeypatch, [query], vocab, get_model("bm25"),
         batch_cap=2, max_terms=4, min_slots=16)
     bit_equal((q_terms, q_weights, qb, widest), want)
     a, b = vocab.lookup("alpha"), vocab.lookup("beta")
     # the two weight-3 terms first, the lower id of them first; then
-    # the two lowest ids among the twelve weight-1 terms
+    # the two weight-1 terms by id
     assert q_terms[0, :2].tolist() == sorted([a, b])
     assert q_weights[0].tolist() == [3.0, 3.0, 1.0, 1.0]
-    rest = sorted(vocab.lookup(w) for w in WORDS[2:14])[:2]
-    assert q_terms[0, 2:].tolist() == rest
+    assert q_terms[0, 2:].tolist() == sorted(
+        vocab.lookup(w) for w in WORDS[2:4])
     assert widest == 4 and not q_terms[1].any()
+
+
+@pytest.mark.parametrize("model_name", ["bm25", "fractional"])
+def test_over_max_terms_is_refused_by_name(vocab, model_name):
+    """A query past the width is never cut to its heaviest terms (the
+    loop above did, in silence): it is refused with its count and the
+    limit, every such query of the chunk named, and counted. Terms the
+    vocabulary lacks count too: the front door counts the same way."""
+    from tfidf_tpu.engine.searcher import TooManyQueryTerms
+    from tfidf_tpu.utils.metrics import global_metrics
+    model = (FractionalModel() if model_name == "fractional"
+             else get_model("bm25"))
+    wide = " ".join(WORDS[:12]) + " alpha alpha beta"
+    unknown = " ".join(f"nosuch{i}" for i in range(9))
+    before = global_metrics.get("query_terms_refused")
+    with pytest.raises(TooManyQueryTerms) as err:
+        vectorize_queries(["kappa", wide, " ".join(WORDS[:8]), unknown],
+                          make_analyzer(), vocab, model,
+                          batch_cap=4, max_terms=8, min_slots=16)
+    assert err.value.refused == ((wide, 12), (unknown, 9))
+    assert err.value.queries == (wide, unknown) and err.value.limit == 8
+    assert "12 distinct terms" in str(err.value) \
+        and "max_query_terms=8" in str(err.value)
+    assert global_metrics.get("query_terms_refused") == before + 2
 
 
 def test_a_batch_of_the_cells_law(monkeypatch, vocab):

@@ -77,6 +77,7 @@ from tfidf_tpu.cluster.router import (ScatterReadPlane, _HttpHandlerBase,
                                       _PlaneServer, _linger_bounds,
                                       list_routers)
 from tfidf_tpu.engine.engine import Engine
+from tfidf_tpu.engine.searcher import TooManyQueryTerms
 from tfidf_tpu.ops.analyzer import UnsupportedMediaType
 from tfidf_tpu.utils import storage
 from tfidf_tpu.utils.config import Config
@@ -707,6 +708,13 @@ class SearchNode(ScatterReadPlane):
         concurrent requests. ``unbounded_results=True`` restores the
         reference's full-ranking behavior (``Worker.java:230``) for
         parity."""
+        # refused before it is queued: the micro-batch it would ride
+        # in answers its other queries (a raise inside the batch fails
+        # them all, and this endpoint turns a failure into [])
+        n_terms = self.query_terms_over_limit(query)
+        if n_terms is not None:
+            raise TooManyQueryTerms([(query, n_terms)],
+                                    self.config.max_query_terms)
         self.commit_if_dirty()
         unbounded = self.config.unbounded_results
         if self.batcher is not None:
@@ -2807,6 +2815,16 @@ class _NodeHandler(_HttpHandlerBase):
                            "text/plain; charset=utf-8",
                            headers={"X-Deadline-Exceeded": "1"})
                 return
+            except TooManyQueryTerms as e:
+                # the caller's fault, not the engine's: a 400 that
+                # names every refused query of the batch with its
+                # count and the limit. A leader of this configuration
+                # refuses such a query at its own door, so only a
+                # direct caller (or a leader configured wider than
+                # this worker) sees it
+                span_event("query_terms_refused", queries=len(e.refused))
+                self._refuse_query_terms(e.refused, e.limit)
+                return
             except Exception as e:
                 # honest failure propagation (ADVICE r5): an
                 # engine failure must surface as a 5xx the
@@ -2879,6 +2897,11 @@ class _NodeHandler(_HttpHandlerBase):
                 with self._worker_span("worker.process"):
                     try:
                         hits = node.worker_search(query)
+                    except TooManyQueryTerms as e:
+                        # ... but a query it will not score whole is
+                        # refused by name, never answered in part
+                        self._refuse_query_terms(e.refused, e.limit)
+                        return
                     except Exception as e:
                         # reference returns [] on any failure
                         # (Worker.java:183)

@@ -74,6 +74,7 @@ from tfidf_tpu.cluster.resilience import (CircuitOpenError,
                                           ClusterResilience,
                                           DeadlineExpired, hedge_laggards)
 from tfidf_tpu.cluster.wire import unpack_hit_lists
+from tfidf_tpu.ops.analyzer import Analyzer, make_analyzer
 from tfidf_tpu.utils import storage as _storage
 from tfidf_tpu.utils.config import Config
 from tfidf_tpu.utils.faults import global_injector
@@ -150,6 +151,35 @@ class ScatterReadPlane:
     placement: PlacementMap
     resilience: ClusterResilience
     quarantine: PoisonQuarantine
+
+    # the workers' analysis chain, made from ``config`` on first use
+    # (a router holds no engine to borrow one from)
+    _query_analyzer: Analyzer | None = None
+
+    def query_terms_over_limit(self, query: str) -> int | None:
+        """The distinct terms of ``query`` where they pass
+        ``max_query_terms``, else None: the front door's half of "no
+        term is dropped in silence". A worker refuses such a query
+        (``engine.searcher.TooManyQueryTerms``) and would fail the
+        coalesced batch it rode in, so the request is refused HERE,
+        alone, before it is queued; its terms are counted as the
+        workers count them, by the same analyzer over the same
+        configuration, and the refusal is counted here
+        (``query_terms_refused``). A term is a character at least, so a
+        query no longer than the limit is never analyzed twice."""
+        limit = self.config.max_query_terms
+        if len(query) <= limit:
+            return None
+        analyzer = self._query_analyzer
+        if analyzer is None:
+            c = self.config
+            analyzer = self._query_analyzer = make_analyzer(
+                c.lowercase, c.stopwords, c.max_token_length)
+        n_terms = len(analyzer.counts(query))
+        if n_terms <= limit:
+            return None
+        global_metrics.inc("query_terms_refused")
+        return n_terms
 
     # ---- policy hooks ----
 
@@ -1009,6 +1039,15 @@ class _HttpHandlerBase(BaseHTTPRequestHandler):
     def _text(self, s: str, code: int = 200) -> None:
         self._send(code, s.encode(), "text/plain; charset=utf-8")
 
+    def _refuse_query_terms(self, refused, limit: int) -> None:
+        """The 400 of a query wider than ``max_query_terms``, at every
+        door: each refused ``(query, distinct terms)`` by name and
+        count, beside the limit."""
+        self._json({"error": "too many query terms",
+                    "max_query_terms": limit,
+                    "refused": [{"query": q[:200], "terms": n}
+                                for q, n in refused]}, code=400)
+
     def _body(self) -> bytes:
         n = int(self.headers.get("Content-Length", "0"))
         return self.rfile.read(n) if n else b""
@@ -1287,6 +1326,18 @@ class _HttpHandlerBase(BaseHTTPRequestHandler):
                 self._json({"error": "dense plane disabled "
                                      "(embedding_enabled=False)",
                             "mode": mode}, code=400)
+                return
+            # a query wider than the padded query matrices is refused
+            # by name, as Lucene refuses a disjunction past
+            # maxClauseCount (1,024 there): never cut to its heaviest
+            # terms, and never a coalesced batch's failure (the dense
+            # stage alone hashes every term: no width to pass)
+            n_terms = (None if mode == "dense"
+                       else node.query_terms_over_limit(query))
+            if n_terms is not None:
+                sp.set_attr("query_terms_refused", n_terms)
+                self._refuse_query_terms([(query, n_terms)],
+                                         node.config.max_query_terms)
                 return
             # poison-query quarantine (after plan validation — a
             # malformed request is 400, not a quarantine verdict): a

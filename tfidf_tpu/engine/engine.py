@@ -531,6 +531,10 @@ class Engine:
 
     def search_batch(self, queries: list[str], k: int | None = None,
                      unbounded: bool = False) -> list[list[SearchHit]]:
+        """The hits of every query, each scored over ALL its terms. A
+        query of more distinct terms than ``max_query_terms`` raises
+        :class:`~tfidf_tpu.engine.searcher.TooManyQueryTerms`, which
+        names it: nothing is cut to fit."""
         return self._run_compute(
             queries,
             lambda qs: self.searcher.search(qs, k=k, unbounded=unbounded),

@@ -9,7 +9,10 @@ bucket).
 
 Only documents containing at least one query term are returned (score > 0),
 matching Lucene's behavior of only scoring docs in the postings of query
-terms. Unknown query terms are dropped (they can match nothing).
+terms. Unknown query terms are dropped (they can match nothing); no other
+term is: a query of more distinct terms than ``max_query_terms`` is
+refused by name (:class:`TooManyQueryTerms`), as Lucene refuses a
+disjunction past ``maxClauseCount``.
 """
 
 from __future__ import annotations
@@ -80,6 +83,32 @@ def device_bytes_limit(array) -> int | None:
 _pipe_init_lock = threading.Lock()
 
 
+class TooManyQueryTerms(ValueError):
+    """A query holds more distinct terms than ``max_query_terms``, the
+    width of the padded query matrices: it is REFUSED, never cut to its
+    heaviest terms. Lucene's ``IndexSearcher.TooManyClauses`` is the
+    model (a disjunction past ``maxClauseCount``, 1,024 by default,
+    throws; it never drops a clause in silence). ``refused`` holds every
+    ``(query, distinct terms)`` of the chunk it was found in, ``queries``
+    the strings alone, ``limit`` the configured width: a caller takes
+    them out and asks again, and the front door answers 400 to the one
+    request (``cluster/router.py``)."""
+
+    def __init__(self, refused, limit: int) -> None:
+        self.refused = tuple(refused)
+        self.limit = limit
+        query, n_terms = self.refused[0]
+        more = (f" (and {len(self.refused) - 1} more of its batch)"
+                if len(self.refused) > 1 else "")
+        super().__init__(
+            f"query of {n_terms} distinct terms, over max_query_terms="
+            f"{limit}: {query[:80]!r}{more}")
+
+    @property
+    def queries(self) -> tuple[str, ...]:
+        return tuple(q for q, _n in self.refused)
+
+
 def vectorize_queries(queries: list[str], analyzer: Analyzer,
                       vocab: Vocabulary, model: ScoringModel,
                       *, batch_cap: int, max_terms: int,
@@ -89,16 +118,25 @@ def vectorize_queries(queries: list[str], analyzer: Analyzer,
     Returns ``(batch, max distinct terms in any one query)`` — the width
     statistic drives the Pallas query-group size.
 
-    Pad entries are inert by construction in the scoring kernel. Queries
-    with more than ``max_terms`` distinct terms keep the highest-weight
-    terms. ``min_slots`` floors the unique-term capacity: searchers pass
-    their high-water mark so successive batches reuse ONE compiled
-    program instead of recompiling whenever the unique count crosses a
-    power-of-two bucket (capacity padding is free in the u-tiled kernel).
+    Pad entries are inert by construction in the scoring kernel. A query
+    of more than ``max_terms`` distinct terms (as the analyzer counts
+    them, in the vocabulary or not: what the front door can count too)
+    raises :class:`TooManyQueryTerms` for its chunk; every term of every
+    other query is scored. ``min_slots`` floors the unique-term
+    capacity: searchers pass their high-water mark so successive batches
+    reuse ONE compiled program instead of recompiling whenever the
+    unique count crosses a power-of-two bucket (capacity padding is free
+    in the u-tiled kernel).
+
+    Two stages of the one timer, inside the caller's ``vectorize``:
+    ``vectorize_analyze`` (tokens, counts, vocabulary lookup, query
+    weights: a Python pass a token) and ``vectorize_pack`` (the one fill
+    of the matrices and ``make_query_batch``'s dedup); and two counters,
+    ``query_terms_seen`` (the entries filled: distinct terms a query,
+    summed over the chunk, before the batch's dedup) and
+    ``query_terms_refused`` (queries refused).
     """
     assert len(queries) <= batch_cap
-    q_terms = np.zeros((batch_cap, max_terms), np.int32)
-    q_weights = np.zeros((batch_cap, max_terms), np.float32)
     term_counts = analyzer.counts
     map_counts = vocab.map_counts
     query_weights = model.query_weights
@@ -107,32 +145,43 @@ def vectorize_queries(queries: list[str], analyzer: Analyzer,
     # numpy scalar store a term was a seventh of this function
     pairs: list[tuple[int, float]] = []
     sizes: list[int] = []
-    for q in queries:
-        items = query_weights(
-            map_counts(term_counts(q), add=False)).items()
-        if len(items) > 1:
-            # heaviest first, ties by term id, i.e. key (-weight, id):
-            # by id, then a stable sort by weight. This is q_terms'
-            # column order, so it is kept to the letter and the compiled
-            # programs see the same arrays
-            items = sorted(items)
-            items.sort(key=_pair_weight, reverse=True)
-            del items[max_terms:]
-        sizes.append(len(items))
-        pairs.extend(items)
-    if pairs:
-        # row-major, the filled entries of the matrices are the pairs in
-        # order: query by query, column by column
-        filled = np.arange(max_terms) < np.array(sizes)[:, None]
-        # one pass from Python objects to numpy for ids and weights
-        # both: a term id is exact in a float64, and a float64 rounds
-        # to float32 as the scalar store did
-        flat = np.fromiter(chain.from_iterable(pairs), np.float64,
-                           2 * len(pairs)).reshape(-1, 2)
-        q_terms[:len(sizes)][filled] = flat[:, 0]
-        q_weights[:len(sizes)][filled] = flat[:, 1]
-    return (make_query_batch(q_terms, q_weights, min_slots=min_slots),
-            max([1, *sizes]))
+    refused: list[tuple[str, int]] = []
+    with trace_phase("vectorize_analyze"):
+        for q in queries:
+            counts = term_counts(q)
+            if len(counts) > max_terms:
+                refused.append((q, len(counts)))
+                continue
+            items = query_weights(map_counts(counts, add=False)).items()
+            if len(items) > 1:
+                # heaviest first, ties by term id, i.e. key (-weight,
+                # id): by id, then a stable sort by weight. This is
+                # q_terms' column order, so it is kept to the letter and
+                # the compiled programs see the same arrays
+                items = sorted(items)
+                items.sort(key=_pair_weight, reverse=True)
+            sizes.append(len(items))
+            pairs.extend(items)
+    if refused:
+        global_metrics.inc("query_terms_refused", len(refused))
+        raise TooManyQueryTerms(refused, max_terms)
+    with trace_phase("vectorize_pack"):
+        q_terms = np.zeros((batch_cap, max_terms), np.int32)
+        q_weights = np.zeros((batch_cap, max_terms), np.float32)
+        if pairs:
+            # row-major, the filled entries of the matrices are the
+            # pairs in order: query by query, column by column
+            filled = np.arange(max_terms) < np.array(sizes)[:, None]
+            # one pass from Python objects to numpy for ids and weights
+            # both: a term id is exact in a float64, and a float64
+            # rounds to float32 as the scalar store did
+            flat = np.fromiter(chain.from_iterable(pairs), np.float64,
+                               2 * len(pairs)).reshape(-1, 2)
+            q_terms[:len(sizes)][filled] = flat[:, 0]
+            q_weights[:len(sizes)][filled] = flat[:, 1]
+        qb = make_query_batch(q_terms, q_weights, min_slots=min_slots)
+    global_metrics.inc("query_terms_seen", len(pairs))
+    return qb, max([1, *sizes])
 
 
 def assemble_hits(vals: np.ndarray, ids: np.ndarray, doc_names,
@@ -381,6 +430,8 @@ class SearchLoop:
             batch_cap=cap, max_terms=self.max_query_terms,
             min_slots=self._u_floor)
         self._u_floor = max(self._u_floor, qb.uniq.shape[0])
+        # the compiled T: the matrices are as wide as the limit
+        global_metrics.set_gauge("query_terms_width", self.max_query_terms)
         return qb, widest
 
     @staticmethod

@@ -151,7 +151,12 @@ class Config:
     dist_num_processes: int = 0        # 0 = auto-detect
     dist_process_id: int = -1          # -1 = auto-detect
     query_batch: int = 32              # padded query batch per scoring step
-    max_query_terms: int = 32          # padded terms per query
+    # The most distinct terms a query may hold, and the width of the
+    # padded [query_batch, max_query_terms] query matrices. A limit that
+    # REFUSES (engine/searcher.py TooManyQueryTerms; a 400 at the
+    # node's doors), never a cut to the heaviest terms: Lucene's
+    # IndexSearcher.maxClauseCount, 1,024 there.
+    max_query_terms: int = 32
     # In-flight query chunks inside one search_batch call. On small
     # corpora the device step is much shorter than the device->host
     # fetch RTT; depth 2 overlaps one fetch with the next chunk's
